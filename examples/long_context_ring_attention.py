@@ -15,11 +15,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-if not hasattr(jax, "shard_map"):  # jax < 0.6 compat
-    from jax.experimental.shard_map import shard_map as _sm
-
-    jax.shard_map = _sm
-
 from mmlspark_tpu.models import dense_attention, ring_attention
 from mmlspark_tpu.models.module import matmul_precision
 from mmlspark_tpu.parallel import MeshSpec, make_mesh

@@ -1,24 +1,20 @@
-"""J001 — version-gated jax APIs must route through the compat shim.
+"""J001 — jax mesh-typing APIs are referenced in one place.
 
-This container runs jax 0.4.37: ``jax.shard_map``, ``jax.sharding.AxisType``
-and ``jax.lax.pcast``/``pvary`` do not exist, and ``jax.experimental.
-shard_map`` moved in later versions. ``parallel/mesh.py`` is the one place
-allowed to touch these names (``shard_map_compat``, the getattr-gated
-AxisType handling); everywhere else a direct reference is a latent
-ImportError/AttributeError on exactly the hardware we target.
+The one installation is jax 0.9.0 (pyproject pins it): ``jax.shard_map``,
+``jax.sharding.AxisType`` and ``jax.lax.pcast`` all exist, so nothing here
+is a version gate any more. The pass is kept (ROADMAP D5 removes it with
+``shard_map_compat``) as a single-owner rule: ``parallel/mesh.py`` is the
+one module that names these APIs, and a direct reference elsewhere carries
+an inline justification.
 
 What counts as a direct reference (AST-level, so comments/docstrings and
-``getattr(obj, "name", default)``/``hasattr(obj, "name")`` probes — which
-are themselves gates — never trigger):
+``getattr(obj, "name", default)``/``hasattr(obj, "name")`` probes never
+trigger):
 
   - an attribute access ``X.shard_map`` / ``jax.lax.pcast`` / ...
   - ``from jax.experimental.shard_map import shard_map`` (or importing any
-    gated name from a jax module)
+    listed name from a jax module)
   - ``import jax.experimental.shard_map``
-
-A reference that is itself behind a ``hasattr`` check is still flagged —
-suppress it with a justification saying so (the suppression documents the
-gate for the next reader).
 """
 
 from __future__ import annotations
@@ -30,14 +26,14 @@ from .framework import AnalysisPass, Finding, SourceFile
 
 GATED_NAMES = ("shard_map", "AxisType", "pcast", "pvary")
 SHIM_MODULE = "mmlspark_tpu/parallel/mesh.py"
-_HINT = "route through parallel/mesh.py compat helpers (jax 0.4.37)"
+_HINT = "route through parallel/mesh.py (the one owner of these names)"
 
 
 class JaxCompatPass(AnalysisPass):
     pass_ids = ("J001",)
     name = "jax-compat"
-    description = ("direct references to version-gated jax APIs "
-                   f"({', '.join(GATED_NAMES)}) outside the compat shim")
+    description = ("direct references to jax mesh-typing APIs "
+                   f"({', '.join(GATED_NAMES)}) outside parallel/mesh.py")
 
     def applies_to(self, rel: str) -> bool:
         return rel.startswith("mmlspark_tpu/") and rel != SHIM_MODULE
@@ -54,7 +50,7 @@ class JaxCompatPass(AnalysisPass):
             seen.add((line, what))
             findings.append(Finding(
                 sf.rel, line, "J001",
-                f"direct reference to version-gated jax API {detail} — "
+                f"direct reference to jax mesh-typing API {detail} — "
                 f"{_HINT}"))
 
         for node in ast.walk(sf.tree):

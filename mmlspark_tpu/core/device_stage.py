@@ -151,6 +151,19 @@ class DeviceFn:
         self.device_finalize_outputs = tuple(self.device_finalize_outputs)
 
 
+#: thread ident -> CompileCache builds in flight on that thread. The serving
+#: watchdog reads it (``building_in``): an XLA compile of a new shape bucket
+#: takes tens of seconds on a TPU and must not be taken for a hung dispatch.
+_BUILDING: Dict[int, int] = {}
+_BUILDING_LOCK = threading.Lock()
+
+
+def building_in(thread_ident: int) -> bool:
+    """True while that thread is inside a ``CompileCache`` build."""
+    with _BUILDING_LOCK:
+        return _BUILDING.get(thread_ident, 0) > 0
+
+
 class CompileCache:
     """Shared fused-executable cache with hit/miss/compile-time counters
     and per-(segment, shape-bucket) XLA cost records.
@@ -304,8 +317,17 @@ class CompileCache:
                     return self._entries[key]
         # build OUTSIDE the lock: XLA compiles can take seconds and other
         # segments/threads must not serialize behind them
+        ident = threading.get_ident()
+        with _BUILDING_LOCK:
+            _BUILDING[ident] = _BUILDING.get(ident, 0) + 1
         t0 = time.perf_counter()
-        fn = builder()
+        try:
+            fn = builder()
+        finally:
+            with _BUILDING_LOCK:
+                _BUILDING[ident] -= 1
+                if not _BUILDING[ident]:
+                    del _BUILDING[ident]
         dt = time.perf_counter() - t0
         cost = None
         if label is not None:

@@ -32,6 +32,7 @@ fallbacks taken.
 
 from __future__ import annotations
 
+import threading
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -41,6 +42,7 @@ from . import profiling
 from .dataframe import DataFrame
 from .device_stage import CompileCache, DeviceFn, FusionUnsupported, compile_cache
 from .pipeline import PipelineModel, Transformer
+from .runtime import ensure_compile_cache
 from .schema import Schema
 
 
@@ -445,6 +447,10 @@ class SegmentExecutor:
         self.segment = segment
         self.cache = cache if cache is not None else compile_cache()
         self.fallbacks: List[str] = []
+        # device -> dispatches whose outputs landed there (placement is an
+        # observable: fusion_stats()["devices"])
+        self.out_devices: Dict[str, int] = {}
+        self._device = None  # set by _put_params on the unsharded path
         # cost-aware bucket SET for short batches (auto-tuner knob; None =
         # the power-of-two default — bitwise-identical cold start)
         self.buckets = tuple(sorted(buckets)) if buckets else None
@@ -528,12 +534,21 @@ class SegmentExecutor:
         return sub.partitions
 
     def _put_params(self, jax):
-        """Stage-params placement: replicated over the mesh when sharded,
-        the plain single-device put (today's code, verbatim) otherwise."""
+        """Stage-params placement: replicated over the mesh when sharded;
+        otherwise on the ONE device this run dispatches to — the innermost
+        ``jax.default_device`` (ReplicaSet pins one per replica), else the
+        first local device. Staged batches and the executable's cache key
+        follow the same device: the ring's put thread does not inherit the
+        caller's default-device context, and an AOT executable is bound to
+        the device it was lowered for."""
         params = tuple(d.params for d in self.segment.dfns)
-        if self.sharding is None:
-            return jax.device_put(params)
-        return self.sharding.put_params(params)
+        if self.sharding is not None:
+            return self.sharding.put_params(params)
+        dev = jax.config.jax_default_device
+        if dev is None or isinstance(dev, str):
+            dev = jax.local_devices(backend=dev)[0]
+        self._device = dev
+        return jax.device_put(params, dev)
 
     # -- fused path ------------------------------------------------------
     def run(self, df: DataFrame, stats) -> DataFrame:
@@ -867,7 +882,8 @@ class SegmentExecutor:
         import jax
 
         if self.sharding is None:
-            return jax.device_put(batch.arrays), batch.num_valid
+            return (jax.device_put(batch.arrays, self._device),
+                    batch.num_valid)
         # sharded staging: each column lands pre-split across the mesh's
         # candidate axis. A failure here (a chip dropping out mid-stage —
         # the mesh.chip_wedge chaos seam) degrades this PARTITION to the
@@ -915,7 +931,8 @@ class SegmentExecutor:
         # and prefix the shape key so the cost model's bucket parser skips
         # sharded records (their per-chip flops would skew the
         # single-device analytic table)
-        key_tail = (sh.cache_key(),) if sh is not None else ()
+        key_tail = (sh.cache_key(),) if sh is not None \
+            else (("device", self._device.id),)
         key_tail = key_tail + self._stitch_tail
         shape_pre = (sh.shape_prefix() if sh is not None else "") + \
             self._stitch_pre
@@ -942,7 +959,9 @@ class SegmentExecutor:
                                     csr_cols=csr_cols),
                 label=seg.label, shape=pre + self._shape_key_of(sig))
             with profiling.annotate(f"fused:{seg.label}"):
-                return compiled(params_dev, x), m
+                ys = compiled(params_dev, x)
+            self._note_devices(ys)
+            return ys, m
 
         return step
 
@@ -956,7 +975,8 @@ class SegmentExecutor:
         staged_cols = state.get("staged_cols") or state["ext"]
         csr_cols = frozenset(state.get("csr") or ())
         sh = self.sharding
-        key_tail = (sh.cache_key(),) if sh is not None else ()
+        key_tail = (sh.cache_key(),) if sh is not None \
+            else (("device", self._device.id),)
         key_tail = key_tail + self._stitch_tail
         shape_pre = (sh.shape_prefix() if sh is not None else "") + \
             self._stitch_pre
@@ -978,9 +998,16 @@ class SegmentExecutor:
                 shape=f"{pre}mega{k};{self._shape_key_of(sig)}")
             cols_seq = tuple({c: x[c] for c in staged_cols} for x in xs)
             with profiling.annotate(f"fused:{seg.label}:mega{k}"):
-                return compiled(params_dev, cols_seq)
+                outs = compiled(params_dev, cols_seq)
+            for ys in outs:
+                self._note_devices(ys)
+            return outs
 
         return mega
+
+    def _note_devices(self, ys) -> None:
+        for d in (ys[0].devices() if ys else ()):
+            self.out_devices[str(d)] = self.out_devices.get(str(d), 0) + 1
 
     @staticmethod
     def _fetch(handle):
@@ -1249,24 +1276,10 @@ class SegmentExecutor:
                                          np.asarray(v).dtype
                                          if not hasattr(v, "dtype") else v.dtype)
                  for c, v in x.items()}
+        # no catch: a Mosaic refusal or a VMEM/HBM OOM must surface, not
+        # be retried as a lazy jit
         with _kernels.activate(variant):
-            try:
-                return jitted.lower(params_dev, specs).compile()
-            except FusionUnsupported:
-                raise
-            except Exception:
-                # AOT path unavailable on this jax: the jitted callable
-                # still compiles (and caches) per shape on first dispatch
-                jax.eval_shape(jitted, params_dev, specs)  # gates fire NOW
-                if variant is None:
-                    return jitted
-
-                def call(p, c, _jitted=jitted, _vid=variant):
-                    # first real dispatch re-traces: keep the variant live
-                    with _kernels.activate(_vid):
-                        return _jitted(p, c)
-
-                return call
+            return jitted.lower(params_dev, specs).compile()
 
     def _build_mega(self, params_dev, x: Dict[str, Any], keys: List[str],
                     k: int, variant: Optional[str] = None,
@@ -1308,20 +1321,7 @@ class SegmentExecutor:
             for c, v in x.items()}
         specs = tuple(dict(spec) for _ in range(k))
         with _kernels.activate(variant):
-            try:
-                return jitted.lower(params_dev, specs).compile()
-            except FusionUnsupported:
-                raise
-            except Exception:
-                jax.eval_shape(jitted, params_dev, specs)
-                if variant is None:
-                    return jitted
-
-                def call(p, c, _jitted=jitted, _vid=variant):
-                    with _kernels.activate(_vid):
-                        return _jitted(p, c)
-
-                return call
+            return jitted.lower(params_dev, specs).compile()
 
 
 # ---------------------------------------------------------------------------
@@ -1346,6 +1346,11 @@ class FusedPipelineModel(PipelineModel):
         self._plans: Dict[Tuple, List[Any]] = {}
         self._seg_stats: Dict[str, Any] = {}
         self._last_fallbacks: List[str] = []
+        # cumulative since construction (replicas share one model across
+        # threads; the per-call fields above are last-writer-wins)
+        self._totals_lock = threading.Lock()
+        self._fallback_total = 0
+        self._out_devices: Dict[str, int] = {}
         self._last_plan: Optional[List[Any]] = None
         # auto-tuning state (core/tune.py Tuner drives these): a cost model
         # feeding plan()'s fuse-vs-host comparison + host-stage timings,
@@ -1510,18 +1515,15 @@ class FusedPipelineModel(PipelineModel):
 
     def _sharding_for(self, node: Segment):
         """Resolve the segment's tuned spec name into a SegmentSharding
-        (None = unsharded: no mesh, no override, 1-shard axis, or any
-        resolution failure — wrong sharding must never fail a transform)."""
+        (None = unsharded: no mesh, no override, an unsupported candidate
+        or a 1-shard axis). A resolution error propagates."""
         name = self._sharding_overrides.get(node.label)
         if self._shard_mesh is None or not name:
             self._seg_sharding.pop(node.label, None)
             return None
-        try:
-            from ..parallel.shardplan import sharding_for
+        from ..parallel.shardplan import sharding_for
 
-            sh = sharding_for(node, self._shard_mesh, name)
-        except Exception:  # noqa: BLE001 — degrade to single-device
-            sh = None
+        sh = sharding_for(node, self._shard_mesh, name)
         if sh is None:
             self._seg_sharding.pop(node.label, None)
         else:
@@ -1539,6 +1541,15 @@ class FusedPipelineModel(PipelineModel):
             kernel_variants=self._variant_overrides.get(node.label),
             stitch=self._stitch_overrides or None,
             layout=self._layout_overrides.get(node.label))
+
+    def _absorb(self, ex: SegmentExecutor) -> None:
+        """Fold one finished executor's fallbacks and output placement
+        into the last-run and cumulative stats."""
+        self._last_fallbacks.extend(ex.fallbacks)
+        with self._totals_lock:
+            self._fallback_total += len(ex.fallbacks)
+            for d, n in ex.out_devices.items():
+                self._out_devices[d] = self._out_devices.get(d, 0) + n
 
     def _host_node(self, node: HostStage, df: DataFrame) -> DataFrame:
         """Run one host plan node, feeding its wall time to the cost model
@@ -1559,6 +1570,7 @@ class FusedPipelineModel(PipelineModel):
             return PipelineModel.transform(self, df)
         from ..parallel.ingest import IngestStats
 
+        ensure_compile_cache()
         nodes = self._plan_for(df.schema)
         self._last_plan = nodes
         self._seg_stats = {}
@@ -1584,15 +1596,15 @@ class FusedPipelineModel(PipelineModel):
                 self._seg_stats[node.label] = stats
                 ex = self._make_executor(node)
                 df = ex.run(df, stats)
-                self._last_fallbacks.extend(ex.fallbacks)
+                self._absorb(ex)
             else:
                 df = self._host_node(node, df)
         return df
 
     def _pipe_plan_for(self, nodes: List[Any]):
         """Resolve the pipe_depth knob into a PipePlan (None = serial:
-        knob off/<= 1, no mesh, no chainable run, or any resolution
-        failure — wrong pipelining must never fail a transform). An
+        knob off/<= 1, no mesh or no chainable run; a planning error
+        propagates). An
         active CSR layout override keeps the plan serial: wire triples
         are staged per-partition on host, which the device-resident
         handoff never materializes (the same explicit exclusion as
@@ -1601,13 +1613,10 @@ class FusedPipelineModel(PipelineModel):
         if not depth or depth <= 1 or self._shard_mesh is None \
                 or self._layout_overrides:
             return None
-        try:
-            from ..parallel.pipeplan import build_pipe_plan
+        from ..parallel.pipeplan import build_pipe_plan
 
-            pplan = build_pipe_plan(nodes, self._shard_mesh, depth,
-                                    model=self._cost_model)
-        except Exception:  # noqa: BLE001 — degrade to serial
-            return None
+        pplan = build_pipe_plan(nodes, self._shard_mesh, depth,
+                                model=self._cost_model)
         if pplan is not None and self._pipe_supervision is not None:
             try:
                 self._pipe_supervision.register(pplan)
@@ -1654,7 +1663,7 @@ class FusedPipelineModel(PipelineModel):
                 self._seg_stats[node.label] = stats
                 ex = self._make_executor(node)
                 frame = ex.run(frame, stats)
-                self._last_fallbacks.extend(ex.fallbacks)
+                self._absorb(ex)
                 return frame
             return self._host_node(node, frame)
 
@@ -1676,7 +1685,7 @@ class FusedPipelineModel(PipelineModel):
                             cost_model=self._cost_model)
         df = runner.run(df)
         for ex in execs:
-            self._last_fallbacks.extend(ex.fallbacks)
+            self._absorb(ex)
         self._pipe_stats = runner.stats_dict(
             requeues=self._pipe_requeues, replans=self._pipe_replans)
         for node in nodes[pplan.last:]:
@@ -1715,6 +1724,7 @@ class FusedPipelineModel(PipelineModel):
         dedicated readback thread while the next batch dispatches."""
         from ..parallel.ingest import IngestStats
 
+        ensure_compile_cache()
         nodes = self._plan_for(df.schema)
         self._last_plan = nodes
         self._seg_stats = {}
@@ -1730,7 +1740,7 @@ class FusedPipelineModel(PipelineModel):
                 self._seg_stats[node.label] = stats
                 ex = self._make_executor(node)
                 df = ex.run(df, stats)
-                self._last_fallbacks.extend(ex.fallbacks)
+                self._absorb(ex)
             else:
                 df = self._host_node(node, df)
         if tail is None:
@@ -1743,7 +1753,7 @@ class FusedPipelineModel(PipelineModel):
 
         def done() -> DataFrame:
             out = resolve()
-            self._last_fallbacks.extend(ex.fallbacks)
+            self._absorb(ex)
             return out
 
         return done
@@ -1787,6 +1797,8 @@ class FusedPipelineModel(PipelineModel):
             "n_fused_segments": sum(isinstance(n, Segment) for n in nodes),
             "per_segment": per_segment,
             "fallbacks": list(self._last_fallbacks),
+            "fallbacks_total": self._fallback_total,
+            "devices": dict(self._out_devices),
             "compile_cache": self._cache.stats(),
             "segment_costs": costs,
             "roofline": roofline,

@@ -3,45 +3,52 @@
 The reference ships AOT-compiled native engines (LightGBM/VW/CNTK pay their
 compile cost at build time); the XLA equivalent is the persistent compilation
 cache — first-ever run of a program shape pays the compile, every later
-process reuses it. Enabled lazily from the training/serving entry points so
-importing the package never touches jax config.
+process reuses it. Enabled lazily from the transform/serving/training entry
+points so importing the package never touches jax config.
 """
 
 from __future__ import annotations
 
-import logging
 import os
+from typing import Optional
 
-log = logging.getLogger("mmlspark_tpu.runtime")
+#: ``<checkout>/.jax_cache`` — a FIXED path (the directory is part of the
+#: cache key's environment, so a temp name, pid or time would never hit).
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
-_cache_enabled = False
+_cache_dir: Optional[str] = None
+_cache_resolved = False
 
 
-def ensure_compile_cache() -> None:
-    """Enable JAX's persistent compilation cache (idempotent).
+def compile_cache_dir() -> str:
+    """Where the persistent compile cache lives: ``JAX_COMPILATION_CACHE_DIR``
+    when the environment sets it (JAX reads that itself), else
+    ``<checkout>/.jax_cache`` — the same from any working directory."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or DEFAULT_CACHE_DIR
 
-    Opt out with MMLSPARK_TPU_COMPILE_CACHE=0; override the directory with
-    MMLSPARK_TPU_COMPILE_CACHE_DIR (default ~/.cache/mmlspark_tpu/xla).
+
+def ensure_compile_cache() -> Optional[str]:
+    """Enable JAX's persistent compilation cache (idempotent) and return the
+    directory in use, or None when the cache is off.
+
+    With ``JAX_COMPILATION_CACHE_DIR`` set, JAX has already configured
+    itself from the environment and nothing is set here. Otherwise the
+    fixed ``<checkout>/.jax_cache`` is used — on accelerators only: CPU
+    executables are cheap to rebuild and their cache entries are tied to
+    the host's instruction set.
     """
-    global _cache_enabled
-    if _cache_enabled:
-        return
-    _cache_enabled = True
-    if os.environ.get("MMLSPARK_TPU_COMPILE_CACHE", "1") in ("0", "false"):
-        return
-    path = os.environ.get("MMLSPARK_TPU_COMPILE_CACHE_DIR") or os.path.join(
-        os.environ.get("XDG_CACHE_HOME",
-                       os.path.join(os.path.expanduser("~"), ".cache")),
-        "mmlspark_tpu", "xla")
-    try:
+    global _cache_dir, _cache_resolved
+    if _cache_resolved:
+        return _cache_dir
+    _cache_resolved = True
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         import jax
 
         if jax.default_backend() == "cpu":
-            # CPU AOT cache entries warn (and can SIGILL) across machine
-            # feature sets, and CPU compiles are cheap — accelerators only
-            return
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception as e:  # jax too old / read-only fs: non-fatal
-        log.debug("compilation cache unavailable: %s", e)
+            return None
+        os.makedirs(DEFAULT_CACHE_DIR, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
+    _cache_dir = compile_cache_dir()
+    return _cache_dir

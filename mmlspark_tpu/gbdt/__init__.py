@@ -6,8 +6,7 @@ SURVEY §2.1/§3.2). This package re-implements the algorithm TPU-first:
   - quantile feature binning (binning.py; LGBM_DatasetCreateFromMat equivalent)
   - binned histogram accumulation + split finding as jitted XLA kernels
     (histogram.py) with a Pallas MXU one-hot-contraction kernel for the hot
-    scatter on TPU (pallas_hist.py, ~13x over the XLA scatter lowering;
-    BENCH_hist.json)
+    scatter on TPU (pallas_hist.py)
   - leaf-wise tree growth with the parent-minus-sibling histogram subtraction
     trick (tree.py; LightGBM's core data structure)
   - boosting loop with gbdt/rf/dart/goss variants, binary/multiclass/regression/

@@ -59,9 +59,19 @@ import numpy as np
 # Row-chunk size for the one-hot contractions (bounds the [*, CHUNK] VMEM
 # tiles); env-tunable for kernel A/B runs like pallas_hist.CHUNK.
 CHUNK = int(os.environ.get("MMLSPARK_TPU_SPARSE_CHUNK", "512"))
-#: VMEM guard for the gather accumulator [N, U_pad] f32 (~8 MB).
+#: Work bound for the MXU gather (its cost is nnz x N x U_pad one-hot
+#: products). Not a VMEM guard: the kernel tiles N and U, so its VMEM
+#: footprint is bounded by the tile constants below whatever the shape.
 _GATHER_MAX_CELLS = 1 << 21
-#: VMEM guard for the sparse-hist accumulator [3, TB_pad] f32 (~1.5 MB).
+#: Gather output tile. Measured on v5e (jax 0.9.0, 16 MiB scoped VMEM): an
+#: UNTILED [N_pad, U_pad] accumulator compiles at 8192 x 128 and is refused
+#: at 16384 x 128 (24.2 MiB) and 4096 x 512 (34.1 MiB) — both inside the
+#: cell guard — so the kernel tiles to [4096, 128] blocks.
+_GATHER_TILE_N = 4096
+_GATHER_TILE_U = 128
+#: Largest flat bin space the sparse-hist kernel takes ([3, TB_pad] f32
+#: accumulator, ~1.5 MB). Measured on v5e (jax 0.9.0): compiles and agrees
+#: with the reference at this limit.
 _SPARSE_HIST_MAX_TB = 128 * 1024
 
 
@@ -149,12 +159,15 @@ def csr_gather_xla(indptr, indices, values, width, used):
 
 
 def _gather_kernel(row_ref, idx_ref, val_ref, uq_ref, out_ref):
-    """One nnz-chunk grid cell of the Pallas gather.
+    """One (row tile, feature tile, nnz chunk) grid cell of the Pallas
+    gather; the nnz axis is innermost, so each output tile accumulates over
+    every chunk before the grid moves on.
 
     row_ref/idx_ref: [1, CHUNK] i32 (entry row / feature id; padded rows
     are out of range -> all-zero row one-hot), val_ref: [1, CHUNK] f32,
-    uq_ref: [U_pad, 1] i32 (clamped used-feature column, full block),
-    out_ref: [N_pad, U_pad] f32 accumulator, VMEM-resident across the grid.
+    uq_ref: [TU, 1] i32 (this tile's clamped used-feature ids),
+    out_ref: [TN, TU] f32 accumulator tile, VMEM-resident across the
+    chunk axis.
 
     out[n, u] += sum_k (row[k] == n) * (uq[u] == idx[k]) * val[k] — both
     one-hots built transposed against dim-0 iotas (the pallas_hist idiom;
@@ -165,21 +178,20 @@ def _gather_kernel(row_ref, idx_ref, val_ref, uq_ref, out_ref):
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    j = pl.program_id(0)
-
-    @pl.when(j == 0)
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    n_pad, u_pad = out_ref.shape
+    tn, tu = out_ref.shape
     chunk = row_ref.shape[1]
-    iota_n = jax.lax.broadcasted_iota(jnp.int32, (n_pad, chunk), 0)
-    row_onehot = (jnp.broadcast_to(row_ref[...], (n_pad, chunk))
-                  == iota_n).astype(jnp.float32)              # [N_pad, CHUNK]
-    feat_onehot = (jnp.broadcast_to(uq_ref[...], (u_pad, chunk))
-                   == jnp.broadcast_to(idx_ref[...], (u_pad, chunk)))
+    iota_n = jax.lax.broadcasted_iota(jnp.int32, (tn, chunk), 0) \
+        + pl.program_id(0) * tn
+    row_onehot = (jnp.broadcast_to(row_ref[...], (tn, chunk))
+                  == iota_n).astype(jnp.float32)              # [TN, CHUNK]
+    feat_onehot = (jnp.broadcast_to(uq_ref[...], (tu, chunk))
+                   == jnp.broadcast_to(idx_ref[...], (tu, chunk)))
     contrib = feat_onehot.astype(jnp.float32) \
-        * jnp.broadcast_to(val_ref[...], (u_pad, chunk))      # [U_pad, CHUNK]
+        * jnp.broadcast_to(val_ref[...], (tu, chunk))         # [TU, CHUNK]
     out_ref[...] += jax.lax.dot_general(
         row_onehot, contrib,
         dimension_numbers=(((1,), (1,)), ((), ())),
@@ -202,8 +214,10 @@ def csr_gather_pallas(indptr, indices, values, width, used,
     w = jnp.asarray(width, dtype=jnp.int32)
     used_q = jnp.minimum(jnp.asarray(used, dtype=jnp.int32), w - 1)
 
-    n_pad = _round_up(max(n, 8), 8)
-    u_pad = _round_up(max(u, 128), 128)
+    tn = min(_round_up(max(n, 8), 8), _GATHER_TILE_N)
+    tu = _GATHER_TILE_U
+    n_pad = _round_up(max(n, 8), tn)
+    u_pad = _round_up(max(u, tu), tu)
     nnz_pad = _round_up(max(nnz, 1), CHUNK)
     row_of = _csr_row_of(indptr, nnz)
     # kernel pad entries: out-of-range row (-1) zeroes the row one-hot
@@ -218,18 +232,18 @@ def csr_gather_pallas(indptr, indices, values, width, used,
 
     out = pl.pallas_call(
         _gather_kernel,
-        grid=(nnz_pad // CHUNK,),
+        grid=(n_pad // tn, u_pad // tu, nnz_pad // CHUNK),
         in_specs=[
-            pl.BlockSpec((1, CHUNK), lambda j: (0, j),
+            pl.BlockSpec((1, CHUNK), lambda i, k, j: (0, j),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, CHUNK), lambda j: (0, j),
+            pl.BlockSpec((1, CHUNK), lambda i, k, j: (0, j),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, CHUNK), lambda j: (0, j),
+            pl.BlockSpec((1, CHUNK), lambda i, k, j: (0, j),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((u_pad, 1), lambda j: (0, 0),
+            pl.BlockSpec((tu, 1), lambda i, k, j: (k, 0),
                          memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((n_pad, u_pad), lambda j: (0, 0),
+        out_specs=pl.BlockSpec((tn, tu), lambda i, k, j: (i, k),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((n_pad, u_pad), jnp.float32),
         interpret=interpret,

@@ -256,6 +256,9 @@ class DNNModel(Model, HasInputCol, HasOutputCol, HasBatchSize):
     def transform(self, df: DataFrame) -> DataFrame:
         import jax
 
+        from ..core.runtime import ensure_compile_cache
+
+        ensure_compile_cache()
         model = self.get_model()
         in_map, out_map = self._io_maps(model)      # input name -> col, col -> tap
         in_cols = list(in_map.values())

@@ -235,8 +235,10 @@ def run_train_loop(state: TrainState, step_fn: Callable, batches: Iterable,
     import time as _time
 
     from .checkpoint import load_train_state, save_train_state
+    from ..core.runtime import ensure_compile_cache
     from ..obs.metrics import TrainRecorder
 
+    ensure_compile_cache()
     recorder = TrainRecorder("dnn", registry=registry)
 
     def _save_timed(st):
@@ -345,8 +347,10 @@ def compile_train_step(module: Module, optimizer, mesh=None):
     tests/test_models.py fails by ~1e-1 without this)."""
     import jax
 
+    from ..core.runtime import ensure_compile_cache
     from .module import activation_sharding
 
+    ensure_compile_cache()
     step = make_train_step(module, optimizer)
     if mesh is None:
         return jax.jit(step, donate_argnums=(0,))

@@ -49,13 +49,35 @@ def _host_tag() -> str:
     return hashlib.md5(ident.encode()).hexdigest()[:8]
 
 
+def _source_path() -> Optional[str]:
+    # dev checkout first; the wheel ships the same source as package data
+    # (native_src/ is a symlink to native/src/ in the repo; wheel builds
+    # materialize it as a real file)
+    candidates = [os.path.join(_NATIVE_DIR, "src", "mmlspark_native.cpp"),
+                  os.path.join(_PKG_DIR, "native_src", "mmlspark_native.cpp")]
+    return next((c for c in candidates if os.path.exists(c)), None)
+
+
+def _source_tag() -> str:
+    """Content hash of the C++ source: what is loaded is always built from
+    the source that is present — an ignored build dir copied along with a
+    checkout (or left by an older commit) can never be picked up stale."""
+    import hashlib
+
+    src = _source_path()
+    if src is None:
+        return "nosrc"
+    with open(src, "rb") as f:
+        return hashlib.sha256(f.read()).hexdigest()[:12]
+
+
 def _so_path() -> str:
     """Repo build dir when the repo layout is present (dev checkout); else a
-    user cache dir (pip-installed: site-packages may be read-only). The ABI
-    version AND a host-CPU tag are part of the filename so co-installed
+    user cache dir (pip-installed: site-packages may be read-only). The
+    source hash AND a host-CPU tag are part of the filename so co-installed
     package versions (or hosts with different CPU features — the build is
     -march=native) sharing a cache dir never load each other's build."""
-    name = f"libmmlspark_native.v{_ABI_VERSION}.{_host_tag()}.so"
+    name = f"libmmlspark_native.{_source_tag()}.{_host_tag()}.so"
     if os.path.isdir(_NATIVE_DIR):
         return os.path.join(_NATIVE_DIR, "build", name)
     cache = os.environ.get("XDG_CACHE_HOME",
@@ -71,12 +93,7 @@ _build_attempted = False
 
 
 def _build() -> bool:
-    # dev checkout first; the wheel ships the same source as package data
-    # (native_src/ is a symlink to native/src/ in the repo; wheel builds
-    # materialize it as a real file)
-    candidates = [os.path.join(_NATIVE_DIR, "src", "mmlspark_native.cpp"),
-                  os.path.join(_PKG_DIR, "native_src", "mmlspark_native.cpp")]
-    src = next((c for c in candidates if os.path.exists(c)), None)
+    src = _source_path()
     if src is None:
         return False
     os.makedirs(os.path.dirname(_SO_PATH), exist_ok=True)

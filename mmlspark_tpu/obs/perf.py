@@ -1,7 +1,7 @@
 """Performance attribution: XLA cost analytics, roofline, memory, SLOs.
 
-BENCH_mfu_roofline.json bounds the image chain at ~16,000 images/s while
-BENCH_image_e2e.json measures 64.7 end-to-end — a ~250x gap the obs layer
+Earlier claim, not measured in this round: the image chain runs end to end
+at a small fraction of its analytic roofline bound — a gap the obs layer
 (PR 5) could time but never ATTRIBUTE: it said how long things took, not how
 far from the hardware bound they ran. This module is the measurement
 substrate the cost-model-driven auto-tuner (ROADMAP; "A Learned Performance
@@ -9,11 +9,10 @@ Model for TPUs", arXiv:2008.01040) will train on — the per-kernel
 flops/bytes/latency tuples, collected where they are cheapest to observe:
 
   - ``extract_cost(compiled)`` harvests ``cost_analysis()`` +
-    ``memory_analysis()`` from an AOT-compiled executable, getattr-gated per
-    the jax 0.4.37 compat convention in ``core/`` (either may be absent,
-    raise, or return None/list/dict depending on backend and version — every
-    shape degrades to None, never to an error). CompileCache calls it once
-    per miss, so steady-state serving pays nothing.
+    ``memory_analysis()`` from an AOT-compiled executable (a backend may
+    report None for either; a non-executable callable — a test double, a
+    deserialized stub — reports nothing). CompileCache calls it once per
+    miss, so steady-state serving pays nothing.
   - ``attribute_segments()`` joins those per-(segment, shape-bucket) costs
     with the IngestStats queue/h2d/compute/readback decomposition into a
     per-segment roofline report: the cost-model bound time per batch, the
@@ -21,11 +20,10 @@ flops/bytes/latency tuples, collected where they are cheapest to observe:
     bound), and a dominant-bottleneck label (``queue``/``h2d``/``compute``/
     ``dispatch``/``host``) — the e2e-vs-roofline gap as a first-class
     per-segment number.
-  - ``device_peaks()`` supplies the roofline ceilings: the public TPU chip
-    specs (tools/mfu_roofline.py table), overridable via
-    ``MMLSPARK_PEAK_FLOPS``/``MMLSPARK_PEAK_GBPS``; unknown devices (CPU
-    containers) get a clearly-labeled nominal ceiling so the ratio stays
-    comparable run-to-run (``peak_source`` says which you got).
+  - ``device_peaks()`` supplies the roofline ceilings from the one
+    ``PEAKS`` table of published chip specs; a device the table does not
+    list (CPU containers included) gets ``peak_source: "unknown"`` and no
+    bound or ratio at all.
   - ``fold_device_memory()`` registers a scrape-time collector over
     ``device.memory_stats()`` (gated: absent or None on CPU backends) as
     ``mmlspark_device_memory_bytes{device, stat}``.
@@ -38,7 +36,6 @@ flops/bytes/latency tuples, collected where they are cheapest to observe:
 from __future__ import annotations
 
 import dataclasses
-import os
 import sys
 import threading
 import time
@@ -52,7 +49,7 @@ __all__ = ["SLOConfig", "SLOTracker", "attribute_segments", "device_peaks",
 
 
 # ---------------------------------------------------------------------------
-# XLA cost harvesting (getattr-gated: jax 0.4.37 compat convention)
+# XLA cost harvesting
 # ---------------------------------------------------------------------------
 
 
@@ -69,48 +66,36 @@ def extract_cost(compiled: Any) -> Optional[Dict[str, float]]:
 
     Returns ``{flops, bytes_accessed, peak_memory_bytes, output_bytes,
     argument_bytes}`` (whatever subset the backend reports), or None when
-    nothing is available. Every access is gated: ``cost_analysis`` /
-    ``memory_analysis`` may be absent (the eval_shape fallback path in
-    core/fusion.py returns a plain jitted callable), may raise, or may
-    return None / a dict / a list of per-computation dicts — all of which
-    must degrade to "no data", never to an exception (the caller sits on
-    the CompileCache miss path of a live server).
+    nothing is available: ``jax.stages.Compiled.cost_analysis()`` /
+    ``memory_analysis()`` return None on a backend without the analysis,
+    and the CompileCache also holds callables that are not executables at
+    all (they have neither method).
     """
     out: Dict[str, float] = {}
     ca = getattr(compiled, "cost_analysis", None)
-    if callable(ca):
-        try:
-            rep = ca()
-        except Exception:  # noqa: BLE001 — backend without the hook
-            rep = None
-        if isinstance(rep, (list, tuple)):
-            rep = rep[0] if rep else None
-        if isinstance(rep, dict):
-            flops = _num_or_none(rep.get("flops"))
-            if flops is not None:
-                out["flops"] = flops
-            nbytes = _num_or_none(rep.get("bytes accessed"))
-            if nbytes is not None:
-                out["bytes_accessed"] = nbytes
+    rep = ca() if callable(ca) else None
+    if isinstance(rep, dict):
+        flops = _num_or_none(rep.get("flops"))
+        if flops is not None:
+            out["flops"] = flops
+        nbytes = _num_or_none(rep.get("bytes accessed"))
+        if nbytes is not None:
+            out["bytes_accessed"] = nbytes
     ma = getattr(compiled, "memory_analysis", None)
-    if callable(ma):
-        try:
-            mem = ma()
-        except Exception:  # noqa: BLE001
-            mem = None
-        if mem is not None:
-            parts = {}
-            for attr in ("temp_size_in_bytes", "argument_size_in_bytes",
-                         "output_size_in_bytes"):
-                v = _num_or_none(getattr(mem, attr, None))
-                if v is not None:
-                    parts[attr] = v
-            if parts:
-                out["peak_memory_bytes"] = sum(parts.values())
-                if "output_size_in_bytes" in parts:
-                    out["output_bytes"] = parts["output_size_in_bytes"]
-                if "argument_size_in_bytes" in parts:
-                    out["argument_bytes"] = parts["argument_size_in_bytes"]
+    mem = ma() if callable(ma) else None
+    if mem is not None:
+        parts = {}
+        for attr in ("temp_size_in_bytes", "argument_size_in_bytes",
+                     "output_size_in_bytes"):
+            v = _num_or_none(getattr(mem, attr, None))
+            if v is not None:
+                parts[attr] = v
+        if parts:
+            out["peak_memory_bytes"] = sum(parts.values())
+            if "output_size_in_bytes" in parts:
+                out["output_bytes"] = parts["output_size_in_bytes"]
+            if "argument_size_in_bytes" in parts:
+                out["argument_bytes"] = parts["argument_size_in_bytes"]
     return out or None
 
 
@@ -118,53 +103,54 @@ def extract_cost(compiled: Any) -> Optional[Dict[str, float]]:
 # Roofline ceilings
 # ---------------------------------------------------------------------------
 
-#: public chip specs (tools/mfu_roofline.py) keyed by device_kind prefix
+#: Published per-chip peaks keyed by ``device_kind`` prefix: dense bf16
+#: FLOP/s and HBM bytes/s from the Google Cloud TPU documentation (system
+#: architecture pages "TPU v4", "TPU v5e", "TPU v5p", "TPU v6e"). The ONE
+#: table in the tree — bench.py and tools/ import it. A device that is not
+#: listed has no roofline: ``device_peaks`` reports ``peak_source:
+#: "unknown"`` and None ceilings, never a stand-in number.
 PEAKS = {
-    "TPU v5 lite": {"flops": 197e12, "bytes_per_s": 819e9},
     "TPU v4": {"flops": 275e12, "bytes_per_s": 1228e9},
-    "TPU v6 lite": {"flops": 918e12, "bytes_per_s": 1640e9},
+    "TPU v5 lite": {"flops": 197e12, "bytes_per_s": 819e9},   # v5e
+    "TPU v5": {"flops": 459e12, "bytes_per_s": 2765e9},       # v5p
+    "TPU v6 lite": {"flops": 918e12, "bytes_per_s": 1640e9},  # v6e
 }
 
-#: clearly-labeled stand-in for devices without a table entry (CPU
-#: containers): ~one modern server core. The roofline RATIO on such hosts is
-#: indicative, not absolute — the bottleneck label never depends on it.
-NOMINAL_PEAKS = {"flops": 1e11, "bytes_per_s": 2e10}
+
+def peaks_for_kind(kind: Optional[str]) -> Optional[Dict[str, float]]:
+    """The table row for one ``device_kind`` (longest matching prefix), or
+    None for a device the table does not list."""
+    for prefix in sorted(PEAKS, key=len, reverse=True):
+        if kind is not None and str(kind).startswith(prefix):
+            return dict(PEAKS[prefix])
+    return None
 
 
 def device_peaks(data_shards: int = 1) -> Dict[str, Any]:
-    """Roofline ceilings for the current device: env override >
-    chip-spec table > nominal stand-in. ``peak_source`` records which.
+    """Roofline ceilings for the current device from the ``PEAKS`` table.
+    An unlisted device (CPU containers included) yields ``{"flops": None,
+    "bytes_per_s": None, "peak_source": "unknown"}`` — callers then report
+    no bound and no ratio.
 
     ``data_shards`` > 1 aggregates over a mesh: a segment sharded N ways
     has N chips' worth of flops and bandwidth as its bound (the
     ``peak_source`` gains an ``xN`` suffix so a mesh-scaled bound is never
     mistaken for a single-chip one)."""
-    env_f = _num_or_none(os.environ.get("MMLSPARK_PEAK_FLOPS"))
-    env_b = _num_or_none(os.environ.get("MMLSPARK_PEAK_GBPS"))
-    if env_f and env_b:
-        out = {"flops": env_f, "bytes_per_s": env_b * 1e9,
-               "peak_source": "env"}
-        return _scale_peaks(out, data_shards)
     kind = None
     jax = sys.modules.get("jax")  # never import (and init a backend) here
     if jax is not None:
-        try:
-            dev = jax.devices()[0]
-            kind = getattr(dev, "device_kind", None) or dev.platform
-        except Exception:  # noqa: BLE001 — backend init failure
-            kind = None
-    if kind is not None:
-        for prefix, peak in PEAKS.items():
-            if str(kind).startswith(prefix):
-                return _scale_peaks({**peak, "peak_source": "table",
-                                     "device_kind": kind}, data_shards)
-    return _scale_peaks({**NOMINAL_PEAKS, "peak_source": "nominal",
+        kind = jax.devices()[0].device_kind
+    row = peaks_for_kind(kind)
+    if row is None:
+        return {"flops": None, "bytes_per_s": None,
+                "peak_source": "unknown", "device_kind": kind}
+    return _scale_peaks({**row, "peak_source": "table",
                          "device_kind": kind}, data_shards)
 
 
 def _scale_peaks(peaks: Dict[str, Any], data_shards: int) -> Dict[str, Any]:
     n = max(1, int(data_shards or 1))
-    if n == 1:
+    if n == 1 or peaks.get("flops") is None:
         return peaks
     return {**peaks, "flops": peaks["flops"] * n,
             "bytes_per_s": peaks["bytes_per_s"] * n,
@@ -284,9 +270,9 @@ def attribute_segments(per_segment: Dict[str, Dict[str, Any]],
             if nnz_bytes is not None:
                 rec["nnz_bytes_per_batch"] = round(nnz_bytes, 1)
         # roofline: bound time = max(compute-bound, bandwidth-bound) per
-        # batch; ratio = bound / measured (1.0 = running at the bound, the
-        # ~250x image-chain gap shows up as ~0.004 here)
-        if (flops or nbytes or nnz_bytes) and wall and wall > 0:
+        # batch; ratio = bound / measured (1.0 = running at the bound)
+        if (flops or nbytes or nnz_bytes) and wall and wall > 0 \
+                and seg_peaks.get("flops") is not None:
             t_flops = (flops or 0.0) / seg_peaks["flops"]
             band_bytes = nnz_bytes if nnz_bytes is not None else nbytes
             t_mem = (band_bytes or 0.0) / seg_peaks["bytes_per_s"]

@@ -1,9 +1,9 @@
 """Unified device-ingest layer: uint8 wire format + transfer ring + stats.
 
-The framework's data plane. BENCH_r05 showed the flagship featurize path
-computing at ~11.5k images/sec/chip per-call but only ~260 images/sec
-end-to-end: the DataFrame -> device ingest path, not XLA compute, was the
-bottleneck (h2d_gbps = 0.036). Two structural fixes live here:
+The framework's data plane. Earlier claim, not measured in this round: the
+flagship featurize path computed ~44x faster per call than end to end —
+the DataFrame -> device ingest path, not XLA compute, was the bottleneck.
+Two structural fixes live here:
 
   - **uint8 on the wire** (``PreprocessSpec``): the host stops doing
     ``astype(float32) * scale`` (+ layout transpose) per image; batches ship
@@ -1047,10 +1047,7 @@ def _block_ready(tree: Any) -> Any:
     jax = sys.modules.get("jax")
     if jax is None:
         return tree
-    try:
-        return jax.block_until_ready(tree)
-    except Exception:
-        return tree
+    return jax.block_until_ready(tree)
 
 
 def _default_fetch(handle: Any) -> Any:

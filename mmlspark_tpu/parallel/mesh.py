@@ -76,7 +76,7 @@ def initialize_distributed(coordinator_address: Optional[str] = None,
         return False
     import jax
 
-    if getattr(jax.distributed, "is_initialized", lambda: False)():
+    if jax.distributed.is_initialized():
         # the user bootstrapped jax.distributed themselves (standard JAX
         # multi-host practice) — respect it, don't double-initialize
         _dist_initialized = True
@@ -161,12 +161,9 @@ def make_mesh(spec: Optional[MeshSpec] = None, device_list: Optional[Sequence] =
     axis_names = tuple(sizes.keys())
     shape = tuple(sizes[a] for a in axis_names)
     # Auto axis types: GSPMD propagation (annotate shardings, XLA inserts
-    # collectives) — jax>=0.9 defaults make_mesh to Explicit, which we don't want
-    # for the framework's implicit-sharding style. Older jax (< 0.5) has no
-    # AxisType and is always Auto — gate on the attribute, not the version.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    kwargs = {} if axis_type is None else \
-        {"axis_types": (axis_type.Auto,) * len(axis_names)}
+    # collectives) — jax 0.9 defaults make_mesh to Explicit, which we don't
+    # want for the framework's implicit-sharding style.
+    kwargs = {"axis_types": (jax.sharding.AxisType.Auto,) * len(axis_names)}
     if device_list is not None:
         arr = np.asarray(devs).reshape(shape)
         return jax.sharding.Mesh(arr, axis_names, **kwargs)
@@ -174,23 +171,10 @@ def make_mesh(spec: Optional[MeshSpec] = None, device_list: Optional[Sequence] =
 
 
 def shard_map_compat(f, **kwargs):
-    """``jax.shard_map`` resolved across jax versions: older jax ships it
-    under ``jax.experimental.shard_map`` and calls the replication-checking
-    kwarg ``check_rep`` instead of ``check_vma``. Drop-in for
-    ``functools.partial(shard_map, ...)`` decorator usage."""
-    import inspect
-
+    """Alias of ``jax.shard_map`` (jax 0.9.0 is the one installation)."""
     import jax
 
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn
-    params = inspect.signature(fn).parameters
-    if "check_vma" in kwargs and "check_vma" not in params:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    elif "check_rep" in kwargs and "check_rep" not in params:
-        kwargs["check_vma"] = kwargs.pop("check_rep")
-    return fn(f, **kwargs)
+    return jax.shard_map(f, **kwargs)
 
 
 def data_sharding(mesh, *batch_axes: str):

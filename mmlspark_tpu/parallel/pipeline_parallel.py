@@ -66,13 +66,9 @@ def pipeline_apply(stage_fn: Callable[[Any, Any], Any], stacked_params,
     stage = jax.lax.axis_index(axis_name)
     local = jax.tree.map(lambda a: a[0], stacked_params)  # [1,...] -> [...]
 
-    # jax < 0.5 has neither pcast nor pvary (and no vma typing to satisfy)
-    if hasattr(jax.lax, "pcast"):
-        # analysis: allow J001 -- hasattr-guarded on the line above: this IS the gate
-        microbatches = jax.lax.pcast(microbatches, (axis_name,), to="varying")
-    elif hasattr(jax.lax, "pvary"):
-        # analysis: allow J001 -- hasattr-guarded on the line above: this IS the gate
-        microbatches = jax.lax.pvary(microbatches, (axis_name,))
+    # shard_map's vma typing: the scan carry becomes stage-varying
+    # analysis: allow J001 -- pinned jax 0.9.0 always has pcast
+    microbatches = jax.lax.pcast(microbatches, (axis_name,), to="varying")
     # derived arrays inherit the varying type from microbatches
     state = jnp.zeros_like(microbatches[0])
     outputs = jnp.zeros_like(microbatches)

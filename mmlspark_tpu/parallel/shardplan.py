@@ -17,8 +17,8 @@ model, never hand-annotated.
     so the Tuner can enumerate them host-side.
   - ``sharding_for(segment, mesh, name)`` resolves a candidate into a
     ``SegmentSharding``: the ``NamedSharding``s for inputs/params/outputs
-    (built over ``make_mesh()`` meshes via the parallel/mesh.py helpers —
-    the jax 0.4.37 compat gates J001 enforces), the pjit kwargs with
+    (built over ``make_mesh()`` meshes via the parallel/mesh.py helpers,
+    the single owner J001 enforces; jax 0.9.0), the pjit kwargs with
     ``donate_argnums`` on the ring-staged inputs, and the sharded
     ``device_put`` the executor stages batches through.
   - ``measure_collectives(mesh)`` times real all-reduce / all-gather
@@ -259,8 +259,8 @@ class SegmentSharding:
     pjit kwargs, and sharded staging for one (segment, candidate, mesh).
 
     Every jax.sharding object is built lazily through the parallel/mesh.py
-    helpers (``data_sharding`` / ``replicated_sharding`` — the jax 0.4.37
-    compat surface J001 allows). ``device_put`` is the chip-wedge chaos
+    helpers (``data_sharding`` / ``replicated_sharding`` — the surface
+    J001 allows; jax 0.9.0). ``device_put`` is the chip-wedge chaos
     seam: ``mesh.chip_wedge`` (core/faults.py) fires per staged batch on
     the SHARDED path only, so injected wedges never perturb the unsharded
     bitwise-parity contract."""

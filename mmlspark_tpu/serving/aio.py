@@ -2,8 +2,8 @@
 
 The thread-per-connection ``ThreadingHTTPServer`` ingress spends a thread
 (and its stack) per open socket and a fresh TCP handshake per non-keep-alive
-client — under the 16-client load test that connection churn already rivals
-compute (BENCH_serving.json queue p95 vs compute p95). This module is the
+client — under a 16-client load that connection churn rivals compute
+(earlier claim, not measured in this round). This module is the
 high-concurrency replacement both ``ServingServer`` and ``RoutingFront``
 mount behind their ``http_mode="async"`` knob:
 
@@ -347,7 +347,7 @@ class AsyncConnectionPool:
     are RETURNED, not raised (the front treats any worker answer as
     authoritative); transport failures raise ``OSError`` /
     ``asyncio.TimeoutError`` so the caller's retry/circuit logic sees the
-    same taxonomy the urlopen path produced. A request that finds its pooled
+    same classification the urlopen path produced. A request that finds its pooled
     socket closed by the peer before any response byte retries ONCE on a
     fresh connection (never after partial reads — no double-processing)."""
 

@@ -33,9 +33,8 @@ bitwise-identical between the two modes.
 ``jax.local_devices()`` — on a multi-chip host each replica computes on its
 own device; on a single-device host replicas still pipeline host-side work.
 ``AdaptiveBatchController`` replaces the static coalescing window with a
-self-tuning one that holds queue wait ~= alpha * compute time (the
-``max_wait_sweep`` in BENCH_serving.json shows the static optimum shifts
-with load).
+self-tuning one that holds queue wait ~= alpha * compute time (the static
+optimum shifts with load: earlier claim, not measured in this round).
 """
 
 from __future__ import annotations
@@ -49,6 +48,7 @@ from collections import deque
 from typing import Any, Callable, Dict, List, Optional
 
 from ..core import faults
+from ..core.device_stage import building_in
 from ..obs import trace as obs_trace
 
 __all__ = ["AdaptiveBatchController", "PipelinedExecutor", "Replica",
@@ -65,8 +65,7 @@ _LOG = logging.getLogger("mmlspark_tpu.serving")
 class AdaptiveBatchController:
     """Self-tuning coalescing window: hold queue_ms ~= alpha * compute_ms.
 
-    The static ``max_wait_ms`` has a load-dependent optimum (the
-    ``max_wait_sweep_resnet18`` in BENCH_serving.json: 0 ms serializes
+    The static ``max_wait_ms`` has a load-dependent optimum (0 ms serializes
     requests behind full computes under load, while any wait at all is pure
     added latency for a single-stream client). Under the executor's
     slot-aware drain, BACKPRESSURE already merges convoys while every
@@ -201,12 +200,12 @@ class ReplicaSet:
     devices (the data-parallel dispatch of Automap, arXiv:2112.02958,
     applied to whole serving batches).
 
-    ``devices`` defaults to ``jax.local_devices()`` (a single ``None``
-    pseudo-device when jax is unavailable, keeping the executor usable for
-    host-only transforms). ``transform_factory(index, device)`` builds a
-    per-replica transform — per-replica CompileCaches, per-replica model
-    copies; the default shares ``transform`` across replicas (jit dispatch
-    is thread-safe and executables are cached per device).
+    ``devices`` defaults to ``jax.local_devices()``.
+    ``transform_factory(index, device)`` builds a per-replica transform —
+    per-replica CompileCaches, per-replica model copies; the default
+    shares ``transform`` across replicas (jit dispatch is thread-safe and
+    fused executables are keyed per device). A replica whose init raises
+    fails the start: a server asked for R replicas never serves on fewer.
     """
 
     def __init__(self, transform: Optional[Callable] = None, n: int = 1,
@@ -215,61 +214,26 @@ class ReplicaSet:
         if transform is None and transform_factory is None:
             raise ValueError("need transform or transform_factory")
         if devices is None:
-            devices = self._local_devices()
-        if not devices:
-            devices = [None]
+            import jax
+
+            devices = list(jax.local_devices())
         self.replicas: List[Replica] = []
-        #: placements skipped because replica init raised: (index, device,
-        #: error string) — surfaced in describe()/stats so a degraded start
-        #: is visible, not silent
-        self.placement_failures: List[Dict[str, Any]] = []
         for i in range(max(1, int(n))):
             dev = devices[i % len(devices)]
-            # a device that raises at replica init (driver fault, OOM on one
-            # chip) must not fail the whole server start: log, skip it, and
-            # serve on the survivors; raise only when nothing survives
-            try:
-                t = transform_factory(i, dev) \
-                    if transform_factory is not None else transform
-            except Exception as e:  # noqa: BLE001 — degrade, don't die
-                _LOG.warning(
-                    "replica %d init failed on device %s — placing the "
-                    "remaining replicas without it", i, dev, exc_info=True)
-                self.placement_failures.append(
-                    {"replica": i, "device": str(dev) if dev is not None
-                     else None, "error": str(e)})
-                continue
+            t = transform_factory(i, dev) \
+                if transform_factory is not None else transform
             self.replicas.append(Replica(i, dev, t))
-        if not self.replicas:
-            raise RuntimeError(
-                "every replica placement failed: "
-                + "; ".join(f"replica {f['replica']} on {f['device']}: "
-                            f"{f['error']}"
-                            for f in self.placement_failures))
 
     def __len__(self) -> int:
         return len(self.replicas)
 
     @staticmethod
-    def _local_devices() -> List[Any]:
-        try:
-            import jax
-
-            return list(jax.local_devices())
-        except Exception:  # noqa: BLE001 — host-only deployment
-            return []
-
-    @staticmethod
     def _device_ctx(device: Any):
-        if device is None:
+        if device is None:  # explicit host-only placement (tests)
             return contextlib.nullcontext()
-        import sys
+        import jax
 
-        jax = sys.modules.get("jax")
-        dd = getattr(jax, "default_device", None) if jax is not None else None
-        if dd is None:
-            return contextlib.nullcontext()
-        return dd(device)
+        return jax.default_device(device)
 
     def run(self, replica: Replica, df):
         """Full transform on the replica's device (dispatch + readback)."""
@@ -589,7 +553,8 @@ class PipelinedExecutor:
                 budget = self.watchdog.budget_s(prep.n, batches=batches)
             with self._lock:
                 gen = prep.wd_gen
-                self._dispatch[replica.index] = [prep, gen, t0, budget]
+                self._dispatch[replica.index] = [prep, gen, t0, budget,
+                                                 threading.get_ident()]
             pending = out = err = None
             try:
                 # chaos seams: a delay plan on WORKER_DISPATCH_HANG wedges
@@ -656,7 +621,13 @@ class PipelinedExecutor:
         requeue, extend, abandon = [], [], []
         with self._lock:
             for idx, entry in list(self._dispatch.items()):
-                prep, gen, t0, budget = entry
+                prep, gen, t0, budget, ident = entry
+                if budget is not None and building_in(ident):
+                    # a compile is not a wedge: a new shape bucket's XLA
+                    # compile outlasts any compute-derived budget, so the
+                    # budget clock restarts when the build ends
+                    entry[2] = now
+                    continue
                 if budget is None or now - t0 <= budget:
                     continue
                 if prep.wd_gen != gen:
@@ -772,7 +743,6 @@ class PipelinedExecutor:
             # states + watchdog trip counters; None when supervision is off
             "supervisor": supervisor,
             "watchdog": watchdog,
-            "placement_failures": self.replicas.placement_failures or None,
             # batches currently past drain and not yet fulfilled: the live
             # slot occupancy (== inflight means the pipeline is saturated
             # — the perf-attribution companion to the ring gauges)

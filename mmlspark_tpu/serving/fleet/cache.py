@@ -59,7 +59,7 @@ _LOG = logging.getLogger(__name__)
 
 #: on-disk format version — bump on any layout change; mismatched entries
 #: are skipped (never parsed further)
-FORMAT = 1
+FORMAT = 2
 MAGIC = b"MMLC1\n"
 _HEADER_LEN_BYTES = 8
 #: entry file suffix (mmlspark compiled)
@@ -142,25 +142,31 @@ def content_key(key: Any, fp: Optional[Dict[str, Any]] = None) -> str:
 
 
 def _serialize_executable(fn: Any) -> Optional[bytes]:
-    """Pickle the AOT executable's portable triple, or None when this jax
-    (or this executable — e.g. the lazy ``jitted`` fallback the builder
-    returns when ``lower().compile()`` is unavailable) can't serialize."""
-    try:
-        from jax.experimental import serialize_executable as se
-    except Exception:  # noqa: BLE001 — older/stripped jax: cost-only tier
-        return None
+    """Pickle the AOT executable's portable triple plus the ids of the
+    devices it was compiled for, or None when ``fn`` is not a serializable
+    executable (the cache also holds plain callables)."""
+    from jax.experimental import serialize_executable as se
+
     try:
         triple = se.serialize(fn)
-        return pickle.dumps(triple)
+        device_ids = [d.id for d in fn.runtime_executable().local_devices()]
+        return pickle.dumps(triple + (device_ids,))
     except Exception:  # noqa: BLE001 — unserializable executable
         return None
 
 
 def _deserialize_executable(payload: bytes) -> Any:
+    """Load the executable onto the devices it was compiled for:
+    ``deserialize_and_load`` defaults to EVERY device of the backend, so a
+    one-device executable would otherwise expect one shard per device."""
+    import jax
     from jax.experimental import serialize_executable as se
 
-    serialized, in_tree, out_tree = pickle.loads(payload)
-    return se.deserialize_and_load(serialized, in_tree, out_tree)
+    serialized, in_tree, out_tree, device_ids = pickle.loads(payload)
+    by_id = {d.id: d for d in jax.devices()}
+    return se.deserialize_and_load(
+        serialized, in_tree, out_tree,
+        execution_devices=[by_id[i] for i in device_ids])
 
 
 class PersistentCompileCache:

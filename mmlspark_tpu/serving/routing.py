@@ -870,7 +870,7 @@ class RoutingFront:
                      if k.lower() not in drop}
 
         async def attempt(addr):
-            """One pooled forward: same breaker/span/deadline taxonomy as
+            """One pooled forward: same breaker/span/deadline classification as
             the threaded _forward_once, over the keep-alive pool."""
             if dl is not None and dl.expired():
                 return ("deadline", None)
@@ -896,7 +896,7 @@ class RoutingFront:
             try:
                 faults.fire(faults.WORKER_FORWARD, addr=addr, path=path)
                 if self._fabric is not None:
-                    # cell-crash chaos seam — same taxonomy as the
+                    # cell-crash chaos seam — same classification as the
                     # threaded transport: replay-safe "error", re-hash
                     faults.fire(faults.FRONT_L2_CRASH, cell=addr,
                                 path=path)
@@ -904,7 +904,7 @@ class RoutingFront:
                     req.method, url, body=body, headers=hdrs,
                     timeout=timeout, deadline=dl)
             except (asyncio.TimeoutError, OSError) as e:
-                # transport failure: same taxonomy as the urlopen path —
+                # transport failure: same classification as the urlopen path —
                 # note the breaker, replay only when safe
                 self._note_failure(addr)
                 fwd_span(error=str(e))

@@ -1694,8 +1694,10 @@ def serve_pipeline(stage, input_col: str, reply_col: str = "reply",
     in as every model's canary config.
     """
     from ..core.pipeline import PipelineModel
+    from ..core.runtime import ensure_compile_cache
     from .stages import parse_request
 
+    ensure_compile_cache()
     if fused and isinstance(stage, PipelineModel):
         stage = stage.fuse()
 

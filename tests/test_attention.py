@@ -105,7 +105,7 @@ class TestDenseAttentionOffsets:
 class TestFlashDispatch:
     """Gate logic for the Pallas flash-attention route (the kernel itself
     only runs on TPU; equivalence there is proven by the TPU-gated test
-    below plus BENCH_seq.json)."""
+    below and by chip_smoke.py's kernels phase)."""
 
     def test_gates_keep_cpu_and_f32_on_xla_path(self):
         from mmlspark_tpu.models.attention import _flash_dispatch

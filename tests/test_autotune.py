@@ -4,7 +4,7 @@ Covers:
   - the SegmentCostModel: analytical roofline prediction from harvested
     costs, measured EWMA refinement, interpolation, confidence/calibration
     gates, serialization round-trip;
-  - degradation paths: cost_analysis absent/raising (CPU backend) leaves
+  - degradation paths: cost_analysis absent (not an executable) leaves
     the model analytical-free but measured-capable; an UNCALIBRATED model
     produces bitwise-identical plans, bucket sequences, and fused outputs
     (the cold-start contract);
@@ -168,20 +168,10 @@ class _NoCost:
         return a
 
 
-class _RaisingCost:
-    def cost_analysis(self):
-        raise RuntimeError("backend says no")
-
-    def __call__(self, *a):
-        return a
-
-
 class TestDegradation:
-    def test_cost_absent_or_raising_still_measures(self):
+    def test_cost_absent_still_measures(self):
         cache = CompileCache()
         cache.get(("k1",), lambda: _NoCost(), label="Seg", shape="x=8:f32")
-        cache.get(("k2",), lambda: _RaisingCost(), label="Seg",
-                  shape="x=16:f32")
         m = SegmentCostModel(peaks=PEAKS, min_obs=2)
         m.ingest_costs(cache.costs())  # only compile_s present — no crash
         assert m.predict("Seg", batch=8) is None  # compile_s alone is no

@@ -923,6 +923,34 @@ class TestDispatchWatchdog:
                 status, body, _ = _post(srv.address, {"data": [5]})
                 assert status == 200 and body["sum"] == 5.0
 
+    def test_compile_is_not_a_wedge(self):
+        """A dispatch that is compiling a new shape bucket (tens of seconds
+        on a TPU) outlasts any compute-derived budget; with no healthy peer
+        the watchdog used to double the budget three times and then answer
+        504. The budget clock is paused while the dispatching thread is
+        inside a CompileCache build."""
+        from mmlspark_tpu.core.device_stage import CompileCache
+
+        cache = CompileCache()
+
+        def slow_build():
+            time.sleep(0.6)         # 12x the fixed 0.05 s budget
+            return lambda *a: a
+
+        def transform(df):
+            cache.get(("bucket-8",), slow_build)
+            return _echo_transform(df)
+
+        from mmlspark_tpu.serving import ServingServer
+
+        with ServingServer(transform, port=0, max_wait_ms=1.0,
+                           async_exec=True, adaptive_batching=False,
+                           replicas=1, watchdog_budget_s=0.05) as srv:
+            status, body, _ = _post(srv.address, {"data": [1, 2]})
+            assert status == 200 and body["sum"] == 3.0
+            assert srv._executor.watchdog.trips == 0
+            assert srv.stats.shed_summary()["total"] == 0
+
     def test_hang_under_load_no_request_lost(self):
         """With a mid-load wedge on one replica, every request either
         completes on a healthy replica or sheds with an accounted reason —
@@ -1296,12 +1324,14 @@ class TestPoolDeadlineGate:
 
 
 # ---------------------------------------------------------------------------
-# ReplicaSet placement: a raising device skips, not fails
+# ReplicaSet placement: a raising device fails the start
 # ---------------------------------------------------------------------------
 
 
-class TestReplicaPlacementSkip:
-    def test_failing_device_is_skipped_with_survivors(self):
+class TestReplicaPlacementFails:
+    def test_failing_device_fails_the_start(self):
+        """A server asked for R replicas never serves on fewer: the init
+        error of one placement propagates out of the constructor."""
         from mmlspark_tpu.serving import ReplicaSet
 
         def factory(i, dev):
@@ -1309,50 +1339,9 @@ class TestReplicaPlacementSkip:
                 raise RuntimeError(f"device {dev} driver init failed")
             return lambda df: df
 
-        rs = ReplicaSet(transform_factory=factory, n=3,
-                        devices=["dev0", "bad-dev", "dev2"])
-        assert [r.index for r in rs.replicas] == [0, 2]
-        assert [r.device for r in rs.replicas] == ["dev0", "dev2"]
-        assert len(rs.placement_failures) == 1
-        f = rs.placement_failures[0]
-        assert f["replica"] == 1 and f["device"] == "bad-dev"
-        assert "driver init failed" in f["error"]
-
-    def test_zero_survivors_raises(self):
-        from mmlspark_tpu.serving import ReplicaSet
-
-        def factory(i, dev):
-            raise RuntimeError("no devices at all")
-
-        with pytest.raises(RuntimeError, match="every replica placement"):
-            ReplicaSet(transform_factory=factory, n=2,
-                       devices=["d0", "d1"])
-
-    def test_degraded_placement_surfaces_in_executor_stats(self):
-        """A degraded ReplicaSet rides into the executor's stats payload
-        (placement_failures) and the survivors still dispatch."""
-        from mmlspark_tpu.core.dataframe import DataFrame
-        from mmlspark_tpu.serving import ServingServer
-        from mmlspark_tpu.serving.executor import PipelinedExecutor, ReplicaSet
-
-        def factory(i, dev):
-            if i == 0:
-                raise RuntimeError("chip 0 wedged at init")
-            return _echo_transform
-
-        rs = ReplicaSet(transform_factory=factory, n=2, devices=[None, None])
-        assert rs.placement_failures and len(rs.replicas) == 1
-        srv = ServingServer(_echo_transform, port=0)  # not started: scaffold
-        ex = PipelinedExecutor(srv, rs)
-        stats = ex.stats()
-        assert stats["placement_failures"][0]["replica"] == 0
-        # the surviving replica still runs transforms
-        out = rs.run(rs.replicas[0], DataFrame.from_dict(
-            {"id": np.array([1], dtype=np.int64),
-             "value": np.array([b'{"data": [1, 2]}'], dtype=object),
-             "headers": np.array([{}], dtype=object),
-             "origin": np.array([""], dtype=object)}))
-        assert out.collect()["reply"][0]["sum"] == 3.0
+        with pytest.raises(RuntimeError, match="driver init failed"):
+            ReplicaSet(transform_factory=factory, n=3,
+                       devices=["dev0", "bad-dev", "dev2"])
 
 
 # ---------------------------------------------------------------------------

@@ -386,12 +386,26 @@ class TestServerObservability:
             assert ei.value.code == 404
             assert srv.tracer is None
 
+    @staticmethod
+    def _spans_with(srv, want, timeout=3.0):
+        """The ingress span is recorded by the HTTP thread AFTER it wrote
+        the reply, so a client that has its answer can still beat it."""
+        import time
+
+        deadline = time.time() + timeout
+        while True:
+            spans = srv.tracer.spans()
+            if want <= {s["name"] for s in spans} or time.time() > deadline:
+                return spans
+            time.sleep(0.01)
+
     def test_traced_request_linked_spans_sync(self):
         with ServingServer(echo_transform, port=0, max_wait_ms=0.0) as srv:
             http_post(srv.address)
-            spans = srv.tracer.spans()
+            want = {"ingress", "drain", "dispatch", "readback"}
+            spans = self._spans_with(srv, want)
             names = {s["name"] for s in spans}
-            assert {"ingress", "drain", "dispatch", "readback"} <= names
+            assert want <= names
             assert len({s["trace_id"] for s in spans}) == 1
             ingress = next(s for s in spans if s["name"] == "ingress")
             for other in spans:
@@ -402,9 +416,9 @@ class TestServerObservability:
         with ServingServer(echo_transform, port=0, max_wait_ms=0.0,
                            async_exec=True, inflight=2) as srv:
             http_post(srv.address)
-            spans = srv.tracer.spans()
-            names = {s["name"] for s in spans}
-            assert {"ingress", "drain", "dispatch", "readback"} <= names
+            want = {"ingress", "drain", "dispatch", "readback"}
+            spans = self._spans_with(srv, want)
+            assert want <= {s["name"] for s in spans}
             assert len({s["trace_id"] for s in spans}) == 1
 
     def test_trace_endpoint(self):

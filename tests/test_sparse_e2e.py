@@ -252,6 +252,17 @@ class TestSparseKernels:
             indptr, indices, values, X.shape[1], used, interpret=True))
         np.testing.assert_array_equal(got, ref)
 
+    def test_pallas_gather_tiles_rows_and_features(self, monkeypatch):
+        # more rows than one row tile and more used features than one
+        # 128-lane feature tile: every (row tile, feature tile) block
+        # accumulates its own chunks and lands in the right place
+        monkeypatch.setattr(pallas_sparse, "_GATHER_TILE_N", 16)
+        X, indptr, indices, values, used = self._gather_case(
+            seed=6, n=40, width=400, n_used=150)
+        got = np.asarray(pallas_sparse.csr_gather_pallas(
+            indptr, indices, values, X.shape[1], used, interpret=True))
+        np.testing.assert_array_equal(got, X[:, used])
+
     def test_gather_clamps_out_of_range_used_features(self):
         # a model trained on MORE features than the rows carry queries
         # columns past ``width``: clamped to the last real column (the

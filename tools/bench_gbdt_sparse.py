@@ -172,7 +172,8 @@ def main():
                  + ds.total_bins * 16 + n * 8)
     print(json.dumps({
         "anchor_100k_x_4096": anchor,
-        "backend": platform,
+        "platform": platform,
+        "device_kind": jax.devices()[0].device_kind,
         "rows": n, "features": width, "nnz": nnz,
         "avg_nnz_per_row": round(nnz / n, 1),
         "total_bins": ds.total_bins,

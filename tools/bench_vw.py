@@ -7,7 +7,7 @@ sequential by construction, like VW itself — so the metric is
 examples/sec/pass through the compiled scan, steady-state, plus the
 featurizer's rows/sec (murmur hashing, host-side C++/numpy).
 
-Prints one JSON line; BENCH_vw.json records the artifact.
+Prints one JSON line.
 """
 
 import json
@@ -16,7 +16,88 @@ import time
 import numpy as np
 
 
+def _shard_scaling_curve(n, nnz, dim_bits):
+    """Shard-scaling curve (the distributed story, psum-averaged passes
+    replacing VW's --span_server AllReduce spanning tree,
+    vw/VowpalWabbitBase.scala:314-342): per-shard scan + weight average on a
+    virtual CPU mesh, one subprocess per shard count. The children are
+    forced onto the CPU and run BEFORE the parent initialises a backend (a
+    chip belongs to one process at a time)."""
+    import os
+    import subprocess
+    import sys
+
+    curve = {}
+    # repo root from the imported package (robust under `python - < tool`
+    # invocations where __file__ is '<stdin>')
+    import mmlspark_tpu as _pkg
+
+    repo_root = os.path.dirname(os.path.dirname(os.path.abspath(
+        _pkg.__file__)))
+    for shards in (1, 2, 4, 8):
+        # one subprocess per shard count: make_mesh requires the spec to
+        # consume the whole device set, so the virtual CPU device count is
+        # set to the shard count each time
+        code = (
+            f"import sys; sys.path.insert(0, {repo_root!r})\n"
+            "import os\n"
+            f"os.environ['XLA_FLAGS']="
+            f"'--xla_force_host_platform_device_count={shards}'\n"
+            "import jax; jax.config.update('jax_platforms','cpu')\n"
+            "import json, time, numpy as np\n"
+            "from mmlspark_tpu.vw.learner import LearnerConfig, "
+            "SparseDataset, train_linear\n"
+            "from mmlspark_tpu.parallel.mesh import MeshSpec, make_mesh\n"
+            f"n, nnz, bits, shards = {min(n, 100_000)}, {nnz}, {dim_bits}, "
+            f"{shards}\n"
+            "rng = np.random.default_rng(0)\n"
+            "idx = rng.integers(0, 1 << bits, size=(n, nnz)).astype(np.int32)\n"
+            "val = (rng.normal(size=(n, nnz)) / np.sqrt(nnz)).astype(np.float32)\n"
+            "w_true = rng.normal(size=1 << bits).astype(np.float32)\n"
+            "y = ((w_true[idx] * val).sum(axis=1) > 0).astype(np.float64)\n"
+            "rows = [{'indices': idx[i], 'values': val[i]} for i in range(n)]\n"
+            "ds = SparseDataset.from_rows(rows, np.where(y > 0, 1.0, -1.0), "
+            "num_bits=bits)\n"
+            "mesh = make_mesh(MeshSpec(data=shards)) if shards > 1 else None\n"
+            "cfg = LearnerConfig(num_bits=bits, loss_function='logistic', "
+            "num_passes=3)\n"
+            "train_linear(cfg, ds, mesh=mesh)\n"
+            "t0 = time.perf_counter()\n"
+            "train_linear(cfg, ds, mesh=mesh)\n"
+            "print(json.dumps(round(3 * n / (time.perf_counter() - t0), 1)))\n")
+        proc = None
+        try:
+            env = dict(os.environ)
+            env["JAX_PLATFORMS"] = "cpu"
+            proc = subprocess.run([sys.executable, "-c", code],
+                                  cwd=repo_root, capture_output=True,
+                                  text=True, timeout=900, env=env)
+            curve[str(shards)] = json.loads(
+                proc.stdout.strip().splitlines()[-1])
+        except Exception as e:
+            stderr_tail = (proc.stderr or "")[-200:] if proc is not None \
+                else ""
+            curve[str(shards)] = {"error": f"{e!r} {stderr_tail}".strip()}
+    return {"shard_scaling_platform": "cpu (forced virtual devices)",
+            "shard_scaling_examples_per_sec_cpu_mesh": curve,
+            "shard_scaling_note":
+            "shards=1 runs the native C++ engine (the framework's "
+            "single-shard default); shards>1 run the per-shard scan + "
+            "psum weight averaging between passes (the --span_server "
+            "AllReduce replacement, vw/VowpalWabbitBase.scala:314-342) "
+            "on ONE host core emulating N devices — the multi-shard "
+            "points show the algorithmic shape; real chips add real "
+            "parallel compute"}
+
+
 def main():
+    import os
+
+    # CPU children first, sized without touching JAX (see the helper)
+    on_accel_env = os.environ.get("JAX_PLATFORMS", "") != "cpu"
+    scaling = _shard_scaling_curve(
+        100_000 if on_accel_env else 20_000, 32 if on_accel_env else 16, 18)
+
     import jax
 
     from mmlspark_tpu.vw.featurizer import VowpalWabbitFeaturizer
@@ -126,78 +207,9 @@ def main():
     except Exception as e:  # sklearn/scipy absent: artifact says so
         skl = {"sklearn_sgd_error": str(e)}
 
-    # ---- shard-scaling curve (the distributed story, psum-averaged
-    # passes replacing VW's --span_server AllReduce spanning tree,
-    # vw/VowpalWabbitBase.scala:314-342): per-shard scan + weight average
-    # on a virtual CPU mesh. Run in a subprocess so the host platform
-    # override never touches this process's accelerator backend.
-    import os
-    import subprocess
-    import sys
-
-    curve = {}
-    # repo root from the imported package (robust under `python - < tool`
-    # invocations where __file__ is '<stdin>')
-    import mmlspark_tpu as _pkg
-
-    repo_root = os.path.dirname(os.path.dirname(os.path.abspath(
-        _pkg.__file__)))
-    for shards in (1, 2, 4, 8):
-        # one subprocess per shard count: make_mesh requires the spec to
-        # consume the whole device set, so the virtual CPU device count is
-        # set to the shard count each time
-        code = (
-            f"import sys; sys.path.insert(0, {repo_root!r})\n"
-            "import os\n"
-            f"os.environ['XLA_FLAGS']="
-            f"'--xla_force_host_platform_device_count={shards}'\n"
-            "import jax; jax.config.update('jax_platforms','cpu')\n"
-            "import json, time, numpy as np\n"
-            "from mmlspark_tpu.vw.learner import LearnerConfig, "
-            "SparseDataset, train_linear\n"
-            "from mmlspark_tpu.parallel.mesh import MeshSpec, make_mesh\n"
-            f"n, nnz, bits, shards = {min(n, 100_000)}, {nnz}, {dim_bits}, "
-            f"{shards}\n"
-            "rng = np.random.default_rng(0)\n"
-            "idx = rng.integers(0, 1 << bits, size=(n, nnz)).astype(np.int32)\n"
-            "val = (rng.normal(size=(n, nnz)) / np.sqrt(nnz)).astype(np.float32)\n"
-            "w_true = rng.normal(size=1 << bits).astype(np.float32)\n"
-            "y = ((w_true[idx] * val).sum(axis=1) > 0).astype(np.float64)\n"
-            "rows = [{'indices': idx[i], 'values': val[i]} for i in range(n)]\n"
-            "ds = SparseDataset.from_rows(rows, np.where(y > 0, 1.0, -1.0), "
-            "num_bits=bits)\n"
-            "mesh = make_mesh(MeshSpec(data=shards)) if shards > 1 else None\n"
-            "cfg = LearnerConfig(num_bits=bits, loss_function='logistic', "
-            "num_passes=3)\n"
-            "train_linear(cfg, ds, mesh=mesh)\n"
-            "t0 = time.perf_counter()\n"
-            "train_linear(cfg, ds, mesh=mesh)\n"
-            "print(json.dumps(round(3 * n / (time.perf_counter() - t0), 1)))\n")
-        proc = None
-        try:
-            env = dict(os.environ)
-            env.pop("JAX_PLATFORMS", None)
-            proc = subprocess.run([sys.executable, "-c", code],
-                                  cwd=repo_root, capture_output=True,
-                                  text=True, timeout=900, env=env)
-            curve[str(shards)] = json.loads(
-                proc.stdout.strip().splitlines()[-1])
-        except Exception as e:
-            stderr_tail = (proc.stderr or "")[-200:] if proc is not None \
-                else ""
-            curve[str(shards)] = {"error": f"{e!r} {stderr_tail}".strip()}
-    scaling = {"shard_scaling_examples_per_sec_cpu_mesh": curve,
-               "shard_scaling_note":
-               "shards=1 runs the native C++ engine (the framework's "
-               "single-shard default); shards>1 run the per-shard scan + "
-               "psum weight averaging between passes (the --span_server "
-               "AllReduce replacement, vw/VowpalWabbitBase.scala:314-342) "
-               "on ONE host core emulating N devices — the multi-shard "
-               "points show the algorithmic shape; real chips add real "
-               "parallel compute"}
-
     print(json.dumps({
-        "backend": dev.platform,
+        "platform": dev.platform,
+        "device_kind": dev.device_kind,
         "examples": n, "nnz_per_example": nnz,
         "engine": engine,
         "learn_examples_per_sec": round(n / pass_s, 1),

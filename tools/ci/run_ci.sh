@@ -52,6 +52,7 @@ PACKAGES=(
   "tests/test_sparse_e2e.py"
   "tests/test_pipeline_mesh.py"
   "tests/test_multimodel.py"
+  "tests/test_chip_bringup.py"
   "tests/test_multiprocess.py"
   "tests/test_examples.py"
 )

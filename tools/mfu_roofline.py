@@ -13,71 +13,14 @@ plus a per-op-category share so the gap decomposes into convolution shapes
 that cannot fill the 128x128 MXU (early layers: C_in=3 stem, C=64 stage-1)
 vs genuinely bandwidth-bound elementwise/normalization traffic.
 
-Peak numbers (v5e): 197 TFLOP/s bf16, 819 GB/s HBM (public chip specs).
-Prints one JSON line for the bench note.
-
-``--refresh`` folds the whole-pipeline compiler-search measurements
-(BENCH_serving.json "compiler_search") into an existing artifact as a
-bound-vs-measured attribution section, WITHOUT touching the analytic
-roofline numbers: on a CPU container the v5e cost-analysis bound cannot
-be re-measured, so the honest refresh keeps it and records what the
-search changed (stitch ratio, chosen kernel variant) plus an env_note
-saying where each number came from.
+Peaks come from the one table in the tree (mmlspark_tpu/obs/perf.py PEAKS);
+a device it does not list gets the XLA counts only, no bound.
+Prints one JSON line.
 """
 
 import json
-import os
 
 import numpy as np
-
-PEAKS = {
-    "TPU v5 lite": {"flops": 197e12, "hbm_gbps": 819e9},
-    "TPU v4": {"flops": 275e12, "hbm_gbps": 1228e9},
-    "TPU v6 lite": {"flops": 918e12, "hbm_gbps": 1640e9},
-}
-
-
-def refresh(artifact_path: str, serving_path: str) -> dict:
-    """Fold BENCH_serving.json's compiler_search section into the roofline
-    artifact as bound-vs-measured attribution. The analytic bound (device,
-    flops, t_mem, roofline_mfu_bound, ...) is retained verbatim — it comes
-    from XLA cost analysis of the TPU lowering and a CPU host cannot
-    reproduce it — and the searched-knob measurements land next to it with
-    an env_note naming the host they were taken on."""
-    import jax
-
-    art = json.load(open(artifact_path))
-    serving = json.load(open(serving_path))
-    cs = serving.get("compiler_search") or {}
-    stitch = cs.get("stitch") or {}
-    hist = cs.get("hist_variant") or {}
-    platform = jax.devices()[0].platform
-    art["compiler_search_attribution"] = {
-        "stitch_e2e_ratio": stitch.get("ratio"),
-        "stitch_parity": {
-            "rawprediction_bitwise": stitch.get("rawprediction_bitwise"),
-            "probability_max_abs_err":
-            stitch.get("probability_max_abs_err"),
-            "finalize_tolerance": stitch.get("finalize_tolerance")},
-        "hist_variant_chosen": hist.get("chosen"),
-        "hist_variant_trial_ms": hist.get("trial_ms"),
-        "note": (
-            "the roofline bound above prices compute+HBM of the compiled "
-            "device program only; the host boundary the stitch removes "
-            "(f64 readback + re-batch + H2D at the terminal GBDT stage) "
-            "sits OUTSIDE that bound, so stitching narrows measured-vs-"
-            "bound without moving the bound itself. The hist chunk "
-            "variant retunes Pallas tiling inside the bound; its CPU "
-            "interpret-mode trial ordering does not transfer to the MXU "
-            "and is recorded as flow evidence, not a TPU claim.")}
-    art["env_note"] = (
-        f"refreshed on a 1-core '{platform}' container: device/peak/"
-        "roofline_* fields are the retained v5e analytic numbers from XLA "
-        "cost analysis (not re-measurable without the chip); "
-        "compiler_search_attribution is measured on this host via "
-        "tools/bench_serving.py --only compiler_search "
-        "(BENCH_serving.json).")
-    return art
 
 
 def main():
@@ -86,10 +29,11 @@ def main():
 
     from mmlspark_tpu.models.module import FunctionModel
     from mmlspark_tpu.models.resnet import resnet
+    from mmlspark_tpu.obs.perf import peaks_for_kind
 
     dev = jax.devices()[0]
-    kind = getattr(dev, "device_kind", dev.platform)
-    peak = next((v for k, v in PEAKS.items() if kind.startswith(k)), None)
+    kind = dev.device_kind
+    peak = peaks_for_kind(kind)
 
     batch, size = (2048, 224) if dev.platform != "cpu" else (16, 224)
     model = resnet(50, num_classes=1000, image_size=size)
@@ -103,23 +47,21 @@ def main():
     x = jax.device_put(np.zeros((batch, size, size, 3), dtype=np.uint8))
     compiled = jax.jit(fwd).lower(params, x).compile()
 
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
+    ca = compiled.cost_analysis() or {}
     flops = float(ca.get("flops", 0.0))
     bytes_accessed = float(ca.get("bytes accessed", 0.0))
 
-    out = {"device": kind, "batch": batch,
+    out = {"platform": dev.platform, "device_kind": kind, "batch": batch,
            "flops_per_call": flops, "bytes_accessed_per_call": bytes_accessed,
            "arithmetic_intensity_flops_per_byte":
            round(flops / bytes_accessed, 1) if bytes_accessed else None}
     if peak and flops:
         t_flops = flops / peak["flops"]
-        t_mem = bytes_accessed / peak["hbm_gbps"]
+        t_mem = bytes_accessed / peak["bytes_per_s"]
         bound = t_flops / max(t_flops, t_mem)
         out.update({
             "peak_flops": peak["flops"],
-            "hbm_bytes_per_sec": peak["hbm_gbps"],
+            "hbm_bytes_per_sec": peak["bytes_per_s"],
             "t_flops_ms": round(t_flops * 1e3, 2),
             "t_mem_ms": round(t_mem * 1e3, 2),
             "roofline_mfu_bound": round(bound, 3),
@@ -131,15 +73,4 @@ def main():
 
 
 if __name__ == "__main__":
-    import sys
-
-    if "--refresh" in sys.argv:
-        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        path = os.path.join(repo, "BENCH_mfu_roofline.json")
-        art = refresh(path, os.path.join(repo, "BENCH_serving.json"))
-        with open(path, "w") as fh:
-            json.dump(art, fh)
-            fh.write("\n")
-        print(json.dumps(art))
-    else:
-        main()
+    main()

@@ -378,8 +378,7 @@ def rows_from_trace(path: str) -> List[Dict[str, Any]]:
 
 
 def demo_rows() -> Tuple[List[Dict[str, Any]], Dict[str, Any]]:
-    """Build + fuse the flagship image chain (the pipeline
-    BENCH_image_e2e.json measures), run it on synthetic images with a
+    """Build + fuse the flagship image chain, run it on synthetic images with a
     cost-model tuner pass, and attribute it — the zero-setup path to a
     real table. Returns (segment rows, tuner stats)."""
     import jax
