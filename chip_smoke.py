@@ -29,8 +29,12 @@ A phase that raises is recorded with its traceback and later phases still
 run (chip calls are budgeted), but any failed phase makes the exit code
 non-zero. Exits non-zero WITHOUT a result line when JAX finds no TPU, or
 when run outside a checkout of the repo. The last line of stdout is one JSON
-object: ``{"ok": ..., "device": {"platform", "kind", "count"}, ...}``.
-Times are printed as set-up information only — never under a metric name.
+object with exactly these keys and no other, the device as JAX reports it:
+``{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}``.
+The line before it (``[chip_smoke] summary {...}``) and
+``chiprun_out/chip_smoke.json`` carry the per-phase status, the set-up
+seconds and the compile-cache directory and entry counts. Times are printed
+as set-up information only — never under a metric name.
 
     python chip_smoke.py                  # everything the device count allows
     python chip_smoke.py --phases kernels,gbdt
@@ -127,6 +131,14 @@ def run_phases(phases: List[Tuple[str, Callable[[], Any]]]
 def exit_code(results: Dict[str, Dict[str, Any]]) -> int:
     bad = [n for n, r in results.items() if r["status"] == "fail"]
     return EXIT_PHASE_FAILED if bad else 0
+
+
+def result_line(ok: bool, device: Dict[str, Any]) -> str:
+    """The last line of stdout: these keys and no other (the driver's check
+    refuses anything else); everything more goes on the summary line."""
+    return json.dumps({"ok": bool(ok), "device": {
+        "platform": str(device["platform"]), "kind": str(device["kind"]),
+        "count": int(device["count"])}})
 
 
 class Checks:
@@ -1151,8 +1163,10 @@ def main(argv: Optional[List[str]] = None) -> int:
     os.makedirs(os.path.join(here, "chiprun_out"), exist_ok=True)
     with open(os.path.join(here, "chiprun_out", "chip_smoke.json"), "w") as f:
         json.dump(summary, f, indent=1, default=str)
-    sys.stdout.flush()
-    print(json.dumps(summary, default=str), flush=True)
+    print("[chip_smoke] summary " + json.dumps(summary, default=str),
+          flush=True)
+    sys.stderr.flush()
+    print(result_line(summary["ok"], device), flush=True)
     return code
 
 

@@ -14,6 +14,7 @@ CPU-checkable halves of the same repairs:
 """
 
 import importlib.util
+import json
 import os
 import subprocess
 import sys
@@ -180,6 +181,32 @@ def test_chip_smoke_alone_is_not_a_checkout(tmp_path):
                        capture_output=True, text=True, timeout=120)
     assert r.returncode != 0
     assert r.stderr.startswith("chip_smoke:") and _no_result_line(r.stdout)
+
+
+def test_the_last_stdout_line_has_the_contract_keys_and_no_other():
+    """The driver refuses a last line with any key besides ok/device and
+    platform/kind/count; the per-phase summary rides on the line before."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, SMOKE, "--tiny", "--phases", "env"],
+                       env=env, cwd=REPO, capture_output=True, text=True,
+                       timeout=300)
+    assert r.returncode == 3                # a --tiny run never passes
+    lines = r.stdout.splitlines()
+    last = json.loads(lines[-1])
+    assert set(last) == {"ok", "device"} and last["ok"] is False
+    assert set(last["device"]) == {"platform", "kind", "count"}
+    assert isinstance(last["device"]["platform"], str)
+    assert isinstance(last["device"]["kind"], str)
+    assert type(last["device"]["count"]) is int
+    assert lines[-2].startswith("[chip_smoke] summary {")
+    summary = json.loads(lines[-2].split("summary ", 1)[1])
+    assert summary["phases"] == {"env": "pass"}
+    assert "compile_cache" in summary and summary["device"] == last["device"]
+    smoke = _load_smoke()
+    ok = json.loads(smoke.result_line(
+        True, {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}))
+    assert ok == {"ok": True, "device": {
+        "platform": "tpu", "kind": "TPU v5 lite", "count": 1}}
 
 
 def test_a_failed_phase_makes_the_exit_code_nonzero(capsys):
