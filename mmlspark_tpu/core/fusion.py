@@ -38,6 +38,8 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..obs.trace import (batch_span, close_span, current_batch, open_span,
+                         root_span)
 from . import profiling
 from .dataframe import DataFrame
 from .device_stage import CompileCache, DeviceFn, FusionUnsupported, compile_cache
@@ -435,6 +437,10 @@ def _default_finalize(outs: Dict[str, np.ndarray], ctx: Dict) -> Dict[str, np.nd
     return cols
 
 
+def _part_rows(part: Dict[str, np.ndarray]) -> int:
+    return len(next(iter(part.values()))) if part else 0
+
+
 class SegmentExecutor:
     """Runs one Segment over a DataFrame, partition by partition, through
     the TransferRing with compile-cache-backed fused executables."""
@@ -451,6 +457,9 @@ class SegmentExecutor:
         # observable: fusion_stats()["devices"])
         self.out_devices: Dict[str, int] = {}
         self._device = None  # set by _put_params on the unsharded path
+        # batches this run has handed to the device so far: the spans'
+        # ``batch`` (an executor serves one run of one call)
+        self._batch_no = 0
         # cost-aware bucket SET for short batches (auto-tuner knob; None =
         # the power-of-two default — bitwise-identical cold start)
         self.buckets = tuple(sorted(buckets)) if buckets else None
@@ -520,20 +529,32 @@ class SegmentExecutor:
         return out
 
     # -- host path -------------------------------------------------------
-    def _host_partition(self, part: Dict[str, np.ndarray], schema: Schema
-                        ) -> List[Dict[str, np.ndarray]]:
+    def _host_partition(self, part: Dict[str, np.ndarray], schema: Schema,
+                        obs=None) -> List[Dict[str, np.ndarray]]:
         sub = DataFrame([dict(part)], schema.copy())
-        n = len(next(iter(part.values()))) if part else 0
+        n = _part_rows(part)
         for s in self.segment.stages:
             t0 = time.perf_counter()
-            sub = s.transform(sub)
+            with batch_span(obs, f"host:{type(s).__name__}", rows=n):
+                sub = s.transform(sub)
             if self.cost_model is not None and n > 0:
                 # the measured HOST side of the fuse-vs-host comparison
                 self.cost_model.observe_host(
                     type(s).__name__, time.perf_counter() - t0, n)
         return sub.partitions
 
-    def _put_params(self, jax):
+    def _put_params(self, jax, obs=None):
+        """``_place_params`` under a ``put_params`` span: the weights are
+        re-placed on every call, and the span says what that costs."""
+        if obs is None:
+            return self._place_params(jax)
+        params = tuple(d.params for d in self.segment.dfns)
+        nbytes = sum(int(getattr(leaf, "nbytes", 0))
+                     for leaf in jax.tree_util.tree_leaves(params))
+        with batch_span(obs, "put_params", bytes=nbytes):
+            return self._place_params(jax)
+
+    def _place_params(self, jax):
         """Stage-params placement: replicated over the mesh when sharded;
         otherwise on the ONE device this run dispatches to — the innermost
         ``jax.default_device`` (ReplicaSet pins one per replica), else the
@@ -554,26 +575,26 @@ class SegmentExecutor:
     def run(self, df: DataFrame, stats) -> DataFrame:
         import jax
 
-        from ..obs.trace import current_batch
-
         seg = self.segment
-        params_dev = self._put_params(jax)
-        obs = current_batch()  # serving batch's trace binding (or None)
+        # the bound batch's trace binding (a serving batch's, else the
+        # call's root on the default recorder, else None); ``own`` is this
+        # segment's open span, which everything below records under
+        own = open_span(current_batch())
         t_wall, t0 = time.time(), time.perf_counter()
+        params_dev = self._put_params(jax, own)
         out_parts: List[Dict[str, np.ndarray]] = []
         for part in df.partitions:
             try:
                 out_parts.append(
-                    self._run_partition(dict(part), params_dev, stats))
+                    self._run_partition(dict(part), params_dev, stats, own))
             except _HostFallback as e:
                 self.fallbacks.append(f"{seg.label}: {e}")
-                out_parts.extend(self._host_partition(part, df.schema))
-        if obs is not None:
-            tracer, ctxs = obs
-            tracer.record_batch(f"segment:{seg.label}", ctxs, t_wall,
-                                time.perf_counter() - t0,
-                                **self._cost_attrs())
-        return self._overlay(df, out_parts)
+                out_parts.extend(self._host_partition(part, df.schema, own))
+        with batch_span(own, "overlay"):
+            out = self._overlay(df, out_parts)
+        close_span(own, f"segment:{seg.label}", t_wall,
+                   time.perf_counter() - t0, **self._cost_attrs())
+        return out
 
     def _overlay(self, df: DataFrame, out_parts: List[Dict[str, np.ndarray]]
                  ) -> DataFrame:
@@ -591,8 +612,15 @@ class SegmentExecutor:
         meta = {k: v for k, v in chained.metadata.items() if k in types}
         return DataFrame(out_parts, Schema(types, meta))
 
-    def _prep_partition(self, part: Dict[str, np.ndarray],
-                        stats=None) -> Dict[str, Any]:
+    def _prep_partition(self, part: Dict[str, np.ndarray], stats=None,
+                        obs=None) -> Dict[str, Any]:
+        """``_prep_state`` under a ``prepare`` span (``obs``: the
+        partition's open span)."""
+        with batch_span(obs, "prepare", rows=_part_rows(part)) as own:
+            return self._prep_state(part, stats, own)
+
+    def _prep_state(self, part: Dict[str, np.ndarray], stats,
+                    obs) -> Dict[str, Any]:
         """Host-side prep for one partition — validity masks, per-stage
         prepare hooks, dtype/sparse/null gates, dense stacking — everything
         up to (but excluding) device dispatch. Raises _HostFallback when the
@@ -628,12 +656,14 @@ class SegmentExecutor:
         # this stage even when it shares the external column's name — its
         # value arrives device-resident, so prepare must not touch it.
         written: set = set()
-        for dfn in seg.dfns:
+        for dfn, stage in zip(seg.dfns, seg.stages):
             if dfn.prepare is not None:
                 mine = {c: sub[c] for c in dfn.in_cols
                         if c in sub and c not in written}
                 if mine:
-                    sub.update(dfn.prepare(mine, ctx))
+                    with batch_span(obs, f"prepare:{type(stage).__name__}",
+                                    rows=int(valid.sum())):
+                        sub.update(dfn.prepare(mine, ctx))
             written |= set(dfn.out_cols)
         # prep can null rows (decode failures): shrink validity like dropNa
         n_valid = int(valid.sum())
@@ -693,6 +723,7 @@ class SegmentExecutor:
             dense: Dict[str, np.ndarray] = {}
             deposit: Dict[str, List[np.ndarray]] = {}
             csr: Dict[str, Tuple] = {}
+            w0, t0 = time.time(), time.perf_counter()
             for c in ext:
                 if c in csr_cols:
                     triple = self._stage_csr(sub[c], stats)
@@ -712,6 +743,14 @@ class SegmentExecutor:
                     deposit[c] = rows
                 else:
                     dense[c] = _stack_col(sub[c], allow_sparse, stats=stats)
+            if obs is not None:
+                # a deposit column's stack is deferred to ``fill``: only
+                # what was materialized here counts in ``bytes``
+                nbytes = sum(a.nbytes for a in dense.values()) + sum(
+                    a.nbytes for t in csr.values() for a in t[:3])
+                obs[0].record_batch("stack", obs[1], w0,
+                                    time.perf_counter() - t0,
+                                    rows=n_valid, bytes=nbytes)
             state["dense"] = dense
             state["deposit"] = deposit
             if csr:
@@ -796,8 +835,12 @@ class SegmentExecutor:
                 return None
         return rows
 
-    def _batches(self, state: Dict[str, Any], stats=None):
+    def _batches(self, state: Dict[str, Any], stats=None, obs=None,
+                 batch0: int = 0):
         """Padded/bucketed Batch stream over the partition's dense arrays.
+        Each batch's build is one ``fill`` span under ``obs``, on whichever
+        thread runs this generator (the slot filler's, else the ring's
+        producer's).
 
         Deposit-eligible columns (``state["deposit"]``) fill a pre-allocated
         SlotPool buffer in place — stack + pad collapse into one copy into
@@ -816,7 +859,9 @@ class SegmentExecutor:
         # evenly across the shards, so targets round UP to a shard multiple
         # (the pad rows are masked out at readback exactly like bucket pad)
         shards = self.sharding.shards if self.sharding is not None else 1
-        for start in range(0, n_valid, batch_size):
+        for batch, start in enumerate(range(0, n_valid, batch_size), batch0):
+            w0, t0 = (time.time(), time.perf_counter()) \
+                if obs is not None else (0.0, 0.0)
             stop = min(start + batch_size, n_valid)
             m = stop - start
             target = batch_size if m == batch_size \
@@ -876,6 +921,10 @@ class SegmentExecutor:
             # analysis: allow D001 -- host-side validity mask, never shipped
             mask = np.zeros(target, dtype=bool)
             mask[:m] = True
+            if obs is not None:
+                obs[0].record_batch(
+                    "fill", obs[1], w0, time.perf_counter() - t0, batch=batch,
+                    bytes=sum(a.nbytes for a in arrays.values()))
             yield Batch(arrays, mask, m, staging=lease)
 
     def _put(self, batch):
@@ -953,11 +1002,18 @@ class SegmentExecutor:
             vid = self._variant_for(sig)
             tail = key_tail + ((("variant", vid),) if vid else ())
             pre = (f"variant={vid};" if vid else "") + shape_pre
-            compiled = self.cache.get(
-                (seg.key, sig) + tail,
-                lambda: self._build(params_dev, x, keys, variant=vid,
-                                    csr_cols=csr_cols),
-                label=seg.label, shape=pre + self._shape_key_of(sig))
+            shape = pre + self._shape_key_of(sig)
+
+            def build():
+                # a CompileCache miss: the build is a child of the open
+                # ``dispatch`` span the caller bound around this step
+                with batch_span(current_batch(), "compile",
+                                label=seg.label, shape=shape):
+                    return self._build(params_dev, x, keys, variant=vid,
+                                       csr_cols=csr_cols)
+
+            compiled = self.cache.get((seg.key, sig) + tail, build,
+                                      label=seg.label, shape=shape)
             with profiling.annotate(f"fused:{seg.label}"):
                 ys = compiled(params_dev, x)
             self._note_devices(ys)
@@ -1014,33 +1070,42 @@ class SegmentExecutor:
         ys, m = handle
         return tuple(np.asarray(y)[:m] for y in ys)
 
-    def _fill_ahead(self, state: Dict[str, Any], stats):
+    def _fill_ahead(self, state: Dict[str, Any], stats, obs=None):
         """Batch source for one partition: the plain generator, wrapped in
         a background fill thread when slot deposit is active — slot N+1
         fills while slot N transfers (the paired-buffer overlap; the
         SlotPool's two buffers per bucket pace the lookahead). Returns
         (iterator, closer)."""
-        src = self._batches(state, stats)
+        src = self._batches(state, stats, obs, self._batch_no)
         if not state.get("deposit"):
             return src, None
         from ..parallel.batching import DevicePrefetcher
 
-        filler = DevicePrefetcher(src, depth=1)
+        filler = DevicePrefetcher(src, depth=1, name="slot-fill")
         return iter(filler), filler
 
     def _run_partition(self, part: Dict[str, np.ndarray], params_dev,
-                       stats) -> Dict[str, np.ndarray]:
+                       stats, obs=None) -> Dict[str, np.ndarray]:
+        """One partition through prepare, the ring and emit, under one
+        ``partition`` span of ``obs`` (the segment's open span)."""
+        with batch_span(obs, "partition", rows=_part_rows(part)) as own:
+            return self._ring_partition(part, params_dev, stats, own)
+
+    def _ring_partition(self, part: Dict[str, np.ndarray], params_dev,
+                        stats, obs) -> Dict[str, np.ndarray]:
         from ..parallel.ingest import TransferRing
 
-        state = self._prep_partition(part, stats)
+        state = self._prep_partition(part, stats, obs)
         collected: Dict[str, List[np.ndarray]] = {k: []
                                                   for k in state["keys"]}
         if state["n_valid"] > 0:
-            src, filler = self._fill_ahead(state, stats)
+            src, filler = self._fill_ahead(state, stats, obs)
             ring = TransferRing(src, put=self._put,
                                 step=self._make_step(params_dev, state),
                                 fetch=self._fetch,
-                                depth=self.segment.ring_depth(), stats=stats)
+                                depth=self.segment.ring_depth(), stats=stats,
+                                obs=obs, batch0=self._batch_no)
+            self._batch_no += self._num_batches(state)
             try:
                 for out in ring:
                     for k, y in zip(state["keys"], out):
@@ -1051,7 +1116,10 @@ class SegmentExecutor:
                 ring.close()
                 if filler is not None:
                     filler.close()
-        return self._emit_partition(state, collected)
+        return self._emit_partition(state, collected, obs)
+
+    def _num_batches(self, state: Dict[str, Any]) -> int:
+        return -(-state["n_valid"] // self.segment.batch_size())
 
     def submit_run(self, df: DataFrame, stats):
         """Non-blocking segment execution: prep + H2D-stage + DISPATCH every
@@ -1064,63 +1132,76 @@ class SegmentExecutor:
         execute synchronously at submit time — never a wrong answer."""
         import jax
 
-        from ..obs.trace import current_batch
-        from ..parallel.ingest import timed_stage
+        from ..parallel.ingest import timed_dispatch, timed_stage
 
         seg = self.segment
-        obs = current_batch()  # serving batch's trace binding (or None)
+        # this segment's open span under the serving batch's trace binding
+        # (None when nothing is bound): closed by resolve()
+        own = open_span(current_batch())
         wall0 = time.perf_counter()
         t_wall = time.time()
-        params_dev = self._put_params(jax)
+        params_dev = self._put_params(jax, own)
         mega_k = max(1, int(self.mega_k or 1))
-        pendings: List[Tuple[str, Any, Any]] = []
+        pendings: List[Tuple[str, Any, Any, Any, int]] = []
         for part in df.partitions:
+            # a partition's span covers what happens to it NOW (prepare,
+            # fill, h2d, dispatch); its drain and emit record under the
+            # same span from resolve(), after it closed
+            p_own = open_span(own)
+            pw0, pt0 = time.time(), time.perf_counter()
             try:
-                state = self._prep_partition(dict(part), stats)
+                state = self._prep_partition(dict(part), stats, p_own)
                 handles = []
+                b0 = self._batch_no
                 if state["n_valid"] > 0:
                     step = self._make_step(params_dev, state)
-                    src, filler = self._fill_ahead(state, stats)
+                    src, filler = self._fill_ahead(state, stats, p_own)
+                    self._batch_no += self._num_batches(state)
                     try:
                         if mega_k <= 1:
                             # K=1: today's stage-then-dispatch loop,
                             # verbatim — bitwise-identical by construction
-                            for batch in src:
+                            for b, batch in enumerate(src, b0):
                                 staged, timing = timed_stage(
-                                    self._put, batch, obs=obs)
-                                td = time.perf_counter()
-                                handle = step(staged)
-                                timing.dispatch_s = \
-                                    time.perf_counter() - td
+                                    self._put, batch, obs=p_own, batch=b)
+                                handle = timed_dispatch(
+                                    step, staged, timing, p_own, b)
                                 handles.append((handle, timing))
                         else:
                             staged_it = (
-                                timed_stage(self._put, batch, obs=obs)
-                                for batch in src)
+                                timed_stage(self._put, batch, obs=p_own,
+                                            batch=b)
+                                for b, batch in enumerate(src, b0))
                             self._dispatch_mega(staged_it, params_dev,
                                                 state, step, mega_k,
-                                                handles)
+                                                handles, p_own, b0)
                     finally:
                         if filler is not None:
                             filler.close()
-                pendings.append(("device", state, handles))
+                pendings.append(("device", state, handles, p_own, b0))
             except (_HostFallback, FusionUnsupported) as e:
                 self.fallbacks.append(f"{seg.label}: {e}")
                 pendings.append(
-                    ("host", self._host_partition(part, df.schema), None))
+                    ("host", self._host_partition(part, df.schema, own),
+                     None, None, 0))
+            finally:
+                close_span(p_own, "partition", pw0,
+                           time.perf_counter() - pt0, rows=_part_rows(part))
 
         def resolve() -> DataFrame:
-            from ..parallel.ingest import _block_ready
+            from ..parallel.ingest import (_block_ready, _tree_nbytes,
+                                           record_drain)
 
             out_parts: List[Dict[str, np.ndarray]] = []
-            for kind, payload, handles in pendings:
+            for kind, payload, handles, p_own, b0 in pendings:
                 if kind == "host":
                     out_parts.extend(payload)
                     continue
                 state = payload
                 collected: Dict[str, List[np.ndarray]] = {
                     k: [] for k in state["keys"]}
-                for handle, timing in handles:
+                for b, (handle, timing) in enumerate(handles, b0):
+                    w0 = time.time() if p_own is not None else 0.0
                     t0 = time.perf_counter()
                     _block_ready(handle)
                     t1 = time.perf_counter()
@@ -1128,21 +1209,24 @@ class SegmentExecutor:
                     out = self._fetch(handle)
                     timing.readback_s = time.perf_counter() - t1
                     stats.record(timing)
+                    if p_own is not None:
+                        record_drain(p_own, timing, w0, b, _tree_nbytes(out))
                     for k, y in zip(state["keys"], out):
                         collected[k].append(y)
-                out_parts.append(self._emit_partition(state, collected))
+                out_parts.append(
+                    self._emit_partition(state, collected, p_own))
             stats.add_wall(time.perf_counter() - wall0)
-            if obs is not None:
-                tracer, ctxs = obs
-                tracer.record_batch(f"segment:{seg.label}", ctxs, t_wall,
-                                    time.perf_counter() - wall0,
-                                    **self._cost_attrs())
-            return self._overlay(df, out_parts)
+            with batch_span(own, "overlay"):
+                out_df = self._overlay(df, out_parts)
+            close_span(own, f"segment:{seg.label}", t_wall,
+                       time.perf_counter() - wall0, **self._cost_attrs())
+            return out_df
 
         return resolve
 
     def _dispatch_mega(self, staged_it, params_dev, state: Dict[str, Any],
-                       step, k: int, handles) -> None:
+                       step, k: int, handles, obs=None,
+                       batch0: int = 0) -> None:
         """Dispatch staged batches in SLIDING K-step groups: pull from the
         (lazily staging) iterator, and the moment K consecutive
         same-signature batches are staged, run them through the compiled
@@ -1156,23 +1240,30 @@ class SegmentExecutor:
         is split evenly across the K timings (the amortization the
         bottleneck attribution shows), with ``timing.mega_k`` tagging the
         share so the cost model can de-amortize it."""
+        from ..parallel.ingest import timed_dispatch
+
         ext = state.get("staged_cols") or state["ext"]
         mega = self._make_mega_step(params_dev, state, k)
 
         def flush(group):
+            b = batch0 + len(handles)
             if len(group) == k:
+                w0 = time.time()
                 td = time.perf_counter()
                 outs = mega(group)
                 share = (time.perf_counter() - td) / k
+                if obs is not None:
+                    # ONE dispatch carried K batches: one span, its first
+                    # batch and K beside it
+                    obs[0].record_batch("dispatch", obs[1], w0, share * k,
+                                        batch=b, mega_k=k)
                 for (staged, timing), ys in zip(group, outs):
                     timing.dispatch_s = share
                     timing.mega_k = k
                     handles.append(((ys, staged[1]), timing))
             else:
-                for staged, timing in group:
-                    td = time.perf_counter()
-                    handle = step(staged)
-                    timing.dispatch_s = time.perf_counter() - td
+                for j, (staged, timing) in enumerate(group, b):
+                    handle = timed_dispatch(step, staged, timing, obs, j)
                     handles.append((handle, timing))
 
         group: List[Any] = []
@@ -1192,8 +1283,16 @@ class SegmentExecutor:
             flush(group)
 
     def _emit_partition(self, state: Dict[str, Any],
-                        collected: Dict[str, List[np.ndarray]]
-                        ) -> Dict[str, np.ndarray]:
+                        collected: Dict[str, List[np.ndarray]],
+                        obs=None) -> Dict[str, np.ndarray]:
+        """``_emit_columns`` under an ``emit`` span (``obs``: the
+        partition's span)."""
+        with batch_span(obs, "emit", rows=state["n"]) as own:
+            return self._emit_columns(state, collected, own)
+
+    def _emit_columns(self, state: Dict[str, Any],
+                      collected: Dict[str, List[np.ndarray]],
+                      obs) -> Dict[str, np.ndarray]:
         """Readback arrays -> finalized partition columns (per writer
         stage, scattered over the validity mask)."""
         seg = self.segment
@@ -1216,15 +1315,16 @@ class SegmentExecutor:
                 continue
             if n_valid == 0:
                 cols = {c: np.empty(0, dtype=object) for c in dfn.out_cols}
-            elif i in transpiled:
-                # transpiled finalize: the numeric reductions already ran
-                # on device (device_finalize); this host shim only shapes
-                # the readbacks into columns
-                cols = dfn.finalize_stitched(outs, ctx)
-            elif dfn.finalize is not None:
-                cols = dfn.finalize(outs, ctx)
             else:
-                cols = _default_finalize(outs, ctx)
+                # transpiled finalize: the numeric reductions already ran
+                # on device (device_finalize); that host shim only shapes
+                # the readbacks into columns
+                finalize = dfn.finalize_stitched if i in transpiled \
+                    else dfn.finalize or _default_finalize
+                with batch_span(
+                        obs, f"finalize:{type(seg.stages[i]).__name__}",
+                        rows=n_valid):
+                    cols = finalize(outs, ctx)
             for c in dfn.out_cols:
                 if c not in cols:
                     continue
@@ -1554,12 +1654,12 @@ class FusedPipelineModel(PipelineModel):
     def _host_node(self, node: HostStage, df: DataFrame) -> DataFrame:
         """Run one host plan node, feeding its wall time to the cost model
         (the measured host side of fuse-vs-demote) when tuning is on."""
-        if self._cost_model is None:
-            return node.stage.transform(df)
-        n = sum(len(next(iter(p.values()))) if p else 0
-                for p in df.partitions)
+        n = sum(_part_rows(p) for p in df.partitions)
         t0 = time.perf_counter()
-        out = node.stage.transform(df)
+        with batch_span(current_batch(), f"host:{node.label}", rows=n):
+            out = node.stage.transform(df)
+        if self._cost_model is None:
+            return out
         if n > 0:
             self._cost_model.observe_host(
                 node.label, time.perf_counter() - t0, n)
@@ -1568,10 +1668,20 @@ class FusedPipelineModel(PipelineModel):
     def transform(self, df: DataFrame, fused: bool = True) -> DataFrame:
         if not fused:
             return PipelineModel.transform(self, df)
+        # one trace a call: with no serving batch bound, the call's root
+        # span opens on the default recorder (obs/trace.py) and is bound
+        # for the call, so every site below records under it
+        attrs = {"rows": sum(_part_rows(p) for p in df.partitions),
+                 "partitions": len(df.partitions)}
+        with root_span("transform", attrs):
+            ensure_compile_cache()
+            nodes = self._plan_for(df.schema)
+            attrs["segments"] = sum(isinstance(n, Segment) for n in nodes)
+            return self._transform_nodes(df, nodes)
+
+    def _transform_nodes(self, df: DataFrame, nodes: List[Any]) -> DataFrame:
         from ..parallel.ingest import IngestStats
 
-        ensure_compile_cache()
-        nodes = self._plan_for(df.schema)
         self._last_plan = nodes
         self._seg_stats = {}
         self._last_fallbacks = []

@@ -15,11 +15,13 @@ import dataclasses
 import functools
 import json
 import os
+import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from ..core import faults as _faults
+from ..obs.trace import close_span, current_batch, open_span, root_span
 from ..parallel.mesh import fetch_global
 
 from .binning import BinMapper
@@ -492,8 +494,6 @@ _SCAN_MASK_BUDGET = 1 << 28
 
 
 def _now() -> float:
-    import time
-
     return time.perf_counter()
 
 
@@ -804,8 +804,11 @@ def _train_scan(params: TrainParams, config: GrowerConfig, booster: "Booster",
             xs["fm"] = jnp.asarray(feat_masks)
         if is_goss:
             xs["gk"] = goss_keys
-    timing = os.environ.get("MMLSPARK_TPU_GBDT_TIMING", "") not in ("", "0")
-    t0 = _now() if timing else 0.0
+    # the fit's phases are spans under the call's root (obs/trace.py): the
+    # scan whole, one child a chunk with its fetch inside, the host tree build
+    obs = current_batch()
+    scan_obs = open_span(obs)
+    w0, t0 = time.time(), _now()
 
     # Chunk the scan: bound row*iteration work (and the stacked per-tree
     # outputs) per dispatch; the (score, comp) carry stays device-resident
@@ -825,20 +828,27 @@ def _train_scan(params: TrainParams, config: GrowerConfig, booster: "Booster",
         # short final chunk overgrows up to ipc-1 surplus trees (same xs rows
         # repeated) that are simply dropped below — one tree of wasted
         # compute beats a second multi-second XLA compile
+        chunk_obs = open_span(scan_obs)
+        wc, tc = time.time(), _now()
         xs_c = None
         if xs is not None:
             idx = np.minimum(np.arange(done, done + ipc), iters - 1)
             xs_c = {k: v[idx] for k, v in xs.items()}
         carry, ys = jax.lax.scan(body, carry, xs_c, length=ipc)
+        wf, tf = time.time(), _now()
         host_chunks.append(fetch_global(ys))
+        if chunk_obs is not None:
+            chunk_obs[0].record_batch("gbdt:fetch", chunk_obs[1], wf,
+                                      _now() - tf)
+            close_span(chunk_obs, "gbdt:scan_chunk", wc, _now() - tc,
+                       rows=n, iterations=ipc)
         done += ipc
     host = jax.tree.map(lambda *c: np.concatenate(c, axis=0), *host_chunks) \
         if len(host_chunks) > 1 else host_chunks[0]
     host = jax.tree.map(lambda a: a[:iters], host)
-    if timing:
-        print(f"[gbdt-scan] exec+fetch ({n_chunks} chunk(s) of <= {ipc}) "
-              f"{_now() - t0:.3f}s", flush=True)
-        t0 = _now()
+    close_span(scan_obs, "gbdt:scan", w0, _now() - t0, chunks=n_chunks,
+               iterations=iters)
+    w0, t0 = time.time(), _now()
 
     for it in range(iters):
         group: List[Tree] = []
@@ -878,8 +888,9 @@ def _train_scan(params: TrainParams, config: GrowerConfig, booster: "Booster",
                 cat_bin_words=cat_words_np,
             ))
         booster.trees.append(group)
-    if timing:
-        print(f"[gbdt-scan] host tree build {_now() - t0:.3f}s", flush=True)
+    if obs is not None:
+        obs[0].record_batch("gbdt:trees", obs[1], w0, _now() - t0,
+                            trees=iters * k)
 
 
 # ---------------------------------------------------------------------------
@@ -1204,6 +1215,23 @@ def train(params: TrainParams,
           init_model: Optional[Booster] = None,
           log: Optional[Callable[[str], None]] = None,
           mesh=None, checkpoint=None) -> Booster:
+    """``_train`` under the call's root span ``fit`` (obs/trace.py: the
+    default recorder's, unless a batch is bound): its phases record as
+    ``gbdt:bin_fit``, ``gbdt:bins``, ``gbdt:scan`` (one ``gbdt:scan_chunk``
+    a chunk, its ``gbdt:fetch`` inside) and ``gbdt:trees``."""
+    attrs = {"rows": len(y), "features": int(X.shape[1]),
+             "iterations": int(params.num_iterations)}
+    with root_span("fit", attrs):
+        return _train(params, X, y, weights, groups, valid, valid_groups,
+                      init_scores, init_model, log, mesh, checkpoint)
+
+
+def _train(params: TrainParams, X: np.ndarray, y: np.ndarray,
+           weights: Optional[np.ndarray], groups: Optional[np.ndarray],
+           valid: Optional[Tuple[np.ndarray, np.ndarray]],
+           valid_groups: Optional[np.ndarray],
+           init_scores: Optional[np.ndarray], init_model: Optional[Booster],
+           log: Optional[Callable[[str], None]], mesh, checkpoint) -> Booster:
     """Full training: bin, boost, early-stop. Returns a Booster.
 
     ``mesh``: optional jax Mesh — rows are sharded over the ``data`` axis and the
@@ -1277,13 +1305,18 @@ def train(params: TrainParams,
     k = max(params.num_class, 1)
     objective = params.objective
     rng = np.random.default_rng(params.seed or params.bagging_seed)
+    obs = current_batch()  # the fit's root span (obs/trace.py), or None
 
     if init_model is not None and init_model.bin_mapper is not None:
         mapper = init_model.bin_mapper
     else:
+        w_fit, t_fit = time.time(), _now()
         mapper = BinMapper.fit(X[:n_real], params.max_bin,
                                params.categorical_feature, seed=params.seed,
                                max_bin_by_feature=params.max_bin_by_feature)
+        if obs is not None:
+            obs[0].record_batch("gbdt:bin_fit", obs[1], w_fit,
+                                _now() - t_fit, rows=n_real, features=num_f)
     # the mapper (possibly inherited from init_model with a different max_bin)
     # is the sole authority on bin count — mixing in params.max_bin would corrupt
     # the flat scatter indices in compute_histogram
@@ -1297,8 +1330,7 @@ def train(params: TrainParams,
     # through the host link) and widen once on device.
     u8 = num_bins <= 256
     bin_dtype = np.uint8 if u8 else np.int32
-    timing = os.environ.get("MMLSPARK_TPU_GBDT_TIMING", "") not in ("", "0")
-    t_bins = _now() if timing else 0.0
+    w_bins, t_bins = time.time(), _now()
     if bins_put is None and n * num_f >= 1 << 22:
         # Overlapped bin+ship: the MAIN thread bins columns (the host has
         # one core — a transform pool cannot help) while a single worker
@@ -1344,9 +1376,9 @@ def train(params: TrainParams,
             bins_dev = _widen_bins(put_bins(jnp.asarray(bins_fm)))
         else:
             bins_dev = put_bins(jnp.asarray(bins_fm))
-    if timing:
-        print(f"[gbdt-bins] transform+ship {_now() - t_bins:.3f}s",
-              flush=True)
+    if obs is not None:
+        obs[0].record_batch("gbdt:bins", obs[1], w_bins, _now() - t_bins,
+                            rows=n, features=num_f)
 
     labels = put(jnp.asarray(y, dtype=jnp.float32))
     w_dev = put(jnp.asarray(weights, dtype=jnp.float32)) if weights is not None else None

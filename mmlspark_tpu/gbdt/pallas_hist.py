@@ -135,6 +135,9 @@ def _hist_slab(bins_slab, vals, b_pad: int, interpret: bool, hilo: bool,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((3, fs * b_pad), jnp.float32),
         interpret=interpret,
+        # the name a device trace shows (`%_compute_histogram_mxu.NN`), pinned
+        # so that a refactor of the jitted caller cannot rename the kernel
+        name="_compute_histogram_mxu",
         cost_estimate=pl.CostEstimate(
             flops=2 * nch * n_pad * fs * b_pad,
             bytes_accessed=bins_slab.size * 4

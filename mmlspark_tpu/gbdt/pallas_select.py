@@ -178,6 +178,8 @@ def _select_rows(bins_fm, grad, hess, mask, cap: int, interpret: bool = False,
             jax.ShapeDtypeStruct((cap_pad, c_pad), jnp.float32),
         ],
         interpret=interpret,
+        # the name a device trace shows (`%_select_rows.NN`), pinned
+        name="_select_rows",
         cost_estimate=pl.CostEstimate(
             flops=2 * n_pad * chunk * (f + 3),
             bytes_accessed=bins_p.size * bins_p.dtype.itemsize

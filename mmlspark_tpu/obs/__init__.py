@@ -24,7 +24,8 @@ from .metrics import (COMPILE_BUCKETS, Counter, DEFAULT_BUCKETS, Gauge,
                       SERVING_LATENCY_BUCKETS, Sample, TrainRecorder,
                       default_registry, set_default_registry)
 from .trace import (Span, SpanContext, TRACE_HEADER, Tracer, batch_context,
-                    current_batch, parse_trace_header)
+                    current_batch, default_tracer, parse_trace_header,
+                    set_default_tracer)
 from .perf import SLOConfig, SLOTracker, attribute_segments, extract_cost
 from . import bridge
 from . import perf
@@ -34,5 +35,6 @@ __all__ = ["COMPILE_BUCKETS", "Counter", "DEFAULT_BUCKETS", "Gauge",
            "SERVING_LATENCY_BUCKETS", "SLOConfig", "SLOTracker", "Sample",
            "Span", "SpanContext", "TRACE_HEADER", "Tracer", "TrainRecorder",
            "attribute_segments", "batch_context", "bridge", "current_batch",
-           "default_registry", "extract_cost", "parse_trace_header", "perf",
-           "set_default_registry"]
+           "default_registry", "default_tracer", "extract_cost",
+           "parse_trace_header", "perf", "set_default_registry",
+           "set_default_tracer"]

@@ -24,6 +24,16 @@ context across existing HTTP hops, and every stage records spans against it.
   - ``batch_context``/``current_batch``: a contextvar carrying the current
     batch's (tracer, sampled contexts) into layers that can't thread them
     explicitly (parallel/ingest.timed_stage records H2D spans through it).
+  - The batch path has no request to own its spans, so a process-wide
+    default ``Tracer`` (``default_tracer``, service ``batch``) takes them:
+    ``FusedPipelineModel.transform`` and the GBDT ``train`` open a root span
+    there when no serving batch is bound. It is ON by default — a flight
+    recorder — and stays cheap because no site records a span per row: the
+    finest span is one per batch per phase. ``set_default_tracer(None)``
+    turns it off; a site then costs two branch checks and reads no clock.
+  - ``open_span``/``close_span``/``batch_span`` nest spans under a binding:
+    the binding of the children is what the enclosed code records under,
+    so one call yields a tree (docs/observability.md has the batch path's).
 """
 
 from __future__ import annotations
@@ -39,7 +49,9 @@ from collections import deque
 from typing import Any, Dict, Iterator, List, Mapping, Optional, Sequence, Tuple
 
 __all__ = ["Span", "SpanContext", "TRACE_HEADER", "Tracer", "batch_context",
-           "current_batch", "parse_trace_header"]
+           "batch_span", "close_span", "current_batch", "default_tracer",
+           "open_span", "parse_trace_header", "root_span",
+           "set_default_tracer"]
 
 #: header carrying the trace context across hops (deadline-header pattern)
 TRACE_HEADER = "X-MMLSpark-Trace"
@@ -106,10 +118,13 @@ def context_from_headers(headers: Optional[Mapping[str, str]]
 
 
 class Span:
-    """One finished span (epoch-second timestamps, duration in seconds)."""
+    """One finished span (epoch-second timestamps, duration in seconds).
+    ``thread`` is the name of the thread that recorded it: the ring's
+    producer and the slot filler are other threads than the caller, and
+    self time is reckoned per thread."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "t0", "dur_s",
-                 "attrs", "service")
+                 "attrs", "service", "thread")
 
     def __init__(self, name: str, ctx: SpanContext, t0: float, dur_s: float,
                  attrs: Optional[Dict[str, Any]] = None, service: str = ""):
@@ -121,12 +136,13 @@ class Span:
         self.dur_s = dur_s
         self.attrs = attrs or {}
         self.service = service
+        self.thread = threading.current_thread().name
 
     def to_dict(self) -> Dict[str, Any]:
         return {"name": self.name, "trace_id": self.trace_id,
                 "span_id": self.span_id, "parent_id": self.parent_id,
                 "t0": self.t0, "dur_s": self.dur_s, "service": self.service,
-                "attrs": self.attrs}
+                "thread": self.thread, "attrs": self.attrs}
 
 
 class Tracer:
@@ -206,6 +222,15 @@ class Tracer:
         """New span context under ``ctx`` (same trace, parent = ctx)."""
         return SpanContext(ctx.trace_id, self._seq_id(),
                            parent_id=ctx.span_id, sampled=ctx.sampled)
+
+    def children(self, ctxs: Sequence[Optional[SpanContext]]
+                 ) -> Tuple[SpanContext, ...]:
+        """One child context per SAMPLED context of a batch: the identity
+        of a span that is still open, so what runs inside it can parent to
+        it (``open_span``)."""
+        return tuple(SpanContext(c.trace_id, self._seq_id(),
+                                 parent_id=c.span_id, sampled=True)
+                     for c in ctxs if c is not None and c.sampled)
 
     def _seq_id(self) -> str:
         """Unique 64-bit span id without taking the RNG lock: a
@@ -361,3 +386,99 @@ def batch_context(tracer: Optional[Tracer],
 def current_batch() -> Optional[Tuple[Tracer, tuple]]:
     """The innermost ``batch_context`` binding, or None."""
     return _BATCH.get()
+
+
+# ---------------------------------------------------------------------------
+# Nested spans under a binding (one tree a call)
+# ---------------------------------------------------------------------------
+
+Binding = Optional[Tuple[Tracer, tuple]]
+
+
+def open_span(obs: Binding) -> Binding:
+    """Binding of a span about to start under every context of ``obs``: its
+    contexts are the span's OWN identity, known before it ends, so nested
+    work can record under it. None in, None out — the off switch."""
+    if obs is None:
+        return None
+    tracer, ctxs = obs
+    return tracer, tracer.children(ctxs)
+
+
+def close_span(own: Binding, name: str, t0: float, dur_s: float,
+               **attrs: Any) -> None:
+    """Record the span ``open_span`` announced, with clock reads the caller
+    made anyway (a phase is clocked once and feeds counter and span)."""
+    if own is None:
+        return
+    tracer, ctxs = own
+    a = attrs or None
+    for ctx in ctxs:
+        tracer._push(Span(name, ctx, t0, dur_s, a, tracer.service))
+
+
+@contextlib.contextmanager
+def batch_span(obs: Binding, name: str, **attrs: Any) -> Iterator[Binding]:
+    """Live span under a binding; yields the children's binding (None when
+    ``obs`` is None: two branch checks, no clock read). Recorded on the way
+    out of a raise too — a host fallback still shows its ``prepare``."""
+    if obs is None:
+        yield None
+        return
+    own = open_span(obs)
+    t0 = time.time()
+    p0 = time.perf_counter()
+    try:
+        yield own
+    finally:
+        close_span(own, name, t0, time.perf_counter() - p0, **(attrs or {}))
+
+
+# ---------------------------------------------------------------------------
+# The default recorder: spans of work that no serving request owns
+# ---------------------------------------------------------------------------
+
+_DEFAULT: Optional[Tracer] = Tracer(sample_rate=1.0, service="batch")
+
+
+def default_tracer() -> Optional[Tracer]:
+    """The process-wide recorder of the batch path (None when off)."""
+    return _DEFAULT
+
+
+def set_default_tracer(tracer: Optional[Tracer]) -> Optional[Tracer]:
+    """Replace the batch path's recorder; ``None`` turns it off. Returns
+    the one that was there, so a caller can put it back."""
+    global _DEFAULT
+    old, _DEFAULT = _DEFAULT, tracer
+    return old
+
+
+@contextlib.contextmanager
+def root_span(name: str, attrs: Optional[Dict[str, Any]] = None
+              ) -> Iterator[Binding]:
+    """Root of one call of the batch path (a transform, a fit): a new trace
+    on the default recorder, bound for the call so the sites below record
+    with no new code path. Under a binding that is already there (a serving
+    request owns the work, or an outer call of the batch path does) nothing
+    is opened and that binding is yielded: the owner's tree takes the
+    spans, as before. ``attrs`` is read when the call ends, so the call
+    may fill it as it learns (a plan's segment count)."""
+    obs = current_batch()
+    tracer = _DEFAULT
+    if obs is not None or tracer is None:
+        yield obs
+        return
+    root = tracer.ingress()
+    if not root.sampled:
+        yield None
+        return
+    own = (tracer, (root,))
+    t0 = time.time()
+    p0 = time.perf_counter()
+    tok = _BATCH.set(own)
+    try:
+        yield own
+    finally:
+        _BATCH.reset(tok)
+        close_span(own, name, t0, time.perf_counter() - p0, **(attrs or {}))

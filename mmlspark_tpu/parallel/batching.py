@@ -57,7 +57,7 @@ class DevicePrefetcher:
     _DONE = object()
 
     def __init__(self, it: Iterator, put: Optional[Callable] = None,
-                 depth: int = 2):
+                 depth: int = 2, name: str = "device-prefetch"):
         import queue
         import threading
 
@@ -92,8 +92,9 @@ class DevicePrefetcher:
             finally:
                 offer(self._DONE)
 
+        # the thread's name is what its spans carry (obs/trace.py Span)
         self._thread = threading.Thread(target=produce, daemon=True,
-                                        name="device-prefetch")
+                                        name=name)
         self._thread.start()
 
     def close(self) -> None:

@@ -26,6 +26,7 @@ e2e section. The ring is generic — anything shaped
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import time
 from collections import deque
 from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
@@ -837,7 +838,8 @@ def _tree_nbytes(item: Any) -> int:
 
 
 def timed_stage(put: Optional[Callable], item: Any,
-                obs: Optional[tuple] = None) -> Tuple[Any, "BatchTiming"]:
+                obs: Optional[tuple] = None,
+                batch: Optional[int] = None) -> Tuple[Any, "BatchTiming"]:
     """Stage one host batch toward the device with ingest accounting: fires
     the INGEST_H2D chaos seam, runs ``put`` (the H2D transfer), blocks until
     the staged arrays are device-resident, and returns (staged, timing) with
@@ -849,7 +851,8 @@ def timed_stage(put: Optional[Callable], item: Any,
     trace binding (obs.trace.current_batch), captured by the CALLER on the
     transform thread because this often runs on the ring's producer thread,
     which does not inherit the contextvar. When set, the H2D transfer is
-    recorded as an ``h2d`` span on every traced request in the batch."""
+    recorded as an ``h2d`` span on every traced request in the batch
+    (``batch``: the batch's ordinal in the call, when the caller counts)."""
     timing = BatchTiming(bytes_in=_tree_nbytes(item), rows=_tree_rows(item),
                          padded_rows=_tree_padded(item))
     # slot-staged batches (SlotPool) carry their lease: the transfer window
@@ -885,8 +888,10 @@ def timed_stage(put: Optional[Callable], item: Any,
         slot.transfer_end()
     if obs is not None:
         tracer, ctxs = obs
-        tracer.record_batch("h2d", ctxs, t_wall, timing.h2d_s,
-                            bytes=timing.bytes_in, rows=timing.rows)
+        attrs = {"bytes": timing.bytes_in, "rows": timing.rows}
+        if batch is not None:
+            attrs["batch"] = batch
+        tracer.record_batch("h2d", ctxs, t_wall, timing.h2d_s, **attrs)
     return staged, timing
 
 
@@ -928,7 +933,8 @@ class TransferRing:
                  step: Optional[Callable] = None,
                  fetch: Optional[Callable] = None,
                  depth: int = 2, prefetch: Optional[int] = None,
-                 stats: Optional[IngestStats] = None):
+                 stats: Optional[IngestStats] = None,
+                 obs: Optional[tuple] = None, batch0: int = 0):
         if depth <= 0:
             raise ValueError("depth must be positive")
         self.depth = depth
@@ -939,14 +945,21 @@ class TransferRing:
         self._fetch = fetch if fetch is not None else _default_fetch
         self._user_put = put
 
-        # capture the serving batch's trace binding HERE (the ring is built
-        # on the transform thread, inside obs.trace.batch_context); the
-        # producer thread the prefetcher spawns would see an empty context
+        # the trace binding the ring's phases record under: the caller's
+        # open span (``obs``: a fused partition's), else the bound batch's,
+        # captured HERE (the ring is built on the transform thread, inside
+        # obs.trace.batch_context); the producer thread the prefetcher
+        # spawns would see an empty context. Every phase is clocked once:
+        # the reads that fill BatchTiming are the span's too. ``batch0`` is
+        # the call's batches before this ring's first (spans' ``batch``).
         from ..obs.trace import current_batch
 
-        obs = current_batch()
+        self._obs = obs = obs if obs is not None else current_batch()
+        self._batch0 = int(batch0)
+        staged_no = itertools.count(self._batch0)
         self._prefetch = DevicePrefetcher(
-            it, put=lambda item: timed_stage(put, item, obs=obs),
+            it, put=lambda item: timed_stage(put, item, obs=obs,
+                                             batch=next(staged_no)),
             depth=max(1, prefetch or depth))
 
     def close(self) -> None:
@@ -955,19 +968,27 @@ class TransferRing:
     def __iter__(self):
         inflight: "deque" = deque()
         src = iter(self._prefetch)
+        obs = self._obs
+        batch = self._batch0
         wall0 = time.perf_counter()
         try:
             while True:
+                w0 = time.time() if obs is not None else 0.0
                 tq = time.perf_counter()
                 try:
                     staged, timing = next(src)
                 except StopIteration:
                     break
                 timing.queue_s = time.perf_counter() - tq
-                td = time.perf_counter()
-                handle = self._step(staged)
-                timing.dispatch_s = time.perf_counter() - td
-                inflight.append((handle, timing))
+                watcher = None
+                if obs is not None:
+                    obs[0].record_batch("queue", obs[1], w0, timing.queue_s,
+                                        batch=batch)
+                handle = timed_dispatch(self._step, staged, timing, obs, batch)
+                if obs is not None:
+                    watcher = watch_in_flight(obs, handle, batch)
+                inflight.append((handle, timing, batch, watcher))
+                batch += 1
                 if hasattr(self.stats, "note_occupancy"):
                     self.stats.note_occupancy(len(inflight))
                 if len(inflight) >= self.depth:
@@ -979,7 +1000,9 @@ class TransferRing:
             self.close()
 
     def _drain(self, inflight: "deque"):
-        handle, timing = inflight.popleft()
+        handle, timing, batch, watcher = inflight.popleft()
+        obs = self._obs
+        w0 = time.time() if obs is not None else 0.0
         t0 = time.perf_counter()
         _block_ready(handle)
         t1 = time.perf_counter()
@@ -987,7 +1010,77 @@ class TransferRing:
         out = self._fetch(handle)
         timing.readback_s = time.perf_counter() - t1
         self.stats.record(timing)
+        if obs is not None:
+            record_drain(obs, timing, w0, batch, _tree_nbytes(out))
+            # the batch was ready a readback ago: its watcher has recorded,
+            # or is about to; no span of a call lands after the call
+            watcher.join()
         return out
+
+
+def timed_dispatch(step: Callable, staged: Any, timing: BatchTiming,
+                   obs: Optional[tuple], batch: int) -> Any:
+    """One dispatch, clocked once into ``timing.dispatch_s`` and — under a
+    trace binding — into a ``dispatch`` span that is bound around the step,
+    so a build on a CompileCache miss records as its child. Shared by the
+    ring and the fused submit path."""
+    if obs is None:
+        td = time.perf_counter()
+        handle = step(staged)
+        timing.dispatch_s = time.perf_counter() - td
+        return handle
+    from ..obs.trace import batch_context, close_span, open_span
+
+    own = open_span(obs)
+    w0 = time.time()
+    td = time.perf_counter()
+    with batch_context(*own):
+        handle = step(staged)
+    timing.dispatch_s = time.perf_counter() - td
+    close_span(own, "dispatch", w0, timing.dispatch_s, batch=batch)
+    return handle
+
+
+def watch_in_flight(obs: tuple, handle: Any, batch: int):
+    """An ``in_flight`` span for one dispatched batch: from now until its
+    outputs are ready, as a short-lived thread of its own sees it (it only
+    sleeps in block-until-ready, off the GIL). The consumer looks at a batch
+    when it drains it, which with a ring two deep is after the NEXT batch's
+    wait and the PREVIOUS batch's readback — long after the device finished;
+    ``compute_wait`` then reads 0 and says nothing of when. This span's end
+    is the one host-clock instant that follows the device's last operation
+    of the batch closely, which is what lets a device trace be laid on the
+    spans' clock (benchmarks/harness/spans.py). Only under a trace binding;
+    a failing step is the drain's to raise, not this thread's. Returns the
+    thread, which the drain joins."""
+    import threading
+
+    w0, p0 = time.time(), time.perf_counter()
+
+    def wait():
+        try:
+            _block_ready(handle)
+        except BaseException:  # noqa: BLE001 - surfaces at the drain
+            return
+        obs[0].record_batch("in_flight", obs[1], w0,
+                            time.perf_counter() - p0, batch=batch)
+
+    watcher = threading.Thread(target=wait, daemon=True, name="device-watch")
+    watcher.start()
+    return watcher
+
+
+def record_drain(obs: tuple, timing: BatchTiming, w0: float, batch: int,
+                 nbytes: int) -> None:
+    """The drain's two phases as spans, from the clock reads that filled
+    ``timing``: ``compute_wait`` (block-until-ready, from ``w0``) and
+    ``readback`` right behind it. Shared by the ring and the fused submit
+    path's ``resolve()``."""
+    tracer, ctxs = obs
+    tracer.record_batch("compute_wait", ctxs, w0, timing.compute_s,
+                        batch=batch)
+    tracer.record_batch("readback", ctxs, w0 + timing.compute_s,
+                        timing.readback_s, batch=batch, bytes=nbytes)
 
 
 #: lazily probed: does this backend's device_put ALIAS aligned host numpy

@@ -41,6 +41,7 @@ PACKAGES=(
   "tests/test_codegen_cli.py tests/test_rgen.py tests/test_plot.py tests/test_datagen.py"
   "tests/test_analysis.py"
   "tests/test_observability.py"
+  "tests/test_transform_spans.py"
   "tests/test_perf_attribution.py"
   "tests/test_autotune.py"
   "tests/test_ingest_zero_copy.py"

@@ -1,9 +1,11 @@
 """Stage-level wall breakdown of the 10M-row dense GBDT training run.
 
 Times each pipeline stage separately (data gen excluded): BinMapper.fit,
-transform, feature-major transpose, H2D, and the scan itself (via
-MMLSPARK_TPU_GBDT_TIMING). Drives the verdict item 'profile the 10M dense
-run, then attack the top cost'.
+transform, feature-major transpose, H2D, and the fit's own phases from the
+spans it records under its root ``fit`` (obs/trace.py: ``gbdt:bin_fit``,
+``gbdt:bins``, ``gbdt:scan`` / ``gbdt:scan_chunk`` / ``gbdt:fetch``,
+``gbdt:trees``). Drives the verdict item 'profile the 10M dense run, then
+attack the top cost'.
 """
 
 import os
@@ -11,7 +13,20 @@ import time
 
 import numpy as np
 
-os.environ.setdefault("MMLSPARK_TPU_GBDT_TIMING", "1")
+
+def print_fit_spans(spans):
+    """One line a span of the last fit, children indented under parents."""
+    roots = [s for s in spans if s["name"] == "fit" and not s["parent_id"]]
+    if not roots:
+        return
+    mine = [s for s in spans if s["trace_id"] == roots[-1]["trace_id"]]
+    by_id = {s["span_id"]: s for s in mine}
+    for s in sorted(mine, key=lambda s: s["t0"]):
+        depth, at = 0, s
+        while at["parent_id"] in by_id:
+            at, depth = by_id[at["parent_id"]], depth + 1
+        print(f"  {'  ' * depth}{s['name']} {s['dur_s']:.3f}s {s['attrs']}",
+              flush=True)
 
 
 def main():
@@ -53,10 +68,13 @@ def main():
     params = TrainParams(objective="binary", num_iterations=iters,
                          num_leaves=31, learning_rate=0.1,
                          min_data_in_leaf=20, max_bin=255, seed=0)
+    from mmlspark_tpu.obs.trace import default_tracer
+
     for run in range(int(os.environ.get("RUNS", "2"))):
         t0 = time.perf_counter()
         train(params, X, y)
         print(f"run{run} total {time.perf_counter()-t0:.1f}s", flush=True)
+        print_fit_spans(default_tracer().spans())
 
 
 if __name__ == "__main__":
