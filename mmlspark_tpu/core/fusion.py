@@ -661,9 +661,17 @@ class SegmentExecutor:
                 mine = {c: sub[c] for c in dfn.in_cols
                         if c in sub and c not in written}
                 if mine:
-                    with batch_span(obs, f"prepare:{type(stage).__name__}",
-                                    rows=int(valid.sum())):
+                    # what the hook leaves under "span_attrs" (the image
+                    # stages: how the column was resized) rides on its span
+                    own = open_span(obs)
+                    w0, t0 = time.time(), time.perf_counter()
+                    try:
                         sub.update(dfn.prepare(mine, ctx))
+                    finally:
+                        close_span(own, f"prepare:{type(stage).__name__}",
+                                   w0, time.perf_counter() - t0,
+                                   rows=int(valid.sum()),
+                                   **ctx.pop("span_attrs", {}))
             written |= set(dfn.out_cols)
         # prep can null rows (decode failures): shrink validity like dropNa
         n_valid = int(valid.sum())

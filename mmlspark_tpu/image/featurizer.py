@@ -34,6 +34,40 @@ from ..models.dnn_model import DNNModel
 from ..models.module import FunctionModel
 from ..ops import image as ops
 from ..parallel.ingest import PreprocessSpec
+from .stages import _resize_column
+
+
+def _model_input_rows(col, h: int, w: int, c: int, ctx=None) -> np.ndarray:
+    """Rows of an image column (encoded bytes, image structs, HWC arrays or
+    unrolled CHW vectors) -> contiguous ``[h, w, c]`` arrays in the decoded
+    dtype, None where a row is null or does not decode: decode, resize (the
+    whole column in one call where it can be: ``stages._resize_column``, which
+    also leaves the `prepare` span's attributes in ``ctx``), channel fix.
+    Reference ImageFeaturizer.scala:141-165 (auto-resize)."""
+    out = np.empty(len(col), dtype=object)
+    for i, row in enumerate(col):
+        if row is None:
+            continue
+        if isinstance(row, (bytes, bytearray)):
+            out[i] = ops.decode_image(bytes(row))
+        elif ImageSchema.is_image(row):
+            out[i] = ImageSchema.to_array(row)
+        else:
+            img = np.asarray(row)
+            if img.ndim == 1:  # unrolled CHW vector
+                img = np.moveaxis(img.reshape(c, h, w), 0, -1)
+            out[i] = img
+    _resize_column(out, h, w, ctx)
+    for i, img in enumerate(out):
+        if img is None:
+            continue
+        if img.ndim == 2:
+            img = img[:, :, None]
+        if img.shape[2] != c:
+            img = (np.repeat(img[:, :, :1], c, axis=2) if img.shape[2] < c
+                   else img[:, :, :c])
+        out[i] = np.ascontiguousarray(img)
+    return out
 
 
 class ImageFeaturizer(Transformer, HasInputCol, HasOutputCol):
@@ -104,31 +138,11 @@ class ImageFeaturizer(Transformer, HasInputCol, HasOutputCol):
         #    reference ImageFeaturizer.scala:141-165); dtype is preserved
         #    (wire dtype) unless hostPreprocess is set
         def prep(part):
-            col = part[in_col]
-            out = np.empty(len(col), dtype=object)
-            for i, row in enumerate(col):
-                img = None
-                if row is None:
-                    pass
-                elif isinstance(row, (bytes, bytearray)):
-                    img = ops.decode_image(bytes(row))
-                elif ImageSchema.is_image(row):
-                    img = ImageSchema.to_array(row)
-                else:
-                    img = np.asarray(row)
-                    if img.ndim == 1:  # unrolled CHW vector
-                        img = np.moveaxis(img.reshape(c, h, w), 0, -1)
-                if img is None:
-                    out[i] = None
-                    continue
-                img = ops.resize(img, h, w)
-                if img.ndim == 2:
-                    img = img[:, :, None]
-                if img.shape[2] != c:
-                    img = (np.repeat(img[:, :, :1], c, axis=2) if img.shape[2] < c
-                           else img[:, :, :c])
-                out[i] = spec.apply_host_row(img) if host_pre \
-                    else np.ascontiguousarray(img)
+            out = _model_input_rows(part[in_col], h, w, c)
+            if host_pre:
+                for i, img in enumerate(out):
+                    if img is not None:
+                        out[i] = spec.apply_host_row(img)
             return out
 
         prepped = df.with_column("__dnn_input__", prep)
@@ -188,34 +202,10 @@ class ImageFeaturizer(Transformer, HasInputCol, HasOutputCol):
                node, spec.cache_key(), h, w, c)
 
         def prepare(cols, ctx):
-            # the unfused per-row prep (decode -> resize -> channel fix);
-            # the spec runs on DEVICE in both hostPreprocess modes — its ops
-            # are exact, so the wire stays the decoded dtype
-            col = cols[in_col]
-            out = np.empty(len(col), dtype=object)
-            for i, row in enumerate(col):
-                img = None
-                if row is None:
-                    pass
-                elif isinstance(row, (bytes, bytearray)):
-                    img = ops.decode_image(bytes(row))
-                elif ImageSchema.is_image(row):
-                    img = ImageSchema.to_array(row)
-                else:
-                    img = np.asarray(row)
-                    if img.ndim == 1:
-                        img = np.moveaxis(img.reshape(c, h, w), 0, -1)
-                if img is None:
-                    out[i] = None
-                    continue
-                img = ops.resize(img, h, w)
-                if img.ndim == 2:
-                    img = img[:, :, None]
-                if img.shape[2] != c:
-                    img = (np.repeat(img[:, :, :1], c, axis=2)
-                           if img.shape[2] < c else img[:, :, :c])
-                out[i] = np.ascontiguousarray(img)
-            return {in_col: out}
+            # the unfused prep (decode -> resize -> channel fix); the spec
+            # runs on DEVICE in both hostPreprocess modes — its ops are
+            # exact, so the wire stays the decoded dtype
+            return {in_col: _model_input_rows(cols[in_col], h, w, c, ctx)}
 
         def accepts(probes):
             p = probes.get(in_col)

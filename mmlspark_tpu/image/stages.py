@@ -86,10 +86,33 @@ def _apply_device_op(x, op):
     raise FusionUnsupported(f"image op {kind!r} has no device mirror")
 
 
-def _image_rows_to_arrays(col, apply_host_ops=None):
+def _resize_column(imgs, height, width, ctx=None):
+    """Resize the non-None rows of ``imgs`` in place: one native call for the
+    column where ``ops.resize_rows`` takes it (the rows then are views of one
+    array, side by side), else ``ops.resize`` row by row — bitwise the same
+    pixels either way. A `prepare` hook's ``ctx`` gets what its span reports:
+    ``resized_rows`` (rows the column call took; 0 = the per-row path) and
+    ``resize_threads`` (0 = nothing was computed: rows already that size)."""
+    some = [i for i, img in enumerate(imgs) if img is not None]
+    resized = ops.resize_rows([imgs[i] for i in some], height, width)
+    if resized is None:
+        for i in some:
+            imgs[i] = ops.resize(imgs[i], height, width)
+    else:
+        for k, i in enumerate(some):
+            imgs[i] = resized[k]
+    if ctx is not None:
+        computed = isinstance(resized, np.ndarray)
+        ctx["span_attrs"] = {
+            "resized_rows": 0 if resized is None else len(some),
+            "resize_threads": ops.resize_threads(len(some)) if computed else 0}
+
+
+def _image_rows_to_arrays(col, apply_host_ops=None, resize_to=None, ctx=None):
     """Struct/array rows -> (array rows, origins): the unfused per-row host
     path (ImageSchema.to_array + optional host ops), shared by the fusion
-    `prepare` hooks below."""
+    `prepare` hooks below. ``resize_to`` = (height, width): a resize that
+    comes before ``apply_host_ops``, made for the whole column at once."""
     out = np.empty(len(col), dtype=object)
     origins = np.empty(len(col), dtype=object)
     for i, row in enumerate(col):
@@ -97,12 +120,15 @@ def _image_rows_to_arrays(col, apply_host_ops=None):
             out[i] = None
             origins[i] = ""
             continue
-        img = ImageSchema.to_array(row) if ImageSchema.is_image(row) \
+        out[i] = ImageSchema.to_array(row) if ImageSchema.is_image(row) \
             else np.asarray(row)
         origins[i] = row.get("origin", "") if isinstance(row, dict) else ""
-        if apply_host_ops is not None:
-            img = apply_host_ops(img)
-        out[i] = np.asarray(img)
+    if resize_to is not None:
+        _resize_column(out, *resize_to, ctx=ctx)
+    if apply_host_ops is not None:
+        for i, img in enumerate(out):
+            if img is not None:
+                out[i] = np.asarray(apply_host_ops(img))
     return out, origins
 
 
@@ -251,14 +277,20 @@ class ImageTransformer(Transformer, HasInputCol, HasOutputCol):
         key = ("ImageTransformer", in_col, out_col,
                tuple(tuple(sorted(op.items())) for op in op_list))
 
+        # a resize at the head of the host chain is made for the column
+        head = host_ops[0] if host_ops and host_ops[0]["op"] == "resize" else None
+        rest = host_ops[1:] if head else host_ops
+
         def prepare(cols, ctx):
             def host_chain(img):
-                for op in host_ops:
+                for op in rest:
                     img = self._apply_op(img, op)
                 return img
 
             rows, origins = _image_rows_to_arrays(
-                cols[in_col], host_chain if host_ops else None)
+                cols[in_col], host_chain if rest else None,
+                resize_to=(head["height"], head["width"]) if head else None,
+                ctx=ctx)
             ctx[f"origins:{in_col}"] = origins
             if out_col != in_col:
                 ctx[f"origins:{out_col}"] = origins
@@ -332,8 +364,7 @@ class ResizeImageTransformer(Transformer, HasInputCol, HasOutputCol):
         nch = self.get("nChannels")
         key = ("ResizeImageTransformer", in_col, out_col, h, w, nch)
 
-        def host_resize(img):
-            img = ops.resize(img, h, w)
+        def fix_channels(img):
             if nch == 1 and (img.ndim == 3 and img.shape[2] != 1):
                 img = ops.color_format(img, "gray")
             elif nch == 3 and (img.ndim == 2 or img.shape[2] == 1):
@@ -341,7 +372,9 @@ class ResizeImageTransformer(Transformer, HasInputCol, HasOutputCol):
             return img
 
         def prepare(cols, ctx):
-            rows, origins = _image_rows_to_arrays(cols[in_col], host_resize)
+            rows, origins = _image_rows_to_arrays(
+                cols[in_col], fix_channels if nch in (1, 3) else None,
+                resize_to=(h, w), ctx=ctx)
             ctx[f"origins:{in_col}"] = origins
             if out_col != in_col:
                 ctx[f"origins:{out_col}"] = origins

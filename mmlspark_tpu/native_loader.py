@@ -15,7 +15,7 @@ import logging
 import os
 import subprocess
 import threading
-from typing import List, Optional
+from typing import List, Optional, Sequence
 
 import numpy as np
 
@@ -26,7 +26,7 @@ _REPO_ROOT = os.path.dirname(_PKG_DIR)
 _NATIVE_DIR = os.path.join(_REPO_ROOT, "native")
 
 
-_ABI_VERSION = 5
+_ABI_VERSION = 6
 
 
 def _host_tag() -> str:
@@ -105,9 +105,10 @@ def _build() -> bool:
     # -ffp-contract=off: no FMA contraction — the predict paths are
     # documented (and test-gated) bit-equal to the numpy references, and
     # contraction changes their rounding by 1 ulp
+    # (native/Makefile carries the same flags)
     cmd = ["g++", "-O3", "-march=native", "-ffp-contract=off",
-           "-funroll-loops", "-fPIC", "-shared", "-std=c++17", "-o", tmp,
-           src]
+           "-funroll-loops", "-fPIC", "-shared", "-std=c++17", "-pthread",
+           "-o", tmp, src]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         os.replace(tmp, _SO_PATH)
@@ -193,12 +194,11 @@ def _declare(lib: ctypes.CDLL) -> None:
     lib.mml_murmur3_32.argtypes = [u8p, ctypes.c_int32, ctypes.c_uint32]
     lib.mml_murmur3_batch.argtypes = [u8p, i64p, ctypes.c_int64,
                                       ctypes.c_uint32, u32p]
-    lib.mml_resize_bilinear_f32.argtypes = [f32p, ctypes.c_int32, ctypes.c_int32,
-                                            ctypes.c_int32, f32p,
-                                            ctypes.c_int32, ctypes.c_int32]
-    lib.mml_resize_bilinear_u8.argtypes = [u8p, ctypes.c_int32, ctypes.c_int32,
-                                           ctypes.c_int32, u8p,
-                                           ctypes.c_int32, ctypes.c_int32]
+    lib.mml_resize_bilinear_rows.restype = None
+    lib.mml_resize_bilinear_rows.argtypes = [
+        ctypes.POINTER(ctypes.c_void_p), i32p, i32p, ctypes.c_int64,
+        ctypes.c_int32, ctypes.c_int32, ctypes.c_void_p, ctypes.c_int32,
+        ctypes.c_int32, ctypes.c_int32]
     lib.mml_unroll_chw_f64.argtypes = [u8p, ctypes.c_int32, ctypes.c_int32,
                                        ctypes.c_int32, f64p, ctypes.c_int32]
     lib.mml_histogram.argtypes = [i32p, f32p, f32p, u8p, ctypes.c_int64,
@@ -261,24 +261,45 @@ def murmur3_batch(strings: List[str], seed: int = 0) -> Optional[np.ndarray]:
     return out.astype(np.int64)
 
 
-def resize_bilinear(img: np.ndarray, oh: int, ow: int) -> Optional[np.ndarray]:
+def resize_bilinear_rows(rows: Sequence[np.ndarray], oh: int, ow: int,
+                         threads: int = 1) -> Optional[np.ndarray]:
+    """Bilinear resize of HWC rows into ONE ``[n, oh, ow, c]`` array, in one
+    native call. The rows share a channel count and a pixel type (uint8 or
+    float32; the caller checks) and may differ in height and width.
+    ``threads`` workers share the rows inside the call, which holds no GIL."""
     lib = load()
     if lib is None:
         return None
-    img = np.ascontiguousarray(img)
+    rows = [np.ascontiguousarray(r) for r in rows]  # kept alive over the call
+    n = len(rows)
+    shape, dt = (rows[0].shape, rows[0].dtype) if n else ((), None)
+    if len(shape) != 3 or dt not in (np.uint8, np.float32) \
+            or min(oh, ow) < 1 or any(
+                r.ndim != 3 or r.dtype != dt or r.shape[2] != shape[2]
+                or 0 in r.shape for r in rows):
+        raise ValueError("resize_bilinear_rows: non-empty HWC rows of one "
+                         "channel count, all uint8 or all float32")
+    c = shape[2]
+    srcs = (ctypes.c_void_p * n)(*[r.ctypes.data for r in rows])
+    hs = np.array([r.shape[0] for r in rows], dtype=np.int32)
+    ws = np.array([r.shape[1] for r in rows], dtype=np.int32)
+    dst = np.empty((n, oh, ow, c), dtype=dt)
+    lib.mml_resize_bilinear_rows(
+        srcs, _ptr(hs, ctypes.c_int32), _ptr(ws, ctypes.c_int32), n, c,
+        int(dt == np.float32), dst.ctypes.data, oh, ow,
+        max(1, min(int(threads), n)))
+    return dst
+
+
+def resize_bilinear(img: np.ndarray, oh: int, ow: int) -> Optional[np.ndarray]:
+    """One image through the column kernel (n = 1): same code, same bits."""
+    img = np.asarray(img)
     if img.ndim == 2:
         img = img[:, :, None]
-    h, w, c = img.shape
-    if img.dtype == np.uint8:
-        dst = np.empty((oh, ow, c), dtype=np.uint8)
-        lib.mml_resize_bilinear_u8(_ptr(img, ctypes.c_uint8), h, w, c,
-                                   _ptr(dst, ctypes.c_uint8), oh, ow)
-        return dst
-    src = np.ascontiguousarray(img, dtype=np.float32)
-    dst = np.empty((oh, ow, c), dtype=np.float32)
-    lib.mml_resize_bilinear_f32(_ptr(src, ctypes.c_float), h, w, c,
-                                _ptr(dst, ctypes.c_float), oh, ow)
-    return dst
+    if img.dtype != np.uint8:
+        img = img.astype(np.float32, copy=False)
+    out = resize_bilinear_rows([img], oh, ow)
+    return None if out is None else out[0]
 
 
 def unroll_chw(img: np.ndarray, normalize: bool = False) -> Optional[np.ndarray]:

@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import io
 import math
+import os
 import struct
 from typing import Optional, Sequence, Tuple
 
@@ -99,7 +100,12 @@ def encode_ppm(img: np.ndarray) -> bytes:
 
 
 def resize(img: np.ndarray, height: int, width: int, method: str = "linear") -> np.ndarray:
-    """Host-side single-image resize (C++ bilinear when built, numpy fallback)."""
+    """Host-side single-image resize: half-pixel-centre bilinear, uint8 rounded
+    half to even. uint8 and float32 images go through the C++ kernel when it
+    is built (float64 arithmetic; the kernel ``resize_rows`` uses, with n = 1);
+    otherwise, and for every other dtype, the numpy fallback computes the same
+    formula in float32. The two may differ by one uint8 level where the exact
+    value is a tie. An image already ``height`` x ``width`` is returned as it is."""
     img = np.asarray(img)
     squeeze = img.ndim == 2
     if squeeze:
@@ -119,6 +125,45 @@ def resize(img: np.ndarray, height: int, width: int, method: str = "linear") -> 
     else:
         out = _resize_numpy(img, height, width)
     return out[:, :, 0] if squeeze else out
+
+
+def resize_threads(n_rows: int) -> int:
+    """Threads ``resize_rows`` gives a column of ``n_rows``: the cores this
+    process may run on, at most one a 64 rows (a served request of a few rows
+    starts none) and at most 16."""
+    return max(1, min(len(os.sched_getaffinity(0)), n_rows // 64, 16))
+
+
+def resize_rows(rows: Sequence[np.ndarray], height: int, width: int):
+    """Bilinear resize of an image column in one native call: ``out[i]`` is
+    bitwise ``resize(rows[i], height, width)``, and the rows of ``out`` lie
+    side by side in one ``[n, height, width(, c)]`` array, so a batch of them
+    is one bulk copy. Source heights and widths may differ from row to row.
+
+    A column whose rows are all ``height`` x ``width`` already comes back as
+    ``rows`` itself (nothing to compute, nothing copied). ``None`` when the
+    column is not eligible — no native library, no rows, a row that is not a
+    uint8 or float32 image array, mixed dtypes, ranks or channel counts —
+    and the caller resizes row by row."""
+    first = rows[0] if len(rows) else None
+    if not isinstance(first, np.ndarray) or first.ndim not in (2, 3) \
+            or first.dtype not in (np.uint8, np.float32):
+        return None
+    ndim, dt, ch = first.ndim, first.dtype, first.shape[2:]
+    presized = True
+    for r in rows:
+        if not isinstance(r, np.ndarray) or r.ndim != ndim or r.dtype != dt \
+                or r.shape[2:] != ch or 0 in r.shape:
+            return None
+        presized = presized and r.shape[:2] == (height, width)
+    if presized:
+        return rows
+    from .. import native_loader
+
+    out = native_loader.resize_bilinear_rows(
+        rows if ndim == 3 else [r[:, :, None] for r in rows], height, width,
+        threads=resize_threads(len(rows)))
+    return out if out is None or ndim == 3 else out[:, :, :, 0]
 
 
 def _resize_numpy(img: np.ndarray, height: int, width: int) -> np.ndarray:

@@ -14,8 +14,11 @@
 #include <cstring>
 #include <cmath>
 #include <algorithm>
+#include <atomic>
 #include <limits>
 #include <queue>
+#include <system_error>
+#include <thread>
 #include <vector>
 
 extern "C" {
@@ -64,9 +67,87 @@ void mml_murmur3_batch(const uint8_t* buf, const int64_t* offsets, int64_t n,
 // Image preprocessing (OpenCV-imgproc replacement for the host pipeline)
 // ---------------------------------------------------------------------------
 
-// Half-pixel-center bilinear resize, HWC float32 (matches ops/image._bilinear).
-void mml_resize_bilinear_f32(const float* src, int32_t h, int32_t w, int32_t c,
-                             float* dst, int32_t oh, int32_t ow) {
+// Half-pixel-centre bilinear resize of an image column: n HWC source rows
+// (each its own pointer, height and width; one channel count and pixel type)
+// into ONE contiguous [n, oh, ow, c] output. All arithmetic is float64 with
+// contraction off, in the order
+//     top = tl * (1 - wx) + tr * wx        (source row y0; bot: row y1)
+//     v   = top * (1 - wy) + bot * wy
+// and uint8 output is nearbyint (half to even) then clamped. The numpy
+// fallback (ops/image._bilinear) computes the same formula in float32 and may
+// differ from this by one level where v lands on a tie.
+//
+// Two passes a row: `top`/`bot` are exactly the horizontally interpolated
+// source rows y0/y1, so each needed source row is interpolated ONCE into a
+// float64 row (two rolling buffers: y1 <= y0 + 1 and y0 never decreases),
+// then blended vertically in a contiguous loop the compiler vectorises. The
+// x tables depend on the source width alone and are rebuilt only when it
+// changes from one row to the next.
+}  // extern "C"
+
+namespace {
+
+struct ResizeXTables {
+    int32_t w = -1;
+    std::vector<int32_t> i0, i1;   // element offsets x0*c+ch, x1*c+ch
+    std::vector<double> w0, w1;    // 1 - wx, wx (per element, so pass 1 is flat)
+    void build(int32_t src_w, int32_t ow, int32_t c) {
+        w = src_w;
+        const size_t m = (size_t)ow * c;
+        i0.resize(m); i1.resize(m); w0.resize(m); w1.resize(m);
+        for (int32_t ox = 0; ox < ow; ox++) {
+            const double fx = ((double)ox + 0.5) * src_w / ow - 0.5;
+            int32_t x0 = (int32_t)std::floor(fx);
+            double wx = fx - x0;
+            if (x0 < 0) { x0 = 0; wx = 0.0; }
+            if (x0 > src_w - 1) { x0 = src_w - 1; wx = 0.0; }
+            const int32_t x1 = std::min(x0 + 1, src_w - 1);
+            if (wx < 0) wx = 0;
+            if (wx > 1) wx = 1;
+            for (int32_t ch = 0; ch < c; ch++) {
+                const size_t j = (size_t)ox * c + ch;
+                i0[j] = x0 * c + ch; i1[j] = x1 * c + ch;
+                w0[j] = 1 - wx; w1[j] = wx;
+            }
+        }
+    }
+};
+
+inline void resize_store(double v, float* d) { *d = (float)v; }
+inline void resize_store(double v, uint8_t* d) {
+    v = std::nearbyint(v);
+    if (v < 0) v = 0;
+    if (v > 255) v = 255;
+    *d = (uint8_t)v;
+}
+
+template <typename T>
+void resize_one(const T* src, int32_t h, int32_t w, int32_t c, T* dst,
+                int32_t oh, int32_t ow, ResizeXTables& xt,
+                std::vector<double>& rowbuf) {
+    const size_t m = (size_t)ow * c;
+    if (h == oh && w == ow) {   // every weight is 0 or 1: the row as it is
+        std::memcpy(dst, src, m * oh * sizeof(T));
+        return;
+    }
+    if (xt.w != w) xt.build(w, ow, c);
+    rowbuf.resize(2 * m);
+    double* const buf[2] = {rowbuf.data(), rowbuf.data() + m};
+    int32_t held[2] = {-1, -1};   // the source row each buffer holds
+    const int32_t* const i0 = xt.i0.data();
+    const int32_t* const i1 = xt.i1.data();
+    const double* const w0 = xt.w0.data();
+    const double* const w1 = xt.w1.data();
+    auto hrow = [&](int32_t y) -> const double* {
+        double* const b = buf[y & 1];
+        if (held[y & 1] != y) {
+            const T* const s = src + (size_t)y * w * c;
+            for (size_t j = 0; j < m; j++)
+                b[j] = (double)s[i0[j]] * w0[j] + (double)s[i1[j]] * w1[j];
+            held[y & 1] = y;
+        }
+        return b;
+    };
     for (int32_t oy = 0; oy < oh; oy++) {
         const double fy = ((double)oy + 0.5) * h / oh - 0.5;
         int32_t y0 = (int32_t)std::floor(fy);
@@ -74,60 +155,64 @@ void mml_resize_bilinear_f32(const float* src, int32_t h, int32_t w, int32_t c,
         if (y0 < 0) { y0 = 0; wy = 0.0; }
         if (y0 > h - 1) { y0 = h - 1; wy = 0.0; }
         const int32_t y1 = std::min(y0 + 1, h - 1);
-        if (wy < 0) wy = 0; if (wy > 1) wy = 1;
-        for (int32_t ox = 0; ox < ow; ox++) {
-            const double fx = ((double)ox + 0.5) * w / ow - 0.5;
-            int32_t x0 = (int32_t)std::floor(fx);
-            double wx = fx - x0;
-            if (x0 < 0) { x0 = 0; wx = 0.0; }
-            if (x0 > w - 1) { x0 = w - 1; wx = 0.0; }
-            const int32_t x1 = std::min(x0 + 1, w - 1);
-            if (wx < 0) wx = 0; if (wx > 1) wx = 1;
-            for (int32_t ch = 0; ch < c; ch++) {
-                const double tl = src[(y0 * w + x0) * c + ch];
-                const double tr = src[(y0 * w + x1) * c + ch];
-                const double bl = src[(y1 * w + x0) * c + ch];
-                const double br = src[(y1 * w + x1) * c + ch];
-                const double top = tl * (1 - wx) + tr * wx;
-                const double bot = bl * (1 - wx) + br * wx;
-                dst[(oy * ow + ox) * c + ch] = (float)(top * (1 - wy) + bot * wy);
-            }
-        }
+        if (wy < 0) wy = 0;
+        if (wy > 1) wy = 1;
+        const double* const top = hrow(y0);
+        const double* const bot = hrow(y1);
+        const double omwy = 1 - wy;
+        T* const d = dst + (size_t)oy * m;
+        for (size_t j = 0; j < m; j++)
+            resize_store(top[j] * omwy + bot[j] * wy, d + j);
     }
 }
 
-void mml_resize_bilinear_u8(const uint8_t* src, int32_t h, int32_t w, int32_t c,
-                            uint8_t* dst, int32_t oh, int32_t ow) {
-    // u8 path: compute in float, round-clamp (matches numpy path)
-    for (int32_t oy = 0; oy < oh; oy++) {
-        const double fy = ((double)oy + 0.5) * h / oh - 0.5;
-        int32_t y0 = (int32_t)std::floor(fy);
-        double wy = fy - y0;
-        if (y0 < 0) { y0 = 0; wy = 0.0; }
-        if (y0 > h - 1) { y0 = h - 1; wy = 0.0; }
-        const int32_t y1 = std::min(y0 + 1, h - 1);
-        if (wy < 0) wy = 0; if (wy > 1) wy = 1;
-        for (int32_t ox = 0; ox < ow; ox++) {
-            const double fx = ((double)ox + 0.5) * w / ow - 0.5;
-            int32_t x0 = (int32_t)std::floor(fx);
-            double wx = fx - x0;
-            if (x0 < 0) { x0 = 0; wx = 0.0; }
-            if (x0 > w - 1) { x0 = w - 1; wx = 0.0; }
-            const int32_t x1 = std::min(x0 + 1, w - 1);
-            if (wx < 0) wx = 0; if (wx > 1) wx = 1;
-            for (int32_t ch = 0; ch < c; ch++) {
-                const double tl = src[(y0 * w + x0) * c + ch];
-                const double tr = src[(y0 * w + x1) * c + ch];
-                const double bl = src[(y1 * w + x0) * c + ch];
-                const double br = src[(y1 * w + x1) * c + ch];
-                const double top = tl * (1 - wx) + tr * wx;
-                const double bot = bl * (1 - wx) + br * wx;
-                double v = std::nearbyint(top * (1 - wy) + bot * wy);
-                if (v < 0) v = 0; if (v > 255) v = 255;
-                dst[(oy * ow + ox) * c + ch] = (uint8_t)v;
-            }
+template <typename T>
+void resize_rows(const void* const* srcs, const int32_t* hs, const int32_t* ws,
+                 int64_t n, int32_t c, T* dst, int32_t oh, int32_t ow,
+                 int32_t threads) {
+    const size_t out_row = (size_t)oh * ow * c;
+    // rows are handed out in blocks from one counter, so ragged rows and a
+    // core a neighbour took slow nobody but the thread they land on
+    const int64_t block = 8;
+    std::atomic<int64_t> next{0};
+    auto work = [&]() {
+        ResizeXTables xt;
+        std::vector<double> rowbuf;
+        for (;;) {
+            const int64_t lo = next.fetch_add(block);
+            if (lo >= n) return;
+            const int64_t hi = std::min(lo + block, n);
+            for (int64_t i = lo; i < hi; i++)
+                resize_one((const T*)srcs[i], hs[i], ws[i], c,
+                           dst + (size_t)i * out_row, oh, ow, xt, rowbuf);
+        }
+    };
+    std::vector<std::thread> pool;
+    for (int32_t t = 1; t < threads; t++) {
+        try {
+            pool.emplace_back(work);
+        } catch (const std::system_error&) {
+            break;   // no more threads to be had: those that started finish it
         }
     }
+    work();
+    for (auto& th : pool) th.join();
+}
+
+}  // namespace
+
+extern "C" {
+
+// is_f32: 0 = uint8 rows, 1 = float32 rows. threads >= 1 is the caller's
+// (ops/image.resize_threads); one thread starts none.
+void mml_resize_bilinear_rows(const void* const* srcs, const int32_t* hs,
+                              const int32_t* ws, int64_t n, int32_t c,
+                              int32_t is_f32, void* dst, int32_t oh,
+                              int32_t ow, int32_t threads) {
+    if (is_f32)
+        resize_rows<float>(srcs, hs, ws, n, c, (float*)dst, oh, ow, threads);
+    else
+        resize_rows<uint8_t>(srcs, hs, ws, n, c, (uint8_t*)dst, oh, ow, threads);
 }
 
 // HWC uint8 -> flat CHW float64 (UnrollImage hot path).
@@ -809,4 +894,4 @@ extern "C" int32_t mml_gbdt_grow_tree(
     return n_nodes;
 }
 
-extern "C" int32_t mml_version() { return 5; }
+extern "C" int32_t mml_version() { return 6; }

@@ -226,6 +226,103 @@ class TestBitwiseParity:
 
 
 # --------------------------------------------------------------------------
+# the head resize, made for the column in one native call
+# --------------------------------------------------------------------------
+
+
+def typed_image_df(dtype, n=23, parts=2, ragged=True, null_at=None, seed=9):
+    rng = np.random.default_rng(seed)
+    rows = np.empty(n, dtype=object)
+    for i in range(n):
+        shape = (20 + (i % 3 if ragged else 0), 24 - (i % 2 if ragged else 0), 3)
+        img = rng.integers(0, 256, shape, dtype=np.uint8) if dtype == np.uint8 \
+            else (rng.normal(size=shape) * 60).astype(np.float32)
+        rows[i] = ImageSchema.make(img, f"img{i}")
+    if null_at is not None:
+        rows[null_at] = None
+    return DataFrame.from_dict({"image": rows}, num_partitions=parts)
+
+
+def resize_head_chain(head, drop_na=False):
+    return PipelineModel([
+        head, ImageFeaturizer(scaleFactor=1 / 255., batchSize=8, dropNa=drop_na)
+        .set_model(toy_cnn())])
+
+
+class TestColumnResize:
+    @pytest.mark.parametrize("dtype", [np.uint8, np.float32])
+    @pytest.mark.parametrize("ragged", [False, True], ids=["uniform", "ragged"])
+    @pytest.mark.parametrize("head", [
+        lambda: ImageTransformer().resize(16, 16),
+        lambda: ImageTransformer().resize(16, 16).blur(3, 3).flip(1),
+        lambda: ResizeImageTransformer(height=16, width=16),
+    ], ids=["resize", "resize-blur-flip", "ResizeImageTransformer"])
+    def test_fused_equals_unfused_bitwise(self, head, ragged, dtype):
+        df = typed_image_df(dtype, ragged=ragged)
+        pm = resize_head_chain(head())
+        fused = fused_of(pm)
+        assert_bitwise(pm.transform(df), fused.transform(df))
+        assert fused.fusion_stats()["fallbacks"] == []
+
+    def test_a_null_row_is_dropped_before_the_column_call(self):
+        df = typed_image_df(np.uint8, null_at=5)
+        pm = resize_head_chain(ImageTransformer().resize(16, 16), drop_na=True)
+        fused = fused_of(pm)
+        ref, got = pm.transform(df), fused.transform(df)
+        assert ref.count() == got.count() == 22
+        assert_bitwise(ref, got)
+
+    def test_without_the_library_both_take_the_numpy_rows(self, monkeypatch):
+        from mmlspark_tpu import native_loader
+
+        monkeypatch.setattr(native_loader, "load", lambda: None)
+        df = typed_image_df(np.uint8)
+        pm = resize_head_chain(ImageTransformer().resize(16, 16))
+        fused = fused_of(pm)
+        assert_bitwise(pm.transform(df), fused.transform(df))
+        assert fused.fusion_stats()["fallbacks"] == []
+
+    @pytest.mark.parametrize("stage", [
+        ImageTransformer().resize(16, 16),
+        ResizeImageTransformer(height=16, width=16),
+        ImageFeaturizer(scaleFactor=1 / 255.).set_model(toy_cnn()),
+    ], ids=lambda s: type(s).__name__)
+    def test_prepared_rows_fill_a_slot_in_one_copy(self, stage):
+        # what `prepare` hands on are views of one array, side by side: the
+        # slot filler's _spanning_view takes them, so a batch is one memcpy
+        from mmlspark_tpu.core.fusion import SegmentExecutor
+        from mmlspark_tpu.parallel.ingest import (IngestStats, _spanning_view,
+                                                  rows_to_batch)
+
+        df = typed_image_df(np.uint8, n=12, parts=1)
+        dfn = stage.device_fn(df.schema)
+        ctx = {}
+        rows = dfn.prepare({"image": df.collect()["image"]}, ctx)["image"]
+        assert ctx["span_attrs"] == {"resized_rows": 12, "resize_threads": 1}
+        deposit = SegmentExecutor._deposit_rows(rows)
+        assert deposit is not None
+        view = _spanning_view(deposit, deposit[0].shape)
+        assert view is not None and view.shape == (12, 16, 16, 3)
+        assert all(np.shares_memory(view[i], deposit[i]) for i in range(12))
+        stats = IngestStats()
+        batch = rows_to_batch(deposit[2:9], stats=stats)
+        assert stats.zero_copy_batches == 1 and stats.copied_batches == 0
+        assert np.shares_memory(batch, deposit[2])
+
+    def test_a_presized_column_is_handed_on_untouched(self):
+        block = np.random.default_rng(4).integers(0, 256, (6, 16, 16, 3),
+                                                  dtype=np.uint8)
+        col = np.empty(6, dtype=object)
+        for i in range(6):
+            col[i] = ImageSchema.make(block[i], f"img{i}")
+        dfn = ImageTransformer().resize(16, 16).device_fn(None)
+        ctx = {}
+        rows = dfn.prepare({"image": col}, ctx)["image"]
+        assert all(np.shares_memory(rows[i], block[i]) for i in range(6))
+        assert ctx["span_attrs"] == {"resized_rows": 6, "resize_threads": 0}
+
+
+# --------------------------------------------------------------------------
 # planning: splits, demotion, terminal stages
 # --------------------------------------------------------------------------
 

@@ -229,6 +229,72 @@ class TestHostSpans:
         assert host[0]["attrs"] == {"rows": 32}
 
 
+class TestPrepareSpanSaysHowTheColumnWasResized:
+    @staticmethod
+    def prepares(recorder):
+        return [s["attrs"] for s in recorder.spans()
+                if s["name"] == "prepare:ImageTransformer"]
+
+    def test_the_column_call_took_every_row(self, warm_call):
+        attrs = [s["attrs"] for s in warm_call[1]
+                 if s["name"] == "prepare:ImageTransformer"]
+        assert attrs == [{"rows": 16, "resized_rows": 16, "resize_threads": 1}] * 2
+
+    def test_threads_come_with_the_rows(self, recorder, monkeypatch):
+        import os
+
+        monkeypatch.setattr(os, "sched_getaffinity", lambda pid: {0, 1, 2, 3})
+        image_chain(batch=128).transform(image_df(n=200, parts=1))
+        assert self.prepares(recorder) == [
+            {"rows": 200, "resized_rows": 200, "resize_threads": 3}]
+
+    def test_a_null_row_never_reaches_the_hook(self, recorder):
+        df = image_df(n=16, parts=1)
+        col = df.collect()["image"].copy()
+        col[3] = None
+        fused = FusedPipelineModel(
+            [ImageTransformer().resize(16, 16),
+             ImageFeaturizer(scaleFactor=1 / 255., batchSize=8, dropNa=True)
+             .set_model(toy_cnn())], cache=CompileCache())
+        fused.transform(DataFrame.from_dict({"image": col}, num_partitions=1))
+        assert self.prepares(recorder) == [
+            {"rows": 15, "resized_rows": 15, "resize_threads": 1}]
+
+    def test_the_per_row_path_reads_zero(self, recorder, monkeypatch):
+        from mmlspark_tpu import native_loader
+
+        monkeypatch.setattr(native_loader, "load", lambda: None)
+        fused = image_chain()
+        fused.transform(image_df())
+        assert self.prepares(recorder) == [
+            {"rows": 16, "resized_rows": 0, "resize_threads": 0}] * 2
+        assert fused.fusion_stats()["fallbacks"] == []
+
+    def test_presized_rows_are_counted_and_nothing_is_computed(self, recorder):
+        rng = np.random.default_rng(5)
+        block = rng.integers(0, 256, (16, 16, 16, 3), dtype=np.uint8)
+        col = np.empty(16, dtype=object)
+        for i in range(16):
+            col[i] = ImageSchema.make(block[i], f"img{i}")
+        image_chain().transform(DataFrame.from_dict({"image": col},
+                                                    num_partitions=1))
+        assert self.prepares(recorder) == [
+            {"rows": 16, "resized_rows": 16, "resize_threads": 0}]
+
+    def test_a_stage_with_no_resize_adds_nothing(self, recorder):
+        fused = FusedPipelineModel(
+            [ImageTransformer().flip(1),
+             ImageFeaturizer(scaleFactor=1 / 255., batchSize=8)
+             .set_model(toy_cnn(size=20, c=3))], cache=CompileCache())
+        rng = np.random.default_rng(6)
+        col = np.empty(8, dtype=object)
+        for i in range(8):
+            col[i] = ImageSchema.make(
+                rng.integers(0, 256, (20, 20, 3), dtype=np.uint8), "")
+        fused.transform(DataFrame.from_dict({"image": col}, num_partitions=1))
+        assert self.prepares(recorder) == [{"rows": 8}]
+
+
 class TestCompileSpan:
     def test_one_on_a_cache_miss_and_none_warm(self, recorder):
         fused, df = image_chain(), image_df()
