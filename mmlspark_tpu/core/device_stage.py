@@ -139,10 +139,22 @@ class DeviceFn:
     # bitwise-equal (or within the kernel's declared tolerance) to ``fn``
     # over the densified equivalent of the same triple.
     sparse_fn: Optional[Callable] = None
+    # --- handed-through columns (docs/pipeline_fusion.md) ----------------
+    # passthrough: {out col: in col} for every output that ``fn`` returns
+    # as the VERY array it was given (``env[in col]``, untouched: the work
+    # was `prepare`'s). The declaration is checked while the program is
+    # traced — a stage whose ``fn`` returns anything else for that column
+    # fails the build. Where the input was staged from host rows as they
+    # are, the executor then emits the column from those rows and leaves
+    # it out of the program's outputs: nothing is read back to rebuild
+    # bytes the host still holds. Later in-segment stages read the column
+    # on the device as before.
+    passthrough: Optional[Dict[str, str]] = None
 
     def __post_init__(self):
         self.in_cols = tuple(self.in_cols)
         self.out_cols = tuple(self.out_cols)
+        self.passthrough = dict(self.passthrough or {})
         self.sparse_cols = tuple(self.sparse_cols)
         if self.device_outputs is None:
             self.device_outputs = self.out_cols

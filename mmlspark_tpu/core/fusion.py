@@ -172,6 +172,29 @@ class Segment:
                 out.extend((k, i) for k in d.device_finalize_outputs)
         return out
 
+    def host_emit_plan(self) -> Dict[str, str]:
+        """{out col: in col} for the columns this segment can emit from
+        the host rows it staged instead of reading them back: the writer
+        declares the column a ``passthrough`` of an input that is
+        segment-external at that stage (no earlier in-segment writer:
+        what was staged IS what the stage saw), and it is the column's
+        final writer. Whether a partition takes the host copy is the
+        executor's to decide from how the column shipped
+        (``SegmentExecutor._host_emit_cols``); ``readback_plan`` keeps
+        listing the column — it stays a device value of the program for
+        whoever chains onto the segment."""
+        final_writer = {c: i for i, d in enumerate(self.dfns)
+                        for c in d.out_cols}
+        out: Dict[str, str] = {}
+        written: set = set()
+        for i, d in enumerate(self.dfns):
+            for c, src in d.passthrough.items():
+                if (c in d.device_outputs and final_writer.get(c) == i
+                        and src in d.in_cols and src not in written):
+                    out[c] = src
+            written |= set(d.out_cols)
+        return out
+
     def batch_size(self) -> int:
         for s in self.stages:
             if s.has_param("batchSize") and s.get("batchSize"):
@@ -192,6 +215,9 @@ class Segment:
                "batch_size": self.batch_size()}
         if self.stitched:  # key absent on unstitched plans: describe parity
             out["stitched"] = list(self.stitched)
+        host_emit = self.host_emit_plan()
+        if host_emit:  # columns emitted from the staged host rows
+            out["host_emit"] = sorted(host_emit)
         return out
 
 
@@ -457,6 +483,9 @@ class SegmentExecutor:
         # observable: fusion_stats()["devices"])
         self.out_devices: Dict[str, int] = {}
         self._device = None  # set by _put_params on the unsharded path
+        # column -> bytes this run emitted from staged host rows instead of
+        # reading them back (fusion_stats()["host_emit"])
+        self.host_emit: Dict[str, int] = {}
         # batches this run has handed to the device so far: the spans'
         # ``batch`` (an executor serves one run of one call)
         self._batch_no = 0
@@ -771,7 +800,30 @@ class SegmentExecutor:
                     else:
                         staged.append(c)
                 state["staged_cols"] = staged
+            host_cols = self._host_emit_cols(probes, dense, deposit)
+            if host_cols:
+                # not program outputs at all: never fetched, never
+                # concatenated; _emit_columns takes them from ``sub``
+                state["host_cols"] = host_cols
+                state["keys"] = [k for k in state["keys"]
+                                 if k not in host_cols]
         return state
+
+    def _host_emit_cols(self, probes: Dict[str, Dict[str, Any]],
+                        dense: Dict[str, np.ndarray],
+                        deposit: Dict[str, List[np.ndarray]]
+                        ) -> Dict[str, str]:
+        """{out col: in col} this partition emits from ``state["sub"]``:
+        the segment's handed-through columns (``Segment.host_emit_plan``)
+        whose input shipped dense in its own dtype — a slot deposit, or a
+        stack that neither narrowed (f64 -> f32, i64 -> i32) nor densified
+        (CSR triples are in neither dict) — so the host rows are, byte for
+        byte, what the device would hand back. Anything else reads back
+        as before."""
+        return {c: src for c, src in self.segment.host_emit_plan().items()
+                if src in deposit or (
+                    src in dense and not probes[src]["sparse"]
+                    and dense[src].dtype == probes[src]["dtype"])}
 
     def _csr_capable(self, probes: Dict[str, Dict[str, Any]]) -> set:
         """External columns eligible for CSR staging: the layout knob says
@@ -975,12 +1027,10 @@ class SegmentExecutor:
             vid = kv.get("*")
         return vid
 
-    def _make_step(self, params_dev, state: Dict[str, Any]):
-        """Dispatch closure: staged batch -> (device outputs, num_valid).
-        Non-blocking (jax dispatch is async); executables come from the
-        shared CompileCache keyed by (segment, shape signature)."""
-        seg, keys = self.segment, state["keys"]
-        staged_cols = state.get("staged_cols") or state["ext"]
+    def _program_tail(self, state: Dict[str, Any]
+                      ) -> Tuple[Tuple, str, frozenset]:
+        """What tells this state's program from the segment's plain one:
+        (CompileCache key tail, shape-key prefix, CSR-staged columns)."""
         csr_cols = frozenset(state.get("csr") or ())
         sh = self.sharding
         # a sharded executable is a DIFFERENT program (GSPMD-partitioned,
@@ -1000,6 +1050,21 @@ class SegmentExecutor:
             # data- not batch-shaped)
             key_tail = key_tail + (("layout", "csr"),)
             shape_pre = "layout=csr;" + shape_pre
+        host_cols = state.get("host_cols")
+        if host_cols:
+            # a program that leaves its handed-through columns out has
+            # fewer outputs under the SAME seg.key and signature: key it
+            # apart (the persistent tier's content address follows)
+            key_tail = key_tail + (("host_emit", tuple(sorted(host_cols))),)
+        return key_tail, shape_pre, csr_cols
+
+    def _make_step(self, params_dev, state: Dict[str, Any]):
+        """Dispatch closure: staged batch -> (device outputs, num_valid).
+        Non-blocking (jax dispatch is async); executables come from the
+        shared CompileCache keyed by (segment, shape signature)."""
+        seg, keys = self.segment, state["keys"]
+        staged_cols = state.get("staged_cols") or state["ext"]
+        key_tail, shape_pre, csr_cols = self._program_tail(state)
 
         def step(staged):
             x, m = staged
@@ -1037,16 +1102,7 @@ class SegmentExecutor:
         a single-batch bucket would skew the analytic roofline)."""
         seg, keys = self.segment, state["keys"]
         staged_cols = state.get("staged_cols") or state["ext"]
-        csr_cols = frozenset(state.get("csr") or ())
-        sh = self.sharding
-        key_tail = (sh.cache_key(),) if sh is not None \
-            else (("device", self._device.id),)
-        key_tail = key_tail + self._stitch_tail
-        shape_pre = (sh.shape_prefix() if sh is not None else "") + \
-            self._stitch_pre
-        if csr_cols:
-            key_tail = key_tail + (("layout", "csr"),)
-            shape_pre = "layout=csr;" + shape_pre
+        key_tail, shape_pre, csr_cols = self._program_tail(state)
 
         def mega(group):
             xs = [x for (x, _m), _t in group]
@@ -1294,15 +1350,42 @@ class SegmentExecutor:
                         collected: Dict[str, List[np.ndarray]],
                         obs=None) -> Dict[str, np.ndarray]:
         """``_emit_columns`` under an ``emit`` span (``obs``: the
-        partition's span)."""
-        with batch_span(obs, "emit", rows=state["n"]) as own:
-            return self._emit_columns(state, collected, own)
+        partition's span): ``host_cols`` columns came from the staged host
+        rows, ``host_bytes`` bytes were not read back for them."""
+        own = open_span(obs)
+        w0, t0 = time.time(), time.perf_counter()
+        host = self._host_columns(state)
+        try:
+            return self._emit_columns(state, collected, own, host)
+        finally:
+            close_span(own, "emit", w0, time.perf_counter() - t0,
+                       rows=state["n"], host_cols=len(host),
+                       host_bytes=sum(a.nbytes for a in host.values()))
+
+    def _host_columns(self, state: Dict[str, Any]) -> Dict[str, np.ndarray]:
+        """The partition's handed-through columns (``state["host_cols"]``)
+        as [n_valid, ...] arrays over the rows that were SHIPPED —
+        ``state["sub"]``, the valid rows in order; never a SlotPool
+        lease's buffer (the next batch reuses it) nor the padded batch.
+        Rows that are views of one block (what a column resize makes) come
+        as one view of it, nothing copied; else they are stacked."""
+        from ..parallel.ingest import rows_to_batch
+
+        host: Dict[str, np.ndarray] = {}
+        for c, src in (state.get("host_cols") or {}).items():
+            col = state["sub"][src]
+            host[c] = rows_to_batch(list(col)) if col.dtype == object \
+                else col
+            self.host_emit[c] = self.host_emit.get(c, 0) + host[c].nbytes
+        return host
 
     def _emit_columns(self, state: Dict[str, Any],
                       collected: Dict[str, List[np.ndarray]],
-                      obs) -> Dict[str, np.ndarray]:
-        """Readback arrays -> finalized partition columns (per writer
-        stage, scattered over the validity mask)."""
+                      obs, host: Dict[str, np.ndarray]
+                      ) -> Dict[str, np.ndarray]:
+        """Readback arrays (and ``host``, the columns that never left) ->
+        finalized partition columns (per writer stage, scattered over the
+        validity mask)."""
         seg = self.segment
         part, ctx = state["part"], state["ctx"]
         valid, n, n_valid = state["valid"], state["n"], state["n_valid"]
@@ -1310,6 +1393,7 @@ class SegmentExecutor:
         full = {k: (np.concatenate(v, axis=0) if v
                     else np.zeros((0,), dtype=np.float32))
                 for k, v in collected.items()}
+        full.update(host)
 
         # finalize per writer stage (stage order), scatter into the partition
         by_writer: Dict[int, Dict[str, np.ndarray]] = {}
@@ -1347,31 +1431,46 @@ class SegmentExecutor:
             out_part = {k: v[valid] for k, v in out_part.items()}
         return out_part
 
+    def _trace_stages(self, params_tuple, cols: Dict[str, Any],
+                      csr_cols: frozenset) -> Dict[str, Any]:
+        """The fused body: every stage's ``fn`` in order over one batch's
+        staged columns, returning the final env. A stage whose input
+        column was CSR-staged (``csr_cols``) traces its ``sparse_fn`` body
+        over the wire-triple env keys instead of ``fn`` — the only point
+        where the two bodies diverge. A declared ``passthrough`` is held
+        to its word here: the output must BE the array the stage was
+        given, or the build fails (never a stale column on the host)."""
+        seg = self.segment
+        transpiled = set(self._transpiled)
+        env = dict(cols)
+        for i, (dfn, p) in enumerate(zip(seg.dfns, params_tuple)):
+            given = {c: env.get(src) for c, src in dfn.passthrough.items()}
+            if dfn.sparse_fn is not None and csr_cols & set(dfn.in_cols):
+                env.update(dfn.sparse_fn(p, env))
+            else:
+                env.update(dfn.fn(p, env))
+            for c, src in dfn.passthrough.items():
+                if given[c] is None or env.get(c) is not given[c]:
+                    raise ValueError(
+                        f"{type(seg.stages[i]).__name__} declares {c!r} a "
+                        f"passthrough of {src!r}, but its device fn "
+                        f"returned another value")
+            if i in transpiled:
+                env.update(dfn.device_finalize(p, env))
+        return env
+
     def _build(self, params_dev, x: Dict[str, Any], keys: List[str],
                variant: Optional[str] = None,
                csr_cols: frozenset = frozenset()):
         """AOT-compile the fused program for one shape signature. A kernel
         ``variant`` id is activated around the trace (core/kernels.py) so
-        variant-aware call sites resolve it as a static parameter. A stage
-        whose input column was CSR-staged (``csr_cols``) traces its
-        ``sparse_fn`` body over the wire-triple env keys instead of
-        ``fn`` — the only point where the two bodies diverge."""
+        variant-aware call sites resolve it as a static parameter."""
         import jax
 
         from . import kernels as _kernels
 
-        seg = self.segment
-        transpiled = set(self._transpiled)
-
         def fused(params_tuple, cols):
-            env = dict(cols)
-            for i, (dfn, p) in enumerate(zip(seg.dfns, params_tuple)):
-                if dfn.sparse_fn is not None and csr_cols & set(dfn.in_cols):
-                    env.update(dfn.sparse_fn(p, env))
-                else:
-                    env.update(dfn.fn(p, env))
-                if i in transpiled:
-                    env.update(dfn.device_finalize(p, env))
+            env = self._trace_stages(params_tuple, cols, csr_cols)
             return tuple(env[k] for k in keys)
 
         # sharded: pjit with the planner's NamedShardings (replicated
@@ -1402,21 +1501,10 @@ class SegmentExecutor:
 
         from . import kernels as _kernels
 
-        seg = self.segment
-        transpiled = set(self._transpiled)
-
         def fused_k(params_tuple, cols_seq):
             outs = []
             for cols in cols_seq:
-                env = dict(cols)
-                for i, (dfn, p) in enumerate(zip(seg.dfns, params_tuple)):
-                    if dfn.sparse_fn is not None \
-                            and csr_cols & set(dfn.in_cols):
-                        env.update(dfn.sparse_fn(p, env))
-                    else:
-                        env.update(dfn.fn(p, env))
-                    if i in transpiled:
-                        env.update(dfn.device_finalize(p, env))
+                env = self._trace_stages(params_tuple, cols, csr_cols)
                 outs.append(tuple(env[kk] for kk in keys))
             return tuple(outs)
 
@@ -1453,6 +1541,9 @@ class FusedPipelineModel(PipelineModel):
         self._cache = cache if cache is not None else compile_cache()
         self._plans: Dict[Tuple, List[Any]] = {}
         self._seg_stats: Dict[str, Any] = {}
+        # segment label -> {column: bytes} the last transform emitted from
+        # staged host rows instead of reading back
+        self._host_emit: Dict[str, Dict[str, int]] = {}
         self._last_fallbacks: List[str] = []
         # cumulative since construction (replicas share one model across
         # threads; the per-call fields above are last-writer-wins)
@@ -1651,9 +1742,10 @@ class FusedPipelineModel(PipelineModel):
             layout=self._layout_overrides.get(node.label))
 
     def _absorb(self, ex: SegmentExecutor) -> None:
-        """Fold one finished executor's fallbacks and output placement
-        into the last-run and cumulative stats."""
+        """Fold one finished executor's fallbacks, host-emitted columns
+        and output placement into the last-run and cumulative stats."""
         self._last_fallbacks.extend(ex.fallbacks)
+        self._host_emit[ex.segment.label] = dict(ex.host_emit)
         with self._totals_lock:
             self._fallback_total += len(ex.fallbacks)
             for d, n in ex.out_devices.items():
@@ -1692,6 +1784,7 @@ class FusedPipelineModel(PipelineModel):
 
         self._last_plan = nodes
         self._seg_stats = {}
+        self._host_emit = {}
         self._last_fallbacks = []
         self._pipe_stats = None
         pplan = self._pipe_plan_for(nodes)
@@ -1846,6 +1939,7 @@ class FusedPipelineModel(PipelineModel):
         nodes = self._plan_for(df.schema)
         self._last_plan = nodes
         self._seg_stats = {}
+        self._host_emit = {}
         self._last_fallbacks = []
         # the submit split stays serial: its contract is a single trailing
         # dispatched segment, not a stream (pipeline stats never linger)
@@ -1914,6 +2008,12 @@ class FusedPipelineModel(PipelineModel):
             "segments": [n.describe() for n in nodes],
             "n_fused_segments": sum(isinstance(n, Segment) for n in nodes),
             "per_segment": per_segment,
+            # per segment of the last transform: the columns emitted from
+            # the staged host rows and the bytes not read back for them
+            # (0 where the mechanism never engaged)
+            "host_emit": {label: {"cols": sorted(cols),
+                                  "bytes": sum(cols.values())}
+                          for label, cols in self._host_emit.items()},
             "fallbacks": list(self._last_fallbacks),
             "fallbacks_total": self._fallback_total,
             "devices": dict(self._out_devices),

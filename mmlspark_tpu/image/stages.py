@@ -310,6 +310,9 @@ class ImageTransformer(Transformer, HasInputCol, HasOutputCol):
             finalize=_image_struct_finalize(in_col, out_col,
                                             _host_forced_dtype(op_list)),
             accepts=_image_accepts,
+            # no device op: fn is the identity, and the column the host
+            # prepared is the column this stage writes
+            passthrough=None if dev_ops else {out_col: in_col},
             # a host-op prefix cannot be replayed on device-resident input:
             # the planner starts a new segment here in that case
             internal_ok=not host_ops)
@@ -356,8 +359,9 @@ class ResizeImageTransformer(Transformer, HasInputCol, HasOutputCol):
         """Fusion contract: the resize + channel fix run per-row in
         `prepare` (the unfused host code — bilinear resize is f64 host
         arithmetic with no exact device mirror); the device body is the
-        identity, which still lets this stage head a fused segment so the
-        resized batch uploads ONCE for everything downstream."""
+        identity (declared: ``passthrough``), which still lets this stage
+        head a fused segment so the resized batch uploads ONCE for
+        everything downstream, and is never read back."""
         in_col = self.get_or_throw("inputCol")
         out_col = self.get_or_throw("outputCol")
         h, w = self.get_or_throw("height"), self.get_or_throw("width")
@@ -386,7 +390,8 @@ class ResizeImageTransformer(Transformer, HasInputCol, HasOutputCol):
         return DeviceFn(
             key=key, in_cols=(in_col,), out_cols=(out_col,), fn=fn,
             prepare=prepare, finalize=_image_struct_finalize(in_col, out_col),
-            accepts=_image_accepts, internal_ok=False)
+            accepts=_image_accepts, passthrough={out_col: in_col},
+            internal_ok=False)
 
 
 class UnrollImage(Transformer, HasInputCol, HasOutputCol):

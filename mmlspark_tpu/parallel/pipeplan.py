@@ -643,6 +643,11 @@ class PipeRunner:
         handles = [handle]
         timings = [timing0]
         env: Dict[str, Any] = dict(zip(states[0]["keys"], handle[0]))
+        # a column stage 0 hands through is no output of its program (the
+        # host emits it from the rows it staged): downstream reads the
+        # staged input itself, which pipelined programs never donate
+        for c, src in (states[0].get("host_cols") or {}).items():
+            env[c] = staged[0][src]
         m = handle[1]
         for j in range(1, len(self.execs)):
             xs = {c: env[c] for c in states[j]["ext"]}

@@ -323,6 +323,215 @@ class TestColumnResize:
 
 
 # --------------------------------------------------------------------------
+# a handed-through column is emitted from the host rows that were shipped
+# --------------------------------------------------------------------------
+
+
+def presized_image_df(n=23, parts=2, size=16, seed=12):
+    # rows already at the resize's target: resize_rows hands them on
+    rng = np.random.default_rng(seed)
+    rows = np.empty(n, dtype=object)
+    for i in range(n):
+        rows[i] = ImageSchema.make(
+            rng.integers(0, 256, (size, size, 3), dtype=np.uint8), f"img{i}")
+    return DataFrame.from_dict({"image": rows}, num_partitions=parts)
+
+
+def host_emit_of(fused):
+    """(columns, bytes) the last transform emitted from host rows, over
+    every fused segment."""
+    sections = fused.fusion_stats()["host_emit"].values()
+    return (sorted(c for s in sections for c in s["cols"]),
+            sum(s["bytes"] for s in sections))
+
+
+IMAGE_BYTES = 16 * 16 * 3  # a resized uint8 row
+
+
+class TestHostEmit:
+    @pytest.mark.parametrize("case", [
+        "resize", "presized", "null-row", "short-last-batch", "dropna",
+        "ResizeImageTransformer", "renamed-column"])
+    def test_image_column_is_bitwise_the_unfused_path(self, case):
+        df = {"presized": presized_image_df,
+              "null-row": lambda: typed_image_df(np.uint8, null_at=5),
+              "dropna": lambda: typed_image_df(np.uint8, null_at=5),
+              "short-last-batch": lambda: typed_image_df(np.uint8, n=21,
+                                                         parts=1),
+              }.get(case, lambda: typed_image_df(np.uint8))()
+        col = "resized" if case == "renamed-column" else "image"
+        head = ResizeImageTransformer(height=16, width=16) \
+            if case == "ResizeImageTransformer" \
+            else ImageTransformer(outputCol=col).resize(16, 16)
+        pm = PipelineModel([
+            head, ImageFeaturizer(inputCol=col, scaleFactor=1 / 255.,
+                                  batchSize=8, dropNa=case == "dropna")
+            .set_model(toy_cnn())])
+        fused = fused_of(pm)
+        ref, got = pm.transform(df), fused.transform(df)
+        assert_bitwise(ref, got)
+        stats = fused.fusion_stats()
+        assert stats["fallbacks"] == []
+        # the plan names the column before anything runs
+        assert stats["segments"][0]["host_emit"] == [col]
+        n_valid = sum(r is not None for r in df.collect()["image"])
+        assert host_emit_of(fused) == ([col], n_valid * IMAGE_BYTES)
+        if case == "null-row":
+            assert got.collect()["image"][5] is None
+        if case == "dropna":
+            assert got.count() == 22
+
+    @pytest.mark.parametrize("head", [
+        lambda: [ImageTransformer().resize(18, 18).crop(1, 1, 16, 16)],
+        lambda: [ImageTransformer().resize(16, 16).flip(1)],
+        # a later in-segment stage that rewrites the column wins
+        lambda: [ImageTransformer().resize(16, 16), ImageTransformer().flip(0)],
+        # a handed-through column that an earlier stage wrote is a device
+        # value, not the staged input
+        lambda: [ImageTransformer().resize(16, 16).flip(1), ImageTransformer()],
+    ], ids=["crop", "flip", "later-writer", "internal-input"])
+    def test_a_column_the_device_wrote_is_read_back(self, head):
+        df = typed_image_df(np.uint8)
+        pm = PipelineModel(head() + [
+            ImageFeaturizer(scaleFactor=1 / 255., batchSize=8)
+            .set_model(toy_cnn())])
+        fused = fused_of(pm)
+        assert_bitwise(pm.transform(df), fused.transform(df))
+        stats = fused.fusion_stats()
+        assert stats["n_fused_segments"] == 1 and stats["fallbacks"] == []
+        assert "host_emit" not in stats["segments"][0]
+        assert host_emit_of(fused) == ([], 0)
+
+    def test_a_float64_image_column_still_falls_back_to_the_host(self):
+        rng = np.random.default_rng(13)
+        rows = np.empty(9, dtype=object)
+        for i in range(9):
+            rows[i] = ImageSchema.make(rng.normal(size=(16, 16, 3)), f"img{i}")
+        df = DataFrame.from_dict({"image": rows})
+        pm = resize_head_chain(ImageTransformer().resize(16, 16))
+        fused = fused_of(pm)
+        assert_bitwise(pm.transform(df), fused.transform(df))
+        assert any("dtype gate" in f for f in fused.fusion_stats()["fallbacks"])
+        assert host_emit_of(fused) == ([], 0)
+
+    def test_a_narrowed_column_is_read_back(self):
+        # int64 rows narrow to int32 on the wire: the host rows are not the
+        # bytes the device holds, so the declaration alone does not engage
+        from mmlspark_tpu.core.device_stage import DeviceFn
+        from mmlspark_tpu.core.fusion import SegmentExecutor
+        from mmlspark_tpu.parallel.ingest import IngestStats
+
+        class HandOn(ImageTransformer):
+            def device_fn(self, schema):
+                return DeviceFn(key=("HandOn",), in_cols=("x",),
+                                out_cols=("y",), heavy=True,
+                                fn=lambda p, env: {"y": env["x"]},
+                                passthrough={"y": "x"})
+
+        seg = Segment()
+        stage = HandOn()
+        seg.add(stage, stage.device_fn(None))
+        assert seg.describe()["host_emit"] == ["y"]
+        for dtype, engaged in [(np.int64, False), (np.int32, True)]:
+            rows = np.empty(5, dtype=object)
+            for i in range(5):
+                rows[i] = np.arange(4, dtype=dtype) + i
+            ex = SegmentExecutor(seg, CompileCache())
+            state = ex._prep_partition({"x": rows})
+            assert state.get("host_cols", {}) == ({"y": "x"} if engaged
+                                                  else {})
+            assert state["keys"] == ([] if engaged else ["y"])
+            # a program left with no output at all still runs and emits
+            out = ex.run(DataFrame.from_dict({"x": rows}), IngestStats())
+            assert ex.fallbacks == []
+            assert ex.host_emit == ({"y": 5 * 4 * 4} if engaged else {})
+            for got, want in zip(out.collect()["y"], rows):
+                np.testing.assert_array_equal(got, want.astype(np.int32))
+
+    def test_output_rows_are_not_views_of_a_slot_buffer(self):
+        pm = resize_head_chain(ImageTransformer().resize(16, 16))
+        fused = fused_of(pm)
+        first = fused.transform(typed_image_df(np.uint8, seed=1)).collect()
+        label = next(iter(fused.fusion_stats()["per_segment"]))
+        assert fused.fusion_stats()["per_segment"][label]["slot_deposits"] > 0
+        pool = fused._get_slot_pool()
+        slots = [buf for b in pool._buckets.values() for buf in b.bufs]
+        assert slots and not any(
+            np.shares_memory(row["data"], buf)
+            for row in first["image"] for buf in slots)
+        held = [row["data"].copy() for row in first["image"]]
+        # the next call refills the same slots with other pixels
+        fused.transform(typed_image_df(np.uint8, seed=2))
+        for row, was in zip(first["image"], held):
+            np.testing.assert_array_equal(row["data"], was)
+
+    def test_the_program_has_one_output_fewer(self):
+        df = typed_image_df(np.uint8, ragged=False)
+        programs = {}
+        for name, head in [("handed-on", ImageTransformer().resize(16, 16)),
+                           ("flipped",
+                            ImageTransformer().resize(16, 16).flip(1))]:
+            cache = CompileCache()
+            fused = fused_of(resize_head_chain(head), cache=cache)
+            fused.transform(df)
+            programs[name] = (cache, fused)
+        outs = {name: {fn.out_tree.num_leaves
+                       for fn in cache._entries.values()}
+                for name, (cache, _) in programs.items()}
+        assert outs == {"handed-on": {1}, "flipped": {2}}
+        cache, fused = programs["handed-on"]
+        # keyed apart from a program of the same segment that returns it
+        assert all(("host_emit", ("image",)) in key for key in cache._entries)
+        assert host_emit_of(fused) == (["image"], 23 * IMAGE_BYTES)
+
+    def test_a_false_declaration_fails_the_build(self):
+        class Liar(ImageTransformer):
+            def device_fn(self, schema):
+                dfn = super().device_fn(schema)
+                dfn.passthrough = {"image": "image"}
+                return dfn
+
+        pm = resize_head_chain(Liar().resize(16, 16).flip(1))
+        with pytest.raises(ValueError, match="passthrough"):
+            fused_of(pm).transform(typed_image_df(np.uint8))
+
+    def test_a_stage_that_hands_nothing_through_reads_zero(self):
+        rng = np.random.default_rng(14)
+        rows = np.empty(20, dtype=object)
+        for i in range(20):
+            rows[i] = rng.normal(size=4).astype(np.float32)
+        df = DataFrame.from_dict({"x": rows}, num_partitions=2)
+        dnn = DNNModel(inputCol="x", outputCol="emb", batchSize=8)
+        dnn.set_model(toy_mlp())
+        fused = fused_of(PipelineModel([dnn]))
+        fused.transform(df)
+        stats = fused.fusion_stats()
+        assert stats["host_emit"] == {"DNNModel": {"cols": [], "bytes": 0}}
+        assert "host_emit" not in stats["segments"][0]
+
+    def test_the_served_path_emits_from_the_host_too(self):
+        df = typed_image_df(np.uint8, null_at=3)
+        pm = resize_head_chain(ImageTransformer().resize(16, 16))
+        cache = CompileCache()
+        fused = fused_of(pm, cache=cache)
+        assert_bitwise(pm.transform(df), fused.transform_submit(df)())
+        assert fused.fusion_stats()["fallbacks"] == []
+        assert host_emit_of(fused) == (["image"], 22 * IMAGE_BYTES)
+        assert {fn.out_tree.num_leaves
+                for fn in cache._entries.values()} == {1}
+
+    def test_the_emit_span_says_what_it_did_not_read_back(self):
+        from mmlspark_tpu.obs.trace import default_tracer
+
+        df = typed_image_df(np.uint8, parts=1)
+        fused = fused_of(resize_head_chain(ImageTransformer().resize(16, 16)))
+        fused.transform(df)
+        emit = [s for s in default_tracer().spans() if s["name"] == "emit"][-1]
+        assert emit["attrs"] == {"rows": 23, "host_cols": 1,
+                                 "host_bytes": 23 * IMAGE_BYTES}
+
+
+# --------------------------------------------------------------------------
 # planning: splits, demotion, terminal stages
 # --------------------------------------------------------------------------
 

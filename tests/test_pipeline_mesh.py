@@ -345,6 +345,9 @@ class TestPipelinedParity:
         out = fused2.transform(df2)
         assert np.array_equal(_col(out), want_emb)
         assert np.array_equal(_col(out, "features"), want_feat)
+        # the resized image never came back from stage 0's sub-mesh
+        assert fused2.fusion_stats()["host_emit"][
+            "ImageTransformer+ImageFeaturizer"]["cols"] == ["image"]
         pipe = fused2.fusion_stats()["pipeline"]
         assert pipe["depth"] == 2 and pipe["replans"] == 0
         assert pipe["serial_fallback_partitions"] == 0
@@ -355,6 +358,46 @@ class TestPipelinedParity:
         assert 0.0 < pipe["bubble_ratio"] < 1.0
         for st in pipe["stages"]:
             assert 0.0 <= st["busy_ratio"] <= 1.0
+
+    def test_a_handed_through_column_crosses_the_handoff(self):
+        # stage 0 emits ``y`` from the host rows it staged, so ``y`` is no
+        # output of its program: the next stage reads the staged input
+        from mmlspark_tpu.stages.basic import UDFTransformer
+
+        class HandOn(UDFTransformer):
+            def device_fn(self, schema):
+                dfn = super().device_fn(schema)
+                dfn.passthrough = {"y": "x"}
+                return dfn
+
+        def chain():
+            head = Sequential([("d1", Dense(8)), ("a", relu()),
+                               ("d2", Dense(3))], name="pipehead")
+            hp, _ = head.init(jax.random.PRNGKey(1), (4,))
+            dnn = DNNModel(inputCol="y", outputCol="emb", batchSize=8)
+            dnn.set_model(FunctionModel(head, hp, (4,), name="pipehead"))
+            hand = HandOn(inputCol="x", outputCol="y",
+                          vectorizedUdf=lambda col: col,
+                          deviceUdf=lambda x: x)
+            return FusedPipelineModel([hand, dnn], cache=CompileCache())
+
+        rng = np.random.default_rng(6)
+        rows = np.empty(20, dtype=object)
+        for i in range(20):
+            rows[i] = rng.normal(size=4).astype(np.float32)
+        df = DataFrame.from_dict({"x": rows}, num_partitions=2)
+        want = chain().transform(df)
+        fused = chain()
+        fused.set_mesh(_pipe_mesh())
+        fused.set_tuning(pipe_depth=2)
+        got = fused.transform(df)
+        stats = fused.fusion_stats()
+        assert stats["pipeline"]["depth"] == 2
+        assert stats["pipeline"]["serial_fallback_partitions"] == 0
+        assert stats["host_emit"]["HandOn"] == {"cols": ["y"],
+                                                "bytes": 20 * 4 * 4}
+        for name in ("y", "emb"):
+            assert np.array_equal(_col(got, name), _col(want, name))
 
     def test_deep_chain_three_stages(self):
         fused, df = _make_chain(deep=True)
