@@ -6,8 +6,8 @@ text features far too wide to densify, trained end to end from raw text.
 The journey: tokenize -> hashTF into a 2^15-wide sparse space ->
 LightGBMClassifier with GOSS (gradient-based one-side sampling, the
 engine's headline speed feature — exact top-k selection + selected-row
-nnz compaction make the sampled fit FASTER than the full fit at scale,
-BENCH_gbdt_sparse.json) -> evaluate -> save/reload.
+nnz compaction, so the sampled fit touches only the selected rows'
+nonzeros) -> evaluate -> save/reload.
 """
 
 import os

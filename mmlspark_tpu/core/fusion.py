@@ -1058,6 +1058,26 @@ class SegmentExecutor:
             key_tail = key_tail + (("host_emit", tuple(sorted(host_cols))),)
         return key_tail, shape_pre, csr_cols
 
+    def _program_key(self, key_tail: Tuple, shape_pre: str, sig: Tuple,
+                     k: int = 1) -> Tuple[Tuple, str, Optional[str]]:
+        """(CompileCache key, shape key, kernel-variant id) of the K-step
+        program for one shape signature, from ``_program_tail``'s key tail
+        and shape prefix. A kernel variant is a DIFFERENT compiled program
+        for the same (segment, signature), and a K-step program holds K
+        batches' worth of flops: each is keyed apart and decorates the
+        shape key (``variant=<id>;``, ``mega<K>;``) so the cost model's
+        bucket_of_shape skips its cost record. K=1 adds nothing to
+        either."""
+        vid = self._variant_for(sig)
+        if vid:
+            key_tail = key_tail + (("variant", vid),)
+            shape_pre = f"variant={vid};" + shape_pre
+        if k > 1:
+            key_tail = (("mega", k),) + key_tail
+            shape_pre = f"{shape_pre}mega{k};"
+        return ((self.segment.key, sig) + key_tail,
+                shape_pre + self._shape_key_of(sig), vid)
+
     def _make_step(self, params_dev, state: Dict[str, Any]):
         """Dispatch closure: staged batch -> (device outputs, num_valid).
         Non-blocking (jax dispatch is async); executables come from the
@@ -1068,14 +1088,8 @@ class SegmentExecutor:
 
         def step(staged):
             x, m = staged
-            sig = self._sig_of(x, staged_cols)
-            # a kernel variant is a DIFFERENT compiled program for the same
-            # (segment, signature): key it apart, and decorate the shape
-            # key (variant=<id>;) so bucket_of_shape skips its cost record
-            vid = self._variant_for(sig)
-            tail = key_tail + ((("variant", vid),) if vid else ())
-            pre = (f"variant={vid};" if vid else "") + shape_pre
-            shape = pre + self._shape_key_of(sig)
+            key, shape, vid = self._program_key(
+                key_tail, shape_pre, self._sig_of(x, staged_cols))
 
             def build():
                 # a CompileCache miss: the build is a child of the open
@@ -1085,8 +1099,8 @@ class SegmentExecutor:
                     return self._build(params_dev, x, keys, variant=vid,
                                        csr_cols=csr_cols)
 
-            compiled = self.cache.get((seg.key, sig) + tail, build,
-                                      label=seg.label, shape=shape)
+            compiled = self.cache.get(key, build, label=seg.label,
+                                      shape=shape)
             with profiling.annotate(f"fused:{seg.label}"):
                 ys = compiled(params_dev, x)
             self._note_devices(ys)
@@ -1096,26 +1110,20 @@ class SegmentExecutor:
 
     def _make_mega_step(self, params_dev, state: Dict[str, Any], k: int):
         """K-step dispatch closure: a list of K same-signature staged
-        batches -> tuple of K output tuples, through ONE compiled call.
-        The shape key is prefixed so the cost model's bucket parser skips
-        mega records (their flops are K batches' worth — folding them into
-        a single-batch bucket would skew the analytic roofline)."""
+        batches -> tuple of K output tuples, through ONE compiled call."""
         seg, keys = self.segment, state["keys"]
         staged_cols = state.get("staged_cols") or state["ext"]
         key_tail, shape_pre, csr_cols = self._program_tail(state)
 
         def mega(group):
             xs = [x for (x, _m), _t in group]
-            sig = self._sig_of(xs[0], staged_cols)
-            vid = self._variant_for(sig)
-            tail = key_tail + ((("variant", vid),) if vid else ())
-            pre = (f"variant={vid};" if vid else "") + shape_pre
+            key, shape, vid = self._program_key(
+                key_tail, shape_pre, self._sig_of(xs[0], staged_cols), k)
             compiled = self.cache.get(
-                (seg.key, sig, ("mega", k)) + tail,
-                lambda: self._build_mega(params_dev, xs[0], keys, k,
-                                         variant=vid, csr_cols=csr_cols),
-                label=seg.label,
-                shape=f"{pre}mega{k};{self._shape_key_of(sig)}")
+                key,
+                lambda: self._build(params_dev, xs[0], keys, k=k,
+                                    variant=vid, csr_cols=csr_cols),
+                label=seg.label, shape=shape)
             cols_seq = tuple({c: x[c] for c in staged_cols} for x in xs)
             with profiling.annotate(f"fused:{seg.label}:mega{k}"):
                 outs = compiled(params_dev, cols_seq)
@@ -1460,62 +1468,44 @@ class SegmentExecutor:
         return env
 
     def _build(self, params_dev, x: Dict[str, Any], keys: List[str],
-               variant: Optional[str] = None,
+               k: int = 1, variant: Optional[str] = None,
                csr_cols: frozenset = frozenset()):
         """AOT-compile the fused program for one shape signature. A kernel
         ``variant`` id is activated around the trace (core/kernels.py) so
-        variant-aware call sites resolve it as a static parameter."""
+        variant-aware call sites resolve it as a static parameter.
+
+        ``k`` > 1 is the K-step mega program: K replicas of the per-batch
+        body traced over a K-tuple of same-shape input dicts in one
+        callable, so one Python dispatch executes K queued micro-batches.
+        Each replica's ops are exactly the per-batch program's, so
+        per-batch outputs match K=1."""
         import jax
 
         from . import kernels as _kernels
 
+        # the K=1 callable's NAME is read outside the program: its
+        # executable is the module ``jit_fused``, by which the benchmark's
+        # device-trace readers find it (benchmarks/layer_metrics)
         def fused(params_tuple, cols):
             env = self._trace_stages(params_tuple, cols, csr_cols)
-            return tuple(env[k] for k in keys)
+            return tuple(env[kk] for kk in keys)
+
+        def fused_k(params_tuple, cols_seq):
+            return tuple(fused(params_tuple, cols) for cols in cols_seq)
 
         # sharded: pjit with the planner's NamedShardings (replicated
         # params, per-column input specs, donated ring-staged inputs) —
         # GSPMD partitions the program and inserts the collectives
-        jit_kwargs = self.sharding.jit_kwargs() \
-            if self.sharding is not None else {}
-        jitted = jax.jit(fused, **jit_kwargs)
-        specs = {c: jax.ShapeDtypeStruct(tuple(np.shape(v)),
-                                         np.asarray(v).dtype
-                                         if not hasattr(v, "dtype") else v.dtype)
-                 for c, v in x.items()}
-        # no catch: a Mosaic refusal or a VMEM/HBM OOM must surface, not
-        # be retried as a lazy jit
-        with _kernels.activate(variant):
-            return jitted.lower(params_dev, specs).compile()
-
-    def _build_mega(self, params_dev, x: Dict[str, Any], keys: List[str],
-                    k: int, variant: Optional[str] = None,
-                    csr_cols: frozenset = frozenset()):
-        """AOT-compile the K-step mega program: K replicas of ``_build``'s
-        per-batch fused body, traced over a K-tuple of same-shape input
-        dicts in one callable — one Python dispatch executes K queued
-        micro-batches (the fixed dispatch cost amortizes K-fold). Each
-        replica's ops are exactly the per-batch program's, so per-batch
-        outputs match the K=1 path."""
-        import jax
-
-        from . import kernels as _kernels
-
-        def fused_k(params_tuple, cols_seq):
-            outs = []
-            for cols in cols_seq:
-                env = self._trace_stages(params_tuple, cols, csr_cols)
-                outs.append(tuple(env[kk] for kk in keys))
-            return tuple(outs)
-
         jit_kwargs = self.sharding.jit_kwargs(mega_k=k) \
             if self.sharding is not None else {}
-        jitted = jax.jit(fused_k, **jit_kwargs)
-        spec = {c: jax.ShapeDtypeStruct(
-            tuple(np.shape(v)),
-            np.asarray(v).dtype if not hasattr(v, "dtype") else v.dtype)
-            for c, v in x.items()}
-        specs = tuple(dict(spec) for _ in range(k))
+        jitted = jax.jit(fused if k == 1 else fused_k, **jit_kwargs)
+        spec = {c: jax.ShapeDtypeStruct(tuple(np.shape(v)),
+                                        np.asarray(v).dtype
+                                        if not hasattr(v, "dtype") else v.dtype)
+                for c, v in x.items()}
+        specs = spec if k == 1 else tuple(dict(spec) for _ in range(k))
+        # no catch: a Mosaic refusal or a VMEM/HBM OOM must surface, not
+        # be retried as a lazy jit
         with _kernels.activate(variant):
             return jitted.lower(params_dev, specs).compile()
 
@@ -1729,17 +1719,27 @@ class FusedPipelineModel(PipelineModel):
             self._seg_sharding[node.label] = sh.describe()
         return sh
 
-    def _make_executor(self, node: Segment) -> SegmentExecutor:
+    def _make_executor(self, node: Segment,
+                       stage_sharding=None) -> SegmentExecutor:
+        """The one construction of a SegmentExecutor from the tuned knobs.
+        ``stage_sharding`` is the stage placement of a pipelined run and
+        becomes the executor's sharding; mega-dispatch is then forced off
+        (the stream IS the dispatch amortization) and the CSR layout is
+        excluded by ``_pipe_plan_for``."""
+        pipelined = stage_sharding is not None
         return SegmentExecutor(
             node, self._cache,
             buckets=self._bucket_overrides.get(node.label),
             cost_model=self._cost_model,
             slot_pool=self._get_slot_pool(),
-            mega_k=self._mega_k_overrides.get(node.label, 1),
-            sharding=self._sharding_for(node),
+            mega_k=1 if pipelined
+            else self._mega_k_overrides.get(node.label, 1),
+            sharding=stage_sharding if pipelined
+            else self._sharding_for(node),
             kernel_variants=self._variant_overrides.get(node.label),
             stitch=self._stitch_overrides or None,
-            layout=self._layout_overrides.get(node.label))
+            layout=None if pipelined
+            else self._layout_overrides.get(node.label))
 
     def _absorb(self, ex: SegmentExecutor) -> None:
         """Fold one finished executor's fallbacks, host-emitted columns
@@ -1765,6 +1765,19 @@ class FusedPipelineModel(PipelineModel):
                 node.label, time.perf_counter() - t0, n)
         return out
 
+    def _run_node(self, node, df: DataFrame) -> DataFrame:
+        """Run one plan node serially: a Segment through a fresh executor
+        and its own IngestStats, a HostStage on the host."""
+        if not isinstance(node, Segment):
+            return self._host_node(node, df)
+        from ..parallel.ingest import IngestStats
+
+        stats = self._seg_stats[node.label] = IngestStats()
+        ex = self._make_executor(node)
+        df = ex.run(df, stats)
+        self._absorb(ex)
+        return df
+
     def transform(self, df: DataFrame, fused: bool = True) -> DataFrame:
         if not fused:
             return PipelineModel.transform(self, df)
@@ -1780,8 +1793,6 @@ class FusedPipelineModel(PipelineModel):
             return self._transform_nodes(df, nodes)
 
     def _transform_nodes(self, df: DataFrame, nodes: List[Any]) -> DataFrame:
-        from ..parallel.ingest import IngestStats
-
         self._last_plan = nodes
         self._seg_stats = {}
         self._host_emit = {}
@@ -1802,14 +1813,7 @@ class FusedPipelineModel(PipelineModel):
                 self._pipe_replan_after_wedge(pplan, e.stage)
                 return self.transform(df, fused=True)
         for node in nodes:
-            if isinstance(node, Segment):
-                stats = IngestStats()
-                self._seg_stats[node.label] = stats
-                ex = self._make_executor(node)
-                df = ex.run(df, stats)
-                self._absorb(ex)
-            else:
-                df = self._host_node(node, df)
+            df = self._run_node(node, df)
         return df
 
     def _pipe_plan_for(self, nodes: List[Any]):
@@ -1835,23 +1839,6 @@ class FusedPipelineModel(PipelineModel):
                 pass
         return pplan
 
-    def _make_pipe_executor(self, node: Segment,
-                            sharding) -> SegmentExecutor:
-        """Executor for one pipelined segment: the ordinary
-        SegmentExecutor with the stage placement as its sharding. Mega-
-        dispatch is forced off (the stream IS the dispatch amortization)
-        and the CSR layout is excluded by ``_pipe_plan_for``."""
-        return SegmentExecutor(
-            node, self._cache,
-            buckets=self._bucket_overrides.get(node.label),
-            cost_model=self._cost_model,
-            slot_pool=self._get_slot_pool(),
-            mega_k=1,
-            sharding=sharding,
-            kernel_variants=self._variant_overrides.get(node.label),
-            stitch=self._stitch_overrides or None,
-            layout=None)
-
     def _transform_pipelined(self, df: DataFrame, nodes: List[Any],
                              pplan) -> DataFrame:
         """Execute the plan with its chainable run pipelined: nodes
@@ -1867,19 +1854,8 @@ class FusedPipelineModel(PipelineModel):
 
         if pplan.nodes is not None:
             nodes = pplan.nodes
-
-        def serial_node(node, frame):
-            if isinstance(node, Segment):
-                stats = IngestStats()
-                self._seg_stats[node.label] = stats
-                ex = self._make_executor(node)
-                frame = ex.run(frame, stats)
-                self._absorb(ex)
-                return frame
-            return self._host_node(node, frame)
-
         for node in nodes[:pplan.first]:
-            df = serial_node(node, df)
+            df = self._run_node(node, df)
         execs, stats = [], []
         for offset, node in enumerate(nodes[pplan.first:pplan.last]):
             stage = pplan.stages[pplan.stage_of[pplan.first + offset]]
@@ -1891,7 +1867,7 @@ class FusedPipelineModel(PipelineModel):
             seg_stats = IngestStats()
             self._seg_stats[node.label] = seg_stats
             stats.append(seg_stats)
-            execs.append(self._make_pipe_executor(node, sh))
+            execs.append(self._make_executor(node, stage_sharding=sh))
         runner = PipeRunner(pplan, execs, stats,
                             cost_model=self._cost_model)
         df = runner.run(df)
@@ -1900,7 +1876,7 @@ class FusedPipelineModel(PipelineModel):
         self._pipe_stats = runner.stats_dict(
             requeues=self._pipe_requeues, replans=self._pipe_replans)
         for node in nodes[pplan.last:]:
-            df = serial_node(node, df)
+            df = self._run_node(node, df)
         return df
 
     def _pipe_replan_after_wedge(self, pplan, stage_index: int) -> None:
@@ -1947,14 +1923,7 @@ class FusedPipelineModel(PipelineModel):
         tail = nodes[-1] if nodes and isinstance(nodes[-1], Segment) else None
         body = nodes[:-1] if tail is not None else nodes
         for node in body:
-            if isinstance(node, Segment):
-                stats = IngestStats()
-                self._seg_stats[node.label] = stats
-                ex = self._make_executor(node)
-                df = ex.run(df, stats)
-                self._absorb(ex)
-            else:
-                df = self._host_node(node, df)
+            df = self._run_node(node, df)
         if tail is None:
             out = df
             return lambda: out

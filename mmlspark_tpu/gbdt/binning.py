@@ -145,8 +145,8 @@ class BinMapper:
                      n_threads: int = 0) -> np.ndarray:
         """Float [N,F] -> FEATURE-MAJOR bins [F,N] (the device column-store
         layout), binning columns in parallel — np.searchsorted releases the
-        GIL, so the 10M-row transform drops from ~30 s single-threaded to
-        the per-core share (tools/profile_gbdt_10m.py)."""
+        GIL, so a many-row transform costs the per-core share of the
+        single-threaded one."""
         import concurrent.futures
         import os
 

@@ -963,8 +963,8 @@ def _native_train_ok(params: TrainParams, n: int) -> bool:
     The reference's engine is LightGBM's C++ core (TrainUtils.scala:170-233);
     this is its small-N equivalent: below ~MMLSPARK_TPU_NATIVE_TRAIN_MAX
     row*iteration*class work the per-dispatch overhead of any accelerator
-    exceeds what one host core does outright (BENCH_gbdt_train.json: 200k
-    was dispatch-bound at 0.44x sklearn through r4). Large fits keep the
+    exceeds what one host core does outright (a small fit on the device
+    is bound by its dispatches, not by its arithmetic). Large fits keep the
     whole-run lax.scan device path. MMLSPARK_TPU_NATIVE_TRAIN=1 forces,
     =0 disables."""
     env = os.environ.get("MMLSPARK_TPU_NATIVE_TRAIN", "")

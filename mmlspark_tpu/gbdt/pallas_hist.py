@@ -8,7 +8,7 @@ serialized sort-major scatter on TPU — correct but far off the roofline.
 This kernel reformulates the scatter as a **one-hot contraction on the MXU**
 over FEATURE-MAJOR inputs (bins [F, N], vals [3, N] — minor dim rows, so the
 HBM arrays carry no lane padding; an [N, 28] int32 layout tiles 28 -> 128
-lanes, a 4.6x HBM blowup that OOMed the 10M-row bench):
+lanes, a 4.6x HBM blowup that runs a 10M-row fit out of memory):
 
     hist[f, b, c] = sum_n (bins[f, n] == b) * vals[c, n]
                   = vals @ onehot_t(bins[f, :]).T          # [3, B] per feature
@@ -52,8 +52,7 @@ from jax.experimental.pallas import tpu as pltpu
 # Row-chunk size: bounds the one-hot VMEM tile ([CHUNK, B_pad] f32 = 256 KB at
 # B_pad=128). FMAX bounds features handled per pallas_call — wider inputs are
 # processed in host-side slabs so the [3, F*B_pad] accumulator stays in VMEM.
-# CHUNK is env-tunable for kernel A/B runs (tools/bench_hist.py).
-CHUNK = int(os.environ.get("MMLSPARK_TPU_HIST_CHUNK", "512"))
+CHUNK = 512
 FMAX = 64
 
 
@@ -67,7 +66,7 @@ def _hist_kernel(bins_ref, vals_ref, out_ref, *, nf: int, b_pad: int,
 
     bins_ref: [nf, CHUNK] int (feature-major: minor dim = rows, so the HBM
     array carries no lane padding — an [N, F] layout tiles F up to 128 lanes,
-    a 4.6x HBM blowup at F=28 that OOMed the 10M-row bench),
+    a 4.6x HBM blowup at F=28 that runs a 10M-row fit out of memory),
     vals_ref: [3, CHUNK] f32 (pre-masked channels x rows) — or, in hi/lo
     mode, [5, CHUNK] bf16 (g_hi, g_lo, h_hi, h_lo, mask),
     out_ref:  [3, nf*B_pad] f32 accumulator, VMEM-resident across the grid.

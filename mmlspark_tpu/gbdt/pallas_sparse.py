@@ -51,14 +51,13 @@ suite and the serving bench exercise.
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import numpy as np
 
 # Row-chunk size for the one-hot contractions (bounds the [*, CHUNK] VMEM
-# tiles); env-tunable for kernel A/B runs like pallas_hist.CHUNK.
-CHUNK = int(os.environ.get("MMLSPARK_TPU_SPARSE_CHUNK", "512"))
+# tiles), as pallas_hist.CHUNK.
+CHUNK = 512
 #: Work bound for the MXU gather (its cost is nnz x N x U_pad one-hot
 #: products). Not a VMEM guard: the kernel tiles N and U, so its VMEM
 #: footprint is bounded by the tile constants below whatever the shape.

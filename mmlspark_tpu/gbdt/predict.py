@@ -347,8 +347,7 @@ class DeviceEnsemble:
                 # advanced-index gathers ([T, m][t, node] -> [N, T]): the
                 # take_along_axis(arr[None], node[:, :, None]) form lowered
                 # to a broadcast materializing [N, T, m] per field — ~2.4 GB
-                # at 200k rows x 50 trees and 29x slower end to end
-                # (BENCH_gbdt_train.json predict history)
+                # at 200k rows x 50 trees
                 f = feature[t_idx, node]
                 thr = threshold[t_idx, node]
                 dl = default_left[t_idx, node]

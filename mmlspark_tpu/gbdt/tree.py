@@ -14,8 +14,7 @@ Two growth paths, identical semantics:
   + scatters the small child's histogram (Pallas MXU kernel on TPU) + derives
   the sibling by subtraction + evaluates both children's splits. One dispatch
   and one host fetch per TREE; the old per-split orchestration cost ~31
-  blocking round trips per tree and was dispatch-bound end-to-end
-  (BENCH_gbdt_train.json).
+  blocking round trips per tree and was dispatch-bound end-to-end.
   Row-sharded (multi-chip) inputs take the same fused path per shard under
   ``shard_map`` with psum'd histograms — replicated split decisions, sharded
   row routing (LightGBM's socket-ring allreduce as one collective stream).
@@ -244,15 +243,15 @@ def _grow_tree_device_body(bins_fm, grad, hess, row_mask, node_of_row,
             "MMLSPARK_TPU_NO_GATHER_HIST", "") in ("", "0"):
         n_rows = int(bins_fm.shape[1])
         caps = []
-        # Tier start (r4 profile, tools/profile_gbdt_10m.py): with the
-        # stream-select kernel the compaction pass streams rows ~5x cheaper
+        # Tier start: with the stream-select kernel the compaction pass
+        # streams rows ~5x cheaper
         # than the histogram kernel (~12.5 vs ~59 ms per 1M rows at F=28),
         # so compacting pays for EVERY small child — tiers start at n/2
         # (small children are always <= n/2). The XLA nonzero+gather
         # fallback is only profitable well below n/4 (axis-1 gather ~19 ms
         # per n/2 rows at N=1M), so it keeps the old n/8 start. The select
         # buffer is [cap, 128ch] f32; the n/2 tier is capped to a 4 GB
-        # budget (bins + buffers must fit 15.75 GB HBM at the 10M bench).
+        # budget (bins + buffers must fit 15.75 GB HBM at 10M rows).
         top_div = 2 if use_sel else 8
         max_tiers = 7 if use_sel else 5
         c = (n_rows // top_div + 511) // 512 * 512

@@ -106,9 +106,9 @@ def extract_cost(compiled: Any) -> Optional[Dict[str, float]]:
 #: Published per-chip peaks keyed by ``device_kind`` prefix: dense bf16
 #: FLOP/s and HBM bytes/s from the Google Cloud TPU documentation (system
 #: architecture pages "TPU v4", "TPU v5e", "TPU v5p", "TPU v6e"). The ONE
-#: table in the tree — bench.py and tools/ import it. A device that is not
-#: listed has no roofline: ``device_peaks`` reports ``peak_source:
-#: "unknown"`` and None ceilings, never a stand-in number.
+#: table in the package. A device that is not listed has no roofline:
+#: ``device_peaks`` reports ``peak_source: "unknown"`` and None ceilings,
+#: never a stand-in number.
 PEAKS = {
     "TPU v4": {"flops": 275e12, "bytes_per_s": 1228e9},
     "TPU v5 lite": {"flops": 197e12, "bytes_per_s": 819e9},   # v5e

@@ -18,8 +18,8 @@ Two structural fixes live here:
     e2e-vs-per-call gap is a first-class measured quantity.
 
 Consumers: DNNModel (models/dnn_model.py) for the DataFrame eval path,
-DeviceEnsemble (gbdt/predict.py) for chunked GBDT scoring, and bench.py's
-e2e section. The ring is generic — anything shaped
+DeviceEnsemble (gbdt/predict.py) for chunked GBDT scoring, and the fused
+segments of core/fusion.py. The ring is generic — anything shaped
 ``host batches -> stage -> dispatch -> readback`` can ride it.
 """
 
@@ -168,8 +168,8 @@ class BatchTiming:
 
 class IngestStats:
     """Accumulates ``BatchTiming`` rows plus ring wall time; ``summary()``
-    renders the e2e decomposition bench.py and the serving stats endpoint
-    surface. Safe to share across sequential ring runs (partitions of one
+    renders the e2e decomposition ``fusion_stats()`` and the serving stats
+    endpoint surface. Safe to share across sequential ring runs (partitions of one
     transform accumulate into one object)."""
 
     def __init__(self):

@@ -703,6 +703,39 @@ class TestCompileCache:
     def test_global_cache_shared(self):
         assert compile_cache() is compile_cache()
 
+    def test_the_k1_program_keeps_the_name_the_benchmark_matches(self):
+        """Two contracts with readers outside the package. The benchmark's
+        device-trace metrics find the fused program by its module's name
+        (``MODULE_PATTERN`` in benchmarks/layer_metrics; the trace shows
+        ``<module>(<fingerprint>)``). JAX's persistent cache, the fleet's
+        tier and the cost model read the K=1 CompileCache key and shape
+        key, which carry nothing of the K-step program's."""
+        import pathlib
+        import re
+
+        cache = CompileCache()
+        fused = fused_of(PipelineModel([
+            ImageTransformer().resize(16, 16).flip(1),
+            ImageFeaturizer(scaleFactor=1 / 255., batchSize=8)
+            .set_model(toy_cnn())]), cache=cache)
+        fused.transform(image_df())
+        assert fused.fusion_stats()["fallbacks"] == []
+        (seg,) = fused._last_plan
+        names = {re.match(r"HloModule ([^\s,]+)", fn.as_text()).group(1)
+                 for fn in cache._entries.values()}
+        assert names == {"jit_fused"}
+        readers = sorted((pathlib.Path(__file__).parent.parent / "benchmarks"
+                          / "layer_metrics").glob("*_roofline_pct.*.py"))
+        assert readers
+        for reader in readers:
+            pattern = re.search(r'^MODULE_PATTERN = r"(.+)"$',
+                                reader.read_text(), re.M).group(1)
+            assert re.search(pattern, "jit_fused(1234567890)"), reader.name
+        for key in cache._entries:
+            assert key[0] == seg.key and len(key) == 3, key
+            assert key[2] == ("device", jax.devices()[0].id)
+        shapes = [shape for label, shape in cache._costs if label == seg.label]
+        assert shapes and all(shape.startswith("image=") for shape in shapes)
 
 # --------------------------------------------------------------------------
 # observability: profiler annotations + stats surfaces

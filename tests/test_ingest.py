@@ -396,7 +396,8 @@ class TestServingIngestSurface:
 
 
 class TestBatcherCloseRaceRegressions:
-    """ADVICE.md round-5: close-vs-producer races in parallel/batching.py."""
+    """Close-vs-producer races in parallel/batching.py (a review finding of
+    an earlier round)."""
 
     def test_dynamic_batcher_sentinel_never_leaks_as_data(self):
         from mmlspark_tpu.parallel.batching import DynamicBufferedBatcher

@@ -439,6 +439,12 @@ class TestMegaDispatch:
         assert mega.mega_k_max == k
         got = _feature_matrix(mega.transform_submit(df)())
         np.testing.assert_array_equal(got, ref)
+        # one builder, two programs: the K-step one is keyed apart from the
+        # per-batch one, whose key says nothing of K
+        assert not any(("mega", k) in key
+                       for key in base.compile_cache._entries)
+        assert any(key[2] == ("mega", k)
+                   for key in mega.compile_cache._entries)
 
     def test_k1_uncalibrated_is_bitwise_identical(self):
         """K=1 + no deposit-eligible frames == the pre-slot-staging path:

@@ -9,7 +9,7 @@
 #   tools/ci/run_ci.sh tests      # per-package matrix only
 #   tools/ci/run_ci.sh chaos      # seeded chaos lane only (-m faults matrix)
 #   tools/ci/run_ci.sh flaky      # retried serving suites only
-#   tools/ci/run_ci.sh multichip  # multichip dryrun gates + sharding bench only
+#   tools/ci/run_ci.sh multichip  # multichip dryrun gates only
 set -u
 cd "$(dirname "$0")/../.."
 
@@ -96,10 +96,6 @@ if [ "$stage" = "multichip" ] || [ "$stage" = "all" ]; then
   # forced virtual CPU devices — keep in sync with ci.yml multichip-smoke
   python __graft_entry__.py || rc=1
   python -c "import __graft_entry__ as g; g.dryrun_multichip(4)" || rc=1
-  echo "=== sharded-execution bench (1-shard vs N-shard A/B) ==="
-  python tools/bench_serving.py --only sharding || rc=1
-  echo "=== pipeline-parallel bench (serial vs pipe=2 A/B) ==="
-  python tools/bench_serving.py --only pipeline || rc=1
   [ "$stage" = "multichip" ] && exit $rc
 fi
 
