@@ -16,8 +16,11 @@ and makes LONG sequences first-class:
     single-chip or ring-parallel under ``shard_map`` — the module code does
     not change, only the mesh placement does (scaling-book style: annotate,
     let XLA/collectives do the rest).
-  - ``LSTM``/``BiLSTM``: ``lax.scan`` over time (static shapes, no Python
-    loops under jit), concat of forward/backward passes.
+  - ``LSTM``/``BiLSTM``: ``lax.scan`` over time, a block of K steps a loop
+    trip (static shapes; the K steps are the same ``cell`` called in turn),
+    concat of forward/backward passes. One step a trip made XLA write each
+    ``h_t`` as a row of every tile of the ``[T, B, H]`` output, 57% of the
+    tagger's device time; K is the rows of a tile, read from ``h``'s dtype.
 
 All modules follow module.py conventions: shapes exclude the batch dim,
 ``init -> (params, out_shape)``, bf16 matmuls via matmul_dtype().
@@ -321,8 +324,19 @@ def transformer_block(dim: int, num_heads: int, mlp_ratio: int = 4,
     ])
 
 
+def _sublane_rows(dtype) -> int:
+    """Rows of one TPU tile of ``dtype``: 8 sublanes of 32 bits, narrower
+    types packed (float32 8, bfloat16 16)."""
+    return 32 // np.dtype(dtype).itemsize
+
+
 class LSTM(Module):
-    """Unidirectional LSTM via lax.scan: [B, T, D] -> [B, T, H]."""
+    """Unidirectional LSTM via lax.scan: [B, T, D] -> [B, T, H].
+
+    The scan advances K steps a trip and writes their ``h`` as one
+    ``[K, B, H]`` block, so a write covers whole tiles of the output instead
+    of one row of each; K = min(rows of a tile of ``h``'s dtype, T), the
+    ``T % K`` steps left over run one a trip."""
 
     def __init__(self, hidden: int, reverse: bool = False):
         self.hidden = hidden
@@ -349,9 +363,13 @@ class LSTM(Module):
         B, T, D = x.shape
         h = self.hidden
         wx, wh, b = (jnp.asarray(params[k]) for k in ("wx", "wh", "b"))
-        # hoist the input projection out of the scan: one big MXU matmul
-        xp = jnp.einsum("btd,dk->btk", x.astype(jnp.float32), wx) + b
-        xp = jnp.swapaxes(xp, 0, 1)  # [T, B, 4H]
+        # time-major BEFORE the projection ([T, B, D] is small): transposing
+        # the projected [T, B, 4H] costs XLA a copy of it once the scan blocks
+        xt = jnp.swapaxes(x.astype(jnp.float32), 0, 1)
+
+        def project(v):
+            # the input projection, hoisted out of the scan: MXU matmuls
+            return jnp.einsum("tbd,dk->tbk", v, wx) + b
 
         def cell(carry, xt):
             hprev, cprev = carry
@@ -361,8 +379,43 @@ class LSTM(Module):
             hh = jax.nn.sigmoid(o) * jnp.tanh(c)
             return (hh, c), hh
 
+        def run(carry, part, k):
+            """``part`` ([n * k, B, D], a stretch of ``xt``) through the
+            recurrence, k steps a trip -> (carry, [n * k, B, H])."""
+            n = part.shape[0] // k
+            part = part.reshape(n, k, B, D)
+            # one projection a position of the block, each [n, B, 4H], in the
+            # order a trip runs them: a step then reads its input in place
+            # (one [T, B, 4H] scanned by blocks is copied out a block a trip)
+            xs = tuple(project(part[:, j]) for j in range(k))
+            if self.reverse:
+                xs = xs[::-1]
+
+            def block(carry, xb):
+                hs = []
+                for xk in xb:
+                    carry, hk = cell(carry, xk)
+                    hs.append(hk)
+                return carry, jnp.stack(hs)
+
+            carry, ys = jax.lax.scan(block, carry, xs, reverse=self.reverse)
+            if self.reverse:  # a block was stacked last step first
+                ys = ys[:, ::-1]
+            return carry, ys.reshape(n * k, B, h)
+
         zeros = jnp.zeros((B, h), dtype=jnp.float32)
-        _, ys = jax.lax.scan(cell, (zeros, zeros), xp, reverse=self.reverse)
+        init = (zeros, zeros)
+        k = min(_sublane_rows(zeros.dtype), T) or 1
+        whole = T - T % k
+        # the T % k steps past the whole blocks (none where k divides T) run
+        # one a trip
+        if self.reverse:
+            carry, tail = run(init, xt[whole:], 1)
+            _, ys = run(carry, xt[:whole], k)
+        else:
+            carry, ys = run(init, xt[:whole], k)
+            _, tail = run(carry, xt[whole:], 1)
+        ys = jnp.concatenate([ys, tail])
         return jnp.swapaxes(ys, 0, 1)  # [B, T, H]
 
 

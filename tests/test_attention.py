@@ -1,6 +1,8 @@
 """Sequence models: ring attention == dense attention on a real 8-device
 seq mesh, transformer encoder, BiLSTM tagger."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -214,16 +216,58 @@ class TestTransformer:
         np.testing.assert_allclose(got, want, atol=5e-5)
 
 
+def _plain_lstm(params, x, reverse):
+    """The recurrence as one ``lax.scan`` step a position (what ``LSTM.apply``
+    was before it blocked its scan): the yardstick for gradients and for the
+    structure guard."""
+    wx, wh, b = (jnp.asarray(params[k]) for k in ("wx", "wh", "b"))
+    xp = jnp.swapaxes(jnp.einsum("btd,dk->btk", x, wx) + b, 0, 1)
+
+    def cell(carry, xt):
+        hprev, cprev = carry
+        gates = xt + hprev @ wh
+        i, f, g, o = jnp.split(gates, 4, axis=-1)
+        c = jax.nn.sigmoid(f) * cprev + jax.nn.sigmoid(i) * jnp.tanh(g)
+        hh = jax.nn.sigmoid(o) * jnp.tanh(c)
+        return (hh, c), hh
+
+    zeros = jnp.zeros((x.shape[0], wh.shape[0]), jnp.float32)
+    _, ys = jax.lax.scan(cell, (zeros, zeros), xp, reverse=reverse)
+    return jnp.swapaxes(ys, 0, 1)
+
+
+def _scan_output_writes(hlo_text, out_elems):
+    """Element counts of the update of every ``dynamic-update-slice`` of the
+    optimised HLO whose result holds ``out_elems`` elements (the writes into
+    the scan's whole output)."""
+    def elems(dims):
+        return int(np.prod([int(d) for d in dims.split(",") if d] or [1]))
+
+    shapes, writes = {}, []
+    for line in hlo_text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.-]+) = \w+\[([0-9,]*)\]", line)
+        if not m:
+            continue
+        shapes[m.group(1)] = elems(m.group(2))
+        if " dynamic-update-slice(" in line and shapes[m.group(1)] == out_elems:
+            update = re.search(r"dynamic-update-slice\(%?[\w.-]+, %?([\w.-]+)",
+                               line).group(1)
+            writes.append(shapes[update])
+    return writes
+
+
 class TestLSTM:
-    def test_scan_matches_manual_loop(self):
+    @pytest.mark.parametrize("reverse", [False, True])
+    @pytest.mark.parametrize("T", [1, 4, 15, 16, 17, 33, 128])
+    def test_scan_matches_manual_loop(self, T, reverse):
         with matmul_precision("float32"):
-            lstm = LSTM(hidden=5)
-            params, out_shape = lstm.init(jax.random.key(0), (4, 3))
-            assert out_shape == (4, 5)
-            rng = np.random.default_rng(0)
-            x = rng.normal(size=(2, 4, 3)).astype(np.float32)
+            lstm = LSTM(hidden=5, reverse=reverse)
+            params, out_shape = lstm.init(jax.random.key(0), (T, 3))
+            assert out_shape == (T, 5)
+            rng = np.random.default_rng(T)
+            x = rng.normal(size=(2, T, 3)).astype(np.float32)
             ys = np.asarray(lstm.apply(params, jnp.asarray(x)))
-            assert ys.shape == (2, 4, 5)
+            assert ys.shape == (2, T, 5)
             # manual numpy re-implementation
             wx, wh, b = (np.asarray(params[k]) for k in ("wx", "wh", "b"))
 
@@ -232,12 +276,53 @@ class TestLSTM:
 
             h = np.zeros((2, 5))
             c = np.zeros((2, 5))
-            for t in range(4):
+            for t in (range(T - 1, -1, -1) if reverse else range(T)):
                 gates = x[:, t] @ wx + b + h @ wh
                 i, f, g, o = np.split(gates, 4, axis=-1)
                 c = sig(f) * c + sig(i) * np.tanh(g)
                 h = sig(o) * np.tanh(c)
                 np.testing.assert_allclose(ys[:, t], h, atol=1e-5)
+
+    @pytest.mark.parametrize("reverse", [False, True])
+    def test_gradients_match_plain_scan(self, reverse):
+        """The training path differentiates through the blocked scan: its
+        gradients are the plain scan's (T = 19: whole blocks and a rest)."""
+        with matmul_precision("float32"):
+            lstm = LSTM(hidden=5, reverse=reverse)
+            params, _ = lstm.init(jax.random.key(1), (19, 3))
+            params = {k: jnp.asarray(v) for k, v in params.items()}
+            rng = np.random.default_rng(2)
+            x = jnp.asarray(rng.normal(size=(3, 19, 3)).astype(np.float32))
+            w = jnp.asarray(rng.normal(size=(3, 19, 5)).astype(np.float32))
+
+            def loss(fn):
+                return lambda p, v: jnp.sum(w * fn(p, v))
+
+            got = jax.grad(loss(lstm.apply), argnums=(0, 1))(params, x)
+            want = jax.grad(loss(lambda p, v: _plain_lstm(p, v, reverse)),
+                            argnums=(0, 1))(params, x)
+            for g, e in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+                np.testing.assert_allclose(np.asarray(g), np.asarray(e),
+                                           rtol=1e-4, atol=1e-5)
+
+    @pytest.mark.parametrize("reverse", [False, True])
+    def test_scan_output_is_written_by_blocks(self, reverse):
+        """The structure, on the CPU: at T = 128 no write into the scan's
+        whole output carries one time step (a one-step write is a row of
+        every tile of the output on the TPU, PERF.md PR 33); the plain scan
+        kept above shows the detector sees one when it is there."""
+        B, T, H = 4, 128, 6
+        lstm = LSTM(hidden=H, reverse=reverse)
+        params, _ = lstm.init(jax.random.key(0), (T, 3))
+        x = jnp.zeros((B, T, 3), jnp.float32)
+
+        def writes(fn):
+            text = jax.jit(fn).lower(params, x).compile().as_text()
+            return _scan_output_writes(text, T * B * H)
+
+        assert writes(lambda p, v: _plain_lstm(p, v, reverse)) == [B * H]
+        blocked = writes(lstm.apply)
+        assert blocked and min(blocked) >= 8 * B * H, blocked
 
     def test_bilstm_backward_sees_future(self):
         bi = BiLSTM(hidden=4)
