@@ -212,6 +212,8 @@ class Segment:
                "stages": [type(s).__name__ for s in self.stages],
                "in_cols": self.external_in_cols,
                "out_cols": sorted(self.written_cols),
+               # the output nodes a batch's program hands back, in order
+               "fetched": [k for k, _ in self.readback_plan()],
                "batch_size": self.batch_size()}
         if self.stitched:  # key absent on unstitched plans: describe parity
             out["stitched"] = list(self.stitched)
@@ -578,9 +580,14 @@ class SegmentExecutor:
         if obs is None:
             return self._place_params(jax)
         params = tuple(d.params for d in self.segment.dfns)
-        nbytes = sum(int(getattr(leaf, "nbytes", 0))
-                     for leaf in jax.tree_util.tree_leaves(params))
-        with batch_span(obs, "put_params", bytes=nbytes):
+        by_dtype: Dict[str, int] = {}
+        for leaf in jax.tree_util.tree_leaves(params):
+            name = str(getattr(leaf, "dtype", type(leaf).__name__))
+            by_dtype[name] = by_dtype.get(name, 0) + int(getattr(leaf, "nbytes", 0))
+        # ``dtypes``: the bytes placed under each dtype, as they were handed
+        # in (placement casts nothing: bfloat16 weights stay bfloat16)
+        with batch_span(obs, "put_params", bytes=sum(by_dtype.values()),
+                        dtypes=",".join(f"{k}={v}" for k, v in sorted(by_dtype.items()))):
             return self._place_params(jax)
 
     def _place_params(self, jax):

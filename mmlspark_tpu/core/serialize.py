@@ -22,7 +22,7 @@ import os
 import pickle
 import shutil
 import time
-from typing import Any, Dict, List
+from typing import Any, Dict, List, Optional
 
 import numpy as np
 
@@ -53,6 +53,23 @@ def _json_default(o: Any):
     raise TypeError(f"Not JSON serializable: {type(o)}")
 
 
+def _npz_safe(arr: np.ndarray) -> np.ndarray:
+    """npz keeps only numpy's own dtypes: an extension type (bfloat16, the
+    float8s) goes in as the unsigned integers of its bytes, and the manifest
+    carries its name so that it comes back as it went."""
+    if arr.dtype.kind == "V":
+        return arr.view(f"u{arr.dtype.itemsize}")
+    return arr
+
+
+def _npz_restore(arr: np.ndarray, dtype: Optional[str]) -> np.ndarray:
+    if dtype is None or str(arr.dtype) == dtype:
+        return arr
+    import ml_dtypes  # noqa: F401 - registers the extension dtypes' names
+
+    return arr.view(np.dtype(dtype))
+
+
 def _save_value(value: Any, path: str) -> Dict[str, Any]:
     """Save one complex value under ``path``; return its type-tag manifest."""
     os.makedirs(path, exist_ok=True)
@@ -65,11 +82,11 @@ def _save_value(value: Any, path: str) -> Dict[str, Any]:
             pickle.dump(value.partitions, f)
         return {"kind": "dataframe"}
     if isinstance(value, np.ndarray) and value.dtype != object:
-        np.savez(os.path.join(path, "array.npz"), arr=value)
-        return {"kind": "ndarray"}
+        np.savez(os.path.join(path, "array.npz"), arr=_npz_safe(value))
+        return {"kind": "ndarray", "dtype": str(value.dtype)}
     if _is_jax_array(value):
-        np.savez(os.path.join(path, "array.npz"), arr=np.asarray(value))
-        return {"kind": "jax_array"}
+        np.savez(os.path.join(path, "array.npz"), arr=_npz_safe(np.asarray(value)))
+        return {"kind": "jax_array", "dtype": str(value.dtype)}
     if isinstance(value, bytes):
         with open(os.path.join(path, "blob.bin"), "wb") as f:
             f.write(value)
@@ -85,10 +102,12 @@ def _save_value(value: Any, path: str) -> Dict[str, Any]:
         if leaves and all(isinstance(l, (np.ndarray,)) or _is_jax_array(l)
                           or isinstance(l, (int, float)) for l in leaves):
             np.savez(os.path.join(path, "tree.npz"),
-                     **{f"leaf_{i}": np.asarray(l) for i, l in enumerate(leaves)})
+                     **{f"leaf_{i}": _npz_safe(np.asarray(l))
+                        for i, l in enumerate(leaves)})
             with open(os.path.join(path, "treedef.pkl"), "wb") as f:
                 pickle.dump(treedef, f)
-            return {"kind": "pytree", "num_leaves": len(leaves)}
+            return {"kind": "pytree", "num_leaves": len(leaves),
+                    "dtypes": [str(np.asarray(l).dtype) for l in leaves]}
     except Exception:
         pass
     with open(os.path.join(path, "value.pkl"), "wb") as f:
@@ -105,7 +124,7 @@ def _load_value(manifest: Dict[str, Any], path: str) -> Any:
             return DataFrame(pickle.load(f))
     if kind in ("ndarray", "jax_array"):
         with np.load(os.path.join(path, "array.npz")) as z:
-            return z["arr"]
+            return _npz_restore(z["arr"], manifest.get("dtype"))
     if kind == "bytes":
         with open(os.path.join(path, "blob.bin"), "rb") as f:
             return f.read()
@@ -114,7 +133,9 @@ def _load_value(manifest: Dict[str, Any], path: str) -> Any:
             return f.read()
     if kind == "pytree":
         with np.load(os.path.join(path, "tree.npz")) as z:
-            leaves = [z[f"leaf_{i}"] for i in range(manifest["num_leaves"])]
+            dtypes = manifest.get("dtypes") or [None] * manifest["num_leaves"]
+            leaves = [_npz_restore(z[f"leaf_{i}"], dtypes[i])
+                      for i in range(manifest["num_leaves"])]
         with open(os.path.join(path, "treedef.pkl"), "rb") as f:
             treedef = pickle.load(f)
         import jax

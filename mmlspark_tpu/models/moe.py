@@ -18,10 +18,22 @@ the all_to_alls):
 
 ``expert_shardings(mesh)`` gives the NamedShardings to place params/buffers;
 the equality test (sharded == single-device) runs on an 8-device mesh.
+
+``ExpertLayer`` is the layer a present-day sparse model asks for, and what
+expert parallelism asks of a chip anyway: told how many experts there are
+and which of them it holds, it routes every token over all of them (top-k,
+no capacity, nothing dropped) and computes the part of the result its own
+experts give. The visits to its experts are gathered sorted by expert, go
+through one grouped product for gate/up and one for down (``moe_gmm``, a
+Pallas kernel on a TPU; ``lax.ragged_dot`` elsewhere), and are combined by
+weight into the token order: memory and work grow with the visits, never with
+tokens x experts. On one chip it runs without an exchange; ``MoE`` keeps the
+mesh tests until ``ExpertLayer`` has one.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional
 
@@ -91,6 +103,277 @@ class MoE(Module):
         combined = jnp.einsum("btec,ebcd->btd", dispatch,
                               out_buf.astype(jnp.float32))
         return combined * gate[..., None]
+
+
+# ---------------------------------------------------------------------------
+# grouped matrix product over ragged groups of rows
+# ---------------------------------------------------------------------------
+
+GMM_TILE = (512, 512, 1024)      # rows, contraction, columns of one kernel step
+
+
+def _gmm_kernel(group_of, active, lhs_ref, rhs_ref, out_ref, acc_scr, *, k_steps):
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    del group_of
+    i, kk = pl.program_id(0), pl.program_id(2)
+    live = i < active[0]
+
+    @pl.when(kk == 0)
+    def _zero():
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    @pl.when(live)
+    def _accumulate():
+        acc_scr[...] += jnp.dot(lhs_ref[...], rhs_ref[...],
+                                preferred_element_type=jnp.float32)
+
+    @pl.when(kk == k_steps - 1)
+    def _store():            # a tile past the last group's rows stores zeros
+        out_ref[...] = acc_scr[...].astype(out_ref.dtype)
+
+
+def gmm_pallas(lhs, rhs, group_sizes, out_dtype, interpret: bool = False):
+    """``lhs [M, K]`` rows in consecutive groups of ``group_sizes [G]`` rows,
+    group g times ``rhs[g] [K, N]`` -> ``[M, N]``. Every group's size is a
+    multiple of the row tile (the caller pads each group), so a tile of rows
+    belongs to one group; tiles past the last group's rows cost a step that
+    computes nothing and fetches nothing new."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    (M, K), (G, _, N) = lhs.shape, rhs.shape
+    tm, tk, tn = (min(t, d) for t, d in zip(GMM_TILE, (M, K, N)))
+    if M % tm or K % tk or N % tn:
+        raise ValueError(f"moe_gmm: {(M, K, N)} is not whole tiles of {(tm, tk, tn)}")
+    ends = jnp.cumsum(group_sizes.astype(jnp.int32))
+    tile_start = jnp.arange(M // tm, dtype=jnp.int32) * tm
+    group_of = jnp.minimum(jnp.sum(tile_start[:, None] >= ends[None, :], axis=1),
+                           G - 1).astype(jnp.int32)
+    active = (ends[-1:] // tm).astype(jnp.int32)
+
+    def at(i, j, kk, active):
+        """(row tile, column tile, contraction step) a step reads: a tile past
+        the last group's rows stays on the block fetched last, so nothing
+        moves for it."""
+        live = i < active[0]
+        return (jnp.where(live, i, jnp.maximum(active[0] - 1, 0)),
+                jnp.where(live, j, N // tn - 1), jnp.where(live, kk, K // tk - 1))
+
+    def lhs_index(i, j, kk, group_of, active):
+        i, _, kk = at(i, j, kk, active)
+        return i, kk
+
+    def rhs_index(i, j, kk, group_of, active):
+        i, j, kk = at(i, j, kk, active)
+        return group_of[i], kk, j
+
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, k_steps=K // tk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(M // tm, N // tn, K // tk),
+            in_specs=[pl.BlockSpec((tm, tk), lhs_index),
+                      pl.BlockSpec((None, tk, tn), rhs_index)],
+            out_specs=pl.BlockSpec((tm, tn), lambda i, j, kk, g, a: (i, j)),
+            scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="moe_gmm",          # the name a device trace shows
+        cost_estimate=pl.CostEstimate(
+            flops=2 * M * K * N, transcendentals=0,
+            bytes_accessed=int(lhs.size * lhs.dtype.itemsize * (N // tn)
+                               + (M // tm) * K * N * rhs.dtype.itemsize
+                               + M * N * np.dtype(out_dtype).itemsize)),
+        interpret=interpret,
+    )(group_of, active, lhs, rhs)
+
+
+def _gmm_ragged(lhs, rhs, group_sizes, out_dtype):
+    import jax
+    import jax.numpy as jnp
+
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes.astype(jnp.int32),
+                              preferred_element_type=jnp.float32).astype(out_dtype)
+
+
+@functools.lru_cache(maxsize=None)
+def _gmm_kernel_vjp():
+    """The kernel with a backward pass: the ragged product's, recomputed."""
+    import jax
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+    def gmm(lhs, rhs, group_sizes, out_dtype):
+        return gmm_pallas(lhs, rhs, group_sizes, out_dtype)
+
+    def fwd(lhs, rhs, group_sizes, out_dtype):
+        return gmm_pallas(lhs, rhs, group_sizes, out_dtype), (lhs, rhs, group_sizes)
+
+    def bwd(out_dtype, res, g):
+        lhs, rhs, group_sizes = res
+        _, vjp = jax.vjp(lambda a, b: _gmm_ragged(a, b, group_sizes, out_dtype),
+                         lhs, rhs)
+        return (*vjp(g), None)
+
+    gmm.defvjp(fwd, bwd)
+    return gmm
+
+
+def _gmm_tile_rows(x) -> int:
+    """Rows every group is padded to: the kernel's row tile where the kernel
+    runs (a TPU, bfloat16 operands), 1 (no padding) for the ragged product."""
+    import jax
+    import jax.numpy as jnp
+
+    return GMM_TILE[0] if jax.default_backend() == "tpu" \
+        and x.dtype == jnp.bfloat16 else 1
+
+
+def grouped_matmul(lhs, rhs, group_sizes, out_dtype, tile_rows: int):
+    if tile_rows > 1:
+        return _gmm_kernel_vjp()(lhs, rhs, group_sizes, out_dtype)
+    return _gmm_ragged(lhs, rhs, group_sizes, out_dtype)
+
+
+class ExpertLayer(Module):
+    """Dropless top-k expert FFN on ``[B, T, D]`` that holds ``experts_held``
+    of ``num_experts`` SwiGLU experts of width ``hidden``, from
+    ``first_expert`` on. The router is ``num_experts`` wide: scores
+    (``scoring``: sigmoid or softmax) in float32, the ``top_k`` largest of
+    score + selection bias chosen, their scores normalised over the chosen
+    (``norm_topk``) and scaled by ``scale``. The result is the held experts'
+    part of the sum; what the others would add is another holder's to compute.
+
+    Parameters: ``router [D, E]``, ``router_bias [E]``, ``w1 [held, D, 2
+    hidden]`` (gate's columns first), ``w2 [held, hidden, D]``: the leaves
+    ``expert_shardings`` shards by their leading dim."""
+
+    def __init__(self, num_experts: int, experts_held: int, top_k: int,
+                 hidden: int, scoring: str = "sigmoid", norm_topk: bool = True,
+                 scale: float = 1.0, first_expert: int = 0,
+                 param_dtype: str = "float32"):
+        if scoring not in ("sigmoid", "softmax"):
+            raise ValueError(f"unknown scoring {scoring!r}")
+        if not 0 <= first_expert <= num_experts - experts_held:
+            raise ValueError("the experts held are not among the router's")
+        self.num_experts, self.experts_held = num_experts, experts_held
+        self.top_k, self.hidden, self.scoring = top_k, hidden, scoring
+        self.norm_topk, self.scale, self.first_expert = norm_topk, scale, first_expert
+        self.param_dtype = param_dtype
+
+    def init(self, rng, in_shape):
+        import jax
+
+        t, d = in_shape
+        kr, kb, k1, k2 = _rng_split(rng, 4)
+        dt, held, h = self.param_dtype, self.experts_held, self.hidden
+
+        def normal(k, shape, std):
+            return (jax.random.normal(k, shape, np.float32)
+                    * np.float32(std)).astype(dt)
+
+        return {"router": normal(kr, (d, self.num_experts), d ** -0.5),
+                "router_bias": normal(kb, (self.num_experts,), 0.01),
+                "w1": normal(k1, (held, d, 2 * h), d ** -0.5),
+                "w2": normal(k2, (held, h, d), h ** -0.5)}, (t, d)
+
+    def route(self, params, x):
+        """``x [N, D]`` -> (chosen experts ``[N, k]`` int32, their weights
+        ``[N, k]`` float32): the whole router, in float32."""
+        import jax
+        import jax.numpy as jnp
+
+        logits = jnp.dot(x.astype(jnp.float32),
+                         jnp.asarray(params["router"]).astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        s = jax.nn.sigmoid(logits) if self.scoring == "sigmoid" \
+            else jax.nn.softmax(logits, axis=-1)
+        _, idx = jax.lax.top_k(
+            s + jnp.asarray(params["router_bias"]).astype(jnp.float32), self.top_k)
+        g = jnp.take_along_axis(s, idx, axis=-1)
+        if self.norm_topk:
+            g = g / jnp.sum(g, axis=-1, keepdims=True)
+        return idx.astype(jnp.int32), g * np.float32(self.scale)
+
+    def apply_with_load(self, params, x, add_to=None):
+        """(``[B, T, D]`` float32, ``[B, experts held]`` float32 visits);
+        ``add_to`` (``[B, T, D]`` float32) is what the result is added to."""
+        import jax
+        import jax.numpy as jnp
+
+        B, T, D = x.shape
+        N, K, held = B * T, self.top_k, self.experts_held
+        dt = getattr(jnp, matmul_dtype())
+        xd = x.reshape(N, D).astype(dt)
+        idx, gate = self.route(params, x.reshape(N, D))
+        local = idx - self.first_expert
+        mine = (local >= 0) & (local < held)                       # [N, K]
+        local = jnp.where(mine, local, held)
+        hot = (local[..., None] == jnp.arange(held)).astype(jnp.int32)   # [N, K, held]
+        load = hot.reshape(B, T * K, held).sum(axis=1)
+        counts = load.sum(axis=0)                                  # [held]
+
+        # the visits sorted by expert, each expert's rows padded to whole tiles
+        tile = _gmm_tile_rows(xd)
+        order = jnp.argsort(local.reshape(N * K), stable=True).astype(jnp.int32)
+        rank = jnp.zeros((N * K,), jnp.int32).at[order].set(
+            jnp.arange(N * K, dtype=jnp.int32))                    # place in the sort
+        padded = -(-counts // tile) * tile
+        p_end = jnp.cumsum(padded)
+        p_start, start = p_end - padded, jnp.cumsum(counts) - counts
+        start_of = jnp.concatenate([start, start[-1:]])            # the sentinel's: unused
+        p_start_of = jnp.concatenate([p_start, p_start[-1:]])
+        # where each (token, choice) sits among the padded rows
+        place = rank.reshape(N, K) - start_of[local] + p_start_of[local]
+
+        # the rows go through `rows` at a time: twice what the held experts
+        # expect, so one trip unless the routing is very uneven (a second
+        # trip costs its gathers again); a trip with nothing in it is skipped
+        most = -(-(N * K + held * (tile - 1)) // tile) * tile
+        expect = -(-N * K * held // self.num_experts)
+        rows = min(-(-(2 * max(expect, tile) + held * (tile - 1)) // tile) * tile, most)
+        trips = -(-most // rows)
+        w1 = jnp.asarray(params["w1"]).astype(dt)
+        w2 = jnp.asarray(params["w2"]).astype(dt)
+
+        def trip(y, c):
+            at = c * rows
+
+            def run(y):
+                j = at + jnp.arange(rows, dtype=jnp.int32)
+                g = jnp.sum(j[:, None] >= p_end[None, :], axis=1)          # group of row j
+                gc = jnp.minimum(g, held - 1)
+                r = j - p_start[gc]
+                real = (g < held) & (r < counts[gc])
+                visit = order[jnp.where(real, start[gc] + r, 0)]
+                xg = jnp.where(real[:, None], xd[visit // K], 0).astype(dt)
+                sizes = jnp.clip(p_end - at, 0, rows) - jnp.clip(p_start - at, 0, rows)
+                hid = grouped_matmul(xg, w1, sizes, dt, tile)
+                gate_h, up_h = jnp.split(hid.astype(jnp.float32), 2, axis=-1)
+                act = (jax.nn.silu(gate_h) * up_h).astype(dt)
+                out = grouped_matmul(act, w2, sizes, dt, tile)             # [rows, D]
+
+                def choice(y, c):           # each token's s-th choice, if it is here
+                    at_s, here, weight = c
+                    here &= (at_s >= at) & (at_s < at + rows)
+                    got = out[jnp.clip(at_s - at, 0, rows - 1)].astype(jnp.float32)
+                    return y + jnp.where(here[:, None], weight[:, None] * got, 0.0), None
+
+                return jax.lax.scan(choice, y, (place.T, mine.T, gate.T))[0]
+
+            return jax.lax.cond(at < p_end[-1], run, lambda y: y, y), None
+
+        y = jnp.zeros((N, D), jnp.float32) if add_to is None \
+            else add_to.reshape(N, D).astype(jnp.float32)
+        y, _ = jax.lax.scan(trip, y, jnp.arange(trips, dtype=jnp.int32))
+        return y.reshape(B, T, D), load.astype(jnp.float32)
+
+    def apply(self, params, x, train: bool = False):
+        return self.apply_with_load(params, x)[0]
 
 
 def expert_shardings(mesh, params):

@@ -1,0 +1,603 @@
+"""The parts of a present-day causal language model, and the scorer built
+from them: RMSNorm, rotary positions, grouped-query attention with a causal
+mask and an optional window, SwiGLU, a dropless expert layer
+(``moe.ExpertLayer``), and ``CausalLM`` / ``causal_lm``, whose output is one
+number a position (the next token's log-probability): the logits never leave
+the device.
+
+Parameters keep the dtype they are handed (``causal_lm(param_dtype=
+"bfloat16")`` makes them bfloat16): a weight matrix is cast to
+``matmul_dtype()`` where it is used, which is no copy when it already has it.
+Norms, the router's scores, softmax and ``log_softmax`` run in float32, and so
+does the residual stream.
+
+Attention runs one of two ways, both grouped (a key/value head is read by its
+query heads in place, never repeated in memory):
+
+  - ``attn_window`` / ``attn_full``: one Pallas kernel under two names (what
+    a device trace shows), a streaming softmax over key blocks. A sliding
+    layer visits only the blocks its window touches, so its work and memory
+    grow with ``T x window``; a full layer stops at the diagonal. Taken on a
+    TPU for bfloat16 heads of 128 lanes at block-aligned ``T``.
+  - plain XLA otherwise (the CPU, float32 tests): a banded two-block form
+    for a window, query blocks against all keys for a full layer; neither
+    holds a ``[heads, T, T]`` tensor. It is also the kernel's VJP (recomputed).
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
+
+import numpy as np
+
+from .module import FunctionModel, Module, _rng_split, matmul_dtype
+
+_NEG = -1e30          # masked score: finite, so a fully masked block stays finite
+
+
+def _mm_dtype():
+    import jax.numpy as jnp
+
+    return getattr(jnp, matmul_dtype())
+
+
+def _normal(rng, shape, std: float, dtype):
+    import jax
+
+    return (jax.random.normal(rng, shape, np.float32) * np.float32(std)).astype(dtype)
+
+
+def rms_norm(x, gain, eps: float):
+    """RMSNorm over the last dim in float32 (the result stays float32)."""
+    import jax
+    import jax.numpy as jnp
+
+    xf = x.astype(jnp.float32)
+    inv = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return xf * inv * jnp.asarray(gain).astype(jnp.float32)
+
+
+def rotary(x, theta: float):
+    """Rotary positions over the whole head of ``[B, T, heads, hd]`` float32:
+    the head's two halves rotate against each other (``rotate_half``)."""
+    import jax.numpy as jnp
+
+    t, hd = x.shape[1], x.shape[-1]
+    inv = 1.0 / theta ** (np.arange(0, hd, 2, dtype=np.float64) / hd)
+    ang = np.arange(t, dtype=np.float64)[:, None] * inv[None, :]
+    cos = jnp.asarray(np.cos(ang), jnp.float32)[None, :, None, :]
+    sin = jnp.asarray(np.sin(ang), jnp.float32)[None, :, None, :]
+    a, b = x[..., :hd // 2], x[..., hd // 2:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+
+
+# ---------------------------------------------------------------------------
+# grouped-query attention: plain XLA
+# ---------------------------------------------------------------------------
+
+def _softmax_rows(s, seen):
+    """Softmax over the last dim of float32 scores, keys not ``seen`` out."""
+    import jax.numpy as jnp
+
+    s = jnp.where(seen, s, _NEG)
+    p = jnp.where(seen, jnp.exp(s - jnp.max(s, axis=-1, keepdims=True)), 0.0)
+    return p / jnp.sum(p, axis=-1, keepdims=True)
+
+
+def gqa_xla(q, k, v, window: int):
+    """q ``[B, T, H, D]``, k / v ``[B, T, KV, D]`` -> ``[B, T, H, D]``; causal,
+    query t sees keys ``t - window < s <= t`` (every ``s <= t`` at window 0).
+    Blocked over queries: a window reads its own and the previous block of
+    ``window`` keys, a full layer a block of queries against all keys."""
+    import jax
+    import jax.numpy as jnp
+
+    B, T, H, D = q.shape
+    KV = k.shape[2]
+    blk = window if window else min(T, 512)
+    pad = -T % blk
+    if pad:
+        q, k, v = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0))) for a in (q, k, v))
+    nb = (T + pad) // blk
+    scale = np.float32(1.0 / math.sqrt(D))
+    qb = q.reshape(B, nb, blk, KV, H // KV, D)
+
+    def probs_values(qn, kk, vv, qpos, kpos):
+        # qn [B, q, KV, G, D], kk / vv [B, s, KV, D]; positions are the row's own
+        s = jnp.einsum("bqkgd,bskd->bkgqs", qn, kk,
+                       preferred_element_type=jnp.float32) * scale
+        seen = (kpos[None, :] <= qpos[:, None]) & (kpos[None, :] >= 0)
+        if window:
+            seen &= kpos[None, :] > qpos[:, None] - window
+        p = _softmax_rows(s, seen)
+        return jnp.einsum("bkgqs,bskd->bqkgd", p.astype(vv.dtype), vv,
+                          preferred_element_type=jnp.float32)
+
+    first = jnp.arange(nb) * blk                  # each query block's first position
+    if window:
+        def band(a):       # each block of keys behind its predecessor: [B, nb, 2 blk, KV, D]
+            a = a.reshape(B, nb, blk, KV, D)
+            prev = jnp.concatenate([jnp.zeros_like(a[:, :1]), a[:, :-1]], axis=1)
+            return jnp.concatenate([prev, a], axis=2)
+
+        # the first block's predecessor lies before the row: positions below 0
+        o = jax.vmap(lambda qn, kk, vv, at: probs_values(
+            qn, kk, vv, at + jnp.arange(blk), at + jnp.arange(-blk, blk)),
+            in_axes=(1, 1, 1, 0), out_axes=1)(qb, band(k), band(v), first)
+    else:
+        kpos = jnp.arange(T + pad)
+        o = jax.lax.map(
+            lambda a: probs_values(a[0], k, v, a[1] + jnp.arange(blk), kpos),
+            (jnp.moveaxis(qb, 1, 0), first))
+        o = jnp.moveaxis(o, 0, 1)
+    return o.reshape(B, T + pad, H, D)[:, :T].astype(v.dtype)
+
+
+# ---------------------------------------------------------------------------
+# grouped-query attention: the Pallas kernel
+# ---------------------------------------------------------------------------
+
+def _attn_blocks(T: int, window: int) -> Optional[Tuple[int, int]]:
+    """(query block, key block) the kernel takes at this length, or None."""
+    if T % 128:
+        return None
+    bq = next(b for b in (512, 256, 128) if T % b == 0)
+    if window:
+        return min(bq, 256), 128
+    return bq, bq
+
+
+def _kv_steps(bq: int, bk: int, T: int, window: int) -> int:
+    """Key blocks a query block may touch: all of them, or the window's band."""
+    if not window:
+        return T // bk
+    return min(T // bk, bq // bk + -(-(window - 1) // bk))
+
+
+def _first_kv(qi, bq: int, bk: int, window: int):
+    """The first key block the query block ``qi`` reads."""
+    import jax.numpy as jnp
+
+    if not window:
+        return 0
+    return jnp.maximum(qi * bq - (window - 1), 0) // bk
+
+
+def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
+                 bq: int, bk: int, window: int, steps: int, scale: float,
+                 group: int, d: int):
+    """One block of queries of the ``group`` query heads that share a
+    key/value head, against one block of its keys: the heads run in turn over
+    the same key and value tiles (fetched once for all of them), each with
+    its own running maximum, denominator and accumulator."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    qi, j = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, _NEG)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    kb = _first_kv(qi, bq, bk, window) + j
+    last = (qi * bq + bq - 1) // bk              # the block of the diagonal
+
+    @pl.when(kb <= last)
+    def _block():
+        k, v = k_ref[...], v_ref[...]
+        qpos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+        kpos = kb * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        seen = kpos <= qpos
+        if window:
+            seen = jnp.logical_and(seen, kpos > qpos - window)
+        for g in range(group):
+            q = q_ref[:, g * d:(g + 1) * d]
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32) * scale
+            s = jnp.where(seen, s, _NEG)
+            m_prev = m_scr[g]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.where(seen, jnp.exp(s - m_new[:, :1]), 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            l_scr[g] = alpha * l_scr[g] + jnp.sum(p, axis=1, keepdims=True)
+            acc_scr[g] = alpha[:, :1] * acc_scr[g] + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[g] = m_new
+
+    @pl.when(j == steps - 1)
+    def _store():
+        for g in range(group):
+            o_ref[:, g * d:(g + 1) * d] = (
+                acc_scr[g] / l_scr[g][:, :1]).astype(o_ref.dtype)
+
+
+def gqa_pallas(q, k, v, window: int, heads: int, kv_heads: int,
+               interpret: bool = False):
+    """q ``[B, T, H * D]``, k / v ``[B, T, KV * D]`` -> ``[B, T, H * D]``: a
+    head is a block of 128 lanes of the projection's own layout, so nothing
+    is transposed; the ``H / KV`` query heads of a key/value head lie side by
+    side and go through one grid step together."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, T, _ = q.shape
+    D = q.shape[2] // heads
+    group = heads // kv_heads
+    bq, bk = _attn_blocks(T, window)
+    steps = _kv_steps(bq, bk, T, window)
+
+    def kv_index(b, h, qi, j):
+        # past the diagonal the index stays put: no block is fetched for a
+        # step that computes nothing
+        kb = jnp.minimum(_first_kv(qi, bq, bk, window) + j, (qi * bq + bq - 1) // bk)
+        return b, kb, h
+
+    kernel = functools.partial(_attn_kernel, bq=bq, bk=bk, window=window,
+                               steps=steps, scale=1.0 / math.sqrt(D),
+                               group=group, d=D)
+    pairs = T * (min(window, T) if window else (T + 1) / 2)   # (query, key) seen
+    return pl.pallas_call(
+        kernel,
+        grid=(B, kv_heads, T // bq, steps),
+        in_specs=[pl.BlockSpec((None, bq, group * D), lambda b, h, qi, j: (b, qi, h)),
+                  pl.BlockSpec((None, bk, D), kv_index),
+                  pl.BlockSpec((None, bk, D), kv_index)],
+        out_specs=pl.BlockSpec((None, bq, group * D), lambda b, h, qi, j: (b, qi, h)),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        scratch_shapes=[pltpu.VMEM((group, bq, 128), jnp.float32),
+                        pltpu.VMEM((group, bq, 128), jnp.float32),
+                        pltpu.VMEM((group, bq, D), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        # the names a device trace shows (docs/observability.md)
+        name="attn_window" if window else "attn_full",
+        cost_estimate=pl.CostEstimate(
+            flops=int(4 * B * heads * D * pairs),
+            transcendentals=int(B * heads * pairs),
+            bytes_accessed=int(2 * q.size * q.dtype.itemsize
+                               + 2 * k.size * k.dtype.itemsize * (T // bq))),
+        interpret=interpret,
+    )(q, k, v)
+
+
+def _pallas_applies(q, heads: int, window: int) -> bool:
+    import jax
+    import jax.numpy as jnp
+
+    return (jax.default_backend() == "tpu" and q.dtype == jnp.bfloat16
+            and q.shape[2] // heads == 128
+            and _attn_blocks(q.shape[1], window) is not None)
+
+
+def _gqa_flat_xla(q, k, v, window: int, heads: int, kv_heads: int):
+    B, T, _ = q.shape
+    o = gqa_xla(q.reshape(B, T, heads, -1), k.reshape(B, T, kv_heads, -1),
+                v.reshape(B, T, kv_heads, -1), window)
+    return o.reshape(B, T, -1)
+
+
+@functools.lru_cache(maxsize=None)
+def _gqa_kernel():
+    """The kernel with a backward pass: it has none of its own, so the
+    gradient is the plain form's, recomputed."""
+    import jax
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+    def attend(q, k, v, window, heads, kv_heads):
+        return gqa_pallas(q, k, v, window, heads, kv_heads)
+
+    def fwd(q, k, v, window, heads, kv_heads):
+        return gqa_pallas(q, k, v, window, heads, kv_heads), (q, k, v)
+
+    def bwd(window, heads, kv_heads, res, g):
+        _, vjp = jax.vjp(lambda q, k, v: _gqa_flat_xla(
+            q, k, v, window, heads, kv_heads), *res)
+        return vjp(g)
+
+    attend.defvjp(fwd, bwd)
+    return attend
+
+
+def gq_attention(q, k, v, window: int, heads: int, kv_heads: int):
+    """Causal grouped-query attention over flat heads (``[B, T, heads * D]``):
+    the kernel where it applies, the plain form elsewhere."""
+    if _pallas_applies(q, heads, window):
+        return _gqa_kernel()(q, k, v, window, heads, kv_heads)
+    return _gqa_flat_xla(q, k, v, window, heads, kv_heads)
+
+
+# ---------------------------------------------------------------------------
+# modules
+# ---------------------------------------------------------------------------
+
+class RMSNorm(Module):
+    """RMSNorm over the last dim: float32 in the arithmetic and the result."""
+
+    def __init__(self, eps: float = 1e-5, param_dtype: str = "float32"):
+        self.eps = eps
+        self.param_dtype = param_dtype
+
+    def init(self, rng, in_shape):
+        return {"scale": np.ones((in_shape[-1],), self.param_dtype)}, tuple(in_shape)
+
+    def apply(self, params, x, train: bool = False):
+        return rms_norm(x, params["scale"], self.eps)
+
+
+class GQAttention(Module):
+    """Causal grouped-query self-attention on ``[B, T, D]``: ``heads`` query
+    heads over ``kv_heads`` key/value heads of ``head_dim``, ``window`` keys
+    back (0: all of them), RMSNorm over each query and key head (``qk_norm``),
+    rotary positions (``rope_theta``; None: none)."""
+
+    def __init__(self, heads: int, kv_heads: int, head_dim: int, window: int = 0,
+                 qk_norm: bool = True, rope_theta: Optional[float] = None,
+                 eps: float = 1e-5, param_dtype: str = "float32"):
+        if heads % kv_heads:
+            raise ValueError(f"{heads} query heads over {kv_heads} key/value heads")
+        self.heads, self.kv_heads, self.head_dim = heads, kv_heads, head_dim
+        self.window, self.qk_norm, self.rope_theta = window, qk_norm, rope_theta
+        self.eps, self.param_dtype = eps, param_dtype
+
+    def init(self, rng, in_shape):
+        t, d = in_shape
+        hq, hkv = self.heads * self.head_dim, self.kv_heads * self.head_dim
+        keys = _rng_split(rng, 4)
+        dt = self.param_dtype
+        params = {"wq": _normal(keys[0], (d, hq), d ** -0.5, dt),
+                  "wk": _normal(keys[1], (d, hkv), d ** -0.5, dt),
+                  "wv": _normal(keys[2], (d, hkv), d ** -0.5, dt),
+                  "wo": _normal(keys[3], (hq, d), hq ** -0.5, dt)}
+        if self.qk_norm:
+            params["q_norm"] = np.ones((self.head_dim,), dt)
+            params["k_norm"] = np.ones((self.head_dim,), dt)
+        return params, (t, d)
+
+    def apply(self, params, x, train: bool = False):
+        import jax.numpy as jnp
+
+        dt = _mm_dtype()
+        B, T, _ = x.shape
+        xd = x.astype(dt)
+
+        def project(name):
+            return jnp.dot(xd, jnp.asarray(params[name]).astype(dt),
+                           preferred_element_type=jnp.float32)
+
+        def heads_of(name, n, gain):
+            y = project(name).reshape(B, T, n, self.head_dim)
+            if gain is not None:
+                y = rms_norm(y, gain, self.eps)
+            if self.rope_theta is not None:
+                y = rotary(y, self.rope_theta)
+            return y.astype(dt).reshape(B, T, n * self.head_dim)
+
+        q = heads_of("wq", self.heads, params.get("q_norm"))
+        k = heads_of("wk", self.kv_heads, params.get("k_norm"))
+        o = gq_attention(q, k, project("wv").astype(dt), self.window,
+                         self.heads, self.kv_heads)
+        return jnp.dot(o, jnp.asarray(params["wo"]).astype(dt),
+                       preferred_element_type=jnp.float32)
+
+
+def _by_rows(fn, x, most_tokens: int = 8192):
+    """``fn`` over ``x [B, T, D]``, rows in equal groups of at most
+    ``most_tokens`` tokens one after the other: what a sublayer holds between
+    its products (float32 heads before their norm, a wide layer's hidden
+    units) then never stands for the whole batch."""
+    import jax
+
+    B, T, D = x.shape
+    groups = -(-B // max(1, most_tokens // T))
+    while B % groups:
+        groups += 1
+    if groups == 1:
+        return fn(x)
+    return jax.lax.map(fn, x.reshape(groups, B // groups, T, D)).reshape(x.shape)
+
+
+class SwiGLU(Module):
+    """``W_down(silu(W_gate x) * W_up x)`` over the last dim, gate and up as
+    one ``[D, 2 hidden]`` matrix (the gate's columns first)."""
+
+    def __init__(self, hidden: int, param_dtype: str = "float32"):
+        self.hidden = hidden
+        self.param_dtype = param_dtype
+
+    def init(self, rng, in_shape):
+        d = in_shape[-1]
+        k1, k2 = _rng_split(rng, 2)
+        return {"w_gate_up": _normal(k1, (d, 2 * self.hidden), d ** -0.5,
+                                     self.param_dtype),
+                "w_down": _normal(k2, (self.hidden, d), self.hidden ** -0.5,
+                                  self.param_dtype)}, tuple(in_shape)
+
+    def apply(self, params, x, train: bool = False):
+        import jax
+        import jax.numpy as jnp
+
+        dt = _mm_dtype()
+        w1 = jnp.asarray(params["w_gate_up"]).astype(dt)
+        w2 = jnp.asarray(params["w_down"]).astype(dt)
+
+        # the hidden pre-activations leave the product in the operands' dtype
+        # (accumulated in float32): a float32 copy of them would be the
+        # largest array of a wide layer
+        hid = jnp.dot(x.astype(dt), w1, preferred_element_type=dt)
+        gate, up = jnp.split(hid.astype(jnp.float32), 2, axis=-1)
+        return jnp.dot((jax.nn.silu(gate) * up).astype(dt), w2,
+                       preferred_element_type=jnp.float32)
+
+
+class DecoderLayer(Module):
+    """``h = x + Attn(RMSNorm(x)); x' = h + FFN(RMSNorm(h))``: the FFN a
+    ``SwiGLU`` (``mlp``), or an ``ExpertLayer`` (``moe``) beside a shared
+    ``SwiGLU`` (``shared``). ``apply_with_load`` also returns the expert
+    layer's ``[B, experts held]`` visit counts (None for a dense layer)."""
+
+    def __init__(self, attn: GQAttention, mlp: Optional[SwiGLU] = None,
+                 moe: Optional[Module] = None, shared: Optional[SwiGLU] = None,
+                 eps: float = 1e-5, param_dtype: str = "float32"):
+        if (mlp is None) == (moe is None):
+            raise ValueError("a layer has a dense MLP or an expert layer, one of them")
+        self.parts: List[Tuple[str, Module]] = [
+            ("attn_norm", RMSNorm(eps, param_dtype)), ("attn", attn),
+            ("mlp_norm", RMSNorm(eps, param_dtype))]
+        self.parts += [(n, m) for n, m in (("mlp", mlp), ("moe", moe),
+                                           ("shared", shared)) if m is not None]
+
+    def init(self, rng, in_shape):
+        keys = _rng_split(rng, len(self.parts))
+        return {n: m.init(k, in_shape)[0]
+                for (n, m), k in zip(self.parts, keys)}, tuple(in_shape)
+
+    def apply_with_load(self, params, x):
+        part = dict(self.parts)
+
+        def sublayer(name, norm):
+            def run(xc):
+                return xc + part[name].apply(
+                    params[name], part[norm].apply(params[norm], xc))
+            return run
+
+        h = _by_rows(sublayer("attn", "attn_norm"), x)
+        if "mlp" in part:
+            return _by_rows(sublayer("mlp", "mlp_norm"), h), None
+        hn = part["mlp_norm"].apply(params["mlp_norm"], h)
+        if "shared" in part:
+            h = h + part["shared"].apply(params["shared"], hn)
+        return part["moe"].apply_with_load(params["moe"], hn, add_to=h)
+
+    def apply(self, params, x, train: bool = False):
+        return self.apply_with_load(params, x)[0]
+
+
+class CausalLM(Module):
+    """Token ids ``[B, T]`` -> the next token's log-probability at every
+    position, ``[B, T]`` float32: ``out[t] = log_softmax(logits_t)[id_{t+1}]``,
+    the last position's target the pad id. The logits exist a row of the
+    batch at a time, on the device, and are never an output.
+
+    A container for ``DNNModel``'s ``fetchDict``: the node ``expert_load``
+    is ``[B, sparse layers, experts held]``, the visits each held expert took
+    from that row's positions (float32: what the routing counted)."""
+
+    is_container = True
+    LOAD = "expert_load"
+
+    def __init__(self, vocab_size: int, hidden: int, layers: Sequence[DecoderLayer],
+                 pad_id: int = 0, eps: float = 1e-5, param_dtype: str = "float32"):
+        self.vocab_size, self.hidden, self.pad_id = vocab_size, hidden, pad_id
+        self.layers = list(layers)
+        self.final_norm = RMSNorm(eps, param_dtype)
+        self.param_dtype = param_dtype
+
+    def init(self, rng, in_shape):
+        (t,) = in_shape
+        keys = _rng_split(rng, len(self.layers) + 2)
+        params: Dict[str, Any] = {
+            "embed": {"table": _normal(keys[0], (self.vocab_size, self.hidden), 1.0,
+                                       self.param_dtype)},
+            "final_norm": self.final_norm.init(None, (t, self.hidden))[0],
+            "head": {"kernel": _normal(keys[1], (self.hidden, self.vocab_size),
+                                       self.hidden ** -0.5, self.param_dtype)}}
+        for i, (layer, k) in enumerate(zip(self.layers, keys[2:])):
+            params[f"layer{i}"] = layer.init(k, (t, self.hidden))[0]
+        return params, (t,)
+
+    def layer_paths(self, prefix: str = "") -> List[str]:
+        return [prefix + self.LOAD] + [f"{prefix}layer{i}" for i in range(len(self.layers))]
+
+    def _log_probs(self, params, x, ids):
+        import jax
+        import jax.numpy as jnp
+
+        dt = _mm_dtype()
+        head = jnp.asarray(params["head"]["kernel"]).astype(dt)
+        target = jnp.concatenate(
+            [ids[:, 1:], jnp.full_like(ids[:, :1], self.pad_id)], axis=1)
+
+        def row(a):
+            xr, tr = a
+            xn = self.final_norm.apply(params["final_norm"], xr).astype(dt)
+            logits = jnp.dot(xn, head, preferred_element_type=jnp.float32)
+            picked = jnp.take_along_axis(logits, tr[:, None], axis=-1)[:, 0]
+            return picked - jax.nn.logsumexp(logits, axis=-1)
+
+        return jax.lax.map(row, (x, target))
+
+    def apply(self, params, x, train: bool = False,
+              taps: Optional[Set[str]] = None,
+              taps_out: Optional[Dict[str, Any]] = None,
+              stats_out: Optional[Dict[str, Any]] = None, _prefix: str = ""):
+        import jax.numpy as jnp
+
+        ids = x.astype(jnp.int32)
+        h = jnp.take(jnp.asarray(params["embed"]["table"]), ids, axis=0
+                     ).astype(jnp.float32)
+        loads = []
+        for i, layer in enumerate(self.layers):
+            h, load = layer.apply_with_load(params[f"layer{i}"], h)
+            if load is not None:
+                loads.append(load)
+            if taps and taps_out is not None and f"{_prefix}layer{i}" in taps:
+                taps_out[f"{_prefix}layer{i}"] = h
+        if taps and taps_out is not None and _prefix + self.LOAD in taps:
+            if not loads:
+                raise KeyError("expert_load: the model has no expert layer")
+            taps_out[_prefix + self.LOAD] = jnp.stack(loads, axis=1)
+        return self._log_probs(params, h, ids)
+
+
+def causal_lm(seq_len: int, vocab_size: int, hidden: int, heads: int,
+              kv_heads: int, head_dim: int, windows: Sequence[int],
+              sparse: Sequence[bool], dense_hidden: int, expert_hidden: int,
+              num_experts: int, experts_held: int, top_k: int,
+              first_expert: int = 0, scoring: str = "sigmoid",
+              norm_topk: bool = True, scale: float = 1.0,
+              shared_experts: int = 1, rope_theta: float = 1e6,
+              rope_layers: str = "sliding", qk_norm: bool = True,
+              eps: float = 1e-5, pad_id: int = 0,
+              param_dtype: str = "float32", seed: int = 0,
+              init: bool = True) -> FunctionModel:
+    """A decoder-only scorer as a FunctionModel: layer i attends
+    ``windows[i]`` keys back (0: full) and has an expert layer where
+    ``sparse[i]``, else a dense SwiGLU. ``rope_layers``: which layers get
+    rotary positions (``"sliding"``, ``"all"`` or ``"none"``). ``init=False``
+    leaves ``params`` empty for a caller that brings its own
+    (``dataclasses.replace(model, params=...)``)."""
+    import jax
+
+    from .moe import ExpertLayer
+
+    if len(windows) != len(sparse):
+        raise ValueError("windows and sparse name the same layers")
+    layers = []
+    for window, is_sparse in zip(windows, sparse):
+        rope = rope_layers == "all" or (rope_layers == "sliding" and window > 0)
+        attn = GQAttention(heads, kv_heads, head_dim, window, qk_norm,
+                           rope_theta if rope else None, eps, param_dtype)
+        if is_sparse:
+            moe = ExpertLayer(num_experts, experts_held, top_k, expert_hidden,
+                              scoring=scoring, norm_topk=norm_topk, scale=scale,
+                              first_expert=first_expert, param_dtype=param_dtype)
+            shared = SwiGLU(expert_hidden * shared_experts, param_dtype) \
+                if shared_experts else None
+            layers.append(DecoderLayer(attn, moe=moe, shared=shared, eps=eps,
+                                       param_dtype=param_dtype))
+        else:
+            layers.append(DecoderLayer(attn, mlp=SwiGLU(dense_hidden, param_dtype),
+                                       eps=eps, param_dtype=param_dtype))
+    module = CausalLM(vocab_size, hidden, layers, pad_id, eps, param_dtype)
+    params = module.init(jax.random.key(seed), (seq_len,))[0] if init else {}
+    names = [CausalLM.LOAD] + [f"layer{i}" for i in reversed(range(len(layers)))]
+    return FunctionModel(module, params, (seq_len,), names, "causal_lm")

@@ -1,0 +1,342 @@
+"""The causal language model the benchmark scores (`models/transformer.py`,
+`models/moe.ExpertLayer`) at a tiny size on the CPU, against the plain
+reference the benchmark keeps (`benchmarks/references/k-exaone-236b-a23b-ep8.py`:
+it imports nothing of the program), through the normal path
+(`PipelineModel([DNNModel]).fuse().transform`); the Pallas kernels in the
+interpreter; and the kernels compiled at the published widths for a described
+v5e (no chip: on-chip-measurement guide, section 2; the one file of `tests/`
+that describes the topology, in a fixture)."""
+
+import dataclasses
+import os
+import sys
+
+import numpy as np
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+from benchmarks.harness import spec  # noqa: E402
+from mmlspark_tpu.core.dataframe import DataFrame  # noqa: E402
+from mmlspark_tpu.core.pipeline import PipelineModel  # noqa: E402
+from mmlspark_tpu.models import moe, transformer  # noqa: E402
+from mmlspark_tpu.models.dnn_model import DNNModel  # noqa: E402
+from mmlspark_tpu.models.module import matmul_precision  # noqa: E402
+
+T = 32
+SEED = 4294970129
+
+
+def tiny_config(**changes):
+    """The satellite's preset: hidden 64, 4 query / 2 key-value heads of 16,
+    layers L, L, L, G, L with window 4 at T 32, 16 experts top-4 with 4 held,
+    vocabulary 64. The same keys the configuration's file has."""
+    cfg = dict(
+        hidden_size=64, head_dim=16, num_attention_heads=4, num_key_value_heads=2,
+        vocab_size=64, num_experts=4, num_experts_published=16, first_expert_held=0,
+        num_experts_per_tok=4, num_shared_experts=1, intermediate_size=96,
+        moe_intermediate_size=32, num_hidden_layers=5,
+        sliding_windows=[4, 4, 4, 0, 4],
+        mlp_layer_types=["dense"] + ["sparse"] * 4, rms_norm_eps=1e-5,
+        rope_parameters={"rope_theta": 1e6}, scoring_func="sigmoid",
+        norm_topk_prob=True, routed_scaling_factor=2.5, pad_id=0,
+        max_positions=T, assumed={"qk_norm": True, "rope_layers": "sliding"})
+    cfg.update(changes)
+    return cfg
+
+
+@pytest.fixture(scope="module")
+def ref():
+    return spec.bench_module("references", "k-exaone-236b-a23b-ep8")
+
+
+@pytest.fixture(scope="module")
+def builder():
+    return spec.bench_module("builders", "k-exaone-236b-a23b-ep8")
+
+
+def rows(n=6, seed=0):
+    ids = np.random.default_rng(seed).integers(1, 64, (n, T), dtype=np.int32)
+    lengths = np.full(n, T)
+    lengths[2], lengths[3] = 20, 5          # rows shorter than the cap
+    ids[np.arange(T)[None, :] >= lengths[:, None]] = 0
+    return ids, lengths
+
+
+def model_with(builder, ref, cfg, weights=None):
+    weights = ref.make_weights(cfg, SEED) if weights is None else weights
+    model = builder.model_of(cfg, T)
+    return dataclasses.replace(model, params=builder._nest(weights)), weights
+
+
+def through_the_pipeline(model, ids, fetch=None):
+    col = np.empty(len(ids), dtype=object)
+    for i in range(len(ids)):
+        col[i] = ids[i]
+    stage = DNNModel(inputCol="tokens", batchSize=4,
+                     fetchDict=fetch or {"logprob": "OUTPUT_0"}).set_model(model)
+    fused = PipelineModel([stage]).fuse()
+    with matmul_precision("float32"):
+        out = fused.transform(DataFrame.from_dict({"tokens": col}, num_partitions=2))
+    assert fused.fusion_stats()["fallbacks_total"] == 0
+    return out, fused
+
+
+LAYERS = {   # one layer of each kind, and the whole preset
+    "sliding-dense": dict(num_hidden_layers=1),
+    "full-dense": dict(num_hidden_layers=1, sliding_windows=[0]),
+    "sliding-sparse": dict(num_hidden_layers=1, mlp_layer_types=["sparse"]),
+    "full-sparse": dict(num_hidden_layers=1, sliding_windows=[0],
+                        mlp_layer_types=["sparse"]),
+    "all-five": {},
+}
+
+
+@pytest.mark.parametrize("kind", sorted(LAYERS))
+def test_the_normal_path_agrees_with_the_plain_reference(kind, ref, builder):
+    cfg = tiny_config(**LAYERS[kind])
+    model, weights = model_with(builder, ref, cfg)
+    ids, lengths = rows()
+    out, _ = through_the_pipeline(model, ids)
+    got = np.stack(list(out.column("logprob")))
+    assert got.shape == (6, T) and got.dtype == np.float32
+    want = ref.score(cfg, SEED, ids, weights=weights)["logprob"]
+    real = np.arange(T)[None, :] < lengths[:, None]
+    # float32 on both sides: what is left is the order of the sums
+    assert np.abs(got - want)[real].max() < 2e-4, kind
+    assert np.abs(got - want).max() < 2e-4          # the pads' outputs too
+
+
+def test_a_pad_after_a_rows_real_tokens_moves_none_of_its_real_outputs(ref, builder):
+    cfg = tiny_config()
+    model, _ = model_with(builder, ref, cfg)
+    ids, lengths = rows()
+    other = ids.copy()
+    other[2, 20:] = 7                                 # other ids where row 2 is padded
+    a = np.stack(list(through_the_pipeline(model, ids)[0].column("logprob")))
+    b = np.stack(list(through_the_pipeline(model, other)[0].column("logprob")))
+    # position 19's target is the id after it, which changed: up to 18 agree
+    assert np.array_equal(a[2, :19], b[2, :19])
+    assert not np.array_equal(a[2, 19:], b[2, 19:])
+    assert np.array_equal(np.delete(a, 2, axis=0), np.delete(b, 2, axis=0))
+
+
+def test_expert_load_is_a_second_output_node_and_counts_the_visits(ref, builder):
+    cfg = tiny_config()
+    model, _ = model_with(builder, ref, cfg)
+    ids, _ = rows()
+    out, fused = through_the_pipeline(
+        model, ids, {"logprob": "OUTPUT_0", "expert_load": "expert_load"})
+    load = np.stack(list(out.column("expert_load")))
+    assert load.shape == (6, 4, 4) and load.dtype == np.float32
+    assert (load == np.round(load)).all() and (load >= 0).all()
+    assert (load.sum(axis=2) <= T * 4).all() and load.sum() > 0
+    seg = fused.fusion_stats()["segments"][0]
+    assert seg["fetched"] == ["logprob", "expert_load"]
+
+
+def test_the_shares_of_the_expert_layer_add_up_to_the_uncut_layer(ref):
+    """Guide, section 4: the parts the four shares give (experts 0-3, 4-7,
+    8-11, 12-15), with the shared expert counted once, are the whole layer
+    the uncut reference gives; and their loads are every visit."""
+    cfg = tiny_config(num_experts=16)                 # the reference holds all 16
+    key = jax.random.key(3)
+    d, eff, n_exp, top_k = 64, 32, 16, 4
+    w = {n: jax.random.normal(jax.random.fold_in(key, i), s, jnp.float32) * sc
+         for i, (n, s, sc) in enumerate([
+             ("moe/router", (d, n_exp), d ** -0.5), ("moe/router_bias", (n_exp,), 0.01),
+             ("moe/w1", (n_exp, d, 2 * eff), d ** -0.5),
+             ("moe/w2", (n_exp, eff, d), eff ** -0.5),
+             ("shared/w_gate_up", (d, 2 * eff), d ** -0.5),
+             ("shared/w_down", (eff, d), eff ** -0.5)])}
+    x = jax.random.normal(jax.random.fold_in(key, 99), (3, T, d), jnp.float32)
+    flat = x.reshape(-1, d)
+    whole = ref.experts(cfg, w, flat, *ref.route(cfg, w, flat, None, None)[:2],
+                        None, None).reshape(x.shape)
+    with matmul_precision("float32"):
+        total = transformer.SwiGLU(eff).apply(
+            {"w_gate_up": w["shared/w_gate_up"], "w_down": w["shared/w_down"]}, x)
+        loads = 0.0
+        for first in (0, 4, 8, 12):
+            layer = moe.ExpertLayer(n_exp, 4, top_k, eff, scale=2.5, first_expert=first)
+            share, load = layer.apply_with_load(
+                {"router": w["moe/router"], "router_bias": w["moe/router_bias"],
+                 "w1": w["moe/w1"][first:first + 4], "w2": w["moe/w2"][first:first + 4]}, x)
+            assert load.shape == (3, 4)
+            total, loads = total + share, loads + float(load.sum())
+    assert loads == 3 * T * top_k
+    assert float(jnp.abs(total - whole).max()) < 1e-4 * float(jnp.abs(whole).max())
+
+
+def test_expert_shardings_places_the_expert_layers_own_leaves():
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    layer = moe.ExpertLayer(16, 8, 4, 32)
+    params, _ = layer.init(jax.random.key(0), (T, 64))
+    mesh = Mesh(np.array(jax.devices()[:4]), ("expert",))
+    placed = moe.expert_shardings(mesh, params)
+    assert placed["w1"].spec == placed["w2"].spec == P("expert")
+    assert placed["router"].spec == placed["router_bias"].spec == P()
+    on_mesh = jax.device_put(params, placed)        # 8 experts over 4 devices
+    assert on_mesh["w1"].addressable_shards[0].data.shape == (2, 64, 64)
+
+
+def test_gradients_of_the_mean_log_probability_agree_with_the_references(ref, builder):
+    cfg = tiny_config()
+    model, weights = model_with(builder, ref, cfg)
+    ids = rows(4, seed=1)[0]
+    f32 = {p: a.astype(jnp.float32) for p, a in weights.items()}
+
+    def mine(w):
+        with matmul_precision("float32"):
+            return jnp.mean(model.module.apply(builder._nest(w), jnp.asarray(ids)))
+
+    def plain(w):
+        x = w["embed/table"][ids]
+        for i, (window, sparse) in enumerate(ref.layer_plan(cfg)):
+            lw = {p[len(f"layer{i}/"):]: a for p, a in w.items()
+                  if p.startswith(f"layer{i}/")}
+            x = ref.layer(cfg, lw, x, window, sparse, None, None)[0]
+        return jnp.mean(jax.vmap(lambda r, i: ref.log_probs(cfg, w, r, i, None))(
+            x, jnp.asarray(ids)))
+
+    got, want = jax.grad(mine)(f32), jax.grad(plain)(f32)
+    for path in want:
+        scale = float(jnp.abs(want[path]).max())
+        if path.endswith("router_bias"):              # it chooses, never weighs
+            assert scale == 0.0 and float(jnp.abs(got[path]).max()) == 0.0
+            continue
+        assert scale > 0.0, path
+        assert float(jnp.abs(got[path] - want[path]).max()) < 2e-3 * scale, path
+
+
+def test_save_and_load_keep_bfloat16_parameters_bfloat16(ref, builder, tmp_path):
+    from mmlspark_tpu.core import serialize
+
+    cfg = tiny_config()
+    model, _ = model_with(builder, ref, cfg)
+    ids, _ = rows()
+    stage = DNNModel(inputCol="tokens", outputCol="logprob", batchSize=4).set_model(model)
+    PipelineModel([stage]).save(str(tmp_path / "m"))
+    loaded = PipelineModel.load(str(tmp_path / "m"))
+    leaves = jax.tree.leaves(loaded.stages[0].get_model().params)
+    assert leaves and {str(leaf.dtype) for leaf in leaves} == {"bfloat16"}
+    a = np.stack(list(through_the_pipeline(model, ids)[0].column("logprob")))
+    b = np.stack(list(through_the_pipeline(loaded.stages[0].get_model(), ids)[0]
+                      .column("logprob")))
+    assert np.array_equal(a, b)
+    # a tree of parameters saved on its own (npz knows no bfloat16) as well
+    tree = {"a": np.asarray(leaves[0]), "b": {"c": leaves[1]}}
+    manifest = serialize._save_value(tree, str(tmp_path / "tree"))
+    back = serialize._load_value(manifest, str(tmp_path / "tree"))
+    assert manifest["kind"] == "pytree" and str(back["a"].dtype) == "bfloat16"
+    assert np.array_equal(np.asarray(back["b"]["c"]), np.asarray(leaves[1]))
+
+
+def test_put_params_says_how_many_bytes_of_which_dtype(ref, builder):
+    from mmlspark_tpu.obs import trace
+
+    cfg = tiny_config(num_hidden_layers=1)
+    model, _ = model_with(builder, ref, cfg)
+    through_the_pipeline(model, rows()[0])
+    spans = [s for s in trace.default_tracer().spans() if s["name"] == "put_params"]
+    nbytes = sum(leaf.nbytes for leaf in jax.tree.leaves(model.params))
+    assert spans and spans[-1]["attrs"]["bytes"] == nbytes
+    assert spans[-1]["attrs"]["dtypes"] == f"bfloat16={nbytes}"
+
+
+# -- the kernels, in the interpreter ----------------------------------------
+
+@pytest.mark.parametrize("window", [0, 128, 200])
+def test_the_attention_kernel_agrees_with_the_plain_form(window):
+    rng = np.random.default_rng(window)
+    B, H, KV, D, t = 2, 4, 2, 128, 512
+    q, k, v = (jnp.asarray(rng.normal(size=(B, t, n * D)), jnp.bfloat16)
+               for n in (H, KV, KV))
+    got = transformer.gqa_pallas(q, k, v, window, H, KV, interpret=True)
+    want = transformer._gqa_flat_xla(*(a.astype(jnp.float32) for a in (q, k, v)),
+                                     window, H, KV)
+    assert got.dtype == jnp.bfloat16
+    assert float(jnp.abs(got.astype(jnp.float32) - want).max()) < 0.02
+
+
+def test_the_plain_window_form_is_the_masked_softmax():
+    rng = np.random.default_rng(5)
+    B, H, KV, D, t, window = 2, 4, 2, 8, 24, 5        # T no multiple of the window
+    q, k, v = (jnp.asarray(rng.normal(size=(B, t, n, D)), jnp.float32)
+               for n in (H, KV, KV))
+    kk, vv = (jnp.repeat(a, H // KV, axis=2) for a in (k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, kk) / np.sqrt(D)
+    pos = np.arange(t)
+    for w in (window, 0):
+        seen = (pos[None, :] <= pos[:, None]) & ((pos[None, :] > pos[:, None] - w) | (w == 0))
+        p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+        want = jnp.einsum("bhqk,bkhd->bqhd", p, vv)
+        assert float(jnp.abs(transformer.gqa_xla(q, k, v, w) - want).max()) < 1e-5
+
+
+def test_the_grouped_product_kernel_agrees_with_the_ragged_product():
+    rng = np.random.default_rng(2)
+    lhs = jnp.asarray(rng.normal(size=(2048, 1024)), jnp.bfloat16)
+    rhs = jnp.asarray(rng.normal(size=(4, 1024, 2048)) / 32, jnp.bfloat16)
+    sizes = jnp.asarray([512, 0, 1024, 0], jnp.int32)   # 512 rows past the groups
+    got = moe.gmm_pallas(lhs, rhs, sizes, jnp.float32, interpret=True)
+    want = moe._gmm_ragged(lhs, rhs, sizes, jnp.float32)
+    assert float(jnp.abs(got - want)[:1536].max()) < 1e-4
+    assert float(jnp.abs(got[1536:]).max()) == 0.0
+
+
+# -- the kernels at the published widths, compiled for a described v5e ------
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compiled_text(fn, *shapes):
+    from jax.experimental.compilation_cache import compilation_cache
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return jax.jit(fn).lower(*shapes).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("window", [128, 0])
+def test_the_attention_kernel_compiles_at_the_published_widths(window, one_chip):
+    B, t, H, KV, D = 2, 4096, 64, 8, 128
+    q = jax.ShapeDtypeStruct((B, t, H * D), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((B, t, KV * D), jnp.bfloat16, sharding=one_chip)
+    text = _compiled_text(lambda q, k, v: transformer.gqa_pallas(q, k, v, window, H, KV),
+                          q, kv, kv)
+    assert "tpu_custom_call" in text
+    assert ("attn_window" if window else "attn_full") in text
+
+
+def test_the_grouped_product_kernel_compiles_at_the_published_widths(one_chip):
+    held, d, eff, rows_ = 16, 6144, 2048, 40960
+    x = jax.ShapeDtypeStruct((rows_, d), jnp.bfloat16, sharding=one_chip)
+    w1 = jax.ShapeDtypeStruct((held, d, 2 * eff), jnp.bfloat16, sharding=one_chip)
+    w2 = jax.ShapeDtypeStruct((held, eff, d), jnp.bfloat16, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((held,), jnp.int32, sharding=one_chip)
+
+    def both(x, w1, w2, sizes):
+        hid = moe.gmm_pallas(x, w1, sizes, jnp.bfloat16)
+        return moe.gmm_pallas(hid[:, :eff], w2, sizes, jnp.bfloat16)
+
+    text = _compiled_text(both, x, w1, w2, sizes)
+    assert text.count("moe_gmm") >= 2 and "tpu_custom_call" in text
