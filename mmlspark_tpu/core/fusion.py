@@ -32,8 +32,10 @@ fallbacks taken.
 
 from __future__ import annotations
 
+import itertools
 import threading
 import time
+from collections import deque
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -469,9 +471,78 @@ def _part_rows(part: Dict[str, np.ndarray]) -> int:
     return len(next(iter(part.values()))) if part else 0
 
 
+class _Part:
+    """One partition on its way through a call. ``own`` is the binding of
+    the ``partition`` span it is prepared under; ``_prepare`` fills in
+    ``state`` (with ``step``, the dispatch closure made from it, and
+    ``batches``, how many it will hand the device) or ``fallback`` (why it
+    goes to the host instead; a refusal at ``put`` or at dispatch sets it
+    later) or ``error`` (anything else: raised where the partition is
+    handed out). The ring path counts what came back in ``drained`` and
+    keeps it in ``collected``."""
+
+    __slots__ = ("index", "part", "own", "entered", "state", "fallback",
+                 "error", "step", "batches", "collected", "drained")
+
+    def __init__(self, index: int, part: Dict[str, np.ndarray], own):
+        self.index, self.part, self.own = index, part, own
+        self.entered = False
+        self.state: Optional[Dict[str, Any]] = None
+        self.fallback: Optional[str] = None
+        self.error: Optional[BaseException] = None
+        self.step = None
+        self.batches = self.drained = 0
+        self.collected: Dict[str, List[np.ndarray]] = {}
+
+
+class _CallerSpans:
+    """The calling thread's ``partition`` spans on the ring path. One ring
+    carries a call's partitions, and with a ring two deep the caller
+    dispatches partition k+1's first batch BEFORE it drains partition k's
+    last: its work for two partitions interleaves. So a ``partition`` span
+    there is a STRETCH, an interval in which the caller works for one
+    partition (prepares it, waits for, dispatches and drains its batches,
+    emits it); a partition that shares a ring has two or more, the first
+    under the binding it was prepared under (``_Part.own``). Stretches
+    never overlap and every span the caller records lies inside one, so
+    self time and idle attribution by innermost span still add up
+    (benchmarks/harness/spans.py)."""
+
+    def __init__(self, own):
+        self._own = own             # the segment's open span; None = off
+        self._at: Optional[Tuple[_Part, Any, float]] = None
+
+    def enter(self, rec: _Part, w0: Optional[float] = None):
+        """Binding of ``rec``'s stretch from wall time ``w0`` (now) on:
+        the one that is open, else a new one after closing another
+        partition's."""
+        if self._own is None:
+            return None
+        if self._at is not None and self._at[0] is rec:
+            return self._at[1]
+        w0 = time.time() if w0 is None else w0
+        self.leave(w0)
+        own = open_span(self._own) if rec.entered else rec.own
+        rec.entered = True
+        self._at = (rec, own, w0)
+        return own
+
+    def leave(self, w1: Optional[float] = None) -> None:
+        """Close the open stretch (at wall time ``w1``, else now)."""
+        if self._at is None:
+            return
+        rec, own, w0 = self._at
+        self._at = None
+        w1 = time.time() if w1 is None else w1
+        close_span(own, "partition", w0, w1 - w0,
+                   rows=_part_rows(rec.part), part=rec.index)
+
+
 class SegmentExecutor:
-    """Runs one Segment over a DataFrame, partition by partition, through
-    the TransferRing with compile-cache-backed fused executables."""
+    """Runs one Segment over a DataFrame: its partitions as a stream
+    through ONE TransferRing a call (partition k+1 prepared and staged
+    while partition k computes), with compile-cache-backed fused
+    executables."""
 
     def __init__(self, segment: Segment, cache: Optional[CompileCache] = None,
                  buckets: Optional[Tuple[int, ...]] = None,
@@ -618,14 +689,7 @@ class SegmentExecutor:
         own = open_span(current_batch())
         t_wall, t0 = time.time(), time.perf_counter()
         params_dev = self._put_params(jax, own)
-        out_parts: List[Dict[str, np.ndarray]] = []
-        for part in df.partitions:
-            try:
-                out_parts.append(
-                    self._run_partition(dict(part), params_dev, stats, own))
-            except _HostFallback as e:
-                self.fallbacks.append(f"{seg.label}: {e}")
-                out_parts.extend(self._host_partition(part, df.schema, own))
+        out_parts = self._ring_partitions(df, params_dev, stats, own)
         with batch_span(own, "overlay"):
             out = self._overlay(df, out_parts)
         close_span(own, f"segment:{seg.label}", t_wall,
@@ -649,11 +713,66 @@ class SegmentExecutor:
         return DataFrame(out_parts, Schema(types, meta))
 
     def _prep_partition(self, part: Dict[str, np.ndarray], stats=None,
-                        obs=None) -> Dict[str, Any]:
+                        obs=None, ahead: bool = False) -> Dict[str, Any]:
         """``_prep_state`` under a ``prepare`` span (``obs``: the
-        partition's open span)."""
-        with batch_span(obs, "prepare", rows=_part_rows(part)) as own:
+        partition's open span; ``ahead``: on the look-ahead thread, beside
+        the partition before it)."""
+        with batch_span(obs, "prepare", rows=_part_rows(part),
+                        ahead=int(ahead)) as own:
             return self._prep_state(part, stats, own)
+
+    def _prepare(self, rec: _Part, params_dev, stats,
+                 ahead: bool = False) -> None:
+        """Prepare one partition into its record, on whichever thread
+        calls: the state, its dispatch closure and batch count, or why it
+        falls back; any other exception is kept for ``_prepared`` to raise
+        where the partition is handed out."""
+        try:
+            state = self._prep_partition(dict(rec.part), stats, rec.own,
+                                         ahead)
+            rec.collected = {k: [] for k in state["keys"]}
+            if state["n_valid"] > 0:
+                rec.step = self._make_step(params_dev, state)
+                rec.batches = self._num_batches(state)
+            rec.state = state
+        except (_HostFallback, FusionUnsupported) as e:
+            rec.fallback = str(e)
+        except BaseException as e:  # noqa: BLE001 - re-raised at hand-out
+            rec.error = e
+        stats.note_partition(ahead)
+
+    def _prepared(self, df: DataFrame, params_dev, stats, own):
+        """The call's partitions as a stream of prepared records, in order
+        (``own``: the segment's open span, under which each gets the
+        binding of its ``partition`` span). The first is prepared on the
+        thread that asks for it; every later one on a thread of its own
+        (``partition-prep``) that starts when the one before it is handed
+        out, so its masks, ``prepare`` hooks and stack run while that one's
+        batches are filled, staged, dispatched and drained. The look-ahead
+        is ONE partition and no knob: the next thread starts only when
+        this one's partition has been taken, and a thread blocks on
+        nothing, so there is none to release when a call is abandoned.
+        Only the record handed out and the one ahead are held here.
+        Shared by ``run`` (the ring's producer pulls the stream) and
+        ``submit_run``."""
+        recs = (_Part(i, part, open_span(own))
+                for i, part in enumerate(df.partitions))
+        rec, ahead = next(recs, None), None
+        while rec is not None:
+            if ahead is None:
+                self._prepare(rec, params_dev, stats)
+            else:
+                ahead.join()
+            if rec.error is not None:
+                raise rec.error
+            nxt, ahead = next(recs, None), None
+            if nxt is not None:
+                ahead = threading.Thread(
+                    target=self._prepare, name="partition-prep", daemon=True,
+                    args=(nxt, params_dev, stats, True))
+                ahead.start()
+            yield rec
+            rec = nxt
 
     def _prep_state(self, part: Dict[str, np.ndarray], stats,
                     obs) -> Dict[str, Any]:
@@ -661,7 +780,7 @@ class SegmentExecutor:
         prepare hooks, dtype/sparse/null gates, dense stacking — everything
         up to (but excluding) device dispatch. Raises _HostFallback when the
         fused contract cannot hold; returns the execution state shared by
-        the blocking ring path (``_run_partition``) and the non-blocking
+        the blocking ring path (``_ring_partitions``) and the non-blocking
         submit path (``submit_run``)."""
         seg = self.segment
         ext = seg.external_in_cols
@@ -1163,39 +1282,117 @@ class SegmentExecutor:
         filler = DevicePrefetcher(src, depth=1, name="slot-fill")
         return iter(filler), filler
 
-    def _run_partition(self, part: Dict[str, np.ndarray], params_dev,
-                       stats, obs=None) -> Dict[str, np.ndarray]:
-        """One partition through prepare, the ring and emit, under one
-        ``partition`` span of ``obs`` (the segment's open span)."""
-        with batch_span(obs, "partition", rows=_part_rows(part)) as own:
-            return self._ring_partition(part, params_dev, stats, own)
-
-    def _ring_partition(self, part: Dict[str, np.ndarray], params_dev,
-                        stats, obs) -> Dict[str, np.ndarray]:
+    def _ring_partitions(self, df: DataFrame, params_dev, stats, own
+                         ) -> List[Dict[str, np.ndarray]]:
+        """Every partition of the call through ONE ring, emitted in order.
+        The ring's producer pulls ``_batch_stream``: partition k+1 is
+        prepared, filled and staged while partition k computes. This
+        thread dispatches and drains in order and settles a partition
+        (``emit``, or the host path for one that fell back) as soon as
+        nothing of it or of an earlier one is left in the ring. What rides
+        with each batch is its partition's record, so a batch is stepped
+        with ITS partition's program, and a refusal at ``put`` or at
+        dispatch demotes that partition alone: its other batches are
+        dropped, the ring goes on."""
         from ..parallel.ingest import TransferRing
 
-        state = self._prep_partition(part, stats, obs)
-        collected: Dict[str, List[np.ndarray]] = {k: []
-                                                  for k in state["keys"]}
-        if state["n_valid"] > 0:
-            src, filler = self._fill_ahead(state, stats, obs)
-            ring = TransferRing(src, put=self._put,
-                                step=self._make_step(params_dev, state),
-                                fetch=self._fetch,
-                                depth=self.segment.ring_depth(), stats=stats,
-                                obs=obs, batch0=self._batch_no)
-            self._batch_no += self._num_batches(state)
-            try:
-                for out in ring:
-                    for k, y in zip(state["keys"], out):
-                        collected[k].append(y)
-            except FusionUnsupported as e:
-                raise _HostFallback(str(e))
-            finally:
+        seg = self.segment
+        caller = _CallerSpans(own)
+        arrived: deque = deque()    # records the stream has reached, in order
+        out_parts: List[Dict[str, np.ndarray]] = []
+
+        def settle() -> None:
+            while arrived and arrived[0].drained == arrived[0].batches:
+                rec = arrived.popleft()
+                if rec.fallback is None:
+                    out_parts.append(self._emit_partition(
+                        rec.state, rec.collected, caller.enter(rec)))
+                    continue
+                caller.enter(rec)   # its span, had it none on this thread
+                caller.leave()
+                self.fallbacks.append(f"{seg.label}: {rec.fallback}")
+                out_parts.extend(
+                    self._host_partition(rec.part, df.schema, own))
+
+        def put(batch):
+            rec = batch.owner
+            if rec.fallback is None:
+                try:
+                    return self._put(batch) + (rec,)
+                except FusionUnsupported as e:
+                    rec.fallback = str(e)
+            return None, batch.num_valid, rec
+
+        def step(staged):
+            x, m, rec = staged
+            if rec.fallback is None:
+                try:
+                    return rec.step((x, m)) + (rec,)
+                except FusionUnsupported as e:
+                    rec.fallback = str(e)
+            return None, m, rec
+
+        def fetch(handle):
+            ys, m, rec = handle
+            dropped = ys is None or rec.fallback is not None
+            return rec, None if dropped else self._fetch((ys, m))
+
+        def obs_of(x, w0):
+            return x.owner.own if w0 is None else caller.enter(x[-1], w0)
+
+        prepared = self._prepared(df, params_dev, stats, own)
+        fillers: List[Any] = []     # the stream's slot filler, to close
+        ring = None
+        try:
+            # the first partition: prepared here, its stretch begun with it
+            w0 = time.time() if own is not None else None
+            first = next(prepared, None)
+            if first is None:
+                return out_parts
+            caller.enter(first, w0)
+            if first.batches or len(df.partitions) > 1:
+                src = self._batch_stream(itertools.chain((first,), prepared),
+                                         stats, arrived, fillers)
+                ring = TransferRing(
+                    src, put=put, step=step, fetch=fetch,
+                    depth=seg.ring_depth(), stats=stats, obs=own,
+                    obs_of=obs_of if own is not None else None)
+                del first
+                for rec, out in ring:
+                    rec.drained += 1
+                    if out is not None:
+                        for k, y in zip(rec.state["keys"], out):
+                            rec.collected[k].append(y)
+                    settle()
+            else:                   # nothing for a ring to carry
+                arrived.append(first)
+            settle()
+        finally:
+            if ring is not None:
                 ring.close()
-                if filler is not None:
-                    filler.close()
-        return self._emit_partition(state, collected, obs)
+            for filler in fillers:
+                filler.close()
+            caller.leave()
+        return out_parts
+
+    def _batch_stream(self, prepared, stats, arrived: deque,
+                      fillers: List[Any]):
+        """The ring's source: every partition's batches in turn, each
+        carrying its record (``Batch.owner``). A batch never spans two
+        partitions, and a partition that fell back at ``prepare`` or has
+        no valid row passes with none. ``arrived`` tells the caller which
+        partitions the stream has reached; ``fillers`` holds the open slot
+        filler, which the caller closes if it abandons the ring."""
+        for rec in prepared:
+            arrived.append(rec)
+            if not rec.batches:
+                continue
+            src, filler = self._fill_ahead(rec.state, stats, rec.own)
+            self._batch_no += rec.batches
+            fillers[:] = [filler] if filler is not None else []
+            for batch in src:
+                batch.owner = rec
+                yield batch
 
     def _num_batches(self, state: Dict[str, Any]) -> int:
         return -(-state["n_valid"] // self.segment.batch_size())
@@ -1222,20 +1419,25 @@ class SegmentExecutor:
         params_dev = self._put_params(jax, own)
         mega_k = max(1, int(self.mega_k or 1))
         pendings: List[Tuple[str, Any, Any, Any, int]] = []
-        for part in df.partitions:
-            # a partition's span covers what happens to it NOW (prepare,
-            # fill, h2d, dispatch); its drain and emit record under the
-            # same span from resolve(), after it closed
-            p_own = open_span(own)
+        prepared = self._prepared(df, params_dev, stats, own)
+        while True:
+            # a partition's span covers what happens to it NOW (the wait
+            # for its prepare, fill, h2d, dispatch); its drain and emit
+            # record under the same span from resolve(), after it closed
             pw0, pt0 = time.time(), time.perf_counter()
+            rec = next(prepared, None)
+            if rec is None:
+                break
+            p_own = rec.own
             try:
-                state = self._prep_partition(dict(part), stats, p_own)
+                if rec.fallback is not None:
+                    raise _HostFallback(rec.fallback)
                 handles = []
                 b0 = self._batch_no
-                if state["n_valid"] > 0:
-                    step = self._make_step(params_dev, state)
+                if rec.batches:
+                    state, step = rec.state, rec.step
                     src, filler = self._fill_ahead(state, stats, p_own)
-                    self._batch_no += self._num_batches(state)
+                    self._batch_no += rec.batches
                     try:
                         if mega_k <= 1:
                             # K=1: today's stage-then-dispatch loop,
@@ -1257,15 +1459,16 @@ class SegmentExecutor:
                     finally:
                         if filler is not None:
                             filler.close()
-                pendings.append(("device", state, handles, p_own, b0))
+                pendings.append(("device", rec.state, handles, p_own, b0))
             except (_HostFallback, FusionUnsupported) as e:
                 self.fallbacks.append(f"{seg.label}: {e}")
                 pendings.append(
-                    ("host", self._host_partition(part, df.schema, own),
+                    ("host", self._host_partition(rec.part, df.schema, own),
                      None, None, 0))
             finally:
                 close_span(p_own, "partition", pw0,
-                           time.perf_counter() - pt0, rows=_part_rows(part))
+                           time.perf_counter() - pt0,
+                           rows=_part_rows(rec.part), part=rec.index)
 
         def resolve() -> DataFrame:
             from ..parallel.ingest import (_block_ready, _tree_nbytes,

@@ -398,12 +398,18 @@ class Batch:
     ``staging``: the SlotPool lease (parallel/ingest.py SlotLease) when the
     arrays live in a pre-allocated staging slot — ``timed_stage`` returns
     the buffers to the pool once the batch is device-resident. None for
-    plainly-allocated batches (bitwise-identical legacy path)."""
+    plainly-allocated batches (bitwise-identical legacy path).
+
+    ``owner``: whatever the producer wants to find again once the batch is
+    staged — the fused path's partition record, where one ring carries the
+    batches of a call's partitions (core/fusion.py). The ring never reads
+    it."""
 
     arrays: Dict[str, np.ndarray]
     mask: np.ndarray          # [B] bool, True = real row
     num_valid: int
     staging: Any = None
+    owner: Any = None
 
     @property
     def size(self) -> int:

@@ -179,6 +179,13 @@ class IngestStats:
         # depth + running mean/max of observed fill, so "is the ring ever
         # actually full?" is a scraped gauge instead of a rerun experiment
         self.ring_depth: int = 0
+        # rings built, the partitions a fused call prepared for them, and
+        # how many of those a look-ahead thread prepared while an earlier
+        # partition was still in the ring (core/fusion.py: one ring a call;
+        # counts, so they do not depend on timing)
+        self.rings: int = 0
+        self.partitions: int = 0
+        self.partitions_ahead: int = 0
         self._occ_sum: int = 0
         self._occ_n: int = 0
         self._occ_max: int = 0
@@ -230,7 +237,15 @@ class IngestStats:
         self.wall_s += seconds
 
     def note_ring(self, depth: int) -> None:
+        """One ring built, ``depth`` deep."""
         self.ring_depth = max(self.ring_depth, int(depth))
+        self.rings += 1
+
+    def note_partition(self, ahead: bool) -> None:
+        """One partition prepared for the device path; ``ahead``: by the
+        look-ahead thread, beside the partition before it."""
+        self.partitions += 1
+        self.partitions_ahead += bool(ahead)
 
     def note_occupancy(self, in_flight: int) -> None:
         n = int(in_flight)
@@ -285,6 +300,9 @@ class IngestStats:
         self.records.extend(other.records)
         self.wall_s += other.wall_s
         self.ring_depth = max(self.ring_depth, other.ring_depth)
+        self.rings += other.rings
+        self.partitions += other.partitions
+        self.partitions_ahead += other.partitions_ahead
         self._occ_sum += other._occ_sum
         self._occ_n += other._occ_n
         self._occ_max = max(self._occ_max, other._occ_max)
@@ -331,9 +349,15 @@ class IngestStats:
         return out
 
     def _staging_summary(self) -> Dict[str, Any]:
-        """Deposit / zero-copy / slot-overlap section (only populated
-        keys, so summaries without staging activity are unchanged)."""
+        """Rings / partitions / deposit / zero-copy / slot-overlap section
+        (only populated keys, so summaries without staging activity are
+        unchanged)."""
         out: Dict[str, Any] = {}
+        if self.rings:
+            out["rings"] = self.rings
+        if self.partitions:
+            out["partitions"] = self.partitions
+            out["partitions_ahead"] = self.partitions_ahead
         if self.deposits or self.copies:
             out["slot_deposits"] = self.deposits
             out["fallback_copies"] = self.copies
@@ -922,6 +946,11 @@ class TransferRing:
     2-deep ``in_flight`` list generalized); ``prefetch`` bounds staged
     batches waiting between put and step (defaults to ``depth``).
 
+    One ring may carry the batches of several owners one after another (a
+    fused call's partitions, core/fusion.py): what differs by owner rides in
+    the values themselves, which the ring never looks into, and ``obs_of``
+    names each batch's trace binding.
+
     Replaces the reference's background-thread batcher pair
     (stages/Batchers.scala:12-160) as the single overlap primitive shared by
     DNN eval, GBDT scoring, and bench. Iterate once; ``close()`` (idempotent,
@@ -934,7 +963,8 @@ class TransferRing:
                  fetch: Optional[Callable] = None,
                  depth: int = 2, prefetch: Optional[int] = None,
                  stats: Optional[IngestStats] = None,
-                 obs: Optional[tuple] = None, batch0: int = 0):
+                 obs: Optional[tuple] = None, batch0: int = 0,
+                 obs_of: Optional[Callable] = None):
         if depth <= 0:
             raise ValueError("depth must be positive")
         self.depth = depth
@@ -952,13 +982,22 @@ class TransferRing:
         # spawns would see an empty context. Every phase is clocked once:
         # the reads that fill BatchTiming are the span's too. ``batch0`` is
         # the call's batches before this ring's first (spans' ``batch``).
+        # ``obs_of(x, w0)`` takes the binding's place where batches of more
+        # than one owner ride the ring: asked on the producer thread for a
+        # host item about to be staged (``w0`` None), and on the consumer
+        # for a staged batch or a handle whose phase began at wall time
+        # ``w0`` (the wait for it, its drain).
         from ..obs.trace import current_batch
 
-        self._obs = obs = obs if obs is not None else current_batch()
+        obs = obs if obs is not None else current_batch()
+        self._traced = obs is not None or obs_of is not None
+        self._obs_of = obs_of if obs_of is not None \
+            else (lambda x, w0=None: obs)
         self._batch0 = int(batch0)
         staged_no = itertools.count(self._batch0)
         self._prefetch = DevicePrefetcher(
-            it, put=lambda item: timed_stage(put, item, obs=obs,
+            it, put=lambda item: timed_stage(put, item,
+                                             obs=self._obs_of(item, None),
                                              batch=next(staged_no)),
             depth=max(1, prefetch or depth))
 
@@ -968,12 +1007,11 @@ class TransferRing:
     def __iter__(self):
         inflight: "deque" = deque()
         src = iter(self._prefetch)
-        obs = self._obs
         batch = self._batch0
         wall0 = time.perf_counter()
         try:
             while True:
-                w0 = time.time() if obs is not None else 0.0
+                w0 = time.time() if self._traced else 0.0
                 tq = time.perf_counter()
                 try:
                     staged, timing = next(src)
@@ -981,6 +1019,7 @@ class TransferRing:
                     break
                 timing.queue_s = time.perf_counter() - tq
                 watcher = None
+                obs = self._obs_of(staged, w0)
                 if obs is not None:
                     obs[0].record_batch("queue", obs[1], w0, timing.queue_s,
                                         batch=batch)
@@ -1001,8 +1040,8 @@ class TransferRing:
 
     def _drain(self, inflight: "deque"):
         handle, timing, batch, watcher = inflight.popleft()
-        obs = self._obs
-        w0 = time.time() if obs is not None else 0.0
+        w0 = time.time() if self._traced else 0.0
+        obs = self._obs_of(handle, w0)
         t0 = time.perf_counter()
         _block_ready(handle)
         t1 = time.perf_counter()
@@ -1012,6 +1051,7 @@ class TransferRing:
         self.stats.record(timing)
         if obs is not None:
             record_drain(obs, timing, w0, batch, _tree_nbytes(out))
+        if watcher is not None:
             # the batch was ready a readback ago: its watcher has recorded,
             # or is about to; no span of a call lands after the call
             watcher.join()

@@ -8,6 +8,8 @@ profiler annotation, and the serving round trip.
 """
 
 import json
+import threading
+import time
 import urllib.request
 
 import numpy as np
@@ -656,6 +658,210 @@ class TestFallbacks:
         fused = fused_of(pm)
         assert_bitwise(pm.transform(df), fused.transform(df))
         assert len(fused.fusion_stats()["fallbacks"]) > 0
+
+
+# --------------------------------------------------------------------------
+# one ring a call, fed by a stream of partitions
+# --------------------------------------------------------------------------
+
+
+def vector_df(sizes, seed=30, ragged_at=None):
+    """float32 rows of width 4 in partitions of the given sizes (0 = an
+    empty partition); ``ragged_at``: that partition gets rows of two widths,
+    which no stack can hold."""
+    rng = np.random.default_rng(seed)
+    parts = []
+    for k, n in enumerate(sizes):
+        col = np.empty(n, dtype=object)
+        for i in range(n):
+            wide = 5 if k == ragged_at and i % 2 else 4
+            col[i] = rng.normal(size=wide).astype(np.float32)
+        parts.append({"x": col, "idx": np.arange(float(n)) + 100 * k})
+    return DataFrame(parts)
+
+
+def dnn_chain(batch=8):
+    dnn = DNNModel(inputCol="x", outputCol="emb", batchSize=batch)
+    dnn.set_model(toy_mlp())
+    return PipelineModel([dnn])
+
+
+def live_threads(*names):
+    return [t.name for t in threading.enumerate()
+            if t.name.startswith(names) and t.is_alive()]
+
+
+def wait_gone(*names, seconds=5.0):
+    end = time.time() + seconds
+    while live_threads(*names) and time.time() < end:
+        time.sleep(0.01)
+    return live_threads(*names)
+
+
+RING_THREADS = ("partition-prep", "device-prefetch", "slot-fill")
+
+
+class TestPartitionStream:
+    @pytest.mark.parametrize("sizes", [(19,), (16, 7), (9, 0, 17, 1, 12)],
+                             ids=["1", "2", "5-uneven-empty"])
+    def test_run_submit_and_unfused_agree_bitwise(self, sizes):
+        # batches of 8: ragged last batches, a partition of one row, an
+        # empty one; every partition's rows come back in its place
+        pm, df = dnn_chain(), vector_df(sizes)
+        fused = fused_of(pm)
+        ref = pm.transform(df)
+        got = fused.transform(df)
+        assert_bitwise(ref, got)
+        assert [len(p["idx"]) for p in got.partitions] == list(sizes)
+        assert fused.fusion_stats()["fallbacks"] == []
+        assert_bitwise(ref, fused.transform_submit(df)())
+        assert fused.fusion_stats()["fallbacks"] == []
+
+    def test_image_chain_of_five_partitions(self):
+        pm = PipelineModel([
+            ImageTransformer().resize(16, 16),
+            ImageFeaturizer(scaleFactor=1 / 255., batchSize=4)
+            .set_model(toy_cnn())])
+        df = image_df(n=23, parts=5, null_at=7)
+        fused = fused_of(pm)
+        assert_bitwise(pm.transform(df), fused.transform(df))
+        assert_bitwise(pm.transform(df), fused.transform_submit(df)())
+
+    @pytest.mark.parametrize("sizes,ahead", [((16, 16), 1), ((19,), 0),
+                                             ((9, 0, 17, 1, 12), 4)])
+    def test_one_ring_a_call_and_the_partitions_ahead_counted(self, sizes,
+                                                              ahead):
+        fused = fused_of(dnn_chain())
+        fused.transform(vector_df(sizes))
+        summary = fused.last_ingest_stats.summary()
+        assert summary["rings"] == 1
+        assert summary["partitions"] == len(sizes)
+        assert summary["partitions_ahead"] == ahead
+        per_seg = fused.fusion_stats()["per_segment"]["DNNModel"]
+        assert (per_seg["rings"], per_seg["partitions"],
+                per_seg["partitions_ahead"]) == (1, len(sizes), ahead)
+        assert summary["n_batches"] == sum(-(-n // 8) for n in sizes)
+
+    def test_a_partition_with_nothing_to_ship_builds_no_ring(self):
+        fused = fused_of(dnn_chain())
+        out = fused.transform(vector_df((0,)))
+        assert out.count() == 0
+        summary = fused.last_ingest_stats.summary()
+        assert "rings" not in summary and summary["partitions"] == 1
+
+    def test_a_fallback_at_prepare_takes_its_partition_alone(self):
+        # the middle partition's rows are of two widths: no stack holds
+        # them, and the unfused path refuses them too; so that one
+        # partition goes to the host path, which here is made to answer
+        from mmlspark_tpu.core.fusion import SegmentExecutor
+
+        pm, df = dnn_chain(), vector_df((9, 6, 12), ragged_at=1)
+        fused = fused_of(pm)
+        seen = []
+
+        def host(self, part, schema, obs=None):
+            seen.append(len(part["x"]))
+            out = dict(part)
+            out["emb"] = np.array([None] * len(part["x"]), dtype=object)
+            return [out]
+
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(SegmentExecutor, "_host_partition", host)
+            got = fused.transform(df)
+        assert seen == [6]
+        assert [len(p["idx"]) for p in got.partitions] == [9, 6, 12]
+        stats = fused.fusion_stats()
+        assert len(stats["fallbacks"]) == 1 and "ragged" in stats["fallbacks"][0]
+        # the neighbours took the device path, and their rows are the
+        # unfused path's, in order
+        summary = fused.last_ingest_stats.summary()
+        assert (summary["rings"], summary["n_batches"]) == (1, 2 + 2)
+        ok = DataFrame([df.partitions[0], df.partitions[2]])
+        ref = pm.transform(ok).partitions
+        for want, have in zip(ref, (got.partitions[0], got.partitions[2])):
+            assert_bitwise(DataFrame([want]), DataFrame([have]))
+        assert all(v is None for v in got.partitions[1]["emb"])
+
+    @pytest.mark.parametrize("submit", [False, True], ids=["run", "submit"])
+    def test_a_build_that_refuses_demotes_its_partition_alone(self, submit):
+        # the middle partition's 9 rows pad to a bucket of 16, a program of
+        # its own, and that build refuses: the partition reruns on the
+        # host, its neighbours (buckets of 32) stay on the device
+        from mmlspark_tpu.core.device_stage import FusionUnsupported
+        from mmlspark_tpu.core.fusion import SegmentExecutor
+
+        pm, df = dnn_chain(batch=32), vector_df((64, 9, 20))
+        fused = fused_of(pm)
+        build = SegmentExecutor._build
+
+        def refusing(self, params_dev, x, keys, **kw):
+            if len(x["x"]) == 16:
+                raise FusionUnsupported("no program for a bucket of 16")
+            return build(self, params_dev, x, keys, **kw)
+
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(SegmentExecutor, "_build", refusing)
+            got = fused.transform_submit(df)() if submit \
+                else fused.transform(df)
+        assert_bitwise(pm.transform(df), got)
+        stats = fused.fusion_stats()
+        assert stats["fallbacks"] == ["DNNModel: no program for a bucket of 16"]
+        assert sum(stats["devices"].values()) == 3    # 2 + 1 batches
+        assert not wait_gone(*RING_THREADS)
+
+    def test_an_error_on_the_look_ahead_thread_reaches_the_caller(
+            self, monkeypatch):
+        from mmlspark_tpu.image import stages as image_stages
+
+        resize = image_stages._resize_column
+        where = []
+
+        def failing(imgs, height, width, ctx=None):
+            where.append(threading.current_thread().name)
+            if len(where) == 2:
+                raise RuntimeError("the second partition's resize broke")
+            return resize(imgs, height, width, ctx=ctx)
+
+        monkeypatch.setattr(image_stages, "_resize_column", failing)
+        fused = fused_of(PipelineModel([
+            ImageTransformer().resize(16, 16),
+            ImageFeaturizer(scaleFactor=1 / 255., batchSize=4)
+            .set_model(toy_cnn())]))
+        with pytest.raises(RuntimeError, match="resize broke"):
+            fused.transform(image_df(n=24, parts=3))
+        assert where[1] == "partition-prep" and where[0] != where[1]
+        assert not wait_gone(*RING_THREADS)
+
+    def test_the_look_ahead_holds_one_prepared_partition(self, monkeypatch):
+        # count partitions that are prepared and not yet taken by the
+        # batch stream: never more than one, whatever the timing
+        from mmlspark_tpu.core.fusion import SegmentExecutor
+
+        lock = threading.Lock()
+        waiting, most = [0], [0]
+        prep = SegmentExecutor._prep_partition
+        fill = SegmentExecutor._fill_ahead
+
+        def prepared(self, part, stats=None, obs=None, ahead=False):
+            state = prep(self, part, stats, obs, ahead)
+            if ahead:
+                with lock:
+                    waiting[0] += 1
+                    most[0] = max(most[0], waiting[0])
+            return state
+
+        def taken(self, state, stats, obs=None):
+            with lock:
+                waiting[0] = max(0, waiting[0] - 1)
+            return fill(self, state, stats, obs)
+
+        monkeypatch.setattr(SegmentExecutor, "_prep_partition", prepared)
+        monkeypatch.setattr(SegmentExecutor, "_fill_ahead", taken)
+        pm, df = dnn_chain(batch=4), vector_df((8,) * 6)
+        fused = fused_of(pm)
+        assert_bitwise(pm.transform(df), fused.transform(df))
+        assert most[0] == 1
+        assert fused.last_ingest_stats.summary()["partitions_ahead"] == 5
 
 
 # --------------------------------------------------------------------------

@@ -103,7 +103,7 @@ class TestTransferRing:
     def test_empty_iterator(self):
         outs, stats = self._run(n=0)
         assert outs == []
-        assert stats.summary() == {"n_batches": 0}
+        assert stats.summary() == {"n_batches": 0, "rings": 1}
 
     def test_put_runs_on_prefetch_thread(self):
         names = []

@@ -42,9 +42,12 @@ TREE = {
     "finalize:ImageTransformer": "emit",
     "finalize:ImageFeaturizer": "emit",
 }
-# spans of one call: 2 partitions of 2 batches
+# spans of one call: 2 partitions of 2 batches. One ring carries both, two
+# deep, so the caller's work for them interleaves and a ``partition`` span
+# is a stretch of it: 0 [prepare .. drain 0], 1 [dispatch 2], 0 [drain 1,
+# emit], 1 [dispatch 3 .. emit]
 COUNTS = {"transform": 1, SEGMENT: 1, "put_params": 1, "overlay": 1,
-          "partition": 2, "prepare": 2, "prepare:ImageTransformer": 2,
+          "partition": 4, "prepare": 2, "prepare:ImageTransformer": 2,
           "stack": 2, "emit": 2, "finalize:ImageTransformer": 2,
           "finalize:ImageFeaturizer": 2, "fill": 4, "h2d": 4, "queue": 4,
           "dispatch": 4, "in_flight": 4, "compute_wait": 4, "readback": 4}
@@ -147,6 +150,49 @@ class TestTree:
                          if s["name"] == name)
             assert got == [0, 1, 2, 3], name
 
+    def test_spans_on_the_calling_thread_nest(self, warm_call):
+        # what the benchmark's self time and idle attribution rest on: two
+        # spans of the calling thread overlap only if one is the other's
+        # ancestor, and a child lies inside its parent there
+        _fused, spans = warm_call
+        by_id = {s["span_id"]: s for s in spans}
+        root = next(s for s in spans if s["name"] == "transform")
+        mine = [s for s in spans if s["thread"] == root["thread"]]
+
+        def ancestors(s):
+            while s["parent_id"] in by_id:
+                s = by_id[s["parent_id"]]
+                yield s["span_id"]
+
+        for i, a in enumerate(mine):
+            for b in mine[i + 1:]:
+                lo = max(a["t0"], b["t0"])
+                hi = min(a["t0"] + a["dur_s"], b["t0"] + b["dur_s"])
+                if hi - lo > 1e-4:
+                    assert a["span_id"] in ancestors(b) \
+                        or b["span_id"] in ancestors(a), (a["name"], b["name"])
+        stretches = [s for s in mine if s["name"] == "partition"]
+        assert [s["attrs"]["part"] for s in
+                sorted(stretches, key=lambda s: s["t0"])] == [0, 1, 0, 1]
+
+    def test_one_dispatch_flight_and_wait_a_batch_across_partitions(
+            self, recorder):
+        # 40 rows in 3 partitions of 14, 13, 13: 2 batches each, the second
+        # ragged; the clock join counts these spans against the programs
+        fused, df = image_chain(), image_df(n=40, parts=3)
+        fused.transform(df)
+        recorder.clear()
+        fused.transform(df)
+        spans = recorder.spans()
+        by_id = {s["span_id"]: s for s in spans}
+        for name in ("dispatch", "in_flight", "compute_wait"):
+            mine = sorted((s for s in spans if s["name"] == name),
+                          key=lambda s: s["attrs"]["batch"])
+            assert [s["attrs"]["batch"] for s in mine] == list(range(6)), name
+            assert [by_id[s["parent_id"]]["attrs"]["part"] for s in mine] \
+                == [0, 0, 1, 1, 2, 2], name
+        assert len(fused.last_ingest_stats.records) == 6
+
 
 class TestNoSpanPerRow:
     def test_spans_a_call_do_not_grow_with_rows(self, recorder):
@@ -170,8 +216,20 @@ class TestThreads:
             assert {s["thread"] for s in mine} == {thread}
             assert {s["trace_id"] for s in mine} == {root["trace_id"]}
             assert all(s["attrs"].get("bytes", 1) > 0 for s in mine)
+        # the first partition is prepared on the calling thread, the second
+        # by the look-ahead, beside the first one's batches
+        prepares = sorted((s for s in spans if s["name"] == "prepare"),
+                          key=lambda s: s["attrs"]["ahead"])
+        assert [s["attrs"]["ahead"] for s in prepares] == [0, 1]
+        assert [s["thread"] for s in prepares] \
+            == [root["thread"], "partition-prep"]
+        by_id = {s["span_id"]: s for s in spans}
+        for s in spans:
+            if s["name"] in ("prepare:ImageTransformer", "stack"):
+                assert s["thread"] == by_id[s["parent_id"]]["thread"]
         caller = {s["thread"] for s in spans
-                  if s["name"] not in ("h2d", "fill", "in_flight")}
+                  if s["name"] not in ("h2d", "fill", "in_flight", "prepare",
+                                       "prepare:ImageTransformer", "stack")}
         assert caller == {root["thread"]}
 
     def test_in_flight_runs_from_the_dispatch_to_before_the_drain(self, warm_call):
@@ -211,8 +269,10 @@ class TestHostSpans:
             assert all(s["attrs"] == {"rows": 16} for s in host)
             assert {by_id[s["parent_id"]]["name"] for s in host} == {SEGMENT}
         # what ran before the fault is still on record, the partition too
-        for name in ("partition", "prepare", "compile"):
+        for name in ("prepare", "compile"):
             assert sum(s["name"] == name for s in spans) == 2, name
+        assert {s["attrs"]["part"] for s in spans
+                if s["name"] == "partition"} == {0, 1}
 
     def test_a_host_stage_of_the_plan_is_a_span(self, recorder):
         fused = FusedPipelineModel(
@@ -384,7 +444,10 @@ class TestServingBindingWins:
                 assert by_id[s["parent_id"]]["name"] == TREE[s["name"]]
         want_counts = {k: v for k, v in COUNTS.items()
                        if k not in ("transform", "queue", "in_flight")}
-        assert counts == want_counts       # the split has no ring: no queue
+        # the split has no ring: no queue, and a partition's work is not
+        # interleaved with another's, so one span a partition
+        want_counts["partition"] = 2
+        assert counts == want_counts
         records = fused.last_ingest_stats.records
         for name, field in (("dispatch", "dispatch_s"),
                             ("compute_wait", "compute_s"),
