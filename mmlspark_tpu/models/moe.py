@@ -112,6 +112,15 @@ class MoE(Module):
 GMM_TILE = (512, 512, 1024)      # rows, contraction, columns of one kernel step
 
 
+def _whole_tile(dim: int, most: int) -> int:
+    """The widest tile of at most ``most`` that ``dim`` is whole tiles of: a
+    multiple of 128 lanes where there is one (896 for a width of 3,584), else
+    ``most`` itself, which the caller then refuses."""
+    if dim <= most:
+        return dim
+    return next((t for t in range(most, 0, -128) if dim % t == 0), most)
+
+
 def _gmm_kernel(group_of, active, lhs_ref, rhs_ref, out_ref, acc_scr, *, k_steps):
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -146,7 +155,7 @@ def gmm_pallas(lhs, rhs, group_sizes, out_dtype, interpret: bool = False):
     from jax.experimental.pallas import tpu as pltpu
 
     (M, K), (G, _, N) = lhs.shape, rhs.shape
-    tm, tk, tn = (min(t, d) for t, d in zip(GMM_TILE, (M, K, N)))
+    tm, tk, tn = (_whole_tile(d, t) for t, d in zip(GMM_TILE, (M, K, N)))
     if M % tm or K % tk or N % tn:
         raise ValueError(f"moe_gmm: {(M, K, N)} is not whole tiles of {(tm, tk, tn)}")
     ends = jnp.cumsum(group_sizes.astype(jnp.int32))
@@ -332,7 +341,9 @@ class ExpertLayer(Module):
 
         # the rows go through `rows` at a time: twice what the held experts
         # expect, so one trip unless the routing is very uneven (a second
-        # trip costs its gathers again); a trip with nothing in it is skipped
+        # trip costs its gathers again); a trip with nothing in it is skipped.
+        # A layer that holds every expert expects every visit: `rows` is
+        # `most`, one trip whatever the routing
         most = -(-(N * K + held * (tile - 1)) // tile) * tile
         expect = -(-N * K * held // self.num_experts)
         rows = min(-(-(2 * max(expect, tile) + held * (tile - 1)) // tile) * tile, most)

@@ -1,27 +1,46 @@
 """The parts of a present-day causal language model, and the scorer built
-from them: RMSNorm, rotary positions, grouped-query attention with a causal
-mask and an optional window, SwiGLU, a dropless expert layer
-(``moe.ExpertLayer``), and ``CausalLM`` / ``causal_lm``, whose output is one
-number a position (the next token's log-probability): the logits never leave
-the device.
+from them: RMSNorm, rotary positions (plain or YaRN), two attention modules,
+SwiGLU, a dropless expert layer (``moe.ExpertLayer``), a decoder layer whose
+residual path is a part of it, and ``CausalLM`` / ``causal_lm`` /
+``latent_causal_lm``, whose output is one number a position (the next token's
+log-probability): the logits never leave the device.
 
 Parameters keep the dtype they are handed (``causal_lm(param_dtype=
 "bfloat16")`` makes them bfloat16): a weight matrix is cast to
 ``matmul_dtype()`` where it is used, which is no copy when it already has it.
 Norms, the router's scores, softmax and ``log_softmax`` run in float32, and so
-does the residual stream.
+does the residual path.
 
-Attention runs one of two ways, both grouped (a key/value head is read by its
-query heads in place, never repeated in memory):
+**The residual path** is a part of ``DecoderLayer``: plain, ``h = x +
+F(norm(x))`` on one stream of the hidden width, or hyper-connected
+(``residual.HyperConnection``): ``n`` streams a token, each sublayer reading
+a learned mix of them and writing back through maps that are made doubly
+stochastic by Sinkhorn steps. ``CausalLM`` widens the embedding to the
+streams and sums them before the final norm.
 
-  - ``attn_window`` / ``attn_full``: one Pallas kernel under two names (what
-    a device trace shows), a streaming softmax over key blocks. A sliding
-    layer visits only the blocks its window touches, so its work and memory
-    grow with ``T x window``; a full layer stops at the diagonal. Taken on a
-    TPU for bfloat16 heads of 128 lanes at block-aligned ``T``.
+**The two attention modules**, both causal:
+
+  - ``GQAttention``: grouped queries (a key/value head is read by its query
+    heads in place, never repeated in memory), an optional window, RMSNorm
+    over each head, rotary positions over the whole head;
+  - ``LatentAttention`` (MLA): queries through a low-rank bottleneck, keys
+    and values decompressed from one narrow latent a token, and a rotary key
+    that all heads share; a score is the sum of a head's own product and the
+    shared rotary one, the value narrower than the two together.
+
+Their cores run one of two ways:
+
+  - ``attn_window`` / ``attn_full`` / ``attn_mla``: Pallas kernels under the
+    names a device trace shows, one streaming softmax over key blocks. A
+    sliding layer visits only the blocks its window touches, so its work and
+    memory grow with ``T x window``; a full or latent layer stops at the
+    diagonal; the latent kernel fetches the shared rotary key once a key
+    block for all the heads of a step. Taken on a TPU for bfloat16 heads of
+    128 lanes at block-aligned ``T``.
   - plain XLA otherwise (the CPU, float32 tests): a banded two-block form
-    for a window, query blocks against all keys for a full layer; neither
-    holds a ``[heads, T, T]`` tensor. It is also the kernel's VJP (recomputed).
+    for a window, query blocks against all keys for a full or latent layer;
+    none holds a ``[heads, T, T]`` tensor. It is also the kernels' VJP
+    (recomputed).
 """
 
 from __future__ import annotations
@@ -35,6 +54,7 @@ import numpy as np
 from .module import FunctionModel, Module, _rng_split, matmul_dtype
 
 _NEG = -1e30          # masked score: finite, so a fully masked block stays finite
+LOGITS_BLOCK = 2 ** 28    # logits the head holds at a time: 1.07 GB of float32
 
 
 def _mm_dtype():
@@ -59,16 +79,58 @@ def rms_norm(x, gain, eps: float):
     return xf * inv * jnp.asarray(gain).astype(jnp.float32)
 
 
-def rotary(x, theta: float):
+def rope_frequencies(dim: int, theta: float, scaling: Optional[Dict[str, Any]] = None):
+    """(the ``dim / 2`` inverse frequencies, float64; what cos and sin are
+    scaled by). ``scaling`` is a configuration's ``rope_scaling``: None or
+    type ``default`` for the plain ``theta^(-2i/dim)``, ``yarn`` for YaRN
+    (arXiv:2309.00071): a frequency that turns more than ``beta_fast`` times
+    over the original context stays, one that turns less than ``beta_slow``
+    times is divided by ``factor``, a linear ramp between."""
+    inv = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    kind = (scaling or {}).get("type", (scaling or {}).get("rope_type", "default"))
+    if kind == "default":
+        return inv, 1.0
+    if kind != "yarn":
+        raise ValueError(f"unknown rope scaling {kind!r}")
+    factor = float(scaling["factor"])
+    context = float(scaling["original_max_position_embeddings"])
+
+    def dim_of(turns: float) -> float:     # the dim that turns so often in `context`
+        return dim * math.log(context / (2 * math.pi * turns)) / (2 * math.log(theta))
+
+    low = max(math.floor(dim_of(float(scaling.get("beta_fast", 32)))), 0)
+    high = min(math.ceil(dim_of(float(scaling.get("beta_slow", 1)))), dim - 1)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / (high - low if high != low else 0.001), 0.0, 1.0)
+
+    return (inv / factor * ramp + inv * (1.0 - ramp),
+            _yarn_mscale(factor, float(scaling.get("mscale", 1)))
+            / _yarn_mscale(factor, float(scaling.get("mscale_all_dim", 0))))
+
+
+def _yarn_mscale(factor: float, m: float) -> float:
+    return 0.1 * m * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_softmax_scale(scaling: Optional[Dict[str, Any]]) -> float:
+    """What a YaRN model multiplies its softmax scale by: ``m^2``, ``m = 0.1
+    mscale_all_dim ln(factor) + 1`` (DeepSeek-V3's attention); 1 without."""
+    if not scaling:
+        return 1.0
+    return _yarn_mscale(float(scaling["factor"]), float(scaling.get("mscale_all_dim", 0))) ** 2
+
+
+def rotary(x, theta: float, scaling: Optional[Dict[str, Any]] = None):
     """Rotary positions over the whole head of ``[B, T, heads, hd]`` float32:
-    the head's two halves rotate against each other (``rotate_half``)."""
+    the head's two halves rotate against each other (``rotate_half``), at
+    the frequencies ``rope_frequencies`` gives."""
     import jax.numpy as jnp
 
     t, hd = x.shape[1], x.shape[-1]
-    inv = 1.0 / theta ** (np.arange(0, hd, 2, dtype=np.float64) / hd)
+    inv, factor = rope_frequencies(hd, theta, scaling)
     ang = np.arange(t, dtype=np.float64)[:, None] * inv[None, :]
-    cos = jnp.asarray(np.cos(ang), jnp.float32)[None, :, None, :]
-    sin = jnp.asarray(np.sin(ang), jnp.float32)[None, :, None, :]
+    cos = jnp.asarray(np.cos(ang) * factor, jnp.float32)[None, :, None, :]
+    sin = jnp.asarray(np.sin(ang) * factor, jnp.float32)[None, :, None, :]
     a, b = x[..., :hd // 2], x[..., hd // 2:]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
 
@@ -165,6 +227,28 @@ def _first_kv(qi, bq: int, bk: int, window: int):
     return jnp.maximum(qi * bq - (window - 1), 0) // bk
 
 
+def _softmax_step(s, seen, v, m_scr, l_scr, acc_scr, g: int):
+    """One key block of head ``g``'s streaming softmax: scores ``s [bq, bk]``
+    float32 (keys not ``seen`` out; None: all are seen) fold into the head's
+    running maximum, denominator and accumulator of ``P v``."""
+    import jax
+    import jax.numpy as jnp
+
+    if seen is not None:
+        s = jnp.where(seen, s, _NEG)
+    m_prev = m_scr[g]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new[:, :1])
+    if seen is not None:
+        p = jnp.where(seen, p, 0.0)
+    alpha = jnp.exp(m_prev - m_new)
+    l_scr[g] = alpha * l_scr[g] + jnp.sum(p, axis=1, keepdims=True)
+    acc_scr[g] = alpha[:, :1] * acc_scr[g] + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    m_scr[g] = m_new
+
+
 def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                  bq: int, bk: int, window: int, steps: int, scale: float,
                  group: int, d: int):
@@ -199,16 +283,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
             q = q_ref[:, g * d:(g + 1) * d]
             s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32) * scale
-            s = jnp.where(seen, s, _NEG)
-            m_prev = m_scr[g]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            p = jnp.where(seen, jnp.exp(s - m_new[:, :1]), 0.0)
-            alpha = jnp.exp(m_prev - m_new)
-            l_scr[g] = alpha * l_scr[g] + jnp.sum(p, axis=1, keepdims=True)
-            acc_scr[g] = alpha[:, :1] * acc_scr[g] + jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_scr[g] = m_new
+            _softmax_step(s, seen, v, m_scr, l_scr, acc_scr, g)
 
     @pl.when(j == steps - 1)
     def _store():
@@ -316,6 +391,166 @@ def gq_attention(q, k, v, window: int, heads: int, kv_heads: int):
 
 
 # ---------------------------------------------------------------------------
+# latent attention: the core over decompressed heads and one shared rotary key
+# ---------------------------------------------------------------------------
+#
+# Layout, flat as the projections leave it: ``q [B, T, H * dq]``, a head's
+# ``dn`` content lanes then its rotary lanes (already scaled: the softmax
+# scale is folded into the queries); ``kv [B, T, H * (dn + dv)]``, a head's
+# content keys then its values; ``kr [B, T, dq - dn]``, the rotary key all
+# heads share. The kernel wants whole tiles of 128 lanes, so its caller pads
+# the rotary lanes of q and kr with zeros (a 64-wide contraction costs the
+# MXU a pass of 128 anyway).
+
+def mla_xla(q, kv, kr, heads: int, dn: int):
+    """-> ``[B, T, H * dv]``; causal, a block of queries against all keys:
+    ``s = q_nope . k_nope + q_rope . k_r``, the second product against the
+    one shared key, never repeated to the heads."""
+    import jax
+    import jax.numpy as jnp
+
+    B, T, _ = q.shape
+    blk = min(T, 512)
+    pad = -T % blk
+    if pad:
+        q, kv, kr = (jnp.pad(a, ((0, 0), (0, pad), (0, 0))) for a in (q, kv, kr))
+    q4 = q.reshape(B, (T + pad) // blk, blk, heads, -1)
+    kv4 = kv.reshape(B, T + pad, heads, -1)
+    kn, v = kv4[..., :dn], kv4[..., dn:]
+    kpos = jnp.arange(T + pad)
+
+    def block(a):
+        qb, first = a                                   # [B, blk, H, dq]
+        s = jnp.einsum("bqhd,bkhd->bhqk", qb[..., :dn], kn,
+                       preferred_element_type=jnp.float32) \
+            + jnp.einsum("bqhd,bkd->bhqk", qb[..., dn:], kr,
+                         preferred_element_type=jnp.float32)
+        p = _softmax_rows(s, kpos[None, :] <= (first + jnp.arange(blk))[:, None])
+        return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v,
+                          preferred_element_type=jnp.float32)
+
+    o = jax.lax.map(block, (jnp.moveaxis(q4, 1, 0),
+                            jnp.arange((T + pad) // blk) * blk))
+    return jnp.moveaxis(o, 0, 1).reshape(B, T + pad, -1)[:, :T].astype(kv.dtype)
+
+
+MLA_HEADS_A_STEP = 4      # heads that share one fetch of the rotary key
+
+
+def _mla_kernel(q_ref, kv_ref, kr_ref, o_ref, m_scr, l_scr, acc_scr, *,
+                blk: int, group: int, dn: int, dq: int, dv: int):
+    """One block of queries of ``group`` heads against one block of keys:
+    the shared rotary key is fetched once for all of them and joins each
+    head's content key as the last lanes of one ``dq``-wide contraction."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    qi, j = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, _NEG)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def heads(seen):
+        kr = kr_ref[...]
+        for g in range(group):
+            at = g * (dn + dv)
+            k = jnp.concatenate([kv_ref[:, at:at + dn], kr], axis=1)
+            s = jax.lax.dot_general(q_ref[:, g * dq:(g + 1) * dq], k,
+                                    (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            _softmax_step(s, seen, kv_ref[:, at + dn:at + dn + dv],
+                          m_scr, l_scr, acc_scr, g)
+
+    @pl.when(j < qi)
+    def _below():            # every key of the block is before every query
+        heads(None)
+
+    @pl.when(j == qi)
+    def _diagonal():
+        heads(jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 1)
+              <= jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 0))
+        for g in range(group):
+            o_ref[:, g * dv:(g + 1) * dv] = (
+                acc_scr[g] / l_scr[g][:, :1]).astype(o_ref.dtype)
+
+
+def mla_pallas(q, kv, kr, heads: int, dn: int, interpret: bool = False):
+    """The kernel form of ``mla_xla`` (same arguments and result): ``dn``,
+    ``dq`` and ``dv`` whole tiles of 128 lanes, query and key blocks alike."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, T, _ = q.shape
+    dq, dv = q.shape[2] // heads, kv.shape[2] // heads - dn
+    group = next(g for g in (MLA_HEADS_A_STEP, 2, 1) if heads % g == 0)
+    blk = _attn_blocks(T, 0)[0]
+
+    def key_index(b, h, qi, j):
+        return b, jnp.minimum(j, qi), h      # past the diagonal nothing is fetched
+
+    pairs = T * (T + 1) / 2
+    return pl.pallas_call(
+        functools.partial(_mla_kernel, blk=blk, group=group, dn=dn, dq=dq, dv=dv),
+        grid=(B, heads // group, T // blk, T // blk),
+        in_specs=[pl.BlockSpec((None, blk, group * dq), lambda b, h, qi, j: (b, qi, h)),
+                  pl.BlockSpec((None, blk, group * (dn + dv)), key_index),
+                  pl.BlockSpec((None, blk, dq - dn),
+                               lambda b, h, qi, j: (b, jnp.minimum(j, qi), 0))],
+        out_specs=pl.BlockSpec((None, blk, group * dv), lambda b, h, qi, j: (b, qi, h)),
+        out_shape=jax.ShapeDtypeStruct((B, T, heads * dv), q.dtype),
+        scratch_shapes=[pltpu.VMEM((group, blk, 128), jnp.float32),
+                        pltpu.VMEM((group, blk, 128), jnp.float32),
+                        pltpu.VMEM((group, blk, dv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        name="attn_mla",         # the name a device trace shows
+        cost_estimate=pl.CostEstimate(
+            flops=int(2 * B * heads * (dq + dv) * pairs),
+            transcendentals=int(B * heads * pairs),
+            bytes_accessed=int(q.size * q.dtype.itemsize * (1 + dv / dq)
+                               + kv.size * kv.dtype.itemsize * (T // blk + 1) / 2)),
+        interpret=interpret,
+    )(q, kv, kr)
+
+
+@functools.lru_cache(maxsize=None)
+def _mla_kernel_vjp(interpret: bool = False):
+    """The kernel with a backward pass: the plain form's, recomputed."""
+    import jax
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+    def attend(q, kv, kr, heads, dn):
+        return mla_pallas(q, kv, kr, heads, dn, interpret)
+
+    def fwd(q, kv, kr, heads, dn):
+        return mla_pallas(q, kv, kr, heads, dn, interpret), (q, kv, kr)
+
+    def bwd(heads, dn, res, g):
+        return jax.vjp(lambda q, kv, kr: mla_xla(q, kv, kr, heads, dn), *res)[1](g)
+
+    attend.defvjp(fwd, bwd)
+    return attend
+
+
+def _mla_pallas_applies(x, nope: int, v_dim: int) -> bool:
+    """Whether a latent layer's core takes the kernel for input ``x [B, T,
+    D]``: a TPU, bfloat16 operands, content and value heads of whole tiles."""
+    import jax
+    import jax.numpy as jnp
+
+    return (jax.default_backend() == "tpu" and _mm_dtype() == jnp.bfloat16
+            and nope % 128 == 0 and v_dim % 128 == 0
+            and _attn_blocks(x.shape[1], 0) is not None)
+
+
+# ---------------------------------------------------------------------------
 # modules
 # ---------------------------------------------------------------------------
 
@@ -389,14 +624,89 @@ class GQAttention(Module):
                        preferred_element_type=jnp.float32)
 
 
-def _by_rows(fn, x, most_tokens: int = 8192):
+class LatentAttention(Module):
+    """Causal multi-head latent attention (DeepSeek-V3's MLA) on ``[B, T,
+    D]``, in the decompressed form a long pass wants: ``c_q = RMSNorm(x
+    W_qa)`` (``q_rank``), a head's query ``[q_nope (nope), q_rope (rope)] =
+    c_q W_qb``; ``[c_kv (kv_rank), k_r (rope)] = x W_kva``, a head's
+    ``[k_nope (nope), v (v_dim)] = RMSNorm(c_kv) W_kvb``; rotary positions
+    (``rope_theta``, ``rope_scaling``: YaRN) on ``q_rope`` and on ``k_r``,
+    which all heads share; ``s = (q_nope . k_nope + q_rope . k_r) (nope +
+    rope)^-0.5 m^2`` (``yarn_softmax_scale``); output ``(P v) W_o``."""
+
+    def __init__(self, heads: int, q_rank: int, kv_rank: int, nope: int, rope: int,
+                 v_dim: int, rope_theta: float = 10000.0,
+                 rope_scaling: Optional[Dict[str, Any]] = None,
+                 eps: float = 1e-6, param_dtype: str = "float32"):
+        self.heads, self.q_rank, self.kv_rank = heads, q_rank, kv_rank
+        self.nope, self.rope, self.v_dim = nope, rope, v_dim
+        self.rope_theta, self.rope_scaling = rope_theta, rope_scaling
+        self.eps, self.param_dtype = eps, param_dtype
+
+    def init(self, rng, in_shape):
+        t, d = in_shape
+        h, dt = self.heads, self.param_dtype
+        keys = _rng_split(rng, 5)
+        return {"wq_a": _normal(keys[0], (d, self.q_rank), d ** -0.5, dt),
+                "q_norm": np.ones((self.q_rank,), dt),
+                "wq_b": _normal(keys[1], (self.q_rank, h * (self.nope + self.rope)),
+                                self.q_rank ** -0.5, dt),
+                "wkv_a": _normal(keys[2], (d, self.kv_rank + self.rope), d ** -0.5, dt),
+                "kv_norm": np.ones((self.kv_rank,), dt),
+                "wkv_b": _normal(keys[3], (self.kv_rank, h * (self.nope + self.v_dim)),
+                                 self.kv_rank ** -0.5, dt),
+                "wo": _normal(keys[4], (h * self.v_dim, d),
+                              (h * self.v_dim) ** -0.5, dt)}, (t, d)
+
+    def apply(self, params, x, train: bool = False):
+        import jax.numpy as jnp
+
+        dt = _mm_dtype()
+        B, T, _ = x.shape
+        h, nope, rope = self.heads, self.nope, self.rope
+        kernel = _mla_pallas_applies(x, nope, self.v_dim)
+        lanes = -rope % 128 if kernel else 0         # zeros after the rotary lanes
+
+        def dot(a, name, out=jnp.float32):
+            return jnp.dot(a.astype(dt), jnp.asarray(params[name]).astype(dt),
+                           preferred_element_type=out)
+
+        def positions(a):                            # [B, T, heads, rope] float32
+            a = rotary(a, self.rope_theta, self.rope_scaling)
+            return jnp.pad(a, ((0, 0),) * 3 + ((0, lanes),)).astype(dt)
+
+        # the softmax scale rides on the queries' latent: one pass over [T, q_rank]
+        scale = (nope + rope) ** -0.5 * yarn_softmax_scale(self.rope_scaling)
+        c_q = rms_norm(dot(x, "wq_a"), params["q_norm"], self.eps) * np.float32(scale)
+        q = dot(c_q, "wq_b").reshape(B, T, h, nope + rope)
+        q = jnp.concatenate([q[..., :nope].astype(dt), positions(q[..., nope:])],
+                            axis=-1).reshape(B, T, -1)
+        ckv = dot(x, "wkv_a")
+        kr = positions(ckv[..., None, self.kv_rank:]).reshape(B, T, -1)
+        kv = dot(rms_norm(ckv[..., :self.kv_rank], params["kv_norm"], self.eps),
+                 "wkv_b", dt)                        # a head's keys, then its values
+        attend = _mla_kernel_vjp() if kernel else mla_xla
+        return dot(attend(q, kv, kr, h, nope), "wo")
+
+
+def _by_rows(fn, x, most_tokens: int = 8192, positionwise: bool = False):
     """``fn`` over ``x [B, T, D]``, rows in equal groups of at most
     ``most_tokens`` tokens one after the other: what a sublayer holds between
     its products (float32 heads before their norm, a wide layer's hidden
-    units) then never stands for the whole batch."""
+    units) then never stands for the whole batch. A row longer than
+    ``most_tokens`` goes through alone; where ``fn`` is ``positionwise`` (it
+    treats every position by itself: a norm, a SwiGLU) such a row is cut into
+    equal pieces of at most ``most_tokens`` positions, which go through as
+    rows do."""
     import jax
 
     B, T, D = x.shape
+    if positionwise and T > most_tokens:
+        pieces = -(-T // most_tokens)
+        while T % pieces:
+            pieces += 1
+        return _by_rows(fn, x.reshape(B * pieces, T // pieces, D),
+                        most_tokens).reshape(B, T, -1)
     groups = -(-B // max(1, most_tokens // T))
     while B % groups:
         groups += 1
@@ -439,14 +749,26 @@ class SwiGLU(Module):
 
 
 class DecoderLayer(Module):
-    """``h = x + Attn(RMSNorm(x)); x' = h + FFN(RMSNorm(h))``: the FFN a
-    ``SwiGLU`` (``mlp``), or an ``ExpertLayer`` (``moe``) beside a shared
-    ``SwiGLU`` (``shared``). ``apply_with_load`` also returns the expert
-    layer's ``[B, experts held]`` visit counts (None for a dense layer)."""
+    """Two sublayers, attention (``GQAttention`` or ``LatentAttention``) and
+    an FFN: a ``SwiGLU`` (``mlp``), or an ``ExpertLayer`` (``moe``) beside a
+    shared ``SwiGLU`` (``shared``), each behind its RMSNorm and inside the
+    layer's residual path, which is a part of the layer:
 
-    def __init__(self, attn: GQAttention, mlp: Optional[SwiGLU] = None,
+      - plain (``hyper`` None), on ``x [B, T, D]``: ``h = x + Attn(RMSNorm(x));
+        x' = h + FFN(RMSNorm(h))``;
+      - hyper-connected (``hyper`` a ``residual.HyperConnection``, the parts
+        ``attn_hc`` and ``mlp_hc``), on ``X [B, T, streams D]``: a sublayer
+        reads ``RMSNorm(sum_i H_pre[i] X[i])`` and its output ``y`` goes back
+        as ``X'[i] = sum_j H_res[i, j] X[j] + H_post[i] y``; the expert
+        layer's sum (``add_to`` the shared expert's) is such a ``y``.
+
+    ``apply_with_load`` also returns the expert layer's ``[B, experts held]``
+    visit counts (None for a dense layer)."""
+
+    def __init__(self, attn: Module, mlp: Optional[SwiGLU] = None,
                  moe: Optional[Module] = None, shared: Optional[SwiGLU] = None,
-                 eps: float = 1e-5, param_dtype: str = "float32"):
+                 eps: float = 1e-5, param_dtype: str = "float32",
+                 hyper: Optional[Module] = None):
         if (mlp is None) == (moe is None):
             raise ValueError("a layer has a dense MLP or an expert layer, one of them")
         self.parts: List[Tuple[str, Module]] = [
@@ -454,6 +776,8 @@ class DecoderLayer(Module):
             ("mlp_norm", RMSNorm(eps, param_dtype))]
         self.parts += [(n, m) for n, m in (("mlp", mlp), ("moe", moe),
                                            ("shared", shared)) if m is not None]
+        if hyper is not None:        # one module, a set of parameters a sublayer
+            self.parts += [("attn_hc", hyper), ("mlp_hc", hyper)]
 
     def init(self, rng, in_shape):
         keys = _rng_split(rng, len(self.parts))
@@ -463,10 +787,16 @@ class DecoderLayer(Module):
     def apply_with_load(self, params, x):
         part = dict(self.parts)
 
+        def normed(name, norm):          # the sublayer behind its norm
+            return lambda xc: part[name].apply(
+                params[name], part[norm].apply(params[norm], xc))
+
+        if "attn_hc" in part:
+            return self._hyper_connected(part, params, x, normed)
+
         def sublayer(name, norm):
             def run(xc):
-                return xc + part[name].apply(
-                    params[name], part[norm].apply(params[norm], xc))
+                return xc + normed(name, norm)(xc)
             return run
 
         h = _by_rows(sublayer("attn", "attn_norm"), x)
@@ -477,6 +807,21 @@ class DecoderLayer(Module):
             h = h + part["shared"].apply(params["shared"], hn)
         return part["moe"].apply_with_load(params["moe"], hn, add_to=h)
 
+    def _hyper_connected(self, part, params, x, normed):
+        T = x.shape[1]
+        hc = part["attn_hc"]
+        x_in, coeffs = hc.pre(params["attn_hc"], x)
+        # attention needs a row's every key: rows one after the other, whole
+        x = hc.post(x, _by_rows(normed("attn", "attn_norm"), x_in, max(T, 8192)), coeffs)
+        x_in, coeffs = hc.pre(params["mlp_hc"], x)
+        if "mlp" in part:
+            y = _by_rows(normed("mlp", "mlp_norm"), x_in, positionwise=True)
+            return hc.post(x, y, coeffs), None
+        hn = part["mlp_norm"].apply(params["mlp_norm"], x_in)
+        y = part["shared"].apply(params["shared"], hn) if "shared" in part else None
+        y, load = part["moe"].apply_with_load(params["moe"], hn, add_to=y)
+        return hc.post(x, y, coeffs), load
+
     def apply(self, params, x, train: bool = False):
         return self.apply_with_load(params, x)[0]
 
@@ -484,8 +829,13 @@ class DecoderLayer(Module):
 class CausalLM(Module):
     """Token ids ``[B, T]`` -> the next token's log-probability at every
     position, ``[B, T]`` float32: ``out[t] = log_softmax(logits_t)[id_{t+1}]``,
-    the last position's target the pad id. The logits exist a row of the
-    batch at a time, on the device, and are never an output.
+    the last position's target the pad id. The logits exist a block of
+    positions at a time (at most ``LOGITS_BLOCK`` numbers: a whole row where
+    the vocabulary lets it), on the device, and are never an output.
+
+    With ``streams`` > 1 (layers with a hyper-connected residual path) the
+    state between layers is ``[B, T, streams hidden]``: every stream starts as
+    a copy of the embedding, and their sum goes into the final norm.
 
     A container for ``DNNModel``'s ``fetchDict``: the node ``expert_load``
     is ``[B, sparse layers, experts held]``, the visits each held expert took
@@ -495,11 +845,13 @@ class CausalLM(Module):
     LOAD = "expert_load"
 
     def __init__(self, vocab_size: int, hidden: int, layers: Sequence[DecoderLayer],
-                 pad_id: int = 0, eps: float = 1e-5, param_dtype: str = "float32"):
+                 pad_id: int = 0, eps: float = 1e-5, param_dtype: str = "float32",
+                 streams: int = 1):
         self.vocab_size, self.hidden, self.pad_id = vocab_size, hidden, pad_id
         self.layers = list(layers)
         self.final_norm = RMSNorm(eps, param_dtype)
         self.param_dtype = param_dtype
+        self.streams = streams
 
     def init(self, rng, in_shape):
         (t,) = in_shape
@@ -533,7 +885,13 @@ class CausalLM(Module):
             picked = jnp.take_along_axis(logits, tr[:, None], axis=-1)[:, 0]
             return picked - jax.nn.logsumexp(logits, axis=-1)
 
-        return jax.lax.map(row, (x, target))
+        # a row in equal pieces whose logits are at most LOGITS_BLOCK numbers
+        B, T, D = x.shape
+        pieces = -(-T // max(1, LOGITS_BLOCK // self.vocab_size))
+        while T % pieces:
+            pieces += 1
+        return jax.lax.map(row, (x.reshape(B * pieces, T // pieces, D),
+                                 target.reshape(B * pieces, T // pieces))).reshape(B, T)
 
     def apply(self, params, x, train: bool = False,
               taps: Optional[Set[str]] = None,
@@ -544,6 +902,8 @@ class CausalLM(Module):
         ids = x.astype(jnp.int32)
         h = jnp.take(jnp.asarray(params["embed"]["table"]), ids, axis=0
                      ).astype(jnp.float32)
+        if self.streams > 1:
+            h = jnp.tile(h, (1, 1, self.streams))
         loads = []
         for i, layer in enumerate(self.layers):
             h, load = layer.apply_with_load(params[f"layer{i}"], h)
@@ -555,6 +915,8 @@ class CausalLM(Module):
             if not loads:
                 raise KeyError("expert_load: the model has no expert layer")
             taps_out[_prefix + self.LOAD] = jnp.stack(loads, axis=1)
+        if self.streams > 1:
+            h = h.reshape(*h.shape[:2], self.streams, self.hidden).sum(axis=2)
         return self._log_probs(params, h, ids)
 
 
@@ -601,3 +963,48 @@ def causal_lm(seq_len: int, vocab_size: int, hidden: int, heads: int,
     params = module.init(jax.random.key(seed), (seq_len,))[0] if init else {}
     names = [CausalLM.LOAD] + [f"layer{i}" for i in reversed(range(len(layers)))]
     return FunctionModel(module, params, (seq_len,), names, "causal_lm")
+
+
+def latent_causal_lm(seq_len: int, vocab_size: int, hidden: int, heads: int,
+                     q_rank: int, kv_rank: int, nope: int, rope: int, v_dim: int,
+                     sparse: Sequence[bool], dense_hidden: int, expert_hidden: int,
+                     num_experts: int, experts_held: int, top_k: int,
+                     first_expert: int = 0, scoring: str = "sigmoid",
+                     norm_topk: bool = True, scale: float = 1.0,
+                     shared_experts: int = 1, rope_theta: float = 10000.0,
+                     rope_scaling: Optional[Dict[str, Any]] = None,
+                     streams: int = 1, sinkhorn_iters: int = 20,
+                     hc_eps: float = 1e-6, hc_clamp: Tuple[float, float] = (-30.0, 30.0),
+                     eps: float = 1e-6, pad_id: int = 0,
+                     param_dtype: str = "float32", seed: int = 0,
+                     init: bool = True) -> FunctionModel:
+    """``causal_lm``'s sibling for a model with latent attention and, where
+    ``streams`` > 1, a hyper-connected residual path: the same ``CausalLM`` of
+    the same ``DecoderLayer`` s, every layer a ``LatentAttention`` and an
+    expert layer where ``sparse[i]``, else a dense SwiGLU."""
+    import jax
+
+    from .moe import ExpertLayer
+    from .residual import HyperConnection
+
+    hyper = HyperConnection(streams, sinkhorn_iters, hc_eps, hc_clamp, eps,
+                            param_dtype) if streams > 1 else None
+    layers = []
+    for is_sparse in sparse:
+        attn = LatentAttention(heads, q_rank, kv_rank, nope, rope, v_dim, rope_theta,
+                               rope_scaling, eps, param_dtype)
+        if is_sparse:
+            moe = ExpertLayer(num_experts, experts_held, top_k, expert_hidden,
+                              scoring=scoring, norm_topk=norm_topk, scale=scale,
+                              first_expert=first_expert, param_dtype=param_dtype)
+            shared = SwiGLU(expert_hidden * shared_experts, param_dtype) \
+                if shared_experts else None
+            layers.append(DecoderLayer(attn, moe=moe, shared=shared, eps=eps,
+                                       param_dtype=param_dtype, hyper=hyper))
+        else:
+            layers.append(DecoderLayer(attn, mlp=SwiGLU(dense_hidden, param_dtype),
+                                       eps=eps, param_dtype=param_dtype, hyper=hyper))
+    module = CausalLM(vocab_size, hidden, layers, pad_id, eps, param_dtype, streams)
+    params = module.init(jax.random.key(seed), (seq_len,))[0] if init else {}
+    names = [CausalLM.LOAD] + [f"layer{i}" for i in reversed(range(len(layers)))]
+    return FunctionModel(module, params, (seq_len,), names, "latent_causal_lm")

@@ -340,3 +340,52 @@ def test_the_grouped_product_kernel_compiles_at_the_published_widths(one_chip):
 
     text = _compiled_text(both, x, w1, w2, sizes)
     assert text.count("moe_gmm") >= 2 and "tpu_custom_call" in text
+
+
+def test_the_latent_attention_kernel_compiles_at_the_published_widths(one_chip):
+    """Xing4.0's core: 32 heads, a 192-wide score (128 content lanes and 64
+    rotary ones padded to a tile) against one shared rotary key, values of
+    128, a row of 16,384."""
+    B, t, H = 1, 16384, 32
+    q = jax.ShapeDtypeStruct((B, t, H * 256), jnp.bfloat16, sharding=one_chip)
+    kr = jax.ShapeDtypeStruct((B, t, 128), jnp.bfloat16, sharding=one_chip)
+    text = _compiled_text(lambda q, kv, kr: transformer.mla_pallas(q, kv, kr, H, 128),
+                          q, q, kr)
+    assert "tpu_custom_call" in text and "attn_mla" in text
+
+
+def test_the_stream_mix_kernels_compile_at_the_published_widths(one_chip):
+    from mmlspark_tpu.models import residual
+
+    n, d, tokens = 4, 3584, 16384
+    x = jax.ShapeDtypeStruct((tokens, n * d), jnp.float32, sharding=one_chip)
+    y = jax.ShapeDtypeStruct((tokens, d), jnp.float32, sharding=one_chip)
+    phi = jax.ShapeDtypeStruct((n * d, 24), jnp.bfloat16, sharding=one_chip)
+    pre = jax.ShapeDtypeStruct((1 + n,), jnp.float32, sharding=one_chip)
+    h = jax.ShapeDtypeStruct((tokens, n + n * n), jnp.float32, sharding=one_chip)
+
+    def both(x, y, phi, pre, h):
+        m, ssq, x_in = residual.mhc_pre_pallas(x, phi, pre, n, 1e-6)
+        return residual.mhc_post_pallas(x, y + x_in, h, n), m, ssq
+
+    text = _compiled_text(both, x, y, phi, pre, h)
+    assert "mhc_pre" in text and "mhc_post" in text and "tpu_custom_call" in text
+
+
+def test_the_grouped_product_kernel_compiles_for_a_width_of_3584(one_chip):
+    """Every expert held: 64 groups, 98,304 padded visit rows of a batch of
+    16,384 positions; the down product's 3,584 columns in tiles of 896."""
+    held, d, eff, rows_ = 64, 3584, 1024, 98304
+    assert moe._whole_tile(d, moe.GMM_TILE[2]) == 896
+    assert [moe._whole_tile(w, moe.GMM_TILE[2]) for w in (4096, 6144)] == [1024, 1024]
+    x = jax.ShapeDtypeStruct((rows_, d), jnp.bfloat16, sharding=one_chip)
+    w1 = jax.ShapeDtypeStruct((held, d, 2 * eff), jnp.bfloat16, sharding=one_chip)
+    w2 = jax.ShapeDtypeStruct((held, eff, d), jnp.bfloat16, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((held,), jnp.int32, sharding=one_chip)
+
+    def both(x, w1, w2, sizes):
+        hid = moe.gmm_pallas(x, w1, sizes, jnp.bfloat16)
+        return moe.gmm_pallas(hid[:, :eff], w2, sizes, jnp.bfloat16)
+
+    text = _compiled_text(both, x, w1, w2, sizes)
+    assert text.count("moe_gmm") >= 2 and "tpu_custom_call" in text
