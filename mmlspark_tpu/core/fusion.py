@@ -452,18 +452,33 @@ def _csr_from_rows(col: np.ndarray, width: int
     return indptr, indices, values
 
 
-def _default_finalize(outs: Dict[str, np.ndarray], ctx: Dict) -> Dict[str, np.ndarray]:
-    """Readback arrays -> partition columns: 1-D stays a numeric column,
-    [n, ...] becomes an object column of per-row views (DNN output parity)."""
+def _join(batches: List[np.ndarray]) -> np.ndarray:
+    """A partition's fetched batches as ONE fresh array: the copy a numeric
+    column and a stage's own ``finalize`` need, and nothing else."""
+    return np.concatenate(batches, axis=0) if batches \
+        else np.zeros((0,), dtype=np.float32)
+
+
+def _default_finalize(outs: Dict[str, Any], ctx: Dict) -> Dict[str, np.ndarray]:
+    """Outputs -> partition columns, for a writer with no ``finalize`` of
+    its own. An output is one array, or the list of a partition's fetched
+    batches as they came back: 1-D stays a numeric column; [n, ...] becomes
+    an object column of per-row VIEWS (DNN output parity), each of the
+    batch it was read back in: nothing joined, nothing copied, so a row is
+    read-only like its batch and keeps it alive."""
     cols: Dict[str, np.ndarray] = {}
-    for name, arr in outs.items():
-        if arr.ndim <= 1:
-            cols[name] = arr
-        else:
-            obj = np.empty(len(arr), dtype=object)
-            for i in range(len(arr)):
-                obj[i] = arr[i]
-            cols[name] = obj
+    for name, out in outs.items():
+        if isinstance(out, np.ndarray) and out.ndim <= 1:
+            cols[name] = out
+            continue
+        batches = [out] if isinstance(out, np.ndarray) else out
+        obj = np.empty(sum(len(b) for b in batches), dtype=object)
+        i = 0
+        for b in batches:
+            for row in b:
+                obj[i] = row
+                i += 1
+        cols[name] = obj
     return cols
 
 
@@ -559,6 +574,10 @@ class SegmentExecutor:
         # column -> bytes this run emitted from staged host rows instead of
         # reading them back (fusion_stats()["host_emit"])
         self.host_emit: Dict[str, int] = {}
+        # bytes of fetched batches this run copied into one array at emit
+        # (fusion_stats()["joined_bytes"]): 0 where every writer's rows are
+        # views of the batch they came back in
+        self.joined_bytes = 0
         # batches this run has handed to the device so far: the spans'
         # ``batch`` (an executor serves one run of one call)
         self._batch_no = 0
@@ -1569,16 +1588,22 @@ class SegmentExecutor:
                         obs=None) -> Dict[str, np.ndarray]:
         """``_emit_columns`` under an ``emit`` span (``obs``: the
         partition's span): ``host_cols`` columns came from the staged host
-        rows, ``host_bytes`` bytes were not read back for them."""
+        rows, ``host_bytes`` bytes were not read back for them,
+        ``joined_bytes`` bytes of fetched batches were copied into one
+        array (0 where every writer's rows are views of their batch)."""
         own = open_span(obs)
         w0, t0 = time.time(), time.perf_counter()
         host = self._host_columns(state)
+        joined = 0
         try:
-            return self._emit_columns(state, collected, own, host)
+            out_part, joined = self._emit_columns(state, collected, own, host)
+            self.joined_bytes += joined
+            return out_part
         finally:
             close_span(own, "emit", w0, time.perf_counter() - t0,
                        rows=state["n"], host_cols=len(host),
-                       host_bytes=sum(a.nbytes for a in host.values()))
+                       host_bytes=sum(a.nbytes for a in host.values()),
+                       joined_bytes=joined)
 
     def _host_columns(self, state: Dict[str, Any]) -> Dict[str, np.ndarray]:
         """The partition's handed-through columns (``state["host_cols"]``)
@@ -1600,25 +1625,25 @@ class SegmentExecutor:
     def _emit_columns(self, state: Dict[str, Any],
                       collected: Dict[str, List[np.ndarray]],
                       obs, host: Dict[str, np.ndarray]
-                      ) -> Dict[str, np.ndarray]:
-        """Readback arrays (and ``host``, the columns that never left) ->
+                      ) -> Tuple[Dict[str, np.ndarray], int]:
+        """Fetched batches (and ``host``, the columns that never left) ->
         finalized partition columns (per writer stage, scattered over the
-        validity mask)."""
+        validity mask) and the bytes joined on the way: a writer with no
+        ``finalize`` of its own is emitted from its batches as they came
+        back (``_default_finalize``)."""
         seg = self.segment
         part, ctx = state["part"], state["ctx"]
         valid, n, n_valid = state["valid"], state["n"], state["n_valid"]
-        readback = state["readback"]
-        full = {k: (np.concatenate(v, axis=0) if v
-                    else np.zeros((0,), dtype=np.float32))
-                for k, v in collected.items()}
-        full.update(host)
+        # fetched: the list of batches; handed through: one array
+        full: Dict[str, Any] = {**collected, **host}
 
         # finalize per writer stage (stage order), scatter into the partition
-        by_writer: Dict[int, Dict[str, np.ndarray]] = {}
-        for k, i in readback:
+        by_writer: Dict[int, Dict[str, Any]] = {}
+        for k, i in state["readback"]:
             by_writer.setdefault(i, {})[k] = full[k]
         out_part = dict(part)
         transpiled = set(self._transpiled)
+        joined = 0
         for i, dfn in enumerate(seg.dfns):
             outs = by_writer.get(i)
             if outs is None:
@@ -1629,12 +1654,21 @@ class SegmentExecutor:
                 # transpiled finalize: the numeric reductions already ran
                 # on device (device_finalize); that host shim only shapes
                 # the readbacks into columns
-                finalize = dfn.finalize_stitched if i in transpiled \
-                    else dfn.finalize or _default_finalize
+                own = dfn.finalize_stitched if i in transpiled \
+                    else dfn.finalize
+                # a finalize of the stage's own takes whole-partition
+                # arrays, and a 1-D output is one numeric column: those
+                # batches are joined. Any other output stays the batches
+                # it came back in (its rows will be views of them)
+                for k, v in list(outs.items()):
+                    if isinstance(v, list) and (
+                            own is not None or not v or v[0].ndim <= 1):
+                        outs[k] = _join(v)
+                        joined += outs[k].nbytes
                 with batch_span(
                         obs, f"finalize:{type(seg.stages[i]).__name__}",
                         rows=n_valid):
-                    cols = finalize(outs, ctx)
+                    cols = (own or _default_finalize)(outs, ctx)
             for c in dfn.out_cols:
                 if c not in cols:
                     continue
@@ -1647,7 +1681,7 @@ class SegmentExecutor:
                     out_part[c] = scat
         if any(d.drop_invalid for d in seg.dfns) and n_valid < n:
             out_part = {k: v[valid] for k, v in out_part.items()}
-        return out_part
+        return out_part, joined
 
     def _trace_stages(self, params_tuple, cols: Dict[str, Any],
                       csr_cols: frozenset) -> Dict[str, Any]:
@@ -1744,6 +1778,7 @@ class FusedPipelineModel(PipelineModel):
         # segment label -> {column: bytes} the last transform emitted from
         # staged host rows instead of reading back
         self._host_emit: Dict[str, Dict[str, int]] = {}
+        self._joined_bytes: Dict[str, int] = {}
         self._last_fallbacks: List[str] = []
         # cumulative since construction (replicas share one model across
         # threads; the per-call fields above are last-writer-wins)
@@ -1952,10 +1987,12 @@ class FusedPipelineModel(PipelineModel):
             else self._layout_overrides.get(node.label))
 
     def _absorb(self, ex: SegmentExecutor) -> None:
-        """Fold one finished executor's fallbacks, host-emitted columns
-        and output placement into the last-run and cumulative stats."""
+        """Fold one finished executor's fallbacks, host-emitted columns,
+        joined bytes and output placement into the last-run and cumulative
+        stats."""
         self._last_fallbacks.extend(ex.fallbacks)
         self._host_emit[ex.segment.label] = dict(ex.host_emit)
+        self._joined_bytes[ex.segment.label] = ex.joined_bytes
         with self._totals_lock:
             self._fallback_total += len(ex.fallbacks)
             for d, n in ex.out_devices.items():
@@ -2006,6 +2043,7 @@ class FusedPipelineModel(PipelineModel):
         self._last_plan = nodes
         self._seg_stats = {}
         self._host_emit = {}
+        self._joined_bytes = {}
         self._last_fallbacks = []
         self._pipe_stats = None
         pplan = self._pipe_plan_for(nodes)
@@ -2126,6 +2164,7 @@ class FusedPipelineModel(PipelineModel):
         self._last_plan = nodes
         self._seg_stats = {}
         self._host_emit = {}
+        self._joined_bytes = {}
         self._last_fallbacks = []
         # the submit split stays serial: its contract is a single trailing
         # dispatched segment, not a stream (pipeline stats never linger)
@@ -2193,6 +2232,11 @@ class FusedPipelineModel(PipelineModel):
             "host_emit": {label: {"cols": sorted(cols),
                                   "bytes": sum(cols.values())}
                           for label, cols in self._host_emit.items()},
+            # per segment of the last transform: the bytes of fetched
+            # batches that emit copied into one array (a writer with its
+            # own finalize, a 1-D output); 0 where rows are views of the
+            # batch they came back in
+            "joined_bytes": dict(self._joined_bytes),
             "fallbacks": list(self._last_fallbacks),
             "fallbacks_total": self._fallback_total,
             "devices": dict(self._out_devices),

@@ -188,6 +188,8 @@ class PipelineModel(Model):
         (core/fusion.py). Returns a FusedPipelineModel whose transform is
         bitwise-identical to this chain but keeps intermediates on device
         across stage boundaries; host-only stages still run per-stage.
+        Array rows of a fused segment's output column are read-only views
+        of the batch they were read back in (copy a row to change it).
         The fused runner is cached — repeated fuse() calls share compiled
         executables."""
         if getattr(self, "_fused_runner", None) is None:

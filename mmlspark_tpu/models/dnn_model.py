@@ -184,7 +184,10 @@ class DNNModel(Model, HasInputCol, HasOutputCol, HasBatchSize):
         PreprocessSpec] + ONE forward fetching every tap — the same traced
         jaxpr the unfused _compiled() path jits, so fused == unfused
         bitwise. Mesh-sharded eval and dict-feed (multi-input) models keep
-        the unfused path."""
+        the unfused path. No ``finalize``: the fused executor emits each
+        output row as a READ-ONLY view of the batch it was read back in
+        (the unfused path's rows are views of one fresh array; same bits,
+        but a caller that writes into a row in place copies it first)."""
         model = self.get("model")
         if model is None or self.get("useMesh") is True:
             return None

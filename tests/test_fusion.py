@@ -530,8 +530,187 @@ class TestHostEmit:
         fused.transform(df)
         emit = [s for s in default_tracer().spans() if s["name"] == "emit"][-1]
         assert emit["attrs"] == {"rows": 23, "host_cols": 1,
-                                 "host_bytes": 23 * IMAGE_BYTES}
+                                 "host_bytes": 23 * IMAGE_BYTES,
+                                 "joined_bytes": 0}
 
+
+# --------------------------------------------------------------------------
+# a writer with no finalize of its own is emitted from its fetched batches
+# --------------------------------------------------------------------------
+
+
+def emit_joined(ex, state, collected, host):
+    """The joined form: what ``emit`` gave when it first joined every
+    output's fetched batches into one array (the plain reference of
+    ``_emit_columns``; nothing of it is shared with the code under test
+    but the stages' own finalize functions)."""
+    full = {k: np.concatenate(v, axis=0) for k, v in collected.items()}
+    full.update(host)
+    by_writer = {}
+    for k, i in state["readback"]:
+        by_writer.setdefault(i, {})[k] = full[k]
+    n, n_valid, valid = state["n"], state["n_valid"], state["valid"]
+    out_part = dict(state["part"])
+    for i, dfn in enumerate(ex.segment.dfns):
+        if i not in by_writer:
+            continue
+        if dfn.finalize is not None:
+            cols = dfn.finalize(by_writer[i], state["ctx"])
+        else:
+            cols = {}
+            for name, arr in by_writer[i].items():
+                cols[name] = arr
+                if arr.ndim > 1:
+                    cols[name] = np.empty(len(arr), dtype=object)
+                    for j in range(len(arr)):
+                        cols[name][j] = arr[j]
+        for c in dfn.out_cols:
+            if c in cols and n_valid == n:
+                out_part[c] = cols[c]
+            elif c in cols:
+                out_part[c] = np.empty(n, dtype=object)
+                out_part[c][np.flatnonzero(valid)] = cols[c]
+    if any(d.drop_invalid for d in ex.segment.dfns) and n_valid < n:
+        out_part = {k: v[valid] for k, v in out_part.items()}
+    return out_part
+
+
+def device_stage(out_fn, out_cols=("y",), **kwargs):
+    """A one-off device stage over the float32 column ``x``; every
+    DeviceFn field comes through ``kwargs``."""
+    from mmlspark_tpu.core.device_stage import DeviceFn
+
+    class Probe(ImageTransformer):
+        def device_fn(self, schema):
+            return DeviceFn(key=("Probe", out_cols), in_cols=("x",),
+                            out_cols=out_cols, heavy=True,
+                            fn=lambda p, env: out_fn(env["x"]), **kwargs)
+
+    seg = Segment()
+    seg.add(Probe(), Probe().device_fn(None))
+    return seg
+
+
+def rows_finalize(outs, ctx):
+    # a finalize of the stage's own: it takes ONE whole-partition array
+    # (it reverses each row, so the test sees that it ran)
+    y = outs["y"]
+    assert isinstance(y, np.ndarray) and y.ndim == 2 and y.flags.writeable
+    col = np.empty(len(y), dtype=object)
+    for i in range(len(y)):
+        col[i] = y[i, ::-1].astype(np.float64)
+    return {"y": col}
+
+
+def plan_segment(pm, df):
+    nodes = fused_of(pm)._plan_for(df.schema)
+    assert [type(n).__name__ for n in nodes] == ["Segment"]
+    return nodes[0]
+
+
+def dnn_segment(df, **params):
+    dnn = DNNModel(inputCol="x", batchSize=8, **params)
+    dnn.set_model(toy_mlp())
+    return plan_segment(PipelineModel([dnn]), df)
+
+
+def null_rows(df, *at):
+    col = df.partitions[0]["x"]
+    for i in at:
+        col[i] = None
+    return df
+
+
+# case -> (the frame, its segment, the columns whose rows are views of the
+# batch they came back in, the bytes emit still joins a partition)
+EMIT_CASES = {
+    "one-batch": lambda: (
+        df := vector_df((7,)), dnn_segment(df, outputCol="emb"), ["emb"], 0),
+    "short-last-batch": lambda: (
+        df := vector_df((13,)), dnn_segment(df, outputCol="emb"), ["emb"], 0),
+    "nulls-scattered": lambda: (
+        df := null_rows(vector_df((21,)), 0, 9, 20),
+        dnn_segment(df, outputCol="emb"), ["emb"], 0),
+    "drop-invalid": lambda: (
+        df := typed_image_df(np.uint8, n=21, parts=1, null_at=9),
+        plan_segment(resize_head_chain(ImageTransformer().resize(16, 16),
+                                       drop_na=True), df), ["features"], 0),
+    "two-columns": lambda: (
+        df := vector_df((13,)),
+        dnn_segment(df, fetchDict={"hidden": "d1", "out": "d2"}),
+        ["hidden", "out"], 0),
+    "one-d-output": lambda: (
+        vector_df((13,)), device_stage(lambda x: {"y": x.sum(axis=1)}),
+        [], 13 * 4),
+    "own-finalize": lambda: (
+        vector_df((13,)),
+        device_stage(lambda x: {"y": x * 2}, finalize=rows_finalize),
+        [], 13 * 4 * 4),
+    "own-finalize-then-default": lambda: (
+        df := tabular_df(n=13, parts=1),
+        plan_segment(PipelineModel([
+            FastVectorAssembler(inputCols=["a", "b"]),
+            DNNModel(inputCol="features", outputCol="emb", batchSize=8)
+            .set_model(toy_mlp())]), df), ["emb"], 13 * 4 * 4),
+}
+
+
+class TestEmitFromBatches:
+    @pytest.mark.parametrize("path", ["run", "submit"])
+    @pytest.mark.parametrize("case", sorted(EMIT_CASES))
+    def test_the_partition_is_bitwise_the_joined_form(self, case, path,
+                                                      monkeypatch):
+        from mmlspark_tpu.core.fusion import SegmentExecutor
+        from mmlspark_tpu.obs.trace import (Tracer, root_span,
+                                            set_default_tracer)
+        from mmlspark_tpu.parallel.ingest import IngestStats
+
+        df, seg, view_cols, joined_bytes = EMIT_CASES[case]()
+        emits = []
+        emit = SegmentExecutor._emit_columns
+
+        def spy(self, state, collected, obs, host):
+            emits.append((state, collected,
+                          emit_joined(self, state, collected, host)))
+            return emit(self, state, collected, obs, host)
+
+        monkeypatch.setattr(SegmentExecutor, "_emit_columns", spy)
+        tracer = Tracer(service="batch")
+        old = set_default_tracer(tracer)
+        try:
+            ex = SegmentExecutor(seg, CompileCache())
+            with root_span("call"):     # the binding the spans go under
+                out = ex.run(df, IngestStats()) if path == "run" \
+                    else ex.submit_run(df, IngestStats())()
+        finally:
+            set_default_tracer(old)
+        assert ex.fallbacks == [] and len(emits) == 1
+        state, collected, want = emits[0]
+        got = out.partitions[0]
+        assert_bitwise(DataFrame([want]), out)
+        assert all(want[c].shape == got[c].shape for c in want)
+        # rows of a writer with no finalize of its own: views of the batch
+        # they were fetched in, read-only as the batch is
+        batch = seg.batch_size()
+        for name in view_cols:
+            assert len(collected[name]) == -(-state["n_valid"] // batch)
+            rows = [r for r in got[name] if r is not None]
+            assert len(rows) == state["n_valid"]
+            for i, row in enumerate(rows):
+                assert np.shares_memory(row, collected[name][i // batch])
+                assert not row.flags.writeable
+            with pytest.raises(ValueError, match="read-only"):
+                rows[0][...] = 0
+        span = [s for s in tracer.spans() if s["name"] == "emit"]
+        assert [s["attrs"]["joined_bytes"] for s in span] == [joined_bytes]
+        assert ex.joined_bytes == joined_bytes
+        if case == "one-d-output":      # still one numeric array
+            assert got["y"].dtype == np.float32 and got["y"].shape == (13,)
+        if case == "nulls-scattered":
+            assert [i for i, r in enumerate(got["emb"]) if r is None] \
+                == [0, 9, 20]
+        if case == "drop-invalid":
+            assert state["n"] == 21 and len(got["features"]) == 20
 
 # --------------------------------------------------------------------------
 # planning: splits, demotion, terminal stages
@@ -1035,4 +1214,6 @@ class TestServingFused:
                 stats = json.loads(resp.read())
         assert "fusion" in stats
         assert stats["fusion"]["n_fused_segments"] == 1
+        # the reply's row was a view of the batch it came back in
+        assert stats["fusion"]["joined_bytes"] == {"DNNModel": 0}
         assert stats["fusion"]["compile_cache"]["hits"] >= 1

@@ -175,6 +175,18 @@ class TestTree:
         assert [s["attrs"]["part"] for s in
                 sorted(stretches, key=lambda s: s["t0"])] == [0, 1, 0, 1]
 
+    def test_emit_says_what_it_joined(self, warm_call):
+        # the image column comes from the staged host rows and the
+        # featurizer has no finalize of its own: its rows are views of the
+        # batches that came back, nothing is joined
+        fused, spans = warm_call
+        emits = [s for s in spans if s["name"] == "emit"]
+        assert [s["attrs"] for s in emits] == [
+            {"rows": 16, "host_cols": 1, "host_bytes": 16 * 16 * 16 * 3,
+             "joined_bytes": 0}] * 2
+        assert fused.fusion_stats()["joined_bytes"] == {
+            "ImageTransformer+ImageFeaturizer": 0}
+
     def test_one_dispatch_flight_and_wait_a_batch_across_partitions(
             self, recorder):
         # 40 rows in 3 partitions of 14, 13, 13: 2 batches each, the second
