@@ -46,7 +46,8 @@ from __future__ import annotations
 import dataclasses
 import threading
 import time
-from typing import Any, Callable, Dict, Optional, Tuple
+import weakref
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 
 class FusionUnsupported(Exception):
@@ -176,6 +177,11 @@ def building_in(thread_ident: int) -> bool:
         return _BUILDING.get(thread_ident, 0) > 0
 
 
+# every cache alive, weakly: ``live_caches()``
+_LIVE_CACHES: "weakref.WeakSet[CompileCache]" = weakref.WeakSet()
+_LIVE_LOCK = threading.Lock()
+
+
 class CompileCache:
     """Shared fused-executable cache with hit/miss/compile-time counters
     and per-(segment, shape-bucket) XLA cost records.
@@ -241,6 +247,8 @@ class CompileCache:
         # optional cross-process second tier (duck-typed: load/store/stats;
         # serving/fleet/cache.py PersistentCompileCache). None = single-tier.
         self._persistent: Optional[Any] = None
+        with _LIVE_LOCK:
+            _LIVE_CACHES.add(self)
 
     @property
     def capacity(self) -> int:
@@ -389,6 +397,15 @@ class CompileCache:
         with self._lock:
             return len(self._entries)
 
+    def resident(self) -> List[Tuple[str, Any]]:
+        """(segment label, executable) of every resident entry that was
+        built or loaded under a label: what ``obs.scopes.programs()`` reads
+        the scope maps from, when asked."""
+        with self._lock:
+            return [(self._cost_key[key][0], fn)
+                    for key, fn in self._entries.items()
+                    if key in self._cost_key]
+
     def costs(self) -> Dict[str, Dict[str, Dict[str, Any]]]:
         """{segment label: {shape bucket: cost record}} — flops /
         bytes_accessed / peak_memory_bytes / compile_s per compiled
@@ -438,6 +455,13 @@ class CompileCache:
 
 
 _GLOBAL_CACHE = CompileCache()
+
+
+def live_caches() -> List[CompileCache]:
+    """Every CompileCache alive in this process (the shared one, a model's
+    own, a replica's), so that one place can list the resident programs."""
+    with _LIVE_LOCK:
+        return list(_LIVE_CACHES)
 
 
 def compile_cache() -> CompileCache:

@@ -40,6 +40,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..obs.scopes import scope
 from ..obs.trace import (batch_span, close_span, current_batch, open_span,
                          root_span)
 from . import profiling
@@ -1697,18 +1698,20 @@ class SegmentExecutor:
         env = dict(cols)
         for i, (dfn, p) in enumerate(zip(seg.dfns, params_tuple)):
             given = {c: env.get(src) for c, src in dfn.passthrough.items()}
-            if dfn.sparse_fn is not None and csr_cols & set(dfn.in_cols):
-                env.update(dfn.sparse_fn(p, env))
-            else:
-                env.update(dfn.fn(p, env))
-            for c, src in dfn.passthrough.items():
-                if given[c] is None or env.get(c) is not given[c]:
-                    raise ValueError(
-                        f"{type(seg.stages[i]).__name__} declares {c!r} a "
-                        f"passthrough of {src!r}, but its device fn "
-                        f"returned another value")
-            if i in transpiled:
-                env.update(dfn.device_finalize(p, env))
+            # the device-side twin of ``prepare:<Stage>`` / ``finalize:<Stage>``
+            with scope(type(seg.stages[i]).__name__):
+                if dfn.sparse_fn is not None and csr_cols & set(dfn.in_cols):
+                    env.update(dfn.sparse_fn(p, env))
+                else:
+                    env.update(dfn.fn(p, env))
+                for c, src in dfn.passthrough.items():
+                    if given[c] is None or env.get(c) is not given[c]:
+                        raise ValueError(
+                            f"{type(seg.stages[i]).__name__} declares {c!r} a "
+                            f"passthrough of {src!r}, but its device fn "
+                            f"returned another value")
+                if i in transpiled:
+                    env.update(dfn.device_finalize(p, env))
         return env
 
     def _build(self, params_dev, x: Dict[str, Any], keys: List[str],

@@ -34,6 +34,7 @@ from typing import Optional, Tuple
 
 import numpy as np
 
+from ..obs.scopes import scope
 from .module import Fn, Module, Sequential, _rng_split, matmul_dtype
 
 
@@ -436,8 +437,11 @@ class BiLSTM(Module):
     def apply(self, params, x, train: bool = False):
         import jax.numpy as jnp
 
-        return jnp.concatenate([self.fwd.apply(params["fwd"], x),
-                                self.bwd.apply(params["bwd"], x)], axis=-1)
+        with scope("fwd"):
+            fwd = self.fwd.apply(params["fwd"], x)
+        with scope("bwd"):
+            bwd = self.bwd.apply(params["bwd"], x)
+        return jnp.concatenate([fwd, bwd], axis=-1)
 
 
 # ---------------------------------------------------------------------------

@@ -26,6 +26,8 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
+from ..obs.scopes import scope
+
 Params = Dict[str, Any]
 
 
@@ -175,14 +177,15 @@ class Sequential(Module):
         for lname, layer in self.layers:
             path = f"{_prefix}{lname}"
             p = params.get(lname, {})
-            if getattr(layer, "is_container", False):
-                x = layer.apply(p, x, train=train, taps=taps, taps_out=taps_out,
-                                stats_out=stats_out, _prefix=path + "/")
-            elif isinstance(layer, BatchNorm):
-                x = layer.apply(p, x, train=train, stats_out=stats_out, _path=path)
-            else:
-                x = layer.apply(p, x, train=train)
-            x = _constrain_activation(x)
+            with scope(lname):
+                if getattr(layer, "is_container", False):
+                    x = layer.apply(p, x, train=train, taps=taps, taps_out=taps_out,
+                                    stats_out=stats_out, _prefix=path + "/")
+                elif isinstance(layer, BatchNorm):
+                    x = layer.apply(p, x, train=train, stats_out=stats_out, _path=path)
+                else:
+                    x = layer.apply(p, x, train=train)
+                x = _constrain_activation(x)
             if taps is not None and taps_out is not None and path in taps:
                 taps_out[path] = x
         return x
@@ -462,14 +465,16 @@ class Residual(Module):
               taps: Optional[Set[str]] = None, taps_out: Optional[Dict[str, Any]] = None,
               stats_out: Optional[Dict[str, Any]] = None, _prefix: str = ""):
         import jax.numpy as jnp
-        y = self.body.apply(params["body"], x, train=train, taps=taps,
-                            taps_out=taps_out, stats_out=stats_out,
-                            _prefix=_prefix + "body/")
+        with scope("body"):
+            y = self.body.apply(params["body"], x, train=train, taps=taps,
+                                taps_out=taps_out, stats_out=stats_out,
+                                _prefix=_prefix + "body/")
         s = x
         if self.shortcut is not None:
-            s = self.shortcut.apply(params["shortcut"], x, train=train, taps=taps,
-                                    taps_out=taps_out, stats_out=stats_out,
-                                    _prefix=_prefix + "shortcut/")
+            with scope("shortcut"):
+                s = self.shortcut.apply(params["shortcut"], x, train=train, taps=taps,
+                                        taps_out=taps_out, stats_out=stats_out,
+                                        _prefix=_prefix + "shortcut/")
         out = y + s
         if getattr(self, "activation", "relu") == "relu":
             out = jnp.maximum(out, 0)

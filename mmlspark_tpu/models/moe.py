@@ -39,6 +39,7 @@ from typing import Optional
 
 import numpy as np
 
+from ..obs.scopes import scope
 from .module import Module, _rng_split, matmul_dtype
 
 
@@ -318,26 +319,28 @@ class ExpertLayer(Module):
         N, K, held = B * T, self.top_k, self.experts_held
         dt = getattr(jnp, matmul_dtype())
         xd = x.reshape(N, D).astype(dt)
-        idx, gate = self.route(params, x.reshape(N, D))
-        local = idx - self.first_expert
-        mine = (local >= 0) & (local < held)                       # [N, K]
-        local = jnp.where(mine, local, held)
-        hot = (local[..., None] == jnp.arange(held)).astype(jnp.int32)   # [N, K, held]
-        load = hot.reshape(B, T * K, held).sum(axis=1)
-        counts = load.sum(axis=0)                                  # [held]
+        with scope("route"):
+            idx, gate = self.route(params, x.reshape(N, D))
+            local = idx - self.first_expert
+            mine = (local >= 0) & (local < held)                       # [N, K]
+            local = jnp.where(mine, local, held)
+            hot = (local[..., None] == jnp.arange(held)).astype(jnp.int32)   # [N, K, held]
+            load = hot.reshape(B, T * K, held).sum(axis=1)
+            counts = load.sum(axis=0)                                  # [held]
 
         # the visits sorted by expert, each expert's rows padded to whole tiles
         tile = _gmm_tile_rows(xd)
-        order = jnp.argsort(local.reshape(N * K), stable=True).astype(jnp.int32)
-        rank = jnp.zeros((N * K,), jnp.int32).at[order].set(
-            jnp.arange(N * K, dtype=jnp.int32))                    # place in the sort
-        padded = -(-counts // tile) * tile
-        p_end = jnp.cumsum(padded)
-        p_start, start = p_end - padded, jnp.cumsum(counts) - counts
-        start_of = jnp.concatenate([start, start[-1:]])            # the sentinel's: unused
-        p_start_of = jnp.concatenate([p_start, p_start[-1:]])
-        # where each (token, choice) sits among the padded rows
-        place = rank.reshape(N, K) - start_of[local] + p_start_of[local]
+        with scope("sort"):
+            order = jnp.argsort(local.reshape(N * K), stable=True).astype(jnp.int32)
+            rank = jnp.zeros((N * K,), jnp.int32).at[order].set(
+                jnp.arange(N * K, dtype=jnp.int32))                    # place in the sort
+            padded = -(-counts // tile) * tile
+            p_end = jnp.cumsum(padded)
+            p_start, start = p_end - padded, jnp.cumsum(counts) - counts
+            start_of = jnp.concatenate([start, start[-1:]])            # the sentinel's: unused
+            p_start_of = jnp.concatenate([p_start, p_start[-1:]])
+            # where each (token, choice) sits among the padded rows
+            place = rank.reshape(N, K) - start_of[local] + p_start_of[local]
 
         # the rows go through `rows` at a time: twice what the held experts
         # expect, so one trip unless the routing is very uneven (a second
@@ -355,18 +358,20 @@ class ExpertLayer(Module):
             at = c * rows
 
             def run(y):
-                j = at + jnp.arange(rows, dtype=jnp.int32)
-                g = jnp.sum(j[:, None] >= p_end[None, :], axis=1)          # group of row j
-                gc = jnp.minimum(g, held - 1)
-                r = j - p_start[gc]
-                real = (g < held) & (r < counts[gc])
-                visit = order[jnp.where(real, start[gc] + r, 0)]
-                xg = jnp.where(real[:, None], xd[visit // K], 0).astype(dt)
-                sizes = jnp.clip(p_end - at, 0, rows) - jnp.clip(p_start - at, 0, rows)
-                hid = grouped_matmul(xg, w1, sizes, dt, tile)
-                gate_h, up_h = jnp.split(hid.astype(jnp.float32), 2, axis=-1)
-                act = (jax.nn.silu(gate_h) * up_h).astype(dt)
-                out = grouped_matmul(act, w2, sizes, dt, tile)             # [rows, D]
+                with scope("gather"):
+                    j = at + jnp.arange(rows, dtype=jnp.int32)
+                    g = jnp.sum(j[:, None] >= p_end[None, :], axis=1)      # group of row j
+                    gc = jnp.minimum(g, held - 1)
+                    r = j - p_start[gc]
+                    real = (g < held) & (r < counts[gc])
+                    visit = order[jnp.where(real, start[gc] + r, 0)]
+                    xg = jnp.where(real[:, None], xd[visit // K], 0).astype(dt)
+                with scope("experts"):
+                    sizes = jnp.clip(p_end - at, 0, rows) - jnp.clip(p_start - at, 0, rows)
+                    hid = grouped_matmul(xg, w1, sizes, dt, tile)
+                    gate_h, up_h = jnp.split(hid.astype(jnp.float32), 2, axis=-1)
+                    act = (jax.nn.silu(gate_h) * up_h).astype(dt)
+                    out = grouped_matmul(act, w2, sizes, dt, tile)         # [rows, D]
 
                 def choice(y, c):           # each token's s-th choice, if it is here
                     at_s, here, weight = c
@@ -374,7 +379,8 @@ class ExpertLayer(Module):
                     got = out[jnp.clip(at_s - at, 0, rows - 1)].astype(jnp.float32)
                     return y + jnp.where(here[:, None], weight[:, None] * got, 0.0), None
 
-                return jax.lax.scan(choice, y, (place.T, mine.T, gate.T))[0]
+                with scope("combine"):
+                    return jax.lax.scan(choice, y, (place.T, mine.T, gate.T))[0]
 
             return jax.lax.cond(at < p_end[-1], run, lambda y: y, y), None
 

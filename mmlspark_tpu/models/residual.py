@@ -31,6 +31,7 @@ from typing import Tuple
 
 import numpy as np
 
+from ..obs.scopes import scope
 from .module import Module, _rng_split, matmul_dtype
 
 TOKEN_BLOCK = 128         # tokens a kernel step reads: 7.3 MB of four 3584-wide streams
@@ -302,9 +303,12 @@ class HyperConnection(Module):
         n = self.streams
         alpha = jnp.asarray(params["alpha"]).astype(jnp.float32)
         b = jnp.asarray(params["b"]).astype(jnp.float32)
-        m, ssq, x_in = mhc_pre(x.reshape(B * T, nd), params["phi"],
-                               jnp.concatenate([alpha[:1], b[:n]]), n, self.norm_eps)
-        return x_in.reshape(B, T, nd // n), self.coefficients(alpha, b, m, ssq, nd)
+        with scope("pre"):
+            m, ssq, x_in = mhc_pre(x.reshape(B * T, nd), params["phi"],
+                                   jnp.concatenate([alpha[:1], b[:n]]), n, self.norm_eps)
+            x_in = x_in.reshape(B, T, nd // n)
+        with scope("coeff"):             # the sigmoids and the Sinkhorn steps
+            return x_in, self.coefficients(alpha, b, m, ssq, nd)
 
     def post(self, x, y, coeffs):
         """``X'``: the streams mixed by ``H_res`` plus ``H_post`` times the
@@ -312,5 +316,6 @@ class HyperConnection(Module):
         import jax.numpy as jnp
 
         B, T, nd = x.shape
-        return mhc_post(x.reshape(B * T, nd), y.reshape(B * T, -1).astype(jnp.float32),
-                        coeffs, self.streams).reshape(x.shape)
+        with scope("post"):
+            return mhc_post(x.reshape(B * T, nd), y.reshape(B * T, -1).astype(jnp.float32),
+                            coeffs, self.streams).reshape(x.shape)

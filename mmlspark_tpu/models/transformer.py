@@ -51,6 +51,7 @@ from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
+from ..obs.scopes import scope
 from .module import FunctionModel, Module, _rng_split, matmul_dtype
 
 _NEG = -1e30          # masked score: finite, so a fully masked block stays finite
@@ -616,12 +617,15 @@ class GQAttention(Module):
                 y = rotary(y, self.rope_theta)
             return y.astype(dt).reshape(B, T, n * self.head_dim)
 
-        q = heads_of("wq", self.heads, params.get("q_norm"))
-        k = heads_of("wk", self.kv_heads, params.get("k_norm"))
-        o = gq_attention(q, k, project("wv").astype(dt), self.window,
-                         self.heads, self.kv_heads)
-        return jnp.dot(o, jnp.asarray(params["wo"]).astype(dt),
-                       preferred_element_type=jnp.float32)
+        with scope("proj_in"):
+            q = heads_of("wq", self.heads, params.get("q_norm"))
+            k = heads_of("wk", self.kv_heads, params.get("k_norm"))
+            v = project("wv").astype(dt)
+        with scope("core"):
+            o = gq_attention(q, k, v, self.window, self.heads, self.kv_heads)
+        with scope("proj_out"):
+            return jnp.dot(o, jnp.asarray(params["wo"]).astype(dt),
+                           preferred_element_type=jnp.float32)
 
 
 class LatentAttention(Module):
@@ -672,21 +676,32 @@ class LatentAttention(Module):
                            preferred_element_type=out)
 
         def positions(a):                            # [B, T, heads, rope] float32
-            a = rotary(a, self.rope_theta, self.rope_scaling)
-            return jnp.pad(a, ((0, 0),) * 3 + ((0, lanes),)).astype(dt)
+            with scope("proj_in"):
+                a = rotary(a, self.rope_theta, self.rope_scaling)
+            with scope("core"):                      # laid out as the core reads it
+                return jnp.pad(a, ((0, 0),) * 3 + ((0, lanes),)).astype(dt)
 
         # the softmax scale rides on the queries' latent: one pass over [T, q_rank]
         scale = (nope + rope) ** -0.5 * yarn_softmax_scale(self.rope_scaling)
-        c_q = rms_norm(dot(x, "wq_a"), params["q_norm"], self.eps) * np.float32(scale)
-        q = dot(c_q, "wq_b").reshape(B, T, h, nope + rope)
-        q = jnp.concatenate([q[..., :nope].astype(dt), positions(q[..., nope:])],
-                            axis=-1).reshape(B, T, -1)
-        ckv = dot(x, "wkv_a")
+        with scope("proj_in"):
+            c_q = rms_norm(dot(x, "wq_a"), params["q_norm"], self.eps) * np.float32(scale)
+            q = dot(c_q, "wq_b").reshape(B, T, h, nope + rope)
+        with scope("core"):
+            q_nope = q[..., :nope].astype(dt)
+        q_rope = positions(q[..., nope:])
+        with scope("core"):
+            q = jnp.concatenate([q_nope, q_rope], axis=-1).reshape(B, T, -1)
+        with scope("proj_in"):
+            ckv = dot(x, "wkv_a")
         kr = positions(ckv[..., None, self.kv_rank:]).reshape(B, T, -1)
-        kv = dot(rms_norm(ckv[..., :self.kv_rank], params["kv_norm"], self.eps),
-                 "wkv_b", dt)                        # a head's keys, then its values
+        with scope("proj_in"):
+            kv = dot(rms_norm(ckv[..., :self.kv_rank], params["kv_norm"], self.eps),
+                     "wkv_b", dt)                    # a head's keys, then its values
         attend = _mla_kernel_vjp() if kernel else mla_xla
-        return dot(attend(q, kv, kr, h, nope), "wo")
+        with scope("core"):
+            o = attend(q, kv, kr, h, nope)
+        with scope("proj_out"):
+            return dot(o, "wo")
 
 
 def _by_rows(fn, x, most_tokens: int = 8192, positionwise: bool = False):
@@ -787,9 +802,12 @@ class DecoderLayer(Module):
     def apply_with_load(self, params, x):
         part = dict(self.parts)
 
+        def apply(name, xc):
+            with scope(name):
+                return part[name].apply(params[name], xc)
+
         def normed(name, norm):          # the sublayer behind its norm
-            return lambda xc: part[name].apply(
-                params[name], part[norm].apply(params[norm], xc))
+            return lambda xc: part[name].apply(params[name], apply(norm, xc))
 
         if "attn_hc" in part:
             return self._hyper_connected(part, params, x, normed)
@@ -799,28 +817,46 @@ class DecoderLayer(Module):
                 return xc + normed(name, norm)(xc)
             return run
 
-        h = _by_rows(sublayer("attn", "attn_norm"), x)
+        # a part's scope is pushed around its `_by_rows`, so the loop's own
+        # slicing and stacking is counted with the part it serves
+        with scope("attn"):
+            h = _by_rows(sublayer("attn", "attn_norm"), x)
         if "mlp" in part:
-            return _by_rows(sublayer("mlp", "mlp_norm"), h), None
-        hn = part["mlp_norm"].apply(params["mlp_norm"], h)
+            with scope("mlp"):
+                return _by_rows(sublayer("mlp", "mlp_norm"), h), None
+        hn = apply("mlp_norm", h)
         if "shared" in part:
-            h = h + part["shared"].apply(params["shared"], hn)
-        return part["moe"].apply_with_load(params["moe"], hn, add_to=h)
+            h = h + apply("shared", hn)
+        with scope("moe"):
+            return part["moe"].apply_with_load(params["moe"], hn, add_to=h)
 
     def _hyper_connected(self, part, params, x, normed):
         T = x.shape[1]
         hc = part["attn_hc"]
-        x_in, coeffs = hc.pre(params["attn_hc"], x)
+        with scope("attn_hc"):
+            x_in, coeffs = hc.pre(params["attn_hc"], x)
         # attention needs a row's every key: rows one after the other, whole
-        x = hc.post(x, _by_rows(normed("attn", "attn_norm"), x_in, max(T, 8192)), coeffs)
-        x_in, coeffs = hc.pre(params["mlp_hc"], x)
+        with scope("attn"):
+            y = _by_rows(normed("attn", "attn_norm"), x_in, max(T, 8192))
+        with scope("attn_hc"):
+            x = hc.post(x, y, coeffs)
+        with scope("mlp_hc"):
+            x_in, coeffs = hc.pre(params["mlp_hc"], x)
         if "mlp" in part:
-            y = _by_rows(normed("mlp", "mlp_norm"), x_in, positionwise=True)
-            return hc.post(x, y, coeffs), None
-        hn = part["mlp_norm"].apply(params["mlp_norm"], x_in)
-        y = part["shared"].apply(params["shared"], hn) if "shared" in part else None
-        y, load = part["moe"].apply_with_load(params["moe"], hn, add_to=y)
-        return hc.post(x, y, coeffs), load
+            with scope("mlp"):
+                y = _by_rows(normed("mlp", "mlp_norm"), x_in, positionwise=True)
+            with scope("mlp_hc"):
+                return hc.post(x, y, coeffs), None
+        with scope("mlp_norm"):
+            hn = part["mlp_norm"].apply(params["mlp_norm"], x_in)
+        y = None
+        if "shared" in part:
+            with scope("shared"):
+                y = part["shared"].apply(params["shared"], hn)
+        with scope("moe"):
+            y, load = part["moe"].apply_with_load(params["moe"], hn, add_to=y)
+        with scope("mlp_hc"):
+            return hc.post(x, y, coeffs), load
 
     def apply(self, params, x, train: bool = False):
         return self.apply_with_load(params, x)[0]
@@ -900,13 +936,15 @@ class CausalLM(Module):
         import jax.numpy as jnp
 
         ids = x.astype(jnp.int32)
-        h = jnp.take(jnp.asarray(params["embed"]["table"]), ids, axis=0
-                     ).astype(jnp.float32)
-        if self.streams > 1:
-            h = jnp.tile(h, (1, 1, self.streams))
+        with scope("embed"):
+            h = jnp.take(jnp.asarray(params["embed"]["table"]), ids, axis=0
+                         ).astype(jnp.float32)
+            if self.streams > 1:
+                h = jnp.tile(h, (1, 1, self.streams))
         loads = []
         for i, layer in enumerate(self.layers):
-            h, load = layer.apply_with_load(params[f"layer{i}"], h)
+            with scope(f"layer{i}"):
+                h, load = layer.apply_with_load(params[f"layer{i}"], h)
             if load is not None:
                 loads.append(load)
             if taps and taps_out is not None and f"{_prefix}layer{i}" in taps:
@@ -915,9 +953,10 @@ class CausalLM(Module):
             if not loads:
                 raise KeyError("expert_load: the model has no expert layer")
             taps_out[_prefix + self.LOAD] = jnp.stack(loads, axis=1)
-        if self.streams > 1:
-            h = h.reshape(*h.shape[:2], self.streams, self.hidden).sum(axis=2)
-        return self._log_probs(params, h, ids)
+        with scope("head"):          # the final norm and the loop over the logits
+            if self.streams > 1:
+                h = h.reshape(*h.shape[:2], self.streams, self.hidden).sum(axis=2)
+            return self._log_probs(params, h, ids)
 
 
 def causal_lm(seq_len: int, vocab_size: int, hidden: int, heads: int,
