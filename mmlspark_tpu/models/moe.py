@@ -26,9 +26,11 @@ no capacity, nothing dropped) and computes the part of the result its own
 experts give. The visits to its experts are gathered sorted by expert, go
 through one grouped product for gate/up and one for down (``moe_gmm``, a
 Pallas kernel on a TPU; ``lax.ragged_dot`` elsewhere), and are combined by
-weight into the token order: memory and work grow with the visits, never with
-tokens x experts. On one chip it runs without an exchange; ``MoE`` keeps the
-mesh tests until ``ExpertLayer`` has one.
+weight into the token order (``moe_combine``, a Pallas kernel where a layer on
+a TPU holds a share of the experts; a scan over the choices elsewhere): memory
+and work grow with the visits, never with tokens x experts. On one chip it
+runs without an exchange; ``MoE`` keeps the mesh tests until ``ExpertLayer``
+has one.
 """
 
 from __future__ import annotations
@@ -249,6 +251,194 @@ def grouped_matmul(lhs, rhs, group_sizes, out_dtype, tile_rows: int):
     return _gmm_ragged(lhs, rhs, group_sizes, out_dtype)
 
 
+# ---------------------------------------------------------------------------
+# the combine: every visit's row, weighted, onto its token
+# ---------------------------------------------------------------------------
+
+COMBINE_TILE = (512, 2048)       # tokens, columns of one kernel step
+COMBINE_CHUNK = 128              # rows of `out` one copy brings into VMEM
+
+
+def combine_xla(y, out, gate, place, mine, at):
+    """``y [N, D]`` float32 plus every token's visits among this trip's rows
+    (``out [rows, D]``, the padded rows ``at .. at + rows``), each row widened
+    to float32 and scaled by its float32 weight: one pass over all the tokens
+    a choice. ``place [N, K]`` is where each (token, choice) sits among the
+    padded rows, ``mine`` whether its expert is held here, ``gate`` its weight."""
+    import jax
+    import jax.numpy as jnp
+
+    rows = out.shape[0]
+
+    def choice(y, c):           # each token's s-th choice, if it is here
+        at_s, here, weight = c
+        here &= (at_s >= at) & (at_s < at + rows)
+        got = out[jnp.clip(at_s - at, 0, rows - 1)].astype(jnp.float32)
+        return y + jnp.where(here[:, None], weight[:, None] * got, 0.0), None
+
+    return jax.lax.scan(choice, y, (place.T, mine.T, gate.T))[0]
+
+
+def _combine_tokens(N: int) -> int:
+    """Tokens of one kernel step: the tile, or all of a smaller batch."""
+    return min(COMBINE_TILE[0], -(-N // 8) * 8)
+
+
+def _combine_kernel(lo_ref, hi_ref, token_ref, weight_ref, y_ref, out_hbm, o_ref,
+                    buf, sem, plan, *, held, tm, td):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    i, j = pl.program_id(0), pl.program_id(1)
+    chunk, piece = COMBINE_CHUNK, min(td, 512)
+
+    def of_expert(e, n):         # the chunks that hold this tile's run of expert e
+        lo, hi = lo_ref[i * held + e], hi_ref[i * held + e]
+        first = lo // chunk
+        chunks = jnp.where(hi > lo, (hi - 1) // chunk - first + 1, 0)
+
+        def note(k, n):
+            plan[3 * n], plan[3 * n + 1], plan[3 * n + 2] = first + k, lo, hi
+            return n + 1
+
+        return jax.lax.fori_loop(0, chunks, note, n)
+
+    total = jax.lax.fori_loop(0, held, of_expert, 0)
+
+    def copy(t, slot):
+        return pltpu.make_async_copy(
+            out_hbm.at[plan[3 * t], :, pl.ds(pl.multiple_of(j * td, 128), td)],
+            buf.at[slot], sem.at[slot])
+
+    o_ref[...] = y_ref[...]
+
+    @pl.when(total > 0)
+    def _first():
+        copy(0, 0).start()
+
+    tokens = i * tm + jax.lax.broadcasted_iota(jnp.int32, (tm, chunk), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1)
+
+    def step(t, carry):
+        slot = t % 2
+
+        @pl.when(t + 1 < total)
+        def _next():
+            copy(t + 1, 1 - slot).start()
+
+        copy(t, slot).wait()
+        c, lo, hi = plan[3 * t], plan[3 * t + 1], plan[3 * t + 2]
+        row = c * chunk + lane
+        token = jnp.where((row >= lo) & (row < hi), token_ref[pl.ds(c, 1), :], -1)
+        hit = tokens == token                            # [tm, chunk]: row r is token t's
+        weight = jnp.sum(jnp.where(hit, weight_ref[pl.ds(c, 1), :], 0.0),
+                         axis=1, keepdims=True)          # float32, one term a token
+        onto = jnp.where(hit, 1.0, 0.0).astype(buf.dtype)
+        for col in range(0, td, piece):                  # exact: one 1 a row of `onto`
+            cols = slice(col, min(col + piece, td))
+            got = jnp.dot(onto, buf[slot, :, cols], preferred_element_type=jnp.float32)
+            o_ref[:, cols] += weight * got
+        return carry
+
+    jax.lax.fori_loop(0, total, step, 0)
+
+
+def combine_pallas(y, out, gate, visit, group, held: int, interpret: bool = False):
+    """The kernel form of ``combine_xla``: a step takes a tile of tokens and a
+    block of columns of ``y`` (written over ``y``), copies in the chunks of
+    ``out`` that hold the tile's visits, places each chunk's rows on their
+    tokens by a product with zeros and ones (exact) and adds them times their
+    float32 weights: ``y`` is read and written once, a visit's row about once.
+    ``visit [rows]`` is each row's place in ``gate.reshape(N * K)`` (``N * K``
+    for padding), ``group [rows]`` its expert (``held`` past the last). The
+    sort is stable and a token chooses an expert once, so inside an expert's
+    rows the tokens ascend and a tile's visits to it are ONE run: where it
+    starts and ends is looked up in the rows' (expert, token), which ascend
+    through the whole trip. A token's visits are added by expert, not by
+    choice; a row that is not finite reaches the tokens of its tile."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    (N, D), (rows, _), K = y.shape, out.shape, gate.shape[1]
+    tm, td, chunk = _combine_tokens(N), _whole_tile(D, COMBINE_TILE[1]), COMBINE_CHUNK
+    tiles = -(-N // tm)
+    if rows % chunk or D % td:
+        raise ValueError(f"moe_combine: {(rows, D)} is not whole tiles of {(chunk, td)}")
+    token = visit // K                                     # N for padding
+    edges = jnp.minimum(jnp.arange(tiles + 1, dtype=jnp.int32) * tm, N)
+    cuts = jnp.searchsorted(
+        group * (N + 1) + token,
+        (jnp.arange(held, dtype=jnp.int32) * (N + 1) + edges[:, None]).reshape(-1),
+    ).astype(jnp.int32).reshape(tiles + 1, held)
+    lo, hi = cuts[:-1].reshape(tiles * held), cuts[1:].reshape(tiles * held)
+    weight = gate.reshape(N * K)[jnp.minimum(visit, N * K - 1)]
+    most = held * ((tm + chunk - 2) // chunk + 1)        # chunks a tile's runs can touch
+    whole = pl.BlockSpec((rows // chunk, chunk), lambda i, j, lo, hi: (0, 0))
+    block = pl.BlockSpec((tm, td), lambda i, j, lo, hi: (i, j))
+    got = pl.pallas_call(
+        functools.partial(_combine_kernel, held=held, tm=tm, td=td),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(tiles, D // td),
+            in_specs=[whole, whole, block, pl.BlockSpec(memory_space=pltpu.HBM)],
+            out_specs=block,
+            scratch_shapes=[pltpu.VMEM((2, chunk, td), out.dtype),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.SMEM((3 * most,), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct((tiles * tm, D), jnp.float32),
+        input_output_aliases={4: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        name="moe_combine",      # the name a device trace shows
+        cost_estimate=pl.CostEstimate(
+            flops=int(2 * tm * (rows + tiles * held * chunk) * D), transcendentals=0,
+            bytes_accessed=int(8 * N * D + (rows + tiles * held * chunk) * D
+                               * out.dtype.itemsize)),
+        interpret=interpret,
+    )(lo, hi, token.reshape(rows // chunk, chunk), weight.reshape(rows // chunk, chunk),
+      jnp.pad(y, ((0, tiles * tm - N), (0, 0))), out.reshape(rows // chunk, chunk, D))
+    return got[:N]
+
+
+@functools.lru_cache(maxsize=None)
+def _combine_kernel_vjp(interpret: bool = False):
+    """The kernel with a backward pass: the scan's, recomputed."""
+    import jax
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(8,))
+    def combine(y, out, gate, place, mine, at, visit, group, held):
+        return combine_pallas(y, out, gate, visit, group, held, interpret)
+
+    def fwd(y, out, gate, place, mine, at, visit, group, held):
+        return (combine_pallas(y, out, gate, visit, group, held, interpret),
+                (y, out, gate, place, mine, at))
+
+    def bwd(held, res, g):
+        y, out, gate, place, mine, at = res
+        _, vjp = jax.vjp(lambda y, out, gate: combine_xla(y, out, gate, place, mine, at),
+                         y, out, gate)
+        return (*vjp(g), None, None, None, None, None)
+
+    combine.defvjp(fwd, bwd)
+    return combine
+
+
+def _combine_kernel_applies(experts_held: int, num_experts: int, backend: str,
+                            dtype) -> bool:
+    """The kernel where ``moe_gmm`` runs (a TPU, bfloat16 operands) and the
+    layer holds a share of the router's experts: there most of a scan pass
+    over all the tokens is thrown away. A layer that holds them all needs
+    every row of every pass, and keeps the scan."""
+    import jax.numpy as jnp
+
+    return backend == "tpu" and dtype == jnp.bfloat16 and experts_held < num_experts
+
+
 class ExpertLayer(Module):
     """Dropless top-k expert FFN on ``[B, T, D]`` that holds ``experts_held``
     of ``num_experts`` SwiGLU experts of width ``hidden``, from
@@ -257,6 +447,15 @@ class ExpertLayer(Module):
     score + selection bias chosen, their scores normalised over the chosen
     (``norm_topk``) and scaled by ``scale``. The result is the held experts'
     part of the sum; what the others would add is another holder's to compute.
+
+    The combine (each visit's row, widened to float32, times its float32
+    weight, added to the token's float32 sum) has two forms of one arithmetic,
+    chosen by what the layer sees in its own shape (``_combine_kernel_applies``):
+    the kernel ``moe_combine`` where ``moe_gmm`` runs (a TPU, bfloat16
+    operands) and the layer holds a share of the router's experts, else the
+    scan over the choices (``combine_xla``), which is also the kernel's VJP.
+    They differ only in the order a token's visits are added: by expert, by
+    choice.
 
     Parameters: ``router [D, E]``, ``router_bias [E]``, ``w1 [held, D, 2
     hidden]`` (gate's columns first), ``w2 [held, hidden, D]``: the leaves
@@ -353,6 +552,8 @@ class ExpertLayer(Module):
         trips = -(-most // rows)
         w1 = jnp.asarray(params["w1"]).astype(dt)
         w2 = jnp.asarray(params["w2"]).astype(dt)
+        in_kernel = _combine_kernel_applies(held, self.num_experts,
+                                            jax.default_backend(), xd.dtype)
 
         def trip(y, c):
             at = c * rows
@@ -373,14 +574,12 @@ class ExpertLayer(Module):
                     act = (jax.nn.silu(gate_h) * up_h).astype(dt)
                     out = grouped_matmul(act, w2, sizes, dt, tile)         # [rows, D]
 
-                def choice(y, c):           # each token's s-th choice, if it is here
-                    at_s, here, weight = c
-                    here &= (at_s >= at) & (at_s < at + rows)
-                    got = out[jnp.clip(at_s - at, 0, rows - 1)].astype(jnp.float32)
-                    return y + jnp.where(here[:, None], weight[:, None] * got, 0.0), None
-
                 with scope("combine"):
-                    return jax.lax.scan(choice, y, (place.T, mine.T, gate.T))[0]
+                    if not in_kernel:
+                        return combine_xla(y, out, gate, place, mine, at)
+                    return _combine_kernel_vjp()(
+                        y, out, gate, place, mine, at,
+                        jnp.where(real, visit, N * K), g, held)
 
             return jax.lax.cond(at < p_end[-1], run, lambda y: y, y), None
 

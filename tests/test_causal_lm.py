@@ -289,6 +289,138 @@ def test_the_grouped_product_kernel_agrees_with_the_ragged_product():
     assert float(jnp.abs(got[1536:]).max()) == 0.0
 
 
+def _routing(idx, first, held, tile, rows_):
+    """What `ExpertLayer.apply_with_load` derives from the router's choices
+    `idx [N, K]`, by hand in numpy: whether each choice is held here, its
+    place among the rows sorted by expert (every expert's rows padded to
+    `tile`), each padded row's visit (`N * K` for padding) and expert
+    (`held` past the last), and the experts' visits; whole trips of `rows_`."""
+    N, K = idx.shape
+    local = idx - first
+    mine = (local >= 0) & (local < held)
+    local = np.where(mine, local, held)
+    order = np.argsort(local.reshape(-1), kind="stable")
+    counts = np.bincount(local.reshape(-1), minlength=held + 1)[:held]
+    padded = -(-counts // tile) * tile
+    p_start, start = np.cumsum(padded) - padded, np.cumsum(counts) - counts
+    visit = np.full(-(-padded.sum() // rows_) * rows_, N * K, np.int32)
+    group = np.full(len(visit), held, np.int32)
+    place = np.zeros(N * K, np.int32)
+    for e in range(held):
+        mine_e = order[start[e]:start[e] + counts[e]]
+        visit[p_start[e]:p_start[e] + counts[e]] = mine_e
+        group[p_start[e]:p_start[e] + padded[e]] = e
+        place[mine_e] = p_start[e] + np.arange(counts[e])
+    return mine, place.reshape(N, K), visit, group, p_start, counts
+
+
+def _hand_built_choices(one_visit: bool):
+    """200 tokens choosing 3 of 8 experts, of which 2-5 are held here: token 0
+    has no visit here, token 1 three, expert 4 (the third held) no rows; or
+    every token at most one visit here."""
+    rng = np.random.default_rng(11)
+    N, K, first = 200, 3, 2
+    if one_visit:
+        idx = np.stack([rng.permutation([0, 1, 6, 7])[:K] for _ in range(N)])
+        here = rng.integers(0, 2, N).astype(bool)
+        idx[here, rng.integers(0, K, N)[here]] = rng.choice([2, 3, 5], here.sum())
+        return idx.astype(np.int32), first
+    idx = np.stack([rng.permutation([0, 1, 2, 3, 5, 6, 7])[:K] for _ in range(N)])
+    idx[0], idx[1] = [0, 1, 6], [5, 2, 3]
+    return idx.astype(np.int32), first
+
+
+@pytest.mark.parametrize("case", ["first-trip", "second-trip", "one-visit-a-token"])
+def test_the_combine_kernel_agrees_with_the_scan(case, monkeypatch):
+    """`moe_combine` in the interpreter against `combine_xla` on a routing
+    built by hand: groups of 79-94 rows padded to 16, so the runs of a tile
+    of 64 tokens straddle the chunks of 128 rows; 200 tokens are three tiles
+    and a part of a fourth; `y` arrives non-zero; two column blocks."""
+    monkeypatch.setattr(moe, "COMBINE_TILE", (64, 128))
+    idx, first = _hand_built_choices(case == "one-visit-a-token")
+    (N, K), held, D, rows_ = idx.shape, 4, 256, 256
+    mine, place, visit, group, p_start, counts = _routing(idx, first, held, 16, rows_)
+    if case != "one-visit-a-token":
+        assert not mine[0].any() and mine[1].all() and counts[2] == 0
+        assert len(visit) == 2 * rows_ and len(set(counts % 16)) > 1
+        assert p_start[3] < rows_ < p_start[3] + counts[3]   # a group cut by the trip
+    rng = np.random.default_rng(12)
+    gate = jnp.asarray(rng.uniform(0.1, 1.0, (N, K)), jnp.float32)
+    y = jnp.asarray(rng.normal(size=(N, D)), jnp.float32)
+    out = jnp.asarray(rng.normal(size=(len(visit), D)), jnp.bfloat16)   # padding too
+    at = rows_ if case == "second-trip" else 0
+    want = moe.combine_xla(y, out[at:at + rows_], gate, jnp.asarray(place),
+                           jnp.asarray(mine), at)
+    got = moe.combine_pallas(y, out[at:at + rows_], gate, jnp.asarray(visit[at:at + rows_]),
+                             jnp.asarray(group[at:at + rows_]), held, interpret=True)
+    assert float(jnp.abs(want - y).max()) > 0.1         # the trip added something
+    if case == "one-visit-a-token":
+        assert bool(jnp.all(got == want))
+    assert float(jnp.abs(got - want).max()) <= 1e-6 * float(jnp.abs(want).max())
+
+
+RULE = [   # experts held, the router's experts, backend, operands: the kernel?
+    (16, 128, "tpu", jnp.bfloat16, True),
+    (64, 64, "tpu", jnp.bfloat16, False),       # every pass of the scan is needed
+    (16, 128, "cpu", jnp.bfloat16, False),
+    (16, 128, "gpu", jnp.bfloat16, False),
+    (16, 128, "tpu", jnp.float32, False),       # where `moe_gmm` does not run either
+]
+
+
+@pytest.mark.parametrize("held,experts,backend,dtype,kernel", RULE)
+def test_the_combines_form_follows_from_the_layers_own_shape(held, experts, backend,
+                                                             dtype, kernel, monkeypatch):
+    import inspect
+
+    assert moe._combine_kernel_applies(held, experts, backend, dtype) is kernel
+    assert list(inspect.signature(moe._combine_kernel_applies).parameters) \
+        == ["experts_held", "num_experts", "backend", "dtype"]
+    asked = []
+    monkeypatch.setattr(moe, "_combine_kernel_applies",
+                        lambda *a: asked.append(a) or False)
+    layer = moe.ExpertLayer(experts, held, 2, 8)
+    params, _ = layer.init(jax.random.key(0), (T, 16))
+    with matmul_precision("float32"):
+        layer.apply(params, jnp.ones((1, T, 16), jnp.float32))
+    assert asked == [(held, experts, "cpu", jnp.float32)]
+    assert not {"combine", "kernel"} & {
+        p for p in inspect.signature(moe.ExpertLayer.__init__).parameters}
+
+
+@pytest.mark.parametrize("add_to", [False, True])
+def test_the_gradient_through_the_combine_kernel_is_the_scans(add_to, monkeypatch):
+    """A small `ExpertLayer` whose combine is the kernel (in the interpreter,
+    groups padded to its chunk of 128 rows): values and gradients, with
+    respect to the input, every parameter and `add_to`, are the plain form's."""
+    layer = moe.ExpertLayer(8, 4, 3, 32, scale=2.5, first_expert=2)
+    params, _ = layer.init(jax.random.key(1), (T, 64))
+    x = jax.random.normal(jax.random.key(2), (3, T, 64), jnp.float32)
+    base = jax.random.normal(jax.random.key(3), (3, T, 64), jnp.float32)
+    tilt = jnp.cos(jnp.arange(64, dtype=jnp.float32))
+
+    def loss(params, x, base):
+        with matmul_precision("float32"):
+            y, _ = layer.apply_with_load(params, x, add_to=base if add_to else None)
+        return jnp.sum(y * tilt), y
+
+    (_, want_y), want = jax.value_and_grad(loss, (0, 1, 2), has_aux=True)(params, x, base)
+    calls = []
+    kernel = moe._combine_kernel_vjp(True)
+    monkeypatch.setattr(moe, "_gmm_tile_rows", lambda x: 128)
+    monkeypatch.setattr(moe, "grouped_matmul",
+                        lambda lhs, rhs, sizes, dt, tile: moe._gmm_ragged(lhs, rhs, sizes, dt))
+    monkeypatch.setattr(moe, "_combine_kernel_applies", lambda *a: True)
+    monkeypatch.setattr(moe, "_combine_kernel_vjp",
+                        lambda: lambda *a: calls.append("moe_combine") or kernel(*a))
+    (_, got_y), got = jax.value_and_grad(loss, (0, 1, 2), has_aux=True)(params, x, base)
+    assert calls == ["moe_combine"]
+    assert float(jnp.abs(got_y - want_y).max()) <= 1e-6 * float(jnp.abs(want_y).max())
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert a.shape == b.shape
+        assert float(jnp.abs(a - b).max()) <= 1e-5 * max(float(jnp.abs(b).max()), 1e-30)
+
+
 # -- the kernels at the published widths, compiled for a described v5e ------
 
 @pytest.fixture(scope="module")
@@ -389,3 +521,19 @@ def test_the_grouped_product_kernel_compiles_for_a_width_of_3584(one_chip):
 
     text = _compiled_text(both, x, w1, w2, sizes)
     assert text.count("moe_gmm") >= 2 and "tpu_custom_call" in text
+
+
+COMBINE_SHAPES = {   # tokens, width, rows a trip, experts held, top k
+    "16-of-128-held": (32768, 6144, 73728, 16, 8),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(COMBINE_SHAPES))
+def test_the_combine_kernel_compiles_at_the_published_widths(shape, one_chip):
+    N, d, rows_, held, top_k = COMBINE_SHAPES[shape]
+    y = jax.ShapeDtypeStruct((N, d), jnp.float32, sharding=one_chip)
+    out = jax.ShapeDtypeStruct((rows_, d), jnp.bfloat16, sharding=one_chip)
+    gate = jax.ShapeDtypeStruct((N, top_k), jnp.float32, sharding=one_chip)
+    visit = jax.ShapeDtypeStruct((rows_,), jnp.int32, sharding=one_chip)
+    text = _compiled_text(lambda *a: moe.combine_pallas(*a, held), y, out, gate, visit, visit)
+    assert "moe_combine" in text and "tpu_custom_call" in text
