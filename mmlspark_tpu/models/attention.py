@@ -187,13 +187,14 @@ def ring_attention(q, k, v, axis_name: str, axis_size: int,
 class LayerNorm(Module):
     """LayerNorm over the last dim (f32 statistics, dtype-preserving)."""
 
-    def __init__(self, eps: float = 1e-5):
+    def __init__(self, eps: float = 1e-5, param_dtype: str = "float32"):
         self.eps = eps
+        self.param_dtype = param_dtype
 
     def init(self, rng, in_shape):
         d = in_shape[-1]
-        return {"scale": np.ones((d,), np.float32),
-                "bias": np.zeros((d,), np.float32)}, tuple(in_shape)
+        return {"scale": np.ones((d,), self.param_dtype),
+                "bias": np.zeros((d,), self.param_dtype)}, tuple(in_shape)
 
     def apply(self, params, x, train: bool = False):
         import jax

@@ -1,9 +1,11 @@
 """The parts of a present-day causal language model, and the scorer built
-from them: RMSNorm, rotary positions (plain or YaRN), two attention modules,
-SwiGLU, a dropless expert layer (``moe.ExpertLayer``), a decoder layer whose
-residual path is a part of it, and ``CausalLM`` / ``causal_lm`` /
-``latent_causal_lm``, whose output is one number a position (the next token's
-log-probability): the logits never leave the device.
+from them: RMSNorm, rotary positions (plain or YaRN), three attention
+modules, SwiGLU, a dropless expert layer (``moe.ExpertLayer``), a decoder
+layer whose token mixer (an attention, or a state-space layer or memory unit
+of ``ssm.py``) and residual path are parts of it, and ``CausalLM`` /
+``causal_lm`` / ``latent_causal_lm`` / ``hybrid_causal_lm``, whose output is
+one number a position (the next token's log-probability): the logits never
+leave the device.
 
 Parameters keep the dtype they are handed (``causal_lm(param_dtype=
 "bfloat16")`` makes them bfloat16): a weight matrix is cast to
@@ -18,7 +20,7 @@ a learned mix of them and writing back through maps that are made doubly
 stochastic by Sinkhorn steps. ``CausalLM`` widens the embedding to the
 streams and sums them before the final norm.
 
-**The two attention modules**, both causal:
+**The three attention modules**, all causal:
 
   - ``GQAttention``: grouped queries (a key/value head is read by its query
     heads in place, never repeated in memory), an optional window, RMSNorm
@@ -26,12 +28,19 @@ streams and sums them before the final norm.
   - ``LatentAttention`` (MLA): queries through a low-rank bottleneck, keys
     and values decompressed from one narrow latent a token, and a rotary key
     that all heads share; a score is the sum of a head's own product and the
-    shared rotary one, the value narrower than the two together.
+    shared rotary one, the value narrower than the two together;
+  - ``DiffAttention`` (differential attention): heads of 64 in pairs, two
+    softmaxes a pair against the pair's 128-wide values, subtracted with a
+    learned ``lambda`` and RMS-normed; a window, a whole row, or (a cross
+    layer) its own queries over the keys and values of an earlier layer,
+    which reach it through the carry between layers (``DecoderLayer``).
 
 Their cores run one of two ways:
 
-  - ``attn_window`` / ``attn_full`` / ``attn_mla``: Pallas kernels under the
-    names a device trace shows, one streaming softmax over key blocks. A
+  - ``attn_window`` / ``attn_full`` / ``attn_mla`` / ``attn_window_diff`` /
+    ``attn_full_diff``: Pallas kernels under the
+    names a device trace shows, one streaming softmax over key blocks (two a
+    pair in the differential kernel). A
     sliding layer visits only the blocks its window touches, so its work and
     memory grow with ``T x window``; a full or latent layer stops at the
     diagonal; the latent kernel fetches the shared rotary key once a key
@@ -149,11 +158,12 @@ def _softmax_rows(s, seen):
     return p / jnp.sum(p, axis=-1, keepdims=True)
 
 
-def gqa_xla(q, k, v, window: int):
-    """q ``[B, T, H, D]``, k / v ``[B, T, KV, D]`` -> ``[B, T, H, D]``; causal,
-    query t sees keys ``t - window < s <= t`` (every ``s <= t`` at window 0).
-    Blocked over queries: a window reads its own and the previous block of
-    ``window`` keys, a full layer a block of queries against all keys."""
+def gqa_xla(q, k, v, window: int, out_dtype=None):
+    """q ``[B, T, H, D]``, k ``[B, T, KV, D]``, v ``[B, T, KV, Dv]`` -> ``[B,
+    T, H, Dv]`` (``out_dtype``; None: v's); causal, query t sees keys ``t -
+    window < s <= t`` (every ``s <= t`` at window 0). Blocked over queries: a
+    window reads its own and the previous block of ``window`` keys, a full
+    layer a block of queries against all keys."""
     import jax
     import jax.numpy as jnp
 
@@ -181,7 +191,7 @@ def gqa_xla(q, k, v, window: int):
     first = jnp.arange(nb) * blk                  # each query block's first position
     if window:
         def band(a):       # each block of keys behind its predecessor: [B, nb, 2 blk, KV, D]
-            a = a.reshape(B, nb, blk, KV, D)
+            a = a.reshape(B, nb, blk, KV, a.shape[-1])
             prev = jnp.concatenate([jnp.zeros_like(a[:, :1]), a[:, :-1]], axis=1)
             return jnp.concatenate([prev, a], axis=2)
 
@@ -195,7 +205,7 @@ def gqa_xla(q, k, v, window: int):
             lambda a: probs_values(a[0], k, v, a[1] + jnp.arange(blk), kpos),
             (jnp.moveaxis(qb, 1, 0), first))
         o = jnp.moveaxis(o, 0, 1)
-    return o.reshape(B, T + pad, H, D)[:, :T].astype(v.dtype)
+    return o.reshape(B, T + pad, H, v.shape[-1])[:, :T].astype(out_dtype or v.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -346,6 +356,13 @@ def gqa_pallas(q, k, v, window: int, heads: int, kv_heads: int,
 
 
 def _pallas_applies(q, heads: int, window: int) -> bool:
+    """Whether grouped-query heads take ``gqa_pallas``: a TPU, bfloat16, a
+    block-aligned ``T``, and heads of exactly 128 lanes, because a head is
+    read in place as one lane tile of the projection's flat layout. A 64-wide
+    head alone is half a tile: slicing it out would copy, and its product
+    costs the MXU a pass of 128 anyway. Such heads go to a kernel only in
+    PAIRS that fill a tile (``diff_pallas``: differential attention's ``[q1 |
+    q2]``, ``[k1 | k2]``, ``[v1 | v2]``), never through this one."""
     import jax
     import jax.numpy as jnp
 
@@ -389,6 +406,186 @@ def gq_attention(q, k, v, window: int, heads: int, kv_heads: int):
     if _pallas_applies(q, heads, window):
         return _gqa_kernel()(q, k, v, window, heads, kv_heads)
     return _gqa_flat_xla(q, k, v, window, heads, kv_heads)
+
+
+# ---------------------------------------------------------------------------
+# differential attention: two softmaxes a pair of heads, subtracted
+# ---------------------------------------------------------------------------
+#
+# Layout, flat as the projections leave it: heads are ``PAIR / 2`` = 64 wide
+# and come in pairs that fill one tile of 128 lanes: a query pair ``[q1 |
+# q2]`` (``q [B, T, pairs * 128]``), a key pair ``[k1 | k2]`` and a value pair
+# ``[v1 | v2]`` (``k``, ``v`` ``[B, T, kv_pairs * 128]``); the ``pairs /
+# kv_pairs`` query pairs of a key pair lie side by side. ``O = softmax(q1 k1^T
+# / 8) V - lam softmax(q2 k2^T / 8) V`` over the 128-wide ``V``, then RMSNorm
+# over the 128 times ``gain`` (arXiv:2410.05258; ``gain`` already carries the
+# ``1 - lambda_init``). ``k`` and ``v`` may be another layer's.
+
+PAIR = 128
+
+
+def diff_xla(q, k, v, lam, gain, window: int, pairs: int, kv_pairs: int, eps: float):
+    """-> ``[B, T, pairs * 128]`` in q's dtype: the two softmaxes through
+    ``gqa_xla`` (heads of 64 against values of 128), everything after them in
+    float32."""
+    import jax.numpy as jnp
+
+    B, T, _ = q.shape
+    q4 = q.reshape(B, T, pairs, 2, -1)             # a pair of any width here
+    k4 = k.reshape(B, T, kv_pairs, 2, -1)
+    v4 = v.reshape(B, T, kv_pairs, -1)
+    o1, o2 = (gqa_xla(q4[:, :, :, i], k4[:, :, :, i], v4, window, jnp.float32)
+              for i in range(2))
+    o = rms_norm(o1 - lam * o2, gain, eps)
+    return o.reshape(q.shape).astype(q.dtype)
+
+
+def _diff_kernel(q_ref, k_ref, v_ref, p_ref, o_ref, m_scr, l_scr, acc_scr, *,
+                 bq: int, bk: int, window: int, steps: int, scale: float,
+                 group: int, eps: float):
+    """``_attn_kernel`` for pairs: a block of queries of the ``group`` query
+    pairs that share a key pair against one block of its keys. A pair's two
+    score maps contract its own 64 lanes (the other half of the query tile
+    zeroed: a 64-wide product costs the MXU a pass of 128 anyway) and both go
+    against the whole 128-wide value tile, each with its own running maximum,
+    denominator and accumulator; ``p_ref`` row 0 is ``lam``, row 1 ``gain``."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    qi, j = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, _NEG)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    kb = _first_kv(qi, bq, bk, window) + j
+    last = (qi * bq + bq - 1) // bk              # the block of the diagonal
+
+    def pairs(masked: bool):
+        k, v = k_ref[...], v_ref[...]
+        seen = None
+        if masked:
+            qpos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+            kpos = kb * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+            seen = kpos <= qpos
+            if window:
+                seen = jnp.logical_and(seen, kpos > qpos - window)
+        first = jax.lax.broadcasted_iota(jnp.int32, (bq, PAIR), 1) < PAIR // 2
+        for g in range(group):
+            q = q_ref[:, g * PAIR:(g + 1) * PAIR]
+            for half in range(2):
+                qh = jnp.where(first if half == 0 else jnp.logical_not(first),
+                               q, jnp.zeros_like(q))
+                s = jax.lax.dot_general(qh, k, (((1,), (1,)), ((), ())),
+                                        preferred_element_type=jnp.float32) * scale
+                _softmax_step(s, seen, v, m_scr, l_scr, acc_scr, 2 * g + half)
+
+    if window:
+        pl.when(kb <= last)(lambda: pairs(True))
+    else:                    # blocks alike: only the diagonal's needs its mask
+        pl.when(kb < last)(lambda: pairs(False))
+        pl.when(kb == last)(lambda: pairs(True))
+
+    @pl.when(j == steps - 1)
+    def _store():
+        lam, gain = p_ref[0:1, :], p_ref[1:2, :]
+        for g in range(group):
+            o = acc_scr[2 * g] / l_scr[2 * g][:, :1] \
+                - lam * (acc_scr[2 * g + 1] / l_scr[2 * g + 1][:, :1])
+            o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=1, keepdims=True) + eps) * gain
+            o_ref[:, g * PAIR:(g + 1) * PAIR] = o.astype(o_ref.dtype)
+
+
+def diff_pallas(q, k, v, lam, gain, window: int, pairs: int, kv_pairs: int,
+                eps: float, interpret: bool = False):
+    """The kernel form of ``diff_xla`` (same arguments and result): a pair
+    is a block of 128 lanes of the projections' own layout, read in place,
+    so another layer's ``k`` and ``v`` cost no copy."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, T, _ = q.shape
+    group = pairs // kv_pairs
+    bq, bk = _attn_blocks(T, window)
+    steps = _kv_steps(bq, bk, T, window)
+
+    def kv_index(b, h, qi, j):       # past the diagonal nothing is fetched
+        kb = jnp.minimum(_first_kv(qi, bq, bk, window) + j, (qi * bq + bq - 1) // bk)
+        return b, kb, h
+
+    rows = jnp.zeros((8, PAIR), jnp.float32)
+    rows = rows.at[0].set(jnp.asarray(lam, jnp.float32)).at[1].set(gain.astype(jnp.float32))
+    seen = T * (min(window, T) if window else (T + 1) / 2)    # (query, key) seen
+    return pl.pallas_call(
+        functools.partial(_diff_kernel, bq=bq, bk=bk, window=window, steps=steps,
+                          scale=1.0 / math.sqrt(PAIR // 2), group=group, eps=eps),
+        grid=(B, kv_pairs, T // bq, steps),
+        in_specs=[pl.BlockSpec((None, bq, group * PAIR), lambda b, h, qi, j: (b, qi, h)),
+                  pl.BlockSpec((None, bk, PAIR), kv_index),
+                  pl.BlockSpec((None, bk, PAIR), kv_index),
+                  pl.BlockSpec((8, PAIR), lambda b, h, qi, j: (0, 0))],
+        out_specs=pl.BlockSpec((None, bq, group * PAIR), lambda b, h, qi, j: (b, qi, h)),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        scratch_shapes=[pltpu.VMEM((2 * group, bq, 128), jnp.float32),
+                        pltpu.VMEM((2 * group, bq, 128), jnp.float32),
+                        pltpu.VMEM((2 * group, bq, PAIR), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        # the names a device trace shows (docs/observability.md)
+        name="attn_window_diff" if window else "attn_full_diff",
+        cost_estimate=pl.CostEstimate(
+            flops=int(6 * B * pairs * PAIR * seen),
+            transcendentals=int(2 * B * pairs * seen),
+            bytes_accessed=int(2 * q.size * q.dtype.itemsize
+                               + 2 * k.size * k.dtype.itemsize * (T // bq))),
+        interpret=interpret,
+    )(q, k, v, rows)
+
+
+@functools.lru_cache(maxsize=None)
+def _diff_kernel_vjp(interpret: bool = False):
+    """The kernel with a backward pass: the plain form's, recomputed."""
+    import jax
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+    def attend(q, k, v, lam, gain, window, pairs, kv_pairs, eps):
+        return diff_pallas(q, k, v, lam, gain, window, pairs, kv_pairs, eps, interpret)
+
+    def fwd(q, k, v, lam, gain, window, pairs, kv_pairs, eps):
+        return diff_pallas(q, k, v, lam, gain, window, pairs, kv_pairs, eps,
+                           interpret), (q, k, v, lam, gain)
+
+    def bwd(window, pairs, kv_pairs, eps, res, g):
+        return jax.vjp(lambda *a: diff_xla(*a, window, pairs, kv_pairs, eps), *res)[1](g)
+
+    attend.defvjp(fwd, bwd)
+    return attend
+
+
+def _diff_pallas_applies(q, window: int, pairs: int) -> bool:
+    """A TPU, bfloat16 pairs of exactly 128 lanes, a block-aligned ``T``
+    (``_pallas_applies`` says why 64-wide heads come here, in pairs)."""
+    import jax
+    import jax.numpy as jnp
+
+    return (jax.default_backend() == "tpu" and q.dtype == jnp.bfloat16
+            and q.shape[2] // pairs == PAIR
+            and _attn_blocks(q.shape[1], window) is not None)
+
+
+def diff_attention(q, k, v, lam, gain, window: int, pairs: int, kv_pairs: int,
+                   eps: float):
+    """Causal differential attention over flat pairs: the kernel where it
+    applies, the plain form elsewhere."""
+    if _diff_pallas_applies(q, window, pairs):
+        return _diff_kernel_vjp()(q, k, v, lam, gain, window, pairs, kv_pairs, eps)
+    return diff_xla(q, k, v, lam, gain, window, pairs, kv_pairs, eps)
 
 
 # ---------------------------------------------------------------------------
@@ -704,6 +901,83 @@ class LatentAttention(Module):
             return dot(o, "wo")
 
 
+class DiffAttention(Module):
+    """Causal differential attention (arXiv:2410.05258) on ``[B, T, D]``:
+    ``heads`` query heads over ``kv_heads`` key/value heads of ``head_dim``
+    (64 where the kernel serves), adjacent heads paired (the layout above
+    ``diff_xla``), ``window`` keys back (0: all of them), biases on every
+    projection, no positions.
+    ``lam = exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init``, ``lambda_init =
+    0.8 - 0.6 exp(-0.3 layer_index)``; the RMSNorm after the subtraction has
+    one gain of ``2 head_dim`` and is scaled by ``1 - lambda_init``.
+
+    Through the layers' carry: with ``hands_on`` the layer leaves its keys
+    and values there (``kv``: the flat ``[B, T, kv_heads head_dim]`` arrays
+    its own core reads, no copy); a ``cross`` layer has no ``W_kv`` and takes
+    those in place of its own."""
+
+    def __init__(self, heads: int, kv_heads: int, head_dim: int, layer_index: int,
+                 window: int = 0, cross: bool = False, hands_on: bool = False,
+                 eps: float = 1e-5, param_dtype: str = "float32"):
+        if heads % 2 or kv_heads % 2 or (heads // 2) % (kv_heads // 2):
+            raise ValueError(f"{heads} query heads over {kv_heads} key/value heads: "
+                             f"no pairs")
+        self.heads, self.kv_heads, self.head_dim = heads, kv_heads, head_dim
+        self.window, self.cross, self.hands_on = window, cross, hands_on
+        self.lambda_init = 0.8 - 0.6 * math.exp(-0.3 * layer_index)
+        self.crosses_layers = cross or hands_on
+        self.eps, self.param_dtype = eps, param_dtype
+
+    def init(self, rng, in_shape):
+        t, d = in_shape
+        hq, hkv, dt = self.heads * self.head_dim, self.kv_heads * self.head_dim, \
+            self.param_dtype
+        keys = _rng_split(rng, 10)
+        params = {"wq": _normal(keys[0], (d, hq), d ** -0.5, dt),
+                  "bq": _normal(keys[1], (hq,), 0.02, dt),
+                  "wo": _normal(keys[2], (hq, d), hq ** -0.5, dt),
+                  "bo": _normal(keys[3], (d,), 0.02, dt),
+                  "subln": np.ones((2 * self.head_dim,), dt)}
+        for name, k in zip(("lq1", "lk1", "lq2", "lk2"), keys[4:8]):
+            params[name] = _normal(k, (self.head_dim,), 0.1, dt)
+        if not self.cross:       # a key head's columns, then a value head's
+            params["wkv"] = _normal(keys[8], (d, 2 * hkv), d ** -0.5, dt)
+            params["bkv"] = _normal(keys[9], (2 * hkv,), 0.02, dt)
+        return params, (t, d)
+
+    def apply_carry(self, params, x, carry: Dict[str, Any]):
+        import jax.numpy as jnp
+
+        dt, f32 = _mm_dtype(), jnp.float32
+        xd = x.astype(dt)
+
+        def affine(a, w, b):
+            return jnp.dot(a, jnp.asarray(params[w]).astype(dt),
+                           preferred_element_type=f32) + jnp.asarray(params[b]).astype(f32)
+
+        def vector(name):
+            return jnp.asarray(params[name]).astype(f32)
+
+        with scope("proj_in"):
+            q = affine(xd, "wq", "bq").astype(dt)
+            if self.cross:
+                k, v = carry["kv"]
+            else:
+                k, v = jnp.split(affine(xd, "wkv", "bkv").astype(dt), 2, axis=-1)
+                if self.hands_on:
+                    carry["kv"] = (k, v)
+        with scope("core"):
+            lam = jnp.exp(jnp.sum(vector("lq1") * vector("lk1"))) \
+                - jnp.exp(jnp.sum(vector("lq2") * vector("lk2"))) + self.lambda_init
+            o = diff_attention(q, k, v, lam, vector("subln") * (1.0 - self.lambda_init),
+                               self.window, self.heads // 2, self.kv_heads // 2, self.eps)
+        with scope("proj_out"):
+            return affine(o, "wo", "bo")
+
+    def apply(self, params, x, train: bool = False):
+        return self.apply_carry(params, x, {})
+
+
 def _by_rows(fn, x, most_tokens: int = 8192, positionwise: bool = False):
     """``fn`` over ``x [B, T, D]``, rows in equal groups of at most
     ``most_tokens`` tokens one after the other: what a sublayer holds between
@@ -763,14 +1037,44 @@ class SwiGLU(Module):
                        preferred_element_type=jnp.float32)
 
 
-class DecoderLayer(Module):
-    """Two sublayers, attention (``GQAttention`` or ``LatentAttention``) and
-    an FFN: a ``SwiGLU`` (``mlp``), or an ``ExpertLayer`` (``moe``) beside a
-    shared ``SwiGLU`` (``shared``), each behind its RMSNorm and inside the
-    layer's residual path, which is a part of the layer:
+def _norm(kind: str, eps: float, param_dtype: str) -> Module:
+    """A sublayer's norm: ``"rms"`` (a gain) or ``"layer"`` (mean, gain and
+    bias), float32 in the arithmetic and, on float32 input, the result."""
+    if kind == "rms":
+        return RMSNorm(eps, param_dtype)
+    if kind == "layer":
+        from .attention import LayerNorm
 
-      - plain (``hyper`` None), on ``x [B, T, D]``: ``h = x + Attn(RMSNorm(x));
-        x' = h + FFN(RMSNorm(h))``;
+        return LayerNorm(eps, param_dtype)
+    raise ValueError(f"unknown norm {kind!r}")
+
+
+class DecoderLayer(Module):
+    """Two sublayers, a token mixer and an FFN: a ``SwiGLU`` (``mlp``), or an
+    ``ExpertLayer`` (``moe``) beside a shared ``SwiGLU`` (``shared``), each
+    behind its norm (``norm``: RMSNorm, or LayerNorm with a bias) and inside
+    the layer's residual path, which is a part of the layer.
+
+    **The mixer** (the first argument; its part, its norm ``<mixer>_norm``
+    and its scope are named by ``mixer``) is one of five: ``GQAttention`` or
+    ``LatentAttention`` (``attn``), ``DiffAttention`` (``attn``: window,
+    full, or cross over another layer's keys and values), ``ssm.Mamba``
+    (``ssm``), ``ssm.GatedMemoryUnit`` (``gmu``).
+
+    **The carry between layers.** ``apply_with_load`` takes, beside ``x``, a
+    small mapping the model's loop over layers hands from layer to layer. A
+    mixer that ``crosses_layers`` gets it (``apply_carry(params, u, carry)``)
+    and may write it (``memory``: a ``Mamba``'s scan output; ``kv``: a
+    ``DiffAttention``'s keys and values) or read what an earlier layer wrote
+    (``GatedMemoryUnit``, a cross ``DiffAttention``). Such a mixer sees the
+    whole batch at once (what it writes must outlive the layer, so no loop
+    over rows may hold it) and bounds its own memory (``ssm.over_pieces``).
+    Every other mixer runs under ``_by_rows`` and never sees the carry.
+
+    The residual path:
+
+      - plain (``hyper`` None), on ``x [B, T, D]``: ``h = x + Mixer(norm(x));
+        x' = h + FFN(norm(h))``;
       - hyper-connected (``hyper`` a ``residual.HyperConnection``, the parts
         ``attn_hc`` and ``mlp_hc``), on ``X [B, T, streams D]``: a sublayer
         reads ``RMSNorm(sum_i H_pre[i] X[i])`` and its output ``y`` goes back
@@ -780,15 +1084,19 @@ class DecoderLayer(Module):
     ``apply_with_load`` also returns the expert layer's ``[B, experts held]``
     visit counts (None for a dense layer)."""
 
+    mixer = "attn"           # a layer pickled before the other mixers has this one
+
     def __init__(self, attn: Module, mlp: Optional[SwiGLU] = None,
                  moe: Optional[Module] = None, shared: Optional[SwiGLU] = None,
                  eps: float = 1e-5, param_dtype: str = "float32",
-                 hyper: Optional[Module] = None):
+                 hyper: Optional[Module] = None, norm: str = "rms",
+                 mixer: str = "attn"):
         if (mlp is None) == (moe is None):
             raise ValueError("a layer has a dense MLP or an expert layer, one of them")
+        self.mixer = mixer
         self.parts: List[Tuple[str, Module]] = [
-            ("attn_norm", RMSNorm(eps, param_dtype)), ("attn", attn),
-            ("mlp_norm", RMSNorm(eps, param_dtype))]
+            (mixer + "_norm", _norm(norm, eps, param_dtype)), (mixer, attn),
+            ("mlp_norm", _norm(norm, eps, param_dtype))]
         self.parts += [(n, m) for n, m in (("mlp", mlp), ("moe", moe),
                                            ("shared", shared)) if m is not None]
         if hyper is not None:        # one module, a set of parameters a sublayer
@@ -799,8 +1107,9 @@ class DecoderLayer(Module):
         return {n: m.init(k, in_shape)[0]
                 for (n, m), k in zip(self.parts, keys)}, tuple(in_shape)
 
-    def apply_with_load(self, params, x):
+    def apply_with_load(self, params, x, carry: Optional[Dict[str, Any]] = None):
         part = dict(self.parts)
+        mixer = self.mixer
 
         def apply(name, xc):
             with scope(name):
@@ -819,11 +1128,15 @@ class DecoderLayer(Module):
 
         # a part's scope is pushed around its `_by_rows`, so the loop's own
         # slicing and stacking is counted with the part it serves
-        with scope("attn"):
-            h = _by_rows(sublayer("attn", "attn_norm"), x)
+        with scope(mixer):
+            if getattr(part[mixer], "crosses_layers", False):
+                h = x + part[mixer].apply_carry(params[mixer],
+                                                apply(mixer + "_norm", x), carry)
+            else:
+                h = _by_rows(sublayer(mixer, mixer + "_norm"), x)
         if "mlp" in part:
             with scope("mlp"):
-                return _by_rows(sublayer("mlp", "mlp_norm"), h), None
+                return _by_rows(sublayer("mlp", "mlp_norm"), h, positionwise=True), None
         hn = apply("mlp_norm", h)
         if "shared" in part:
             h = h + apply("shared", hn)
@@ -873,31 +1186,44 @@ class CausalLM(Module):
     state between layers is ``[B, T, streams hidden]``: every stream starts as
     a copy of the embedding, and their sum goes into the final norm.
 
+    **The carry between layers.** Beside ``h`` the loop over layers hands on
+    one small mapping, empty at layer 0, which a layer's mixer may write and
+    a later one read (``DecoderLayer``): a scan's output as ``memory``, a
+    layer's keys and values as ``kv``. It lives for one call and is no output.
+
+    ``norm`` chooses the final norm (``"rms"`` or ``"layer"``); with ``tied``
+    the head is the embedding table transposed and ``params`` has no ``head``.
+
     A container for ``DNNModel``'s ``fetchDict``: the node ``expert_load``
     is ``[B, sparse layers, experts held]``, the visits each held expert took
     from that row's positions (float32: what the routing counted)."""
 
     is_container = True
     LOAD = "expert_load"
+    tied = False             # a model pickled before the tied head has none
 
     def __init__(self, vocab_size: int, hidden: int, layers: Sequence[DecoderLayer],
                  pad_id: int = 0, eps: float = 1e-5, param_dtype: str = "float32",
-                 streams: int = 1):
+                 streams: int = 1, norm: str = "rms", tied: bool = False):
         self.vocab_size, self.hidden, self.pad_id = vocab_size, hidden, pad_id
         self.layers = list(layers)
-        self.final_norm = RMSNorm(eps, param_dtype)
+        self.final_norm = _norm(norm, eps, param_dtype)
         self.param_dtype = param_dtype
         self.streams = streams
+        self.tied = tied
 
     def init(self, rng, in_shape):
         (t,) = in_shape
         keys = _rng_split(rng, len(self.layers) + 2)
+        # a tied table is the head too: its logits are of order 1 at this scale
         params: Dict[str, Any] = {
-            "embed": {"table": _normal(keys[0], (self.vocab_size, self.hidden), 1.0,
+            "embed": {"table": _normal(keys[0], (self.vocab_size, self.hidden),
+                                       self.hidden ** -0.5 if self.tied else 1.0,
                                        self.param_dtype)},
-            "final_norm": self.final_norm.init(None, (t, self.hidden))[0],
-            "head": {"kernel": _normal(keys[1], (self.hidden, self.vocab_size),
-                                       self.hidden ** -0.5, self.param_dtype)}}
+            "final_norm": self.final_norm.init(None, (t, self.hidden))[0]}
+        if not self.tied:
+            params["head"] = {"kernel": _normal(keys[1], (self.hidden, self.vocab_size),
+                                                self.hidden ** -0.5, self.param_dtype)}
         for i, (layer, k) in enumerate(zip(self.layers, keys[2:])):
             params[f"layer{i}"] = layer.init(k, (t, self.hidden))[0]
         return params, (t,)
@@ -910,14 +1236,18 @@ class CausalLM(Module):
         import jax.numpy as jnp
 
         dt = _mm_dtype()
-        head = jnp.asarray(params["head"]["kernel"]).astype(dt)
+        # [hidden, vocab], or the tied table [vocab, hidden] contracted in place
+        head = jnp.asarray(params["embed"]["table"] if self.tied
+                           else params["head"]["kernel"]).astype(dt)
+        over = (((1,), (1 if self.tied else 0,)), ((), ()))
         target = jnp.concatenate(
             [ids[:, 1:], jnp.full_like(ids[:, :1], self.pad_id)], axis=1)
 
         def row(a):
             xr, tr = a
             xn = self.final_norm.apply(params["final_norm"], xr).astype(dt)
-            logits = jnp.dot(xn, head, preferred_element_type=jnp.float32)
+            logits = jax.lax.dot_general(xn, head, over,
+                                         preferred_element_type=jnp.float32)
             picked = jnp.take_along_axis(logits, tr[:, None], axis=-1)[:, 0]
             return picked - jax.nn.logsumexp(logits, axis=-1)
 
@@ -942,9 +1272,10 @@ class CausalLM(Module):
             if self.streams > 1:
                 h = jnp.tile(h, (1, 1, self.streams))
         loads = []
+        carry: Dict[str, Any] = {}          # what a layer hands a later one
         for i, layer in enumerate(self.layers):
             with scope(f"layer{i}"):
-                h, load = layer.apply_with_load(params[f"layer{i}"], h)
+                h, load = layer.apply_with_load(params[f"layer{i}"], h, carry)
             if load is not None:
                 loads.append(load)
             if taps and taps_out is not None and f"{_prefix}layer{i}" in taps:
@@ -1047,3 +1378,61 @@ def latent_causal_lm(seq_len: int, vocab_size: int, hidden: int, heads: int,
     params = module.init(jax.random.key(seed), (seq_len,))[0] if init else {}
     names = [CausalLM.LOAD] + [f"layer{i}" for i in reversed(range(len(layers)))]
     return FunctionModel(module, params, (seq_len,), names, "latent_causal_lm")
+
+
+def hybrid_plan(num_layers: int, mb_per_layer: int = 2) -> List[str]:
+    """The mixer of each layer of a SambaY decoder (arXiv:2507.06607): in the
+    first half (the self-decoder, which ends at layer ``num_layers / 2``)
+    every ``mb_per_layer``-th layer is ``"mamba"`` and the others ``"window"``
+    attention; layer ``num_layers / 2`` is the Mamba whose memory is handed
+    on (``"mamba_memory"``), the next is ``"full"`` attention whose keys and
+    values are handed on; after it (the cross-decoder) ``"gmu"`` and
+    ``"cross"`` attention take turns."""
+    half = num_layers // 2
+    plan = []
+    for i in range(num_layers):
+        state = i % mb_per_layer == 0
+        if i <= half:
+            plan.append(("mamba_memory" if i == half else "mamba") if state else "window")
+        elif i == half + 1:
+            plan.append("full")
+        else:
+            plan.append("gmu" if state else "cross")
+    return plan
+
+
+def hybrid_causal_lm(seq_len: int, vocab_size: int, hidden: int, heads: int,
+                     kv_heads: int, num_layers: int, dense_hidden: int,
+                     window: int, mb_per_layer: int = 2, d_state: int = 16,
+                     d_conv: int = 4, expand: int = 2, dt_rank: Optional[int] = None,
+                     eps: float = 1e-5, pad_id: int = 0,
+                     param_dtype: str = "float32", seed: int = 0,
+                     init: bool = True) -> FunctionModel:
+    """``causal_lm``'s sibling for a decoder of state-space layers,
+    differential attention and Gated Memory Units (``hybrid_plan``): the same
+    ``CausalLM`` of the same ``DecoderLayer`` s, each with the mixer its
+    index gives it and a dense SwiGLU, LayerNorm before every sublayer and
+    the head, the head tied to the embedding, no positions."""
+    import jax
+
+    from .ssm import GatedMemoryUnit, Mamba
+
+    d_inner, head_dim = expand * hidden, hidden // heads
+    layers = []
+    for i, kind in enumerate(hybrid_plan(num_layers, mb_per_layer)):
+        if kind.startswith("mamba"):
+            part, name = Mamba(d_inner, d_state, d_conv, dt_rank,
+                               kind == "mamba_memory", param_dtype), "ssm"
+        elif kind == "gmu":
+            part, name = GatedMemoryUnit(d_inner, param_dtype), "gmu"
+        else:
+            part, name = DiffAttention(
+                heads, kv_heads, head_dim, i, window if kind == "window" else 0,
+                kind == "cross", kind == "full", eps, param_dtype), "attn"
+        layers.append(DecoderLayer(part, mlp=SwiGLU(dense_hidden, param_dtype), eps=eps,
+                                   param_dtype=param_dtype, norm="layer", mixer=name))
+    module = CausalLM(vocab_size, hidden, layers, pad_id, eps, param_dtype,
+                      norm="layer", tied=True)
+    params = module.init(jax.random.key(seed), (seq_len,))[0] if init else {}
+    names = [f"layer{i}" for i in reversed(range(len(layers)))]
+    return FunctionModel(module, params, (seq_len,), names, "hybrid_causal_lm")

@@ -537,3 +537,32 @@ def test_the_combine_kernel_compiles_at_the_published_widths(shape, one_chip):
     visit = jax.ShapeDtypeStruct((rows_,), jnp.int32, sharding=one_chip)
     text = _compiled_text(lambda *a: moe.combine_pallas(*a, held), y, out, gate, visit, visit)
     assert "moe_combine" in text and "tpu_custom_call" in text
+
+
+def test_the_selective_scan_kernel_compiles_at_the_published_widths(one_chip):
+    """A piece of 8,192 positions of the hybrid model's 5,120 channels, 16
+    states: five tiles of 1,024 channels, `B_t` and `C_t` blocked into SMEM."""
+    from mmlspark_tpu.models import ssm
+
+    B, t, C, N = 1, 8192, 5120, 16
+
+    def of(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    text = _compiled_text(ssm.ssm_scan_pallas, of(B, t, C), of(B, t, C), of(B, t, N),
+                          of(B, t, N), of(C, N), of(C), of(B, C, N))
+    assert "tpu_custom_call" in text and "ssm_scan" in text
+
+
+@pytest.mark.parametrize("window", [512, 0])
+def test_the_differential_kernel_compiles_at_the_published_widths(window, one_chip):
+    """20 query pairs over 10 key/value pairs of 128 lanes, a row of 32,768."""
+    B, t = 1, 32768
+    q = jax.ShapeDtypeStruct((B, t, 20 * 128), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((B, t, 10 * 128), jnp.bfloat16, sharding=one_chip)
+    lam = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    gain = jax.ShapeDtypeStruct((128,), jnp.float32, sharding=one_chip)
+    text = _compiled_text(lambda *a: transformer.diff_pallas(*a, window, 20, 10, 1e-5),
+                          q, kv, kv, lam, gain)
+    assert "tpu_custom_call" in text
+    assert ("attn_window_diff" if window else "attn_full_diff") in text
