@@ -101,6 +101,10 @@ class Sizes:
         self.hist_rows = 4096 if tiny else 1_000_000
         self.select_rows = 6000 if tiny else 2_000_000
         self.flash_t = (256,) if tiny else (2048, 8192)
+        # B, T, D, H: the tagger's batch; T = 19 ends in a part of a block of
+        # steps; 130 + 200 does not fit one operand tile beside h ([h | 0 | x | 0])
+        self.lstm_shapes = (((8, 19, 3, 5),) if tiny else
+                            ((8192, 128, 50, 300), (1024, 19, 50, 300), (256, 16, 200, 130)))
 
 
 def run_phases(phases: List[Tuple[str, Callable[[], Any]]]
@@ -484,6 +488,57 @@ def phase_kernels(fx: Fixtures) -> Dict[str, Any]:
     for t in sz.flash_t:
         for causal in (False, True):
             ck.run(f"flash.t{t}.causal{int(causal)}", flash_case(t, causal))
+
+    # -- lstm_scan: the tagger's recurrence, the kernel against the plain form
+    def lstm_case(B, T, D, H, which):
+        """``which``: 0 forward, 1 reverse, 2 both as a ``BiLSTM`` runs them
+        (the first direction's result handed to the second's call)."""
+        def go():
+            def weights():
+                return (jnp.asarray(rng.normal(size=(D, 4 * H)) / np.sqrt(D), jnp.float32),
+                        jnp.asarray(rng.normal(size=(H, 4 * H)) / np.sqrt(H), jnp.float32),
+                        jnp.asarray(rng.normal(size=(4 * H,)) * 0.1, jnp.float32))
+
+            x = jnp.asarray(rng.normal(size=(B, T, D)), jnp.float32)
+            if which < 2:
+                args = (x, *weights())
+                plain = jax.jit(lambda *a: attention.lstm_scan_xla(*a, bool(which)))
+                kern = jax.jit(lambda *a: attention.lstm_scan_pallas(
+                    *a, bool(which), interpret=interp))
+            else:
+                args = (x, *weights(), *weights())
+                plain = jax.jit(lambda x, *w: jnp.concatenate(
+                    [attention.lstm_scan_xla(x, *w[:3]),
+                     attention.lstm_scan_xla(x, *w[3:], True)], axis=-1))
+                kern = jax.jit(lambda x, *w: attention.lstm_scan_pallas(
+                    x, *w[3:], True, interpret=interp,
+                    beside=attention.lstm_scan_pallas(x, *w[:3], padded=True,
+                                                      interpret=interp)))
+
+            def ms(fn):     # the median of three calls after the one that compiles
+                out, took = fn(*args).block_until_ready(), []
+                for _ in range(3):
+                    t0 = time.perf_counter()
+                    fn(*args).block_until_ready()
+                    took.append((time.perf_counter() - t0) * 1e3)
+                return out, round(sorted(took)[1], 2)
+
+            (want, plain_ms), (got, kernel_ms) = ms(plain), ms(kern)
+            _check(got.shape == want.shape, f"{got.shape} != {want.shape}")
+            gap = float(jnp.max(jnp.abs(got - want)))
+            # both round their operands to bfloat16 on the chip and add in
+            # another order; the interpreter's plain form is float32
+            _check(gap <= 2e-2, f"largest gap of h {gap} > 0.02")
+            if interp:      # a CPU run yields no rate
+                return {"largest_gap_h": round(gap, 5)}
+            _check(_has_custom_call(kern, *args), "no tpu_custom_call in the program")
+            return {"largest_gap_h": round(gap, 5), "kernel_ms": kernel_ms,
+                    "plain_ms": plain_ms}
+        return go
+
+    for shape in sz.lstm_shapes:
+        for which, name in enumerate(("forward", "reverse", "both")):
+            ck.run("lstm_scan.b%d.t%d.d%d.h%d." % shape + name, lstm_case(*shape, which))
     return ck.finish()
 
 

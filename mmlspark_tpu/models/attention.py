@@ -16,11 +16,17 @@ and makes LONG sequences first-class:
     single-chip or ring-parallel under ``shard_map`` — the module code does
     not change, only the mesh placement does (scaling-book style: annotate,
     let XLA/collectives do the rest).
-  - ``LSTM``/``BiLSTM``: ``lax.scan`` over time, a block of K steps a loop
-    trip (static shapes; the K steps are the same ``cell`` called in turn),
-    concat of forward/backward passes. One step a trip made XLA write each
-    ``h_t`` as a row of every tile of the ``[T, B, H]`` output, 57% of the
-    tagger's device time; K is the rows of a tile, read from ``h``'s dtype.
+  - ``LSTM``/``BiLSTM``: the recurrence ``lstm_scan``, one of two forms of
+    one arithmetic. On a TPU the Pallas kernel ``lstm_scan_pallas`` (state in
+    VMEM through the blocks of time, the input product inside a step, the
+    reverse direction by its index map, a ``BiLSTM``'s two halves written
+    side by side by the second direction's call). Elsewhere, and as the
+    kernel's VJP, ``lstm_scan_xla``: ``lax.scan`` over time, a block of K
+    steps a loop trip (static shapes; the K steps are the same ``cell``
+    called in turn), concat of forward/backward passes. One step a trip made
+    XLA write each ``h_t`` as a row of every tile of the ``[T, B, H]`` output,
+    57% of the tagger's device time; K is the rows of a tile, read from
+    ``h``'s dtype.
 
 All modules follow module.py conventions: shapes exclude the batch dim,
 ``init -> (params, out_shape)``, bf16 matmuls via matmul_dtype().
@@ -28,6 +34,7 @@ All modules follow module.py conventions: shapes exclude the batch dim,
 
 from __future__ import annotations
 
+import functools
 import math
 import os
 from typing import Optional, Tuple
@@ -332,13 +339,340 @@ def _sublane_rows(dtype) -> int:
     return 32 // np.dtype(dtype).itemsize
 
 
-class LSTM(Module):
-    """Unidirectional LSTM via lax.scan: [B, T, D] -> [B, T, H].
+# ---------------------------------------------------------------------------
+# the LSTM recurrence
+# ---------------------------------------------------------------------------
+#
+# ``gates_t = x_t Wx + b + h_{t-1} Wh`` split ``i, f, g, o``; ``c_t = sig(f)
+# c_{t-1} + sig(i) tanh(g)``; ``h_t = sig(o) tanh(c_t)``; from zeros; a reverse
+# direction runs t = T-1 .. 0 and writes each ``h_t`` at its own position. It
+# runs one of two ways, one arithmetic:
+#
+#   - ``lstm_scan_pallas``: a Pallas kernel, ``lstm_scan`` in a device trace.
+#     Grid (row blocks, time blocks), time ``arbitrary``: ``h`` and ``c`` of a
+#     row block stay in VMEM from the first time block to the last. Each gate
+#     is padded to ``Hp`` lanes (whole lane tiles; zero columns and a zero bias
+#     keep the padded lanes of ``c`` and ``h`` at 0), and the input product is
+#     inside the step: the left operand is ``[h_{t-1} | x_t | 0]`` against
+#     ``[[Wh]; [Wx]; [0]]`` where ``x`` fits the pad of ``h``'s last tile, else
+#     ``[h_{t-1} | 0 | x_t | 0]``: one bfloat16 product a step, float32
+#     accumulation, gates and state float32. The reverse direction is the
+#     index map (time block ``n - 1 - j``, a block's steps last to first). A
+#     step's ``h`` goes straight into the batch-major ``[B, T, H]`` result;
+#     in a ``BiLSTM`` the first direction leaves its own time-major and
+#     lane-padded, and the second writes both into ``[B, T, 2 H]``.
+#   - ``lstm_scan_xla``: ``lax.scan`` over time, a block of K steps a trip;
+#     every other backend's form and the kernel's VJP.
 
-    The scan advances K steps a trip and writes their ``h`` as one
-    ``[K, B, H]`` block, so a write covers whole tiles of the output instead
-    of one row of each; K = min(rows of a tile of ``h``'s dtype, T), the
-    ``T % K`` steps left over run one a trip."""
+LSTM_ROWS = (512, 256, 128, 64, 32, 16, 8)   # rows of a kernel step: the first to divide B and fit
+LSTM_STEPS = 8                               # time steps of a kernel step
+LSTM_VMEM = 64 * 2 ** 20                     # bytes a kernel step may hold (a v5e has 128 MiB)
+
+
+def _whole_tiles(n: int) -> int:
+    """``n`` lanes rounded up to whole tiles of 128."""
+    return -(-n // 128) * 128
+
+
+def lstm_scan_xla(x, wx, wh, b, reverse: bool = False):
+    """``x [B, T, D]``, ``wx [D, 4H]``, ``wh [H, 4H]``, ``b [4H]`` -> ``[B, T,
+    H]`` float32. The scan advances K steps a trip and writes their ``h`` as
+    one ``[K, B, H]`` block, so a write covers whole tiles of the output
+    instead of one row of each; K = min(rows of a tile of ``h``'s dtype, T),
+    the ``T % K`` steps left over run one a trip."""
+    import jax
+    import jax.numpy as jnp
+
+    B, T, D = x.shape
+    h = wh.shape[0]
+    # time-major BEFORE the projection ([T, B, D] is small): transposing
+    # the projected [T, B, 4H] costs XLA a copy of it once the scan blocks
+    xt = jnp.swapaxes(x.astype(jnp.float32), 0, 1)
+
+    def project(v):
+        # the input projection, hoisted out of the scan: MXU matmuls
+        return jnp.einsum("tbd,dk->tbk", v, wx) + b
+
+    def cell(carry, xt):
+        hprev, cprev = carry
+        gates = xt + hprev @ wh
+        i, f, g, o = jnp.split(gates, 4, axis=-1)
+        c = jax.nn.sigmoid(f) * cprev + jax.nn.sigmoid(i) * jnp.tanh(g)
+        hh = jax.nn.sigmoid(o) * jnp.tanh(c)
+        return (hh, c), hh
+
+    def run(carry, part, k):
+        """``part`` ([n * k, B, D], a stretch of ``xt``) through the
+        recurrence, k steps a trip -> (carry, [n * k, B, H])."""
+        n = part.shape[0] // k
+        part = part.reshape(n, k, B, D)
+        # one projection a position of the block, each [n, B, 4H], in the
+        # order a trip runs them: a step then reads its input in place
+        # (one [T, B, 4H] scanned by blocks is copied out a block a trip)
+        xs = tuple(project(part[:, j]) for j in range(k))
+        if reverse:
+            xs = xs[::-1]
+
+        def block(carry, xb):
+            hs = []
+            for xk in xb:
+                carry, hk = cell(carry, xk)
+                hs.append(hk)
+            return carry, jnp.stack(hs)
+
+        carry, ys = jax.lax.scan(block, carry, xs, reverse=reverse)
+        if reverse:  # a block was stacked last step first
+            ys = ys[:, ::-1]
+        return carry, ys.reshape(n * k, B, h)
+
+    zeros = jnp.zeros((B, h), dtype=jnp.float32)
+    init = (zeros, zeros)
+    k = min(_sublane_rows(zeros.dtype), T) or 1
+    whole = T - T % k
+    # the T % k steps past the whole blocks (none where k divides T) run
+    # one a trip
+    if reverse:
+        carry, tail = run(init, xt[whole:], 1)
+        _, ys = run(carry, xt[:whole], k)
+    else:
+        carry, ys = run(init, xt[:whole], k)
+        _, tail = run(carry, xt[whole:], 1)
+    ys = jnp.concatenate([ys, tail])
+    return jnp.swapaxes(ys, 0, 1)  # [B, T, H]
+
+
+def _side_by_side(left, h, H: int):
+    """``[rows, 2 H]``: ``left``'s first ``H`` lanes, then ``h``'s; both
+    ``[rows, Hp]`` with zeros past lane ``H``. ``h`` is rotated to the lane
+    of its tile where ``left`` ends, so the tile they share is a sum of two
+    with disjoint lanes and every other tile is one of theirs, whole."""
+    import jax.numpy as jnp
+    from jax.experimental.pallas import tpu as pltpu
+
+    base = H // 128 * 128
+    if base == H:
+        return jnp.concatenate([left, h], axis=1)
+    wide = _whole_tiles(2 * H - base)
+    if wide > h.shape[1]:
+        h = jnp.concatenate([h, jnp.zeros((h.shape[0], wide - h.shape[1]), h.dtype)], axis=1)
+    h = pltpu.roll(h, H - base, 1)
+    tiles = [left[:, :base], left[:, base:] + h[:, :128], h[:, 128:]]
+    return jnp.concatenate([t for t in tiles if t.shape[1]], axis=1)[:, :2 * H]
+
+
+def _lstm_kernel(*refs, T: int, H: int, base: int, reverse: bool, padded: bool):
+    """One block of time steps of one block of rows. ``x_ref [steps, rows,
+    Kp - base]`` holds ``x_t`` on the lanes its rows of ``w_ref [Kp, 4 Hp]``
+    have past lane ``base``; ``h_ref``, ``c_ref`` ``[rows, Hp]`` float32
+    scratch, the state between steps and blocks. ``o_ref`` is ``[steps, rows,
+    Hp]`` where ``padded``, else ``[rows, steps, H]``, or ``[rows, steps, 2 H]``
+    where ``refs`` has a fourth input, another direction's ``padded`` result
+    that goes before this one's at each position."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    x_ref, w_ref, b_ref, *left_ref, o_ref, h_ref, c_ref = refs
+    steps, rows = x_ref.shape[:2]
+    Hp = h_ref.shape[1]
+    j, n = pl.program_id(1), pl.num_programs(1)
+
+    @pl.when(j == 0)
+    def _start():
+        h_ref[...] = jnp.zeros_like(h_ref)
+        c_ref[...] = jnp.zeros_like(c_ref)
+
+    first = (n - 1 - j if reverse else j) * steps
+    f32, dt = jnp.float32, w_ref.dtype
+
+    def sigmoid(a):                         # one pass of the transcendental unit
+        return 0.5 * jnp.tanh(0.5 * a) + 0.5
+
+    def step(k):
+        h, x = h_ref[...], x_ref[k]
+        if base < Hp:   # x_t rides the pad of h's last lane tile: their lanes are disjoint
+            u = (h[:, base:] + x.astype(f32)).astype(dt)
+            if base:
+                u = jnp.concatenate([h[:, :base].astype(dt), u], axis=1)
+        else:
+            u = jnp.concatenate([h.astype(dt), x], axis=1)
+        gates = jnp.dot(u, w_ref[...], preferred_element_type=f32) + b_ref[...]
+        i, f, g, o = (gates[:, q * Hp:(q + 1) * Hp] for q in range(4))
+        c = sigmoid(f) * c_ref[...] + sigmoid(i) * jnp.tanh(g)
+        h = sigmoid(o) * jnp.tanh(c)
+        c_ref[...] = c
+        h_ref[...] = h
+        if padded:
+            o_ref[k] = h
+        elif left_ref:
+            o_ref[:, k, :] = _side_by_side(left_ref[0][k], h, H)
+        else:
+            o_ref[:, k, :] = h[:, :H]
+
+    for k in (range(steps - 1, -1, -1) if reverse else range(steps)):
+        if T % steps:   # the last block of time holds steps past the row's end
+            pl.when(first + k < T)(functools.partial(step, k))
+        else:
+            step(k)
+
+
+def _lstm_layout(D: int, H: int):
+    """``(Hp, xo, base, Kp)``: the lanes of a gate; the row of the weights
+    ``x``'s first lane meets (``H`` where ``x`` fits the pad of ``h``'s last
+    tile, else ``Hp``); the lanes before it that are ``h``'s alone; the
+    operand's width."""
+    Hp = _whole_tiles(H)
+    xo = H if H + D <= Hp else Hp
+    return Hp, xo, xo // 128 * 128, _whole_tiles(xo + D)
+
+
+def _lstm_blocks(B: int, T: int, D: int, H: int):
+    """(rows, steps) of a kernel step: the most rows that divide ``B`` and
+    keep a step within ``LSTM_VMEM``, None where no block does."""
+    Hp, _, base, Kp = _lstm_layout(D, H)
+    steps = LSTM_STEPS
+    weights = 2 * (Kp * 4 * Hp * 2 + 4 * Hp * 4)        # bfloat16, fetched into two buffers
+    # a row's share: x, the widest result and another direction's block in two
+    # buffers each; h and c; a step's operand, gates and some six temporaries
+    row = (2 * steps * ((Kp - base) * 2 + _whole_tiles(2 * H) * 4 + Hp * 4)
+           + 2 * Hp * 4 + Kp * 2 + (4 + 6) * Hp * 4)
+    rows = next((r for r in LSTM_ROWS if B % r == 0 and weights + r * row <= LSTM_VMEM), None)
+    return (rows, steps) if rows and T >= steps else None
+
+
+def lstm_scan_pallas(x, wx, wh, b, reverse: bool = False, beside=None,
+                     padded: bool = False, interpret: bool = False,
+                     operands="bfloat16"):
+    """The kernel form of ``lstm_scan_xla`` (same arguments and result): ``B``
+    a multiple of 8, ``T`` at least a block of steps. ``operands`` is the
+    dtype of the product's two sides. ``padded``: the result as another
+    direction's call takes it for ``beside``, time-major ``[blocks * steps, B,
+    Hp]`` with zeros past lane ``H`` (steps past ``T`` not written). With
+    ``beside`` the result is ``[B, T, 2 H]``: at each position ``beside``'s
+    ``h``, then this direction's."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    B, T, D = x.shape
+    H = wh.shape[0]
+    rows, steps = _lstm_blocks(B, T, D, H)
+    Hp, xo, base, Kp = _lstm_layout(D, H)
+    blocks = -(-T // steps)
+
+    def gates(m):                           # [r, 4 H] -> [r, 4 Hp], a gate a whole tile
+        m = jnp.pad(m.astype(f32).reshape(-1, 4, H), ((0, 0), (0, 0), (0, Hp - H)))
+        return m.reshape(-1, 4 * Hp)
+
+    w = jnp.zeros((Kp, 4 * Hp), f32).at[:H].set(gates(wh)).at[xo:xo + D].set(gates(wx))
+    # time-major, on its lanes of the operand, whole blocks of steps
+    xp = jnp.pad(jnp.swapaxes(x, 0, 1).astype(operands),
+                 ((0, blocks * steps - T), (0, 0), (xo - base, Kp - xo - D)))
+
+    def at(j):                              # the time block of grid step j
+        return blocks - 1 - j if reverse else j
+
+    def by_time(width):                     # a [steps, rows, width] block of a time-major array
+        return pl.BlockSpec((steps, rows, width), lambda i, j: (at(j), i, 0))
+
+    def by_rows(width):                     # a [rows, steps, width] block of a batch-major one
+        return pl.BlockSpec((rows, steps, width), lambda i, j: (i, at(j), 0))
+
+    ins = [xp, w.astype(operands), gates(b[None])]
+    in_specs = [by_time(Kp - base), pl.BlockSpec((Kp, 4 * Hp), lambda i, j: (0, 0)),
+                pl.BlockSpec((1, 4 * Hp), lambda i, j: (0, 0))]
+    if padded:
+        out_spec, out_shape = by_time(Hp), (blocks * steps, B, Hp)
+    else:
+        width = H if beside is None else 2 * H
+        out_spec, out_shape = by_rows(width), (B, T, width)
+    if beside is not None:
+        ins.append(beside)
+        in_specs.append(by_time(Hp))
+    return pl.pallas_call(
+        functools.partial(_lstm_kernel, T=T, H=H, base=base, reverse=reverse,
+                          padded=padded),
+        grid=(B // rows, blocks),
+        in_specs=in_specs,
+        out_specs=out_spec,
+        out_shape=jax.ShapeDtypeStruct(out_shape, f32),
+        scratch_shapes=[pltpu.VMEM((rows, Hp), f32), pltpu.VMEM((rows, Hp), f32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=96 * 1024 * 1024),
+        name="lstm_scan",            # the name a device trace shows
+        cost_estimate=pl.CostEstimate(
+            flops=int(2 * B * T * Kp * 4 * Hp), transcendentals=int(5 * B * T * Hp),
+            bytes_accessed=int(B * T * (2 * (Kp - base) + 4 * out_shape[2]
+                                        + (4 * Hp if beside is not None else 0)))),
+        interpret=interpret,
+    )(*ins)
+
+
+def _lstm_kernel_plain(x, wx, wh, b, beside, reverse: bool, padded: bool):
+    """What ``lstm_scan_pallas`` returns, from the plain form."""
+    import jax.numpy as jnp
+
+    T, H = x.shape[1], wh.shape[0]
+    y = lstm_scan_xla(x, wx, wh, b, reverse)
+    if beside is not None:
+        y = jnp.concatenate([jnp.swapaxes(beside[:T, :, :H], 0, 1), y], axis=-1)
+    if padded:
+        y = jnp.pad(jnp.swapaxes(y, 0, 1),
+                    ((0, -T % LSTM_STEPS), (0, 0), (0, _whole_tiles(H) - H)))
+    return y
+
+
+@functools.lru_cache(maxsize=None)
+def _lstm_kernel_vjp(reverse: bool, padded: bool = False, interpret: bool = False):
+    """The kernel with a backward pass: the plain form's, recomputed."""
+    import jax
+
+    @jax.custom_vjp
+    def scan(x, wx, wh, b, beside):
+        return lstm_scan_pallas(x, wx, wh, b, reverse, beside, padded, interpret)
+
+    def fwd(*operands):
+        return scan(*operands), operands
+
+    def bwd(operands, g):
+        plain = functools.partial(_lstm_kernel_plain, reverse=reverse, padded=padded)
+        return jax.vjp(plain, *operands)[1](g)
+
+    scan.defvjp(fwd, bwd)
+    return scan
+
+
+def _lstm_kernel_applies(x, hidden: int) -> bool:
+    """Whether the recurrence over ``x [B, T, D]`` into ``hidden`` units takes
+    the kernel: a TPU at its default precision (one bfloat16 pass, what the
+    kernel's product is), float32 or bfloat16 rows, a ``B`` and a ``T`` its
+    blocks fit, widths whose step fits VMEM."""
+    import jax
+    import jax.numpy as jnp
+
+    return (jax.default_backend() == "tpu"
+            and jax.config.jax_default_matmul_precision in (None, "default", "bfloat16")
+            and x.dtype in (jnp.float32, jnp.bfloat16)
+            and _lstm_blocks(*x.shape, hidden) is not None)
+
+
+def _lstm_weights(params):
+    import jax.numpy as jnp
+
+    return tuple(jnp.asarray(params[k]) for k in ("wx", "wh", "b"))
+
+
+def lstm_scan(x, wx, wh, b, reverse: bool = False):
+    """The recurrence: the kernel where it applies, the plain form elsewhere."""
+    if _lstm_kernel_applies(x, wh.shape[0]):
+        return _lstm_kernel_vjp(reverse)(x, wx, wh, b, None)
+    return lstm_scan_xla(x, wx, wh, b, reverse)
+
+
+class LSTM(Module):
+    """Unidirectional LSTM: [B, T, D] -> [B, T, H] (``lstm_scan``)."""
 
     def __init__(self, hidden: int, reverse: bool = False):
         self.hidden = hidden
@@ -359,66 +693,7 @@ class LSTM(Module):
         }, (t, h)
 
     def apply(self, params, x, train: bool = False):
-        import jax
-        import jax.numpy as jnp
-
-        B, T, D = x.shape
-        h = self.hidden
-        wx, wh, b = (jnp.asarray(params[k]) for k in ("wx", "wh", "b"))
-        # time-major BEFORE the projection ([T, B, D] is small): transposing
-        # the projected [T, B, 4H] costs XLA a copy of it once the scan blocks
-        xt = jnp.swapaxes(x.astype(jnp.float32), 0, 1)
-
-        def project(v):
-            # the input projection, hoisted out of the scan: MXU matmuls
-            return jnp.einsum("tbd,dk->tbk", v, wx) + b
-
-        def cell(carry, xt):
-            hprev, cprev = carry
-            gates = xt + hprev @ wh
-            i, f, g, o = jnp.split(gates, 4, axis=-1)
-            c = jax.nn.sigmoid(f) * cprev + jax.nn.sigmoid(i) * jnp.tanh(g)
-            hh = jax.nn.sigmoid(o) * jnp.tanh(c)
-            return (hh, c), hh
-
-        def run(carry, part, k):
-            """``part`` ([n * k, B, D], a stretch of ``xt``) through the
-            recurrence, k steps a trip -> (carry, [n * k, B, H])."""
-            n = part.shape[0] // k
-            part = part.reshape(n, k, B, D)
-            # one projection a position of the block, each [n, B, 4H], in the
-            # order a trip runs them: a step then reads its input in place
-            # (one [T, B, 4H] scanned by blocks is copied out a block a trip)
-            xs = tuple(project(part[:, j]) for j in range(k))
-            if self.reverse:
-                xs = xs[::-1]
-
-            def block(carry, xb):
-                hs = []
-                for xk in xb:
-                    carry, hk = cell(carry, xk)
-                    hs.append(hk)
-                return carry, jnp.stack(hs)
-
-            carry, ys = jax.lax.scan(block, carry, xs, reverse=self.reverse)
-            if self.reverse:  # a block was stacked last step first
-                ys = ys[:, ::-1]
-            return carry, ys.reshape(n * k, B, h)
-
-        zeros = jnp.zeros((B, h), dtype=jnp.float32)
-        init = (zeros, zeros)
-        k = min(_sublane_rows(zeros.dtype), T) or 1
-        whole = T - T % k
-        # the T % k steps past the whole blocks (none where k divides T) run
-        # one a trip
-        if self.reverse:
-            carry, tail = run(init, xt[whole:], 1)
-            _, ys = run(carry, xt[:whole], k)
-        else:
-            carry, ys = run(init, xt[:whole], k)
-            _, tail = run(carry, xt[whole:], 1)
-        ys = jnp.concatenate([ys, tail])
-        return jnp.swapaxes(ys, 0, 1)  # [B, T, H]
+        return lstm_scan(x, *_lstm_weights(params), self.reverse)
 
 
 class BiLSTM(Module):
@@ -438,6 +713,14 @@ class BiLSTM(Module):
     def apply(self, params, x, train: bool = False):
         import jax.numpy as jnp
 
+        if _lstm_kernel_applies(x, self.fwd.hidden):
+            # the second direction's kernel writes both halves of a position:
+            # no concatenation, no pass over either result
+            with scope("fwd"):
+                fwd = _lstm_kernel_vjp(False, padded=True)(
+                    x, *_lstm_weights(params["fwd"]), None)
+            with scope("bwd"):
+                return _lstm_kernel_vjp(True)(x, *_lstm_weights(params["bwd"]), fwd)
         with scope("fwd"):
             fwd = self.fwd.apply(params["fwd"], x)
         with scope("bwd"):

@@ -1,6 +1,7 @@
 """Sequence models: ring attention == dense attention on a real 8-device
 seq mesh, transformer encoder, BiLSTM tagger."""
 
+import functools
 import re
 
 import jax
@@ -18,6 +19,7 @@ from mmlspark_tpu.models import (
     ring_attention,
     transformer_encoder,
 )
+from mmlspark_tpu.models import attention
 from mmlspark_tpu.models.module import matmul_precision
 from mmlspark_tpu.parallel import MeshSpec, make_mesh
 
@@ -346,6 +348,133 @@ class TestLSTM:
         assert out.shape == (3, 10, 4)
         emb = np.asarray(m.apply(jnp.asarray(toks), tap="embed"))
         assert emb.shape == (3, 10, 8)
+
+
+def _lstm_operands(B, T, D, H, seed=0):
+    rng = np.random.default_rng(seed + B + T)
+    return (jnp.asarray(rng.normal(size=(B, T, D)), jnp.float32),
+            {"wx": jnp.asarray(rng.normal(size=(D, 4 * H)) / np.sqrt(D), jnp.float32),
+             "wh": jnp.asarray(rng.normal(size=(H, 4 * H)) / np.sqrt(H), jnp.float32),
+             "b": jnp.asarray(rng.normal(size=(4 * H,)) * 0.3, jnp.float32)})
+
+
+class TestLSTMKernel:
+    """``lstm_scan_pallas`` in the Pallas interpreter on the CPU against the
+    recurrence a step a position (``_plain_lstm``), and what chooses it."""
+
+    # H, D: 5 + 3 and 300 + 50 fit one operand ([h | x | 0]); 130 + 200 and
+    # 128 + 3 do not ([h | 0 | x | 0]). T: 8 and 16 are whole blocks of 8
+    # steps, 19, 11 and 9 end in a part of one. B: 8 and 16 are one row
+    # block, 24 three of 8.
+    SHAPES = [(8, 8, 3, 5), (24, 19, 3, 5), (8, 16, 50, 300), (16, 11, 50, 300),
+              (16, 11, 200, 130), (8, 9, 3, 128)]
+
+    @pytest.mark.parametrize("reverse", [False, True])
+    @pytest.mark.parametrize("B,T,D,H", SHAPES)
+    def test_kernel_is_the_plain_recurrence(self, B, T, D, H, reverse):
+        """float32 operands: the two differ by the order of a sum alone."""
+        x, p = _lstm_operands(B, T, D, H)
+        want = _plain_lstm(p, x, reverse)
+        got = attention.lstm_scan_pallas(x, p["wx"], p["wh"], p["b"], reverse,
+                                         interpret=True, operands="float32")
+        assert got.shape == (B, T, H) and got.dtype == jnp.float32
+        assert float(jnp.abs(got - want).max()) < 2e-6
+
+    @pytest.mark.parametrize("reverse", [False, True])
+    @pytest.mark.parametrize("B,T,D,H", [(24, 19, 3, 5), (8, 16, 50, 300)])
+    def test_kernel_as_the_chip_runs_it(self, B, T, D, H, reverse):
+        """bfloat16 operands, float32 accumulation and state, as on the TPU
+        at its default precision: within 0.02 of the float32 recurrence."""
+        x, p = _lstm_operands(B, T, D, H)
+        got = attention.lstm_scan_pallas(x, p["wx"], p["wh"], p["b"], reverse,
+                                         interpret=True)
+        assert float(jnp.abs(got - _plain_lstm(p, x, reverse)).max()) < 2e-2
+
+    @pytest.mark.parametrize("reverse", [False, True])
+    def test_gradient_through_the_kernel_is_the_plain_forms(self, reverse):
+        x, p = _lstm_operands(8, 19, 3, 5)
+        w = jnp.cos(jnp.arange(8 * 19 * 5, dtype=jnp.float32)).reshape(8, 19, 5)
+
+        def loss(fn):
+            return lambda p, v: jnp.sum(w * fn(p, v))
+
+        kernel = attention._lstm_kernel_vjp(reverse, False, True)
+        got = jax.grad(loss(lambda p, v: kernel(v, p["wx"], p["wh"], p["b"], None)),
+                       argnums=(0, 1))(p, x)
+        want = jax.grad(loss(lambda p, v: _plain_lstm(p, v, reverse)),
+                        argnums=(0, 1))(p, x)
+        for g, e in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(e),
+                                       rtol=1e-4, atol=1e-5)
+
+    @staticmethod
+    def _kernels_on_the_cpu(monkeypatch, calls):
+        """``BiLSTM`` as on a TPU, its kernels in the interpreter at float32."""
+        monkeypatch.setattr(attention, "_lstm_kernel_applies", lambda x, hidden: True)
+        monkeypatch.setattr(attention, "_lstm_kernel_vjp", lambda reverse, padded=False: (
+            lambda x, wx, wh, b, beside: calls.append((reverse, padded, beside is not None))
+            or attention.lstm_scan_pallas(x, wx, wh, b, reverse, beside, padded,
+                                          interpret=True, operands="float32")))
+
+    # H = 5, 100, 300 end inside a lane tile (the second direction is rotated
+    # to there: 100 + 100 and 300 + 300 spill into a tile of their own, 5 + 5
+    # does not), 128 at its end, 130 two lanes into the next
+    @pytest.mark.parametrize("B,T,D,H", [(8, 16, 3, 5), (24, 19, 7, 100), (8, 9, 3, 128),
+                                         (16, 11, 200, 130), (8, 16, 50, 300)])
+    def test_bilstm_is_the_two_directions_side_by_side(self, B, T, D, H, monkeypatch):
+        """The first direction's kernel leaves its result time-major and
+        lane-padded, the second's writes both halves of every position."""
+        x, pf = _lstm_operands(B, T, D, H)
+        _, pb = _lstm_operands(B, T, D, H, seed=7)
+        bi, params = BiLSTM(hidden=H), {"fwd": pf, "bwd": pb}
+        plain = bi.apply(params, x)
+        calls = []
+        self._kernels_on_the_cpu(monkeypatch, calls)
+        got = bi.apply(params, x)
+        assert calls == [(False, True, False), (True, False, True)]
+        want = jnp.concatenate([_plain_lstm(pf, x, False), _plain_lstm(pb, x, True)], -1)
+        assert got.shape == (B, T, 2 * H) and got.dtype == jnp.float32
+        assert float(jnp.abs(got - want).max()) < 2e-6
+        assert float(jnp.abs(got - plain).max()) < 2e-6
+
+    def test_gradient_through_both_kernels_of_a_bilstm(self, monkeypatch):
+        x, pf = _lstm_operands(8, 11, 3, 5)
+        _, pb = _lstm_operands(8, 11, 3, 5, seed=7)
+        bi, params = BiLSTM(hidden=5), {"fwd": pf, "bwd": pb}
+        w = jnp.cos(jnp.arange(8 * 11 * 10, dtype=jnp.float32)).reshape(8, 11, 10)
+
+        def grads():
+            return jax.grad(lambda p, v: jnp.sum(w * bi.apply(p, v)), argnums=(0, 1))(params, x)
+
+        want = grads()
+        monkeypatch.setattr(attention, "_lstm_kernel_applies", lambda x, hidden: True)
+        monkeypatch.setattr(attention, "_lstm_kernel_vjp", functools.partial(
+            attention._lstm_kernel_vjp.__wrapped__, interpret=True))
+        for g, e in zip(jax.tree.leaves(grads()), jax.tree.leaves(want)):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(e), rtol=1e-4, atol=1e-5)
+
+    # the tagger's batch; B = 24 and 768 take the most rows that divide them;
+    # no block where B is no multiple of 8 or T under a block of steps; wider
+    # layers take fewer rows a step, and none once the weights alone fill VMEM
+    @pytest.mark.parametrize("B,T,D,H,want", [
+        (8192, 128, 50, 300, (512, 8)), (24, 19, 3, 5, (8, 8)), (768, 8, 50, 300, (256, 8)),
+        (7, 128, 50, 300, None), (8, 4, 50, 300, None), (8192, 128, 256, 1024, (128, 8)),
+        (8192, 128, 512, 2048, None)])
+    def test_blocks_of_a_kernel_step(self, B, T, D, H, want):
+        assert attention._lstm_blocks(B, T, D, H) == want
+
+    def test_off_the_tpu_the_plain_form_runs(self, monkeypatch):
+        """Whatever the shapes: the CPU suite keeps running ``lstm_scan_xla``."""
+        x, p = _lstm_operands(8, 16, 3, 5)
+        assert not attention._lstm_kernel_applies(jnp.zeros((8192, 128, 50)), 300)
+        assert not attention._lstm_kernel_applies(x, 5)
+
+        def never(*a, **k):
+            raise AssertionError("the kernel ran off the TPU")
+
+        monkeypatch.setattr(attention, "lstm_scan_pallas", never)
+        got = LSTM(hidden=5).apply(p, x)
+        assert float(jnp.abs(got - _plain_lstm(p, x, False)).max()) < 1e-5
 
 
 class TestSequenceModelsThroughDNNModel:

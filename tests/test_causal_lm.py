@@ -554,6 +554,50 @@ def test_the_selective_scan_kernel_compiles_at_the_published_widths(one_chip):
     assert "tpu_custom_call" in text and "ssm_scan" in text
 
 
+@pytest.mark.parametrize("form", ["forward", "reverse", "both"])
+def test_the_lstm_kernel_compiles_at_the_taggers_widths(form, one_chip):
+    """The tagger's batch: 8,192 rows of 128 positions, 50 wide, into 300
+    hidden units a direction (16 row blocks of 512, 16 time blocks of 8);
+    ``both`` as a ``BiLSTM`` runs them: the first direction's result, time-
+    major and lane-padded, goes into the second's call, which writes
+    ``[B, T, 600]``."""
+    from mmlspark_tpu.models import attention
+
+    B, t, D, H = 8192, 128, 50, 300
+
+    def of(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def run(x, wx, wh, b):
+        if form != "both":
+            return attention.lstm_scan_pallas(x, wx, wh, b, form == "reverse")
+        left = attention.lstm_scan_pallas(x, wx, wh, b, padded=True)
+        return attention.lstm_scan_pallas(x, wx, wh, b, True, beside=left)
+
+    text = _compiled_text(run, of(B, t, D), of(D, 4 * H), of(H, 4 * H), of(4 * H))
+    assert "tpu_custom_call" in text and "lstm_scan" in text
+    assert f"f32[8192,128,{600 if form == 'both' else 300}]" in text
+
+
+def test_the_lstm_kernel_of_a_wider_layer_fits_the_chip(one_chip):
+    """512 hidden units over 128-wide rows: ``_lstm_blocks`` takes 256 rows a
+    step to stay within its VMEM budget, and the compiler agrees."""
+    from mmlspark_tpu.models import attention
+
+    B, t, D, H = 1024, 16, 128, 512
+    assert attention._lstm_blocks(B, t, D, H) == (256, 8)
+
+    def of(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def run(x, wx, wh, b):
+        left = attention.lstm_scan_pallas(x, wx, wh, b, padded=True)
+        return attention.lstm_scan_pallas(x, wx, wh, b, True, beside=left)
+
+    text = _compiled_text(run, of(B, t, D), of(D, 4 * H), of(H, 4 * H), of(4 * H))
+    assert "lstm_scan" in text and "f32[1024,16,1024]" in text
+
+
 @pytest.mark.parametrize("window", [512, 0])
 def test_the_differential_kernel_compiles_at_the_published_widths(window, one_chip):
     """20 query pairs over 10 key/value pairs of 128 lanes, a row of 32,768."""
