@@ -445,7 +445,11 @@ class ExpertLayer(Module):
     ``first_expert`` on. The router is ``num_experts`` wide: scores
     (``scoring``: sigmoid or softmax) in float32, the ``top_k`` largest of
     score + selection bias chosen, their scores normalised over the chosen
-    (``norm_topk``) and scaled by ``scale``. The result is the held experts'
+    (``norm_topk``) and scaled by ``scale``. With ``n_group`` > 1 the choice
+    is group-limited (DeepSeek-V3's ``noaux_tc``): the experts fall in
+    ``n_group`` equal groups, a group scores the sum of its two largest score
+    + bias, and the ``top_k`` are chosen among the ``topk_group`` best
+    groups' experts alone. The result is the held experts'
     part of the sum; what the others would add is another holder's to compute.
 
     The combine (each visit's row, widened to float32, times its float32
@@ -461,14 +465,20 @@ class ExpertLayer(Module):
     hidden]`` (gate's columns first), ``w2 [held, hidden, D]``: the leaves
     ``expert_shardings`` shards by their leading dim."""
 
+    n_group, topk_group = 1, 1           # a layer pickled before groups has neither
+
     def __init__(self, num_experts: int, experts_held: int, top_k: int,
                  hidden: int, scoring: str = "sigmoid", norm_topk: bool = True,
                  scale: float = 1.0, first_expert: int = 0,
-                 param_dtype: str = "float32"):
+                 param_dtype: str = "float32", n_group: int = 1, topk_group: int = 1):
         if scoring not in ("sigmoid", "softmax"):
             raise ValueError(f"unknown scoring {scoring!r}")
         if not 0 <= first_expert <= num_experts - experts_held:
             raise ValueError("the experts held are not among the router's")
+        if num_experts % n_group or not 1 <= topk_group <= n_group:
+            raise ValueError(f"{num_experts} experts in {n_group} groups, "
+                             f"{topk_group} of them kept")
+        self.n_group, self.topk_group = n_group, topk_group
         self.num_experts, self.experts_held = num_experts, experts_held
         self.top_k, self.hidden, self.scoring = top_k, hidden, scoring
         self.norm_topk, self.scale, self.first_expert = norm_topk, scale, first_expert
@@ -501,12 +511,27 @@ class ExpertLayer(Module):
                          precision=jax.lax.Precision.HIGHEST)
         s = jax.nn.sigmoid(logits) if self.scoring == "sigmoid" \
             else jax.nn.softmax(logits, axis=-1)
-        _, idx = jax.lax.top_k(
-            s + jnp.asarray(params["router_bias"]).astype(jnp.float32), self.top_k)
+        chosen_by = s + jnp.asarray(params["router_bias"]).astype(jnp.float32)
+        if self.n_group > 1:
+            chosen_by = self._in_kept_groups(chosen_by)
+        _, idx = jax.lax.top_k(chosen_by, self.top_k)
         g = jnp.take_along_axis(s, idx, axis=-1)
         if self.norm_topk:
             g = g / jnp.sum(g, axis=-1, keepdims=True)
         return idx.astype(jnp.int32), g * np.float32(self.scale)
+
+    def _in_kept_groups(self, chosen_by):
+        """``chosen_by [N, E]`` with the experts outside each token's
+        ``topk_group`` best groups at minus infinity."""
+        import jax
+        import jax.numpy as jnp
+
+        N, E = chosen_by.shape
+        grouped = chosen_by.reshape(N, self.n_group, E // self.n_group)
+        group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)     # [N, groups]
+        _, kept = jax.lax.top_k(group_score, self.topk_group)
+        keep = jnp.any(kept[:, :, None] == jnp.arange(self.n_group), axis=1)
+        return jnp.where(keep[:, :, None], grouped, -jnp.inf).reshape(N, E)
 
     def apply_with_load(self, params, x, add_to=None):
         """(``[B, T, D]`` float32, ``[B, experts held]`` float32 visits);

@@ -28,7 +28,10 @@ streams and sums them before the final norm.
   - ``LatentAttention`` (MLA): queries through a low-rank bottleneck, keys
     and values decompressed from one narrow latent a token, and a rotary key
     that all heads share; a score is the sum of a head's own product and the
-    shared rotary one, the value narrower than the two together;
+    shared rotary one, the value narrower than the two together. Optionally
+    sparse (DeepSeek Sparse Attention's lightning indexer: a query reads only
+    the ``index_topk`` keys an indexer of its own scores highest) and with an
+    elementwise sigmoid gate on each head's output;
   - ``DiffAttention`` (differential attention): heads of 64 in pairs, two
     softmaxes a pair against the pair's 128-wide values, subtracted with a
     learned ``lambda`` and RMS-normed; a window, a whole row, or (a cross
@@ -38,9 +41,10 @@ streams and sums them before the final norm.
 Their cores run one of two ways:
 
   - ``attn_window`` / ``attn_full`` / ``attn_mla`` / ``attn_window_diff`` /
-    ``attn_full_diff``: Pallas kernels under the
+    ``attn_full_diff`` / ``attn_mla_sparse``: Pallas kernels under the
     names a device trace shows, one streaming softmax over key blocks (two a
-    pair in the differential kernel). A
+    pair in the differential kernel; the sparse latent one masked by the
+    selection ``mla_index`` made, a kernel of its own). A
     sliding layer visits only the blocks its window touches, so its work and
     memory grow with ``T x window``; a full or latent layer stops at the
     diagonal; the latent kernel fetches the shared rotary key once a key
@@ -600,10 +604,11 @@ def diff_attention(q, k, v, lam, gain, window: int, pairs: int, kv_pairs: int,
 # the rotary lanes of q and kr with zeros (a 64-wide contraction costs the
 # MXU a pass of 128 anyway).
 
-def mla_xla(q, kv, kr, heads: int, dn: int):
+def mla_xla(q, kv, kr, heads: int, dn: int, bits=None):
     """-> ``[B, T, H * dv]``; causal, a block of queries against all keys:
     ``s = q_nope . k_nope + q_rope . k_r``, the second product against the
-    one shared key, never repeated to the heads."""
+    one shared key, never repeated to the heads. With ``bits`` (a
+    selection, ``pack_selection``'s) a query sees the keys they select."""
     import jax
     import jax.numpy as jnp
 
@@ -618,17 +623,24 @@ def mla_xla(q, kv, kr, heads: int, dn: int):
     kpos = jnp.arange(T + pad)
 
     def block(a):
-        qb, first = a                                   # [B, blk, H, dq]
+        qb, first = a[0], a[1]                          # [B, blk, H, dq]
         s = jnp.einsum("bqhd,bkhd->bhqk", qb[..., :dn], kn,
                        preferred_element_type=jnp.float32) \
             + jnp.einsum("bqhd,bkd->bhqk", qb[..., dn:], kr,
                          preferred_element_type=jnp.float32)
-        p = _softmax_rows(s, kpos[None, :] <= (first + jnp.arange(blk))[:, None])
+        seen = kpos[None, :] <= (first + jnp.arange(blk))[:, None]
+        if bits is not None:     # a padded query sees its causal keys: no 0 / 0
+            pad_row = (first + jnp.arange(blk) >= T)[:, None]
+            seen = (seen & pad_row) | unpack_selection(a[2], T + pad)[:, None]
+        p = _softmax_rows(s, seen)
         return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v,
                           preferred_element_type=jnp.float32)
 
-    o = jax.lax.map(block, (jnp.moveaxis(q4, 1, 0),
-                            jnp.arange((T + pad) // blk) * blk))
+    blocks = (jnp.moveaxis(q4, 1, 0), jnp.arange((T + pad) // blk) * blk)
+    if bits is not None:
+        bits = jnp.pad(bits, ((0, 0), (0, pad), (0, 0)))
+        blocks += (jnp.moveaxis(bits.reshape(B, -1, blk, bits.shape[-1]), 1, 0),)
+    o = jax.lax.map(block, blocks)
     return jnp.moveaxis(o, 0, 1).reshape(B, T + pad, -1)[:, :T].astype(kv.dtype)
 
 
@@ -749,6 +761,357 @@ def _mla_pallas_applies(x, nope: int, v_dim: int) -> bool:
 
 
 # ---------------------------------------------------------------------------
+# sparse latent attention: an indexer chooses the keys, the core reads them
+# ---------------------------------------------------------------------------
+#
+# The indexer (DeepSeek Sparse Attention's, arXiv:2512.02556): ``qi [B, T,
+# heads * dim]`` (a head's lanes side by side), ``ki [B, T, dim]`` one key for
+# all its heads, ``wi [B, T, heads]`` float32 weights that already carry every
+# scale; ``I[t, s] = sum_j wi[t, j] relu(qi[t, j] . ki[s])`` for ``s <= t``,
+# and a query keeps the ``topk`` keys of largest ``I`` (all of them where it
+# has fewer), ties to the lower position.
+#
+# The selection travels as bits, 32 keys an int32: a query's ``SELECT_GROUP``
+# = 4,096 keys are one tile of 128 words, and key ``s`` is bit ``(s % 4096) //
+# 128`` of word ``s // 4096 * 128 + s % 128``. A key block of 128-lane chunks
+# then unpacks with a shift and a mask a chunk, lanes in place: ``[B, T,
+# ceil(T / 4096) * 128]`` int32, 134 MB for a row of 32,768.
+
+SELECT_GROUP = 4096
+_INT_MIN = -2 ** 31
+
+
+def selection_words(t: int) -> int:
+    """int32 words a query's selection takes at a row of ``t`` keys."""
+    return -(-t // SELECT_GROUP) * 128
+
+
+def pack_selection(sel):
+    """``sel [..., T]`` bool -> ``[..., selection_words(T)]`` int32."""
+    import jax.numpy as jnp
+
+    t = sel.shape[-1]
+    pad = selection_words(t) * 32 - t
+    sel = jnp.pad(sel, [(0, 0)] * (sel.ndim - 1) + [(0, pad)]).astype(jnp.uint32)
+    sel = sel.reshape(*sel.shape[:-1], -1, 32, 128)
+    word = jnp.sum(sel << jnp.arange(32, dtype=jnp.uint32)[:, None], axis=-2,
+                   dtype=jnp.uint32)
+    return word.reshape(*word.shape[:-2], -1).view(jnp.int32)
+
+
+def unpack_selection(bits, t: int):
+    """``pack_selection``'s inverse: ``[..., words]`` -> ``[..., t]`` bool."""
+    import jax.numpy as jnp
+
+    b = bits.view(jnp.uint32).reshape(*bits.shape[:-1], -1, 1, 128)
+    sel = (b >> jnp.arange(32, dtype=jnp.uint32)[:, None]) & 1
+    return sel.reshape(*bits.shape[:-1], -1)[..., :t] != 0
+
+
+def index_scores_xla(qi, ki, wi, heads: int):
+    """``I [B, T, T]`` float32 (the plain form; keys past a query left in)."""
+    import jax.numpy as jnp
+
+    B, T, _ = qi.shape
+    s = jnp.einsum("btjd,bsd->btjs", qi.reshape(B, T, heads, -1), ki,
+                   preferred_element_type=jnp.float32)
+    return jnp.einsum("btjs,btj->bts", jnp.maximum(s, 0.0), wi,
+                      precision="highest")
+
+
+def mla_index_xla(qi, ki, wi, heads: int, topk: int):
+    """The selection bits of every query (the plain form of ``mla_index``):
+    the scores a block of queries at a time, ``lax.top_k`` over the causal
+    ones (it keeps the lower position of a tie), packed."""
+    import jax
+    import jax.numpy as jnp
+
+    B, T, _ = qi.shape
+    blk = min(T, 512)
+    pad = -T % blk
+    if pad:
+        qi, wi = (jnp.pad(a, ((0, 0), (0, pad), (0, 0))) for a in (qi, wi))
+    kpos = jnp.arange(T)
+
+    def block(a):
+        qb, wb, first = a
+        causal = kpos[None, :] <= (first + jnp.arange(blk))[:, None]
+        s = jnp.where(causal, index_scores_xla(qb, ki, wb, heads), -jnp.inf)
+        _, idx = jax.lax.top_k(s, min(topk, T))                       # [B, blk, k]
+        sel = jnp.any(idx[..., None] == kpos, axis=-2) & causal
+        return pack_selection(sel)
+
+    n = (T + pad) // blk
+    bits = jax.lax.map(block, (jnp.moveaxis(qi.reshape(B, n, blk, -1), 1, 0),
+                               jnp.moveaxis(wi.reshape(B, n, blk, -1), 1, 0),
+                               jnp.arange(n) * blk))
+    return jnp.moveaxis(bits, 0, 1).reshape(B, T + pad, -1)[:, :T]
+
+
+INDEX_QUERY_BLOCK = 128     # queries a step of `mla_index`: their scores stay in VMEM
+INDEX_KEY_BLOCK = 512       # keys a product of the scores
+
+
+def _sortable(x):
+    """float32 -> int32 in the same order (-0.0 as +0.0)."""
+    import jax
+    import jax.numpy as jnp
+
+    u = jax.lax.bitcast_convert_type(x + 0.0, jnp.int32)
+    return u ^ (jax.lax.shift_right_arithmetic(u, 31) & 0x7FFFFFFF)
+
+
+def _index_kernel(q_ref, k_ref, w_ref, o_ref, s_scr, *, bq: int, bk: int,
+                  heads: int, dim: int, topk: int, groups: int):
+    """One block of ``bq`` queries: their scores against every key block up
+    to the diagonal into VMEM (``s_scr``: 128-lane chunks of sortable int32,
+    keys past a query at the least int32), the exact ``topk``-th largest a
+    query by a bitwise search of 32 passes, the lower positions of a tie at
+    that threshold by a search of positions only where a tie crosses it, and
+    the bits written."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    first = pl.program_id(1) * bq
+    last = (first + bq - 1) // bk                  # the key block of the diagonal
+    per = bk // 128
+    qpos = first + jax.lax.broadcasted_iota(jnp.int32, (bq, 128), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (bq, 128), 1)
+
+    def scores(kb, carry):
+        at = pl.multiple_of(kb * bk, bk)
+        k = k_ref[pl.ds(at, bk), :]
+        acc = jnp.zeros((bq, bk), jnp.float32)
+        for j in range(heads):
+            s = jax.lax.dot_general(q_ref[:, j * dim:(j + 1) * dim], k,
+                                    (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            acc = acc + jnp.maximum(s, 0.0) * w_ref[:, j:j + 1]
+        key = _sortable(acc)
+        for c in range(per):
+            s_scr[kb * per + c] = jnp.where(at + c * 128 + lane <= qpos,
+                                            key[:, c * 128:(c + 1) * 128], _INT_MIN)
+        return carry
+
+    jax.lax.fori_loop(0, last + 1, scores, 0)
+
+    def count(test):
+        """(bq, 128), the same in every lane: a query's keys for which
+        ``test(key, position)`` holds, over the chunks that hold scores."""
+        def body(c, acc):
+            return acc + test(s_scr[c], c * 128 + lane).astype(jnp.int32)
+
+        acc = jax.lax.fori_loop(0, (last + 1) * per, body, jnp.zeros((bq, 128), jnp.int32))
+        return jnp.broadcast_to(jnp.sum(acc, axis=1, keepdims=True), (bq, 128))
+
+    def bit(b, tau):                               # tau in the unsigned order
+        cand = tau | jax.lax.shift_left(jnp.int32(1), 31 - b)
+        ok = count(lambda key, _pos: key >= (cand ^ _INT_MIN)) >= topk
+        return jnp.where(ok, cand, tau)
+
+    tau = jax.lax.fori_loop(0, 32, bit, jnp.zeros((bq, 128), jnp.int32)) ^ _INT_MIN
+    need = topk - count(lambda key, _pos: key > tau)   # of the keys equal to tau
+    over = count(lambda key, _pos: key >= tau) - topk
+    # a query with fewer causal keys than `topk` keeps them all (tau the least int32)
+    tied = jnp.max(jnp.where(tau == _INT_MIN, 0, over)) > 0
+    nbits = (groups * SELECT_GROUP - 1).bit_length()
+
+    def cutoff():
+        """The position of the ``need``-th key equal to tau."""
+        def pos_bit(b, r):
+            cand = r + jax.lax.shift_left(jnp.int32(1), nbits - 1 - b)
+            seen = count(lambda key, pos: (key == tau) & (pos < cand))
+            return jnp.where(seen < need, cand, r)
+
+        return jax.lax.fori_loop(0, nbits, pos_bit, jnp.zeros((bq, 128), jnp.int32))
+
+    p = jax.lax.cond(tied, cutoff, lambda: jnp.full((bq, 128), 2 ** 31 - 1, jnp.int32))
+    done = (first + bq - 1) // SELECT_GROUP       # the last group with causal keys
+
+    for g in range(groups):
+        @pl.when(g <= done)
+        def _write():
+            word = jnp.zeros((bq, 128), jnp.int32)
+            for c in range(32):
+                key = s_scr[g * 32 + c]
+                pos = g * SELECT_GROUP + c * 128 + lane
+                sel = ((key > tau) | ((key == tau) & (pos <= p))) & (pos <= qpos)
+                word = word | jax.lax.shift_left(sel.astype(jnp.int32), c)
+            o_ref[:, g * 128:(g + 1) * 128] = word
+
+        @pl.when(g > done)
+        def _nothing():
+            o_ref[:, g * 128:(g + 1) * 128] = jnp.zeros((bq, 128), jnp.int32)
+
+
+def mla_index_pallas(qi, ki, wi, heads: int, topk: int, interpret: bool = False):
+    """The kernel form of ``mla_index_xla`` (same arguments and result): a
+    grid step is a block of queries, every key of the row is in VMEM."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, T, _ = qi.shape
+    dim = ki.shape[2]
+    bq, bk = INDEX_QUERY_BLOCK, min(INDEX_KEY_BLOCK, T)
+    words = selection_words(T)
+    ki = jnp.pad(ki, ((0, 0), (0, words * 32 - T), (0, 0)))
+    pairs = T * (T + 1) / 2
+    return pl.pallas_call(
+        functools.partial(_index_kernel, bq=bq, bk=bk, heads=heads, dim=dim,
+                          topk=topk, groups=words // 128),
+        grid=(B, T // bq),
+        in_specs=[pl.BlockSpec((None, bq, heads * dim), lambda b, i: (b, i, 0)),
+                  pl.BlockSpec((None, words * 32, dim), lambda b, i: (b, 0, 0)),
+                  pl.BlockSpec((None, bq, heads), lambda b, i: (b, i, 0))],
+        out_specs=pl.BlockSpec((None, bq, words), lambda b, i: (b, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, T, words), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((words // 4, bq, 128), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=100 * 1024 * 1024),
+        name="mla_index",        # the name a device trace shows
+        cost_estimate=pl.CostEstimate(
+            flops=int(2 * B * heads * dim * pairs), transcendentals=0,
+            bytes_accessed=int(qi.size * qi.dtype.itemsize + ki.size * ki.dtype.itemsize
+                               + 4 * B * T * (heads + words))),
+        interpret=interpret,
+    )(qi, ki, wi)
+
+
+def mla_sparse_xla(q, kv, kr, bits, heads: int, dn: int):
+    """``mla_xla`` over the keys each query's ``bits`` select."""
+    return mla_xla(q, kv, kr, heads, dn, bits)
+
+
+def _mla_sparse_kernel(q_ref, kv_ref, kr_ref, b_ref, o_ref, m_scr, l_scr, acc_scr, *,
+                       blk: int, group: int, dn: int, dq: int, dv: int):
+    """``_mla_kernel`` with every block masked by the query's selection: the
+    block's 128-lane chunks of keys are bits of the one tile of words the
+    block lies in, unpacked in place. The selection lies inside the causal
+    keys, so the diagonal block needs nothing more."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    qi, j = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, _NEG)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    @pl.when(j <= qi)
+    def _block():
+        words = b_ref[...]
+        c0 = (j * blk) % SELECT_GROUP // 128
+        seen = jnp.concatenate(
+            [jax.lax.shift_right_logical(words, c0 + c) & 1 for c in range(blk // 128)],
+            axis=1) != 0
+        kr = kr_ref[...]
+        for g in range(group):
+            at = g * (dn + dv)
+            k = jnp.concatenate([kv_ref[:, at:at + dn], kr], axis=1)
+            s = jax.lax.dot_general(q_ref[:, g * dq:(g + 1) * dq], k,
+                                    (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            _softmax_step(s, seen, kv_ref[:, at + dn:at + dn + dv],
+                          m_scr, l_scr, acc_scr, g)
+
+    @pl.when(j == qi)
+    def _store():
+        for g in range(group):
+            o_ref[:, g * dv:(g + 1) * dv] = (
+                acc_scr[g] / l_scr[g][:, :1]).astype(o_ref.dtype)
+
+
+def mla_sparse_pallas(q, kv, kr, bits, heads: int, dn: int, interpret: bool = False):
+    """The kernel form of ``mla_sparse_xla`` (same arguments and result):
+    ``mla_pallas``'s grid and blocks, the selection's tile of words beside
+    each key block."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, T, _ = q.shape
+    dq, dv = q.shape[2] // heads, kv.shape[2] // heads - dn
+    group = next(g for g in (MLA_HEADS_A_STEP, 2, 1) if heads % g == 0)
+    blk = _attn_blocks(T, 0)[0]
+
+    def key_index(b, h, qi, j):
+        return b, jnp.minimum(j, qi), h      # past the diagonal nothing is fetched
+
+    def bits_index(b, h, qi, j):
+        return b, qi, jnp.minimum(j, qi) * blk // SELECT_GROUP
+
+    pairs = T * (T + 1) / 2
+    return pl.pallas_call(
+        functools.partial(_mla_sparse_kernel, blk=blk, group=group, dn=dn, dq=dq, dv=dv),
+        grid=(B, heads // group, T // blk, T // blk),
+        in_specs=[pl.BlockSpec((None, blk, group * dq), lambda b, h, qi, j: (b, qi, h)),
+                  pl.BlockSpec((None, blk, group * (dn + dv)), key_index),
+                  pl.BlockSpec((None, blk, dq - dn),
+                               lambda b, h, qi, j: (b, jnp.minimum(j, qi), 0)),
+                  pl.BlockSpec((None, blk, 128), bits_index)],
+        out_specs=pl.BlockSpec((None, blk, group * dv), lambda b, h, qi, j: (b, qi, h)),
+        out_shape=jax.ShapeDtypeStruct((B, T, heads * dv), q.dtype),
+        scratch_shapes=[pltpu.VMEM((group, blk, 128), jnp.float32),
+                        pltpu.VMEM((group, blk, 128), jnp.float32),
+                        pltpu.VMEM((group, blk, dv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        name="attn_mla_sparse",  # the name a device trace shows
+        cost_estimate=pl.CostEstimate(
+            flops=int(2 * B * heads * (dq + dv) * pairs),
+            transcendentals=int(B * heads * pairs),
+            bytes_accessed=int(q.size * q.dtype.itemsize * (1 + dv / dq)
+                               + kv.size * kv.dtype.itemsize * (T // blk + 1) / 2
+                               + bits.size * 4 * heads // group)),
+        interpret=interpret,
+    )(q, kv, kr, bits)
+
+
+@functools.lru_cache(maxsize=None)
+def _mla_sparse_kernel_vjp(interpret: bool = False):
+    """The kernel with a backward pass: the plain form's, recomputed."""
+    import jax
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+    def attend(q, kv, kr, bits, heads, dn):
+        return mla_sparse_pallas(q, kv, kr, bits, heads, dn, interpret)
+
+    def fwd(q, kv, kr, bits, heads, dn):
+        return mla_sparse_pallas(q, kv, kr, bits, heads, dn, interpret), (q, kv, kr, bits)
+
+    def bwd(heads, dn, res, g):
+        q, kv, kr, bits = res
+        return (*jax.vjp(lambda q, kv, kr: mla_sparse_xla(q, kv, kr, bits, heads, dn),
+                         q, kv, kr)[1](g), None)
+
+    attend.defvjp(fwd, bwd)
+    return attend
+
+
+def _index_pallas_applies(x, index_dim: int) -> bool:
+    """Whether the indexer takes ``mla_index``: the latent kernel's
+    conditions (a TPU, bfloat16, block-aligned ``T``), heads of whole tiles,
+    and a row whose scores for a block of queries and whose keys fit VMEM."""
+    import jax
+    import jax.numpy as jnp
+
+    T = x.shape[1]
+    words = selection_words(T) * 32
+    return (jax.default_backend() == "tpu" and _mm_dtype() == jnp.bfloat16
+            and index_dim % 128 == 0 and T % INDEX_QUERY_BLOCK == 0
+            and words * (4 * INDEX_QUERY_BLOCK + 4 * index_dim) <= 80 * 2 ** 20)
+
+
+# ---------------------------------------------------------------------------
 # modules
 # ---------------------------------------------------------------------------
 
@@ -764,6 +1127,36 @@ class RMSNorm(Module):
 
     def apply(self, params, x, train: bool = False):
         return rms_norm(x, params["scale"], self.eps)
+
+
+class GatedRMSNorm(Module):
+    """RMSNorm with a low-rank sigmoid gate on its own output: ``n =
+    RMSNorm(x)``, ``n sigmoid((n A) B)``, ``A [D, rank]``, ``B [rank, D]``
+    (the products with the matmul dtype's operands, float32 accumulation);
+    float32 in the arithmetic and the result."""
+
+    def __init__(self, eps: float = 1e-5, rank: int = 16, param_dtype: str = "float32"):
+        self.eps, self.rank, self.param_dtype = eps, rank, param_dtype
+
+    def init(self, rng, in_shape):
+        d = in_shape[-1]
+        ka, kb = _rng_split(rng, 2)
+        return {"scale": np.ones((d,), self.param_dtype),
+                "gate_a": _normal(ka, (d, self.rank), d ** -0.5, self.param_dtype),
+                "gate_b": _normal(kb, (self.rank, d), self.rank ** -0.5,
+                                  self.param_dtype)}, tuple(in_shape)
+
+    def apply(self, params, x, train: bool = False):
+        import jax
+        import jax.numpy as jnp
+
+        dt = _mm_dtype()
+        n = rms_norm(x, params["scale"], self.eps)
+        low = jnp.dot(n.astype(dt), jnp.asarray(params["gate_a"]).astype(dt),
+                      preferred_element_type=jnp.float32)
+        gate = jnp.dot(low.astype(dt), jnp.asarray(params["gate_b"]).astype(dt),
+                       preferred_element_type=jnp.float32)
+        return n * jax.nn.sigmoid(gate)
 
 
 class GQAttention(Module):
@@ -833,33 +1226,104 @@ class LatentAttention(Module):
     ``[k_nope (nope), v (v_dim)] = RMSNorm(c_kv) W_kvb``; rotary positions
     (``rope_theta``, ``rope_scaling``: YaRN) on ``q_rope`` and on ``k_r``,
     which all heads share; ``s = (q_nope . k_nope + q_rope . k_r) (nope +
-    rope)^-0.5 m^2`` (``yarn_softmax_scale``); output ``(P v) W_o``."""
+    rope)^-0.5 m^2`` (``yarn_softmax_scale``); output ``(P v) W_o``.
+
+    **Sparse** (``index_topk`` > 0; DeepSeek Sparse Attention's lightning
+    indexer): ``index_heads`` heads of ``index_dim``, ``q^I = c_q W^I_q`` (the
+    latent before the softmax scale), one key ``k^I = LayerNorm(x W^I_k)``,
+    rotary positions on the first ``rope`` lanes of both, ``w = x W^I_w
+    index_heads^-0.5``; ``I[t, s] = index_dim^-0.5 sum_j w_j relu(q^I_j .
+    k^I_s)``, and a query's softmax runs over its ``index_topk`` keys of
+    largest ``I`` alone (every key it has where it has fewer: a row no
+    longer than ``index_topk`` is the dense layer). Scopes ``index`` (the
+    indexer's projections, norm and positions), ``select`` (the scores and
+    the choice: ``mla_index``), ``core``.
+
+    **Gated** (``out_gate``): ``W_qb`` is ``v_dim`` columns a head wider,
+    ``[q_nope, q_rope, g] = c_q W_qb``, and a head's output is ``o
+    sigmoid(g)`` before ``W_o``.
+
+    ``index_topk`` 0 and ``out_gate`` False are the dense, ungated layer."""
+
+    index_topk, out_gate = 0, False      # a layer pickled before them has neither
 
     def __init__(self, heads: int, q_rank: int, kv_rank: int, nope: int, rope: int,
                  v_dim: int, rope_theta: float = 10000.0,
                  rope_scaling: Optional[Dict[str, Any]] = None,
-                 eps: float = 1e-6, param_dtype: str = "float32"):
+                 eps: float = 1e-6, param_dtype: str = "float32",
+                 index_heads: int = 0, index_dim: int = 0, index_topk: int = 0,
+                 out_gate: bool = False):
         self.heads, self.q_rank, self.kv_rank = heads, q_rank, kv_rank
         self.nope, self.rope, self.v_dim = nope, rope, v_dim
         self.rope_theta, self.rope_scaling = rope_theta, rope_scaling
         self.eps, self.param_dtype = eps, param_dtype
+        self.index_heads, self.index_dim = index_heads, index_dim
+        self.index_topk, self.out_gate = index_topk, out_gate
 
     def init(self, rng, in_shape):
         t, d = in_shape
         h, dt = self.heads, self.param_dtype
-        keys = _rng_split(rng, 5)
-        return {"wq_a": _normal(keys[0], (d, self.q_rank), d ** -0.5, dt),
-                "q_norm": np.ones((self.q_rank,), dt),
-                "wq_b": _normal(keys[1], (self.q_rank, h * (self.nope + self.rope)),
-                                self.q_rank ** -0.5, dt),
-                "wkv_a": _normal(keys[2], (d, self.kv_rank + self.rope), d ** -0.5, dt),
-                "kv_norm": np.ones((self.kv_rank,), dt),
-                "wkv_b": _normal(keys[3], (self.kv_rank, h * (self.nope + self.v_dim)),
-                                 self.kv_rank ** -0.5, dt),
-                "wo": _normal(keys[4], (h * self.v_dim, d),
-                              (h * self.v_dim) ** -0.5, dt)}, (t, d)
+        keys = _rng_split(rng, 8)
+        head = self.nope + self.rope + (self.v_dim if self.out_gate else 0)
+        params = {"wq_a": _normal(keys[0], (d, self.q_rank), d ** -0.5, dt),
+                  "q_norm": np.ones((self.q_rank,), dt),
+                  "wq_b": _normal(keys[1], (self.q_rank, h * head), self.q_rank ** -0.5, dt),
+                  "wkv_a": _normal(keys[2], (d, self.kv_rank + self.rope), d ** -0.5, dt),
+                  "kv_norm": np.ones((self.kv_rank,), dt),
+                  "wkv_b": _normal(keys[3], (self.kv_rank, h * (self.nope + self.v_dim)),
+                                   self.kv_rank ** -0.5, dt),
+                  "wo": _normal(keys[4], (h * self.v_dim, d),
+                                (h * self.v_dim) ** -0.5, dt)}
+        if self.index_topk:
+            ih, idim = self.index_heads, self.index_dim
+            params.update(
+                index_wq=_normal(keys[5], (self.q_rank, ih * idim), self.q_rank ** -0.5, dt),
+                index_wk=_normal(keys[6], (d, idim), d ** -0.5, dt),
+                index_k_scale=np.ones((idim,), dt), index_k_bias=np.zeros((idim,), dt),
+                index_ww=_normal(keys[7], (d, ih), d ** -0.5, dt))
+        return params, (t, d)
+
+    def index_inputs(self, params, x, c_q):
+        """The indexer's ``(qi [B, T, heads * dim], ki [B, T, dim], wi [B, T,
+        heads])`` for ``mla_index`` from the layer's input ``x`` and its
+        query latent ``c_q`` (after its norm, before the softmax scale)."""
+        import jax.numpy as jnp
+
+        from .attention import LayerNorm
+
+        dt = _mm_dtype()
+        B, T, _ = x.shape
+        ih, idim, rope = self.index_heads, self.index_dim, self.rope
+
+        def dot(a, name, out=jnp.float32):
+            return jnp.dot(a.astype(dt), jnp.asarray(params[name]).astype(dt),
+                           preferred_element_type=out)
+
+        def positions(a):                  # rotary positions on the first lanes
+            return jnp.concatenate(
+                [rotary(a[..., :rope].astype(jnp.float32), self.rope_theta,
+                        self.rope_scaling).astype(dt), a[..., rope:].astype(dt)], axis=-1)
+
+        # the queries leave their product in the matmul dtype: [T, heads * dim]
+        qi = positions(dot(c_q, "index_wq", dt).reshape(B, T, ih, idim)).reshape(B, T, -1)
+        ki = LayerNorm(self.eps).apply(
+            {"scale": jnp.asarray(params["index_k_scale"]).astype(jnp.float32),
+             "bias": jnp.asarray(params["index_k_bias"]).astype(jnp.float32)},
+            dot(x, "index_wk"))
+        ki = positions(ki[:, :, None, :]).reshape(B, T, idim)
+        return qi, ki, dot(x, "index_ww") * np.float32(ih ** -0.5 * idim ** -0.5)
+
+    def _selection(self, params, x, c_q):
+        """The selection bits of every query (``pack_selection``'s layout)."""
+        with scope("index"):
+            qi, ki, wi = self.index_inputs(params, x, c_q)
+        with scope("select"):
+            if _index_pallas_applies(x, self.index_dim):
+                return mla_index_pallas(qi, ki, wi, self.index_heads, self.index_topk)
+            return mla_index_xla(qi, ki, wi, self.index_heads, self.index_topk)
 
     def apply(self, params, x, train: bool = False):
+        import jax
         import jax.numpy as jnp
 
         dt = _mm_dtype()
@@ -878,14 +1342,29 @@ class LatentAttention(Module):
             with scope("core"):                      # laid out as the core reads it
                 return jnp.pad(a, ((0, 0),) * 3 + ((0, lanes),)).astype(dt)
 
-        # the softmax scale rides on the queries' latent: one pass over [T, q_rank]
+        def columns(first, last):          # of every head's columns of W_qb
+            w = jnp.asarray(params["wq_b"]).astype(dt)
+            return w.reshape(self.q_rank, h, -1)[..., first:last].reshape(self.q_rank, -1)
+
+        # the softmax scale rides on the queries' latent: one pass over [T,
+        # q_rank]. Where W_qb holds the gates' columns too, the queries'
+        # columns and the gates' are two products: the gates' comes after the
+        # core, so the two never stand in memory together, and the queries'
+        # leaves its product in the matmul dtype (a float32 copy of it would be
+        # the layer's largest array)
         scale = (nope + rope) ** -0.5 * yarn_softmax_scale(self.rope_scaling)
+        sparse = self.index_topk and T > self.index_topk
         with scope("proj_in"):
-            c_q = rms_norm(dot(x, "wq_a"), params["q_norm"], self.eps) * np.float32(scale)
-            q = dot(c_q, "wq_b").reshape(B, T, h, nope + rope)
+            c_q = rms_norm(dot(x, "wq_a"), params["q_norm"], self.eps)
+            if self.out_gate:
+                q = jnp.dot((c_q * np.float32(scale)).astype(dt), columns(0, nope + rope),
+                            preferred_element_type=dt).reshape(B, T, h, nope + rope)
+            else:
+                q = dot(c_q * np.float32(scale), "wq_b").reshape(B, T, h, nope + rope)
+        bits = self._selection(params, x, c_q) if sparse else None
         with scope("core"):
             q_nope = q[..., :nope].astype(dt)
-        q_rope = positions(q[..., nope:])
+        q_rope = positions(q[..., nope:].astype(jnp.float32))
         with scope("core"):
             q = jnp.concatenate([q_nope, q_rope], axis=-1).reshape(B, T, -1)
         with scope("proj_in"):
@@ -894,10 +1373,17 @@ class LatentAttention(Module):
         with scope("proj_in"):
             kv = dot(rms_norm(ckv[..., :self.kv_rank], params["kv_norm"], self.eps),
                      "wkv_b", dt)                    # a head's keys, then its values
-        attend = _mla_kernel_vjp() if kernel else mla_xla
         with scope("core"):
-            o = attend(q, kv, kr, h, nope)
+            if bits is None:
+                o = (_mla_kernel_vjp() if kernel else mla_xla)(q, kv, kr, h, nope)
+            else:
+                o = (_mla_sparse_kernel_vjp() if kernel else mla_sparse_xla)(
+                    q, kv, kr, bits, h, nope)
         with scope("proj_out"):
+            if self.out_gate:
+                gate = jnp.dot(c_q.astype(dt), columns(nope + rope, None),
+                               preferred_element_type=jnp.float32)
+                o = (o.astype(jnp.float32) * jax.nn.sigmoid(gate)).astype(dt)
             return dot(o, "wo")
 
 
@@ -1037,11 +1523,14 @@ class SwiGLU(Module):
                        preferred_element_type=jnp.float32)
 
 
-def _norm(kind: str, eps: float, param_dtype: str) -> Module:
-    """A sublayer's norm: ``"rms"`` (a gain) or ``"layer"`` (mean, gain and
-    bias), float32 in the arithmetic and, on float32 input, the result."""
+def _norm(kind: str, eps: float, param_dtype: str, rank: int = 0) -> Module:
+    """A sublayer's norm: ``"rms"`` (a gain), ``"gated"`` (``GatedRMSNorm``
+    of ``rank``) or ``"layer"`` (mean, gain and bias), float32 in the
+    arithmetic and, on float32 input, the result."""
     if kind == "rms":
         return RMSNorm(eps, param_dtype)
+    if kind == "gated":
+        return GatedRMSNorm(eps, rank, param_dtype)
     if kind == "layer":
         from .attention import LayerNorm
 
@@ -1052,7 +1541,8 @@ def _norm(kind: str, eps: float, param_dtype: str) -> Module:
 class DecoderLayer(Module):
     """Two sublayers, a token mixer and an FFN: a ``SwiGLU`` (``mlp``), or an
     ``ExpertLayer`` (``moe``) beside a shared ``SwiGLU`` (``shared``), each
-    behind its norm (``norm``: RMSNorm, or LayerNorm with a bias) and inside
+    behind its norm (``norm``: RMSNorm, a gated RMSNorm of ``norm_rank``, or
+    LayerNorm with a bias) and inside
     the layer's residual path, which is a part of the layer.
 
     **The mixer** (the first argument; its part, its norm ``<mixer>_norm``
@@ -1090,13 +1580,13 @@ class DecoderLayer(Module):
                  moe: Optional[Module] = None, shared: Optional[SwiGLU] = None,
                  eps: float = 1e-5, param_dtype: str = "float32",
                  hyper: Optional[Module] = None, norm: str = "rms",
-                 mixer: str = "attn"):
+                 mixer: str = "attn", norm_rank: int = 0):
         if (mlp is None) == (moe is None):
             raise ValueError("a layer has a dense MLP or an expert layer, one of them")
         self.mixer = mixer
         self.parts: List[Tuple[str, Module]] = [
-            (mixer + "_norm", _norm(norm, eps, param_dtype)), (mixer, attn),
-            ("mlp_norm", _norm(norm, eps, param_dtype))]
+            (mixer + "_norm", _norm(norm, eps, param_dtype, norm_rank)), (mixer, attn),
+            ("mlp_norm", _norm(norm, eps, param_dtype, norm_rank))]
         self.parts += [(n, m) for n, m in (("mlp", mlp), ("moe", moe),
                                            ("shared", shared)) if m is not None]
         if hyper is not None:        # one module, a set of parameters a sublayer
@@ -1347,11 +1837,19 @@ def latent_causal_lm(seq_len: int, vocab_size: int, hidden: int, heads: int,
                      hc_eps: float = 1e-6, hc_clamp: Tuple[float, float] = (-30.0, 30.0),
                      eps: float = 1e-6, pad_id: int = 0,
                      param_dtype: str = "float32", seed: int = 0,
-                     init: bool = True) -> FunctionModel:
+                     init: bool = True, index_heads: int = 0, index_dim: int = 0,
+                     index_topk: int = 0, out_gate: bool = False,
+                     gated_norm_rank: int = 0, n_group: int = 1,
+                     topk_group: int = 1) -> FunctionModel:
     """``causal_lm``'s sibling for a model with latent attention and, where
     ``streams`` > 1, a hyper-connected residual path: the same ``CausalLM`` of
     the same ``DecoderLayer`` s, every layer a ``LatentAttention`` and an
-    expert layer where ``sparse[i]``, else a dense SwiGLU."""
+    expert layer where ``sparse[i]``, else a dense SwiGLU. ``index_*`` make
+    the attention sparse and ``out_gate`` gates its heads (``LatentAttention``);
+    ``gated_norm_rank`` > 0 gates the two norms before a layer's sublayers
+    (``GatedRMSNorm``; the final norm stays plain); ``n_group`` /
+    ``topk_group`` limit the routing to groups (``moe.ExpertLayer``). Their
+    defaults are the model without any of them."""
     import jax
 
     from .moe import ExpertLayer
@@ -1359,21 +1857,25 @@ def latent_causal_lm(seq_len: int, vocab_size: int, hidden: int, heads: int,
 
     hyper = HyperConnection(streams, sinkhorn_iters, hc_eps, hc_clamp, eps,
                             param_dtype) if streams > 1 else None
+    norm = dict(norm="gated", norm_rank=gated_norm_rank) if gated_norm_rank else {}
     layers = []
     for is_sparse in sparse:
         attn = LatentAttention(heads, q_rank, kv_rank, nope, rope, v_dim, rope_theta,
-                               rope_scaling, eps, param_dtype)
+                               rope_scaling, eps, param_dtype, index_heads, index_dim,
+                               index_topk, out_gate)
         if is_sparse:
             moe = ExpertLayer(num_experts, experts_held, top_k, expert_hidden,
                               scoring=scoring, norm_topk=norm_topk, scale=scale,
-                              first_expert=first_expert, param_dtype=param_dtype)
+                              first_expert=first_expert, param_dtype=param_dtype,
+                              n_group=n_group, topk_group=topk_group)
             shared = SwiGLU(expert_hidden * shared_experts, param_dtype) \
                 if shared_experts else None
             layers.append(DecoderLayer(attn, moe=moe, shared=shared, eps=eps,
-                                       param_dtype=param_dtype, hyper=hyper))
+                                       param_dtype=param_dtype, hyper=hyper, **norm))
         else:
             layers.append(DecoderLayer(attn, mlp=SwiGLU(dense_hidden, param_dtype),
-                                       eps=eps, param_dtype=param_dtype, hyper=hyper))
+                                       eps=eps, param_dtype=param_dtype, hyper=hyper,
+                                       **norm))
     module = CausalLM(vocab_size, hidden, layers, pad_id, eps, param_dtype, streams)
     params = module.init(jax.random.key(seed), (seq_len,))[0] if init else {}
     names = [CausalLM.LOAD] + [f"layer{i}" for i in reversed(range(len(layers)))]

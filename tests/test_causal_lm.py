@@ -610,3 +610,29 @@ def test_the_differential_kernel_compiles_at_the_published_widths(window, one_ch
                           q, kv, kv, lam, gain)
     assert "tpu_custom_call" in text
     assert ("attn_window_diff" if window else "attn_full_diff") in text
+
+
+def test_the_index_kernel_compiles_at_the_published_widths(one_chip):
+    """A.X-K2's indexer: 64 heads of 128 against one key a position, the top
+    2,048 of a row of 32,768, the scores of a block of 128 queries in VMEM."""
+    B, t = 1, 32768
+    qi = jax.ShapeDtypeStruct((B, t, 64 * 128), jnp.bfloat16, sharding=one_chip)
+    ki = jax.ShapeDtypeStruct((B, t, 128), jnp.bfloat16, sharding=one_chip)
+    wi = jax.ShapeDtypeStruct((B, t, 64), jnp.float32, sharding=one_chip)
+    text = _compiled_text(lambda *a: transformer.mla_index_pallas(*a, 64, 2048), qi, ki, wi)
+    assert "tpu_custom_call" in text and "mla_index" in text
+    assert "s32[1,32768,1024]" in text                 # a bit a key
+
+
+def test_the_sparse_latent_kernel_compiles_at_the_published_widths(one_chip):
+    """A.X-K2's core: 64 heads, a 192-wide score padded to 256 against the
+    shared rotary key, values of 128, masked by a selection's bits, a row of
+    32,768."""
+    B, t, H = 1, 32768, 64
+    q = jax.ShapeDtypeStruct((B, t, H * 256), jnp.bfloat16, sharding=one_chip)
+    kr = jax.ShapeDtypeStruct((B, t, 128), jnp.bfloat16, sharding=one_chip)
+    bits = jax.ShapeDtypeStruct((B, t, transformer.selection_words(t)), jnp.int32,
+                                sharding=one_chip)
+    text = _compiled_text(lambda q, kv, kr, b: transformer.mla_sparse_pallas(
+        q, kv, kr, b, H, 128), q, q, kr, bits)
+    assert "tpu_custom_call" in text and "attn_mla_sparse" in text
