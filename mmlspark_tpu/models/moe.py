@@ -23,12 +23,14 @@ the equality test (sharded == single-device) runs on an 8-device mesh.
 expert parallelism asks of a chip anyway: told how many experts there are
 and which of them it holds, it routes every token over all of them (top-k,
 no capacity, nothing dropped) and computes the part of the result its own
-experts give. The visits to its experts are gathered sorted by expert, go
-through one grouped product for gate/up and one for down (``moe_gmm``, a
-Pallas kernel on a TPU; ``lax.ragged_dot`` elsewhere), and are combined by
-weight into the token order (``moe_combine``, a Pallas kernel where a layer on
-a TPU holds a share of the experts; a scan over the choices elsewhere): memory
-and work grow with the visits, never with tokens x experts. On one chip it
+experts give. The visits to its experts are sorted by expert, go through one
+grouped product for gate/up and one for down (``moe_gmm``, a Pallas kernel on
+a TPU, whose first product copies its visits' rows itself, ``moe_gmm_gather``,
+where two row tiles fit VMEM; ``lax.ragged_dot`` on rows XLA gathers
+elsewhere), and are combined by weight into the token order (``moe_combine``,
+a Pallas kernel where a layer on a TPU holds a share of the experts; a scan
+over the choices elsewhere): memory and work grow with the visits, never
+with tokens x experts. On one chip it
 runs without an exchange; ``MoE`` keeps the mesh tests until ``ExpertLayer``
 has one.
 """
@@ -124,6 +126,19 @@ def _whole_tile(dim: int, most: int) -> int:
     return next((t for t in range(most, 0, -128) if dim % t == 0), most)
 
 
+def _tile_groups(group_sizes, tiles: int, tm: int):
+    """(each row tile's group, ``[tiles]``; the number of live tiles, ``[1]``)
+    for groups of ``group_sizes`` rows, each whole tiles of ``tm``: a tile
+    past the last group's rows takes the last group."""
+    import jax.numpy as jnp
+
+    ends = jnp.cumsum(group_sizes.astype(jnp.int32))
+    tile_start = jnp.arange(tiles, dtype=jnp.int32) * tm
+    group_of = jnp.minimum(jnp.sum(tile_start[:, None] >= ends[None, :], axis=1),
+                           group_sizes.shape[0] - 1).astype(jnp.int32)
+    return group_of, (ends[-1:] // tm).astype(jnp.int32)
+
+
 def _gmm_kernel(group_of, active, lhs_ref, rhs_ref, out_ref, acc_scr, *, k_steps):
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -157,15 +172,11 @@ def gmm_pallas(lhs, rhs, group_sizes, out_dtype, interpret: bool = False):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    (M, K), (G, _, N) = lhs.shape, rhs.shape
+    (M, K), (_, _, N) = lhs.shape, rhs.shape
     tm, tk, tn = (_whole_tile(d, t) for t, d in zip(GMM_TILE, (M, K, N)))
     if M % tm or K % tk or N % tn:
         raise ValueError(f"moe_gmm: {(M, K, N)} is not whole tiles of {(tm, tk, tn)}")
-    ends = jnp.cumsum(group_sizes.astype(jnp.int32))
-    tile_start = jnp.arange(M // tm, dtype=jnp.int32) * tm
-    group_of = jnp.minimum(jnp.sum(tile_start[:, None] >= ends[None, :], axis=1),
-                           G - 1).astype(jnp.int32)
-    active = (ends[-1:] // tm).astype(jnp.int32)
+    group_of, active = _tile_groups(group_sizes, M // tm, tm)
 
     def at(i, j, kk, active):
         """(row tile, column tile, contraction step) a step reads: a tile past
@@ -214,16 +225,17 @@ def _gmm_ragged(lhs, rhs, group_sizes, out_dtype):
 
 
 @functools.lru_cache(maxsize=None)
-def _gmm_kernel_vjp():
+def _gmm_kernel_vjp(interpret: bool = False):
     """The kernel with a backward pass: the ragged product's, recomputed."""
     import jax
 
     @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
     def gmm(lhs, rhs, group_sizes, out_dtype):
-        return gmm_pallas(lhs, rhs, group_sizes, out_dtype)
+        return gmm_pallas(lhs, rhs, group_sizes, out_dtype, interpret)
 
     def fwd(lhs, rhs, group_sizes, out_dtype):
-        return gmm_pallas(lhs, rhs, group_sizes, out_dtype), (lhs, rhs, group_sizes)
+        return (gmm_pallas(lhs, rhs, group_sizes, out_dtype, interpret),
+                (lhs, rhs, group_sizes))
 
     def bwd(out_dtype, res, g):
         lhs, rhs, group_sizes = res
@@ -249,6 +261,271 @@ def grouped_matmul(lhs, rhs, group_sizes, out_dtype, tile_rows: int):
     if tile_rows > 1:
         return _gmm_kernel_vjp()(lhs, rhs, group_sizes, out_dtype)
     return _gmm_ragged(lhs, rhs, group_sizes, out_dtype)
+
+
+# ---------------------------------------------------------------------------
+# the same product with its rows gathered as they load
+# ---------------------------------------------------------------------------
+
+GATHER_VMEM = 48 * 1024 * 1024   # what a step of the gathering product may hold
+
+
+def _gather_lanes(K: int) -> int:
+    """Rows of 128 lanes one token's ``K`` values are laid out in for its
+    copy: whole ``(8, 128)`` float32 tiles, so that a token is a slice of the
+    major dimension (one row of a tiled ``[T, K]`` is no whole tile)."""
+    return -(-K // 1024) * 8
+
+
+def _gather_vmem_bytes(K: int, N: int) -> int:
+    """VMEM a step of ``gmm_gather_pallas`` holds: the float32 copies of two
+    row tiles, the tile in bfloat16 for the product, the weight and output
+    blocks (two each) and the float32 accumulator."""
+    tm, tk, tn = (_whole_tile(d, t) for t, d in zip(GMM_TILE, (GMM_TILE[0], K, N)))
+    return (2 * tm * _gather_lanes(K) * 128 * 4 + tm * K * 2
+            + 2 * tk * tn * 2 + 2 * tm * tn * 2 + tm * tn * 4)
+
+
+def _gmm_gathers(backend: str, dtype, K: int, N: int) -> bool:
+    """The gathering form where ``moe_gmm`` runs (a TPU, bfloat16 operands),
+    its contraction is whole tiles of 128 lanes and its step fits VMEM;
+    elsewhere XLA gathers the rows and ``gmm_pallas`` takes them."""
+    import jax.numpy as jnp
+
+    return (backend == "tpu" and dtype == jnp.bfloat16 and K % 128 == 0
+            and _gather_vmem_bytes(K, N) <= GATHER_VMEM)
+
+
+def _gather_kernel(group_of, active, real, token_hbm, rows_hbm, rhs_ref, out_ref,
+                   copies, lhs, idx, sem, idx_sem, acc_scr, *, k_steps):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    del group_of
+    i, j, kk = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    _, tm, lanes, _ = copies.shape
+    live, slot = i < active[0], i % 2
+
+    def fetch(t, slot):              # tile t's real rows on their way into `copies[slot]`
+        index = pltpu.make_async_copy(token_hbm.at[t], idx, idx_sem.at[0])
+        index.start()
+        index.wait()
+
+        def row(r, carry):
+            pltpu.make_async_copy(rows_hbm.at[idx[0, r]], copies.at[slot, r],
+                                  sem.at[slot]).start()
+            return carry
+
+        jax.lax.fori_loop(0, real[t], row, 0)
+
+    @pl.when(live & (j == 0) & (kk == 0))
+    def _gather():
+        @pl.when(i == 0)
+        def _first():
+            fetch(0, 0)
+
+        @pl.when(i + 1 < active[0])
+        def _next():
+            fetch(i + 1, 1 - slot)
+
+        def wait(r, carry):
+            pltpu.make_async_copy(rows_hbm.at[0], copies.at[slot, 0], sem.at[slot]).wait()
+            return carry
+
+        jax.lax.fori_loop(0, real[i], wait, 0)
+        got = copies.at[slot].reshape(tm * lanes, 128)   # token r's lanes: rows r * lanes + c
+        keep = jax.lax.broadcasted_iota(jnp.int32, (tm, 128), 0) < real[i]
+        per_step = lhs.shape[2] // 128
+
+        def lay_out(c, carry):       # contraction step c's lanes in the operands' dtype, pads 0
+            for p in range(per_step):
+                piece = got[pl.ds(c * per_step + p, tm, stride=lanes), :]
+                lhs[c, :, 128 * p:128 * (p + 1)] = jnp.where(keep, piece, 0.0).astype(lhs.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, k_steps, lay_out, 0)
+
+    @pl.when(kk == 0)
+    def _zero():
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    @pl.when(live)
+    def _accumulate():
+        acc_scr[...] += jnp.dot(lhs[kk], rhs_ref[...], preferred_element_type=jnp.float32)
+
+    @pl.when(kk == k_steps - 1)
+    def _store():            # a tile past the last group's rows stores zeros
+        out_ref[...] = acc_scr[...].astype(out_ref.dtype)
+
+
+def gather_layout(x):
+    """``x [T, K]`` as ``gmm_gather_pallas`` copies it, a token at a time:
+    ``[T, lanes, 128]`` float32, each token's values whole ``(8, 128)`` tiles
+    of its own (zeros past ``K``). The plain form of ``gather_layout_pallas``,
+    and its VJP."""
+    import jax.numpy as jnp
+
+    T, K = x.shape
+    lanes = _gather_lanes(K)
+    return jnp.pad(x.astype(jnp.float32), ((0, 0), (0, lanes * 128 - K))).reshape(T, lanes, 128)
+
+
+LAYOUT_TOKENS = 256              # tokens a step of `moe_gather_layout` lays out
+
+
+def _layout_kernel(x_ref, o_ref, *, lanes):
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    tb, K = x_ref.shape
+    for c in range(lanes):       # lanes c * 128 ... of every token: row c of its tiles
+        rows = pl.ds(c, tb, stride=lanes)
+        if c * 128 < K:
+            o_ref[rows, :] = x_ref[:, c * 128:(c + 1) * 128].astype(o_ref.dtype)
+        else:
+            o_ref[rows, :] = jnp.zeros((tb, 128), o_ref.dtype)
+
+
+def gather_layout_pallas(x, interpret: bool = False):
+    """``gather_layout(x)`` as one pass: a step reads 256 tokens' rows and
+    writes each 128 lanes of them as a row of the token's own tiles (a
+    strided store), so the relayout is one read of ``x`` and one write, and
+    ``x`` may be the bfloat16 copy the layer makes anyway (XLA's relayout
+    copy would need the float32 input written out first)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    T, K = x.shape
+    lanes, tb = _gather_lanes(K), min(LAYOUT_TOKENS, T)
+    out = pl.pallas_call(
+        functools.partial(_layout_kernel, lanes=lanes),
+        grid=(pl.cdiv(T, tb),),
+        in_specs=[pl.BlockSpec((tb, K), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((tb * lanes, 128), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((T * lanes, 128), jnp.float32),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",),
+                                             vmem_limit_bytes=64 * 1024 * 1024),
+        name="moe_gather_layout",  # the name a device trace shows
+        cost_estimate=pl.CostEstimate(flops=0, transcendentals=0,
+                                      bytes_accessed=int(x.size * x.dtype.itemsize
+                                                         + T * lanes * 128 * 4)),
+        interpret=interpret,
+    )(x)
+    return out.reshape(T, lanes, 128)
+
+
+@functools.lru_cache(maxsize=None)
+def _layout_kernel_vjp(interpret: bool = False):
+    """The layout kernel with a backward pass: the plain form's."""
+    import jax
+
+    @jax.custom_vjp
+    def layout(x):
+        return gather_layout_pallas(x, interpret)
+
+    def fwd(x):
+        return gather_layout_pallas(x, interpret), x
+
+    def bwd(x, g):
+        return jax.vjp(gather_layout, x)[1](g)
+
+    layout.defvjp(fwd, bwd)
+    return layout
+
+
+def gmm_gather_pallas(rows, token, rhs, group_sizes, out_dtype, interpret: bool = False):
+    """``gmm_pallas(xg, rhs, group_sizes, out_dtype)`` for ``xg[r] = x[token[r]]``
+    rounded to ``rhs``'s dtype (``rows = gather_layout(x)``), zero where
+    ``token[r]`` is negative, without ``xg``: a row tile's rows are copied
+    from ``rows`` one token a copy into VMEM once, for all its column tiles
+    and contraction steps, the next live tile's copies in flight while this
+    one computes. A tile's rows that gather come first in it (each group is
+    padded at its end), so a tile copies its first ``real`` rows and zeroes
+    the rest. The steps, their order and the float32 accumulator are
+    ``gmm_pallas``'s: every output bit is the same."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    (_, lanes, _), (M,), (_, K, N) = rows.shape, token.shape, rhs.shape
+    tm, tk, tn = (_whole_tile(d, t) for t, d in zip(GMM_TILE, (M, K, N)))
+    if M % tm or K % tk or N % tn or tk % 128 or lanes != _gather_lanes(K):
+        raise ValueError(f"moe_gmm: {(M, K, N)} is not whole tiles of {(tm, tk, tn)}")
+    tiles = M // tm
+    group_of, active = _tile_groups(group_sizes, tiles, tm)
+    real = jnp.sum(token.reshape(tiles, tm) >= 0, axis=1).astype(jnp.int32)
+
+    def rhs_index(i, j, kk, group_of, active, real):
+        live = i < active[0]         # a tile past the last group's stays on the last block
+        i = jnp.where(live, i, jnp.maximum(active[0] - 1, 0))
+        return (group_of[i], jnp.where(live, kk, K // tk - 1),
+                jnp.where(live, j, N // tn - 1))
+
+    return pl.pallas_call(
+        functools.partial(_gather_kernel, k_steps=K // tk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(tiles, N // tn, K // tk),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec((None, tk, tn), rhs_index)],
+            out_specs=pl.BlockSpec((tm, tn), lambda i, j, kk, g, a, r: (i, j)),
+            scratch_shapes=[pltpu.VMEM((2, tm, lanes, 128), rows.dtype),
+                            pltpu.VMEM((K // tk, tm, tk), rhs.dtype),
+                            pltpu.SMEM((1, tm), jnp.int32),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.SemaphoreType.DMA((1,)),
+                            pltpu.VMEM((tm, tn), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        name="moe_gmm_gather",   # the name a device trace shows
+        cost_estimate=pl.CostEstimate(
+            flops=2 * M * K * N, transcendentals=0,
+            bytes_accessed=int(M * lanes * 128 * rows.dtype.itemsize
+                               + tiles * K * N * rhs.dtype.itemsize
+                               + M * N * np.dtype(out_dtype).itemsize)),
+        interpret=interpret,
+    )(group_of, active, real, jnp.maximum(token, 0).reshape(tiles, 1, tm), rows, rhs)
+
+
+def _gathered(rows, token, K: int, dtype):
+    """The rows ``gmm_gather_pallas`` reads, as one array: ``xg``."""
+    import jax.numpy as jnp
+
+    x = rows.reshape(rows.shape[0], -1)[:, :K].astype(dtype)
+    return jnp.where((token >= 0)[:, None], x[jnp.maximum(token, 0)], 0)
+
+
+@functools.lru_cache(maxsize=None)
+def _gather_kernel_vjp(interpret: bool = False):
+    """The gathering kernel with a backward pass: the gather's and the ragged
+    product's, recomputed."""
+    import jax
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+    def gmm(rows, token, rhs, group_sizes, out_dtype):
+        return gmm_gather_pallas(rows, token, rhs, group_sizes, out_dtype, interpret)
+
+    def fwd(rows, token, rhs, group_sizes, out_dtype):
+        return (gmm_gather_pallas(rows, token, rhs, group_sizes, out_dtype, interpret),
+                (rows, token, rhs, group_sizes))
+
+    def bwd(out_dtype, res, g):
+        rows, token, rhs, group_sizes = res
+        _, vjp = jax.vjp(lambda r, b: _gmm_ragged(_gathered(r, token, b.shape[1], b.dtype),
+                                                  b, group_sizes, out_dtype), rows, rhs)
+        d_rows, d_rhs = vjp(g)
+        return d_rows, None, d_rhs, None
+
+    gmm.defvjp(fwd, bwd)
+    return gmm
 
 
 # ---------------------------------------------------------------------------
@@ -452,6 +729,13 @@ class ExpertLayer(Module):
     groups' experts alone. The result is the held experts'
     part of the sum; what the others would add is another holder's to compute.
 
+    The first product has two forms of one result, chosen the same way
+    (``_gmm_gathers``): ``moe_gmm_gather``, which copies each visit's row
+    from the layer's input (laid out once by ``moe_gather_layout``) into
+    VMEM itself, where ``moe_gmm`` runs and two row tiles fit VMEM; else XLA
+    gathers the rows and the product takes them, which is also the kernel's
+    VJP. Their outputs are the same to the last bit.
+
     The combine (each visit's row, widened to float32, times its float32
     weight, added to the token's float32 sum) has two forms of one arithmetic,
     chosen by what the layer sees in its own shape (``_combine_kernel_applies``):
@@ -579,6 +863,8 @@ class ExpertLayer(Module):
         w2 = jnp.asarray(params["w2"]).astype(dt)
         in_kernel = _combine_kernel_applies(held, self.num_experts,
                                             jax.default_backend(), xd.dtype)
+        gathers = _gmm_gathers(jax.default_backend(), xd.dtype, D, w1.shape[2])
+        laid_out = _layout_kernel_vjp()(xd) if gathers else None
 
         def trip(y, c):
             at = c * rows
@@ -591,10 +877,14 @@ class ExpertLayer(Module):
                     r = j - p_start[gc]
                     real = (g < held) & (r < counts[gc])
                     visit = order[jnp.where(real, start[gc] + r, 0)]
-                    xg = jnp.where(real[:, None], xd[visit // K], 0).astype(dt)
+                    if gathers:          # the first product fetches its rows itself
+                        token = jnp.where(real, visit // K, -1)
+                    else:
+                        xg = jnp.where(real[:, None], xd[visit // K], 0).astype(dt)
                 with scope("experts"):
                     sizes = jnp.clip(p_end - at, 0, rows) - jnp.clip(p_start - at, 0, rows)
-                    hid = grouped_matmul(xg, w1, sizes, dt, tile)
+                    hid = _gather_kernel_vjp()(laid_out, token, w1, sizes, dt) if gathers \
+                        else grouped_matmul(xg, w1, sizes, dt, tile)
                     gate_h, up_h = jnp.split(hid.astype(jnp.float32), 2, axis=-1)
                     act = (jax.nn.silu(gate_h) * up_h).astype(dt)
                     out = grouped_matmul(act, w2, sizes, dt, tile)         # [rows, D]

@@ -421,6 +421,129 @@ def test_the_gradient_through_the_combine_kernel_is_the_scans(add_to, monkeypatc
         assert float(jnp.abs(a - b).max()) <= 1e-5 * max(float(jnp.abs(b).max()), 1e-30)
 
 
+@pytest.mark.parametrize("case", ["first-trip", "second-trip"])
+@pytest.mark.parametrize("width", [1024, 1536])
+def test_the_gathering_product_is_the_grouped_products_to_the_last_bit(case, width):
+    """`moe_gmm_gather` in the interpreter against `gmm_pallas` on the rows
+    XLA gathers, on a routing built by hand: 200 tokens, 3 choices, experts
+    2-5 of 8 held, expert 4 with no visits; each group of 79-94 visits padded
+    to a tile of 512, so a tile's pads follow its rows; trips of 1,024 rows,
+    the second one live tile and one past the last group's rows; 2 or 3
+    contraction steps (1,536 is no multiple of 1,024: its copies carry pad
+    lanes) and 2 column tiles."""
+    idx, first = _hand_built_choices(False)
+    (N, K), held, tile, rows_ = idx.shape, 4, moe.GMM_TILE[0], 1024
+    mine, place, visit, group, p_start, counts = _routing(idx, first, held, tile, rows_)
+    assert counts[2] == 0 and len(visit) == 2 * rows_ and (counts[counts > 0] % tile).all()
+    rng = np.random.default_rng(width)
+    x = jnp.asarray(rng.normal(size=(N, width)), jnp.bfloat16)
+    w = jnp.asarray(rng.normal(size=(held, width, 2048)) / 32, jnp.bfloat16)
+    at = rows_ if case == "second-trip" else 0
+    padded = -(-counts // tile) * tile
+    p_end = np.cumsum(padded)
+    sizes = jnp.asarray(np.clip(p_end - at, 0, rows_) - np.clip(p_end - padded - at, 0, rows_),
+                        jnp.int32)
+    token = jnp.asarray(np.where(visit < N * K, visit // K, -1)[at:at + rows_])
+    xg = jnp.where((token >= 0)[:, None], x[jnp.maximum(token, 0)], 0).astype(jnp.bfloat16)
+    want = moe.gmm_pallas(xg, w, sizes, jnp.bfloat16, interpret=True)
+    got = moe.gmm_gather_pallas(moe.gather_layout(x), token, w, sizes, jnp.bfloat16,
+                                interpret=True)
+    assert float(jnp.abs(want[:512].astype(jnp.float32)).max()) > 0.1
+    assert bool(jnp.all(got == want))
+
+
+@pytest.mark.parametrize("tokens,width", [(512, 1024), (300, 1536), (200, 384)])
+def test_the_layout_kernel_is_the_plain_layout(tokens, width):
+    """`moe_gather_layout` in the interpreter: steps of 256 tokens (300 is a
+    step and part of one), widths whose tiles end in pad lanes (1,536 and
+    384 are no multiple of 1,024), a bfloat16 input widened exactly."""
+    x = jnp.asarray(np.random.default_rng(tokens).normal(size=(tokens, width)), jnp.bfloat16)
+    got = moe.gather_layout_pallas(x, interpret=True)
+    assert got.shape == (tokens, moe._gather_lanes(width), 128) and got.dtype == jnp.float32
+    assert bool(jnp.all(got == moe.gather_layout(x)))
+    assert bool(jnp.all(got.reshape(tokens, -1)[:, :width] == x.astype(jnp.float32)))
+
+
+def _with_the_kernels_in_the_interpreter(monkeypatch):
+    """An `ExpertLayer` whose products are the kernels in the interpreter:
+    rows padded to a tile of 128; the first product the gathering one while
+    the list returned holds True."""
+    gathers = [False]
+    kernel, layout = moe._gather_kernel_vjp(True), moe._layout_kernel_vjp(True)
+    monkeypatch.setattr(moe, "GMM_TILE", (128, 256, 256))
+    monkeypatch.setattr(moe, "_gmm_tile_rows", lambda x: 128)
+    monkeypatch.setattr(moe, "grouped_matmul", lambda lhs, rhs, sizes, dt, tile:
+                        moe._gmm_kernel_vjp(True)(lhs, rhs, sizes, dt))
+    monkeypatch.setattr(moe, "_gmm_gathers", lambda *a: gathers[0])
+    monkeypatch.setattr(moe, "_gather_kernel_vjp", lambda: kernel)
+    monkeypatch.setattr(moe, "_layout_kernel_vjp", lambda: layout)
+    return gathers
+
+
+def test_the_expert_layer_through_the_gathering_product_is_the_plain_forms(monkeypatch):
+    layer = moe.ExpertLayer(8, 4, 3, 128, scale=2.5, first_expert=2)
+    params, _ = layer.init(jax.random.key(4), (T, 512))
+    params = jax.tree.map(lambda a: a.astype(jnp.bfloat16), params)
+    x = jax.random.normal(jax.random.key(5), (3, T, 512), jnp.float32)
+    outs, gathers = [], _with_the_kernels_in_the_interpreter(monkeypatch)
+    for gathers[0] in (False, True):
+        outs.append(layer.apply_with_load(params, x))
+    (want, want_load), (got, got_load) = outs
+    assert float(jnp.abs(want).max()) > 0.1
+    assert bool(jnp.all(got == want)) and bool(jnp.all(got_load == want_load))
+
+
+def test_the_gradient_through_the_gathering_product_is_the_plain_forms(monkeypatch):
+    """Values and gradients, with respect to the input and every parameter,
+    through the gathering kernel's VJP against the plain gather's and
+    product's."""
+    layer = moe.ExpertLayer(8, 4, 3, 128, scale=2.5, first_expert=2)
+    params, _ = layer.init(jax.random.key(6), (T, 256))
+    x = jax.random.normal(jax.random.key(7), (2, T, 256), jnp.float32)
+    tilt = jnp.cos(jnp.arange(256, dtype=jnp.float32))
+
+    def loss(params, x):
+        y, _ = layer.apply_with_load(params, x)
+        return jnp.sum(y * tilt), y
+
+    runs, gathers = [], _with_the_kernels_in_the_interpreter(monkeypatch)
+    for gathers[0] in (False, True):
+        runs.append(jax.value_and_grad(loss, (0, 1), has_aux=True)(params, x))
+    ((_, want_y), want), ((_, got_y), got) = runs
+    assert bool(jnp.all(got_y == want_y))
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert a.shape == b.shape
+        assert float(jnp.abs(a - b).max()) <= 1e-5 * max(float(jnp.abs(b).max()), 1e-30)
+
+
+GATHER_RULE = [   # backend, operands, contraction width, columns: the gathering form?
+    ("tpu", jnp.bfloat16, 6144, 4096, True),          # kexaone.score
+    ("tpu", jnp.bfloat16, 3584, 2048, True),          # xing4.score: no multiple of 1,024
+    ("tpu", jnp.bfloat16, 7168, 4096, True),          # axk2.score
+    ("cpu", jnp.bfloat16, 6144, 4096, False),
+    ("gpu", jnp.bfloat16, 6144, 4096, False),
+    ("tpu", jnp.float32, 6144, 4096, False),          # where `moe_gmm` does not run either
+    ("tpu", jnp.bfloat16, 600, 4096, False),          # no whole tiles of 128 lanes
+    ("tpu", jnp.bfloat16, 30720, 4096, False),        # two row tiles do not fit VMEM
+]
+
+
+@pytest.mark.parametrize("backend,dtype,width,columns,gathers", GATHER_RULE)
+def test_the_first_products_form_follows_from_backend_dtype_and_width(
+        backend, dtype, width, columns, gathers, monkeypatch):
+    import inspect
+
+    assert moe._gmm_gathers(backend, dtype, width, columns) is gathers
+    asked = []
+    monkeypatch.setattr(moe, "_gmm_gathers", lambda *a: asked.append(a) or False)
+    layer = moe.ExpertLayer(8, 4, 2, 8)
+    params, _ = layer.init(jax.random.key(0), (T, 16))
+    with matmul_precision("float32"):
+        layer.apply(params, jnp.ones((1, T, 16), jnp.float32))
+    assert asked == [("cpu", jnp.float32, 16, 16)]
+    assert not {"gather", "gathers"} & set(inspect.signature(moe.ExpertLayer.__init__).parameters)
+
+
 # -- the kernels at the published widths, compiled for a described v5e ------
 
 @pytest.fixture(scope="module")
@@ -521,6 +644,27 @@ def test_the_grouped_product_kernel_compiles_for_a_width_of_3584(one_chip):
 
     text = _compiled_text(both, x, w1, w2, sizes)
     assert text.count("moe_gmm") >= 2 and "tpu_custom_call" in text
+
+
+GATHER_SHAPES = {    # tokens, width, rows a trip, experts held, columns of `w1`
+    "kexaone.score": (32768, 6144, 73728, 16, 4096),
+    "xing4.score": (16384, 3584, 98304, 64, 2048),
+    "axk2.score": (32768, 7168, 40960, 16, 4096),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(GATHER_SHAPES))
+def test_the_gathering_product_compiles_at_the_published_widths(shape, one_chip):
+    N, d, rows_, held, cols = GATHER_SHAPES[shape]
+    assert moe._gmm_gathers("tpu", jnp.bfloat16, d, cols)
+    x = jax.ShapeDtypeStruct((N, d), jnp.bfloat16, sharding=one_chip)
+    token = jax.ShapeDtypeStruct((rows_,), jnp.int32, sharding=one_chip)
+    w1 = jax.ShapeDtypeStruct((held, d, cols), jnp.bfloat16, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((held,), jnp.int32, sharding=one_chip)
+    text = _compiled_text(lambda x, token, w1, sizes: moe.gmm_gather_pallas(
+        moe.gather_layout_pallas(x), token, w1, sizes, jnp.bfloat16), x, token, w1, sizes)
+    assert "moe_gmm_gather" in text and "moe_gather_layout" in text
+    assert "tpu_custom_call" in text
 
 
 COMBINE_SHAPES = {   # tokens, width, rows a trip, experts held, top k
