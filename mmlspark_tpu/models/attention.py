@@ -43,6 +43,7 @@ import numpy as np
 
 from ..obs.scopes import scope
 from .module import Fn, Module, Sequential, _rng_split, matmul_dtype
+from .paired import pair
 
 
 # ---------------------------------------------------------------------------
@@ -624,24 +625,10 @@ def _lstm_kernel_plain(x, wx, wh, b, beside, reverse: bool, padded: bool):
     return y
 
 
-@functools.lru_cache(maxsize=None)
-def _lstm_kernel_vjp(reverse: bool, padded: bool = False, interpret: bool = False):
-    """The kernel with a backward pass: the plain form's, recomputed."""
-    import jax
-
-    @jax.custom_vjp
-    def scan(x, wx, wh, b, beside):
-        return lstm_scan_pallas(x, wx, wh, b, reverse, beside, padded, interpret)
-
-    def fwd(*operands):
-        return scan(*operands), operands
-
-    def bwd(operands, g):
-        plain = functools.partial(_lstm_kernel_plain, reverse=reverse, padded=padded)
-        return jax.vjp(plain, *operands)[1](g)
-
-    scan.defvjp(fwd, bwd)
-    return scan
+_lstm_pair = pair(
+    lambda x, wx, wh, b, beside, reverse, padded, interpret: lstm_scan_pallas(
+        x, wx, wh, b, reverse, beside, padded, interpret),
+    _lstm_kernel_plain, static=2)
 
 
 def _lstm_kernel_applies(x, hidden: int) -> bool:
@@ -667,7 +654,7 @@ def _lstm_weights(params):
 def lstm_scan(x, wx, wh, b, reverse: bool = False):
     """The recurrence: the kernel where it applies, the plain form elsewhere."""
     if _lstm_kernel_applies(x, wh.shape[0]):
-        return _lstm_kernel_vjp(reverse)(x, wx, wh, b, None)
+        return _lstm_pair(x, wx, wh, b, None, reverse, False)
     return lstm_scan_xla(x, wx, wh, b, reverse)
 
 
@@ -717,10 +704,9 @@ class BiLSTM(Module):
             # the second direction's kernel writes both halves of a position:
             # no concatenation, no pass over either result
             with scope("fwd"):
-                fwd = _lstm_kernel_vjp(False, padded=True)(
-                    x, *_lstm_weights(params["fwd"]), None)
+                fwd = _lstm_pair(x, *_lstm_weights(params["fwd"]), None, False, True)
             with scope("bwd"):
-                return _lstm_kernel_vjp(True)(x, *_lstm_weights(params["bwd"]), fwd)
+                return _lstm_pair(x, *_lstm_weights(params["bwd"]), fwd, True, False)
         with scope("fwd"):
             fwd = self.fwd.apply(params["fwd"], x)
         with scope("bwd"):

@@ -120,6 +120,14 @@ def _constrain_activation(x):
     return jax.lax.with_sharding_constraint(x, s)
 
 
+def equal_pieces(n: int, most: int) -> int:
+    """The fewest equal pieces of at most ``most`` that ``n`` cuts into."""
+    pieces = -(-n // most)
+    while n % pieces:
+        pieces += 1
+    return pieces
+
+
 def _rng_split(rng, n):
     import jax
     return jax.random.split(rng, n)

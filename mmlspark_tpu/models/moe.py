@@ -45,6 +45,7 @@ import numpy as np
 
 from ..obs.scopes import scope
 from .module import Module, _rng_split, matmul_dtype
+from .paired import pair
 
 
 class MoE(Module):
@@ -224,27 +225,7 @@ def _gmm_ragged(lhs, rhs, group_sizes, out_dtype):
                               preferred_element_type=jnp.float32).astype(out_dtype)
 
 
-@functools.lru_cache(maxsize=None)
-def _gmm_kernel_vjp(interpret: bool = False):
-    """The kernel with a backward pass: the ragged product's, recomputed."""
-    import jax
-
-    @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-    def gmm(lhs, rhs, group_sizes, out_dtype):
-        return gmm_pallas(lhs, rhs, group_sizes, out_dtype, interpret)
-
-    def fwd(lhs, rhs, group_sizes, out_dtype):
-        return (gmm_pallas(lhs, rhs, group_sizes, out_dtype, interpret),
-                (lhs, rhs, group_sizes))
-
-    def bwd(out_dtype, res, g):
-        lhs, rhs, group_sizes = res
-        _, vjp = jax.vjp(lambda a, b: _gmm_ragged(a, b, group_sizes, out_dtype),
-                         lhs, rhs)
-        return (*vjp(g), None)
-
-    gmm.defvjp(fwd, bwd)
-    return gmm
+_gmm_pair = pair(gmm_pallas, _gmm_ragged, static=1, nondiff=(2,))
 
 
 def _gmm_tile_rows(x) -> int:
@@ -259,7 +240,7 @@ def _gmm_tile_rows(x) -> int:
 
 def grouped_matmul(lhs, rhs, group_sizes, out_dtype, tile_rows: int):
     if tile_rows > 1:
-        return _gmm_kernel_vjp()(lhs, rhs, group_sizes, out_dtype)
+        return _gmm_pair(lhs, rhs, group_sizes, out_dtype)
     return _gmm_ragged(lhs, rhs, group_sizes, out_dtype)
 
 
@@ -418,23 +399,7 @@ def gather_layout_pallas(x, interpret: bool = False):
     return out.reshape(T, lanes, 128)
 
 
-@functools.lru_cache(maxsize=None)
-def _layout_kernel_vjp(interpret: bool = False):
-    """The layout kernel with a backward pass: the plain form's."""
-    import jax
-
-    @jax.custom_vjp
-    def layout(x):
-        return gather_layout_pallas(x, interpret)
-
-    def fwd(x):
-        return gather_layout_pallas(x, interpret), x
-
-    def bwd(x, g):
-        return jax.vjp(gather_layout, x)[1](g)
-
-    layout.defvjp(fwd, bwd)
-    return layout
+_layout_pair = pair(gather_layout_pallas, gather_layout)
 
 
 def gmm_gather_pallas(rows, token, rhs, group_sizes, out_dtype, interpret: bool = False):
@@ -495,37 +460,17 @@ def gmm_gather_pallas(rows, token, rhs, group_sizes, out_dtype, interpret: bool 
     )(group_of, active, real, jnp.maximum(token, 0).reshape(tiles, 1, tm), rows, rhs)
 
 
-def _gathered(rows, token, K: int, dtype):
-    """The rows ``gmm_gather_pallas`` reads, as one array: ``xg``."""
+def _gmm_gather_plain(rows, token, rhs, group_sizes, out_dtype):
+    """``gmm_gather_pallas``'s result from the rows XLA gathers (``xg``) and
+    the ragged product."""
     import jax.numpy as jnp
 
-    x = rows.reshape(rows.shape[0], -1)[:, :K].astype(dtype)
-    return jnp.where((token >= 0)[:, None], x[jnp.maximum(token, 0)], 0)
+    x = rows.reshape(rows.shape[0], -1)[:, :rhs.shape[1]].astype(rhs.dtype)
+    xg = jnp.where((token >= 0)[:, None], x[jnp.maximum(token, 0)], 0)
+    return _gmm_ragged(xg, rhs, group_sizes, out_dtype)
 
 
-@functools.lru_cache(maxsize=None)
-def _gather_kernel_vjp(interpret: bool = False):
-    """The gathering kernel with a backward pass: the gather's and the ragged
-    product's, recomputed."""
-    import jax
-
-    @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-    def gmm(rows, token, rhs, group_sizes, out_dtype):
-        return gmm_gather_pallas(rows, token, rhs, group_sizes, out_dtype, interpret)
-
-    def fwd(rows, token, rhs, group_sizes, out_dtype):
-        return (gmm_gather_pallas(rows, token, rhs, group_sizes, out_dtype, interpret),
-                (rows, token, rhs, group_sizes))
-
-    def bwd(out_dtype, res, g):
-        rows, token, rhs, group_sizes = res
-        _, vjp = jax.vjp(lambda r, b: _gmm_ragged(_gathered(r, token, b.shape[1], b.dtype),
-                                                  b, group_sizes, out_dtype), rows, rhs)
-        d_rows, d_rhs = vjp(g)
-        return d_rows, None, d_rhs, None
-
-    gmm.defvjp(fwd, bwd)
-    return gmm
+_gather_pair = pair(gmm_gather_pallas, _gmm_gather_plain, static=1, nondiff=(1, 3))
 
 
 # ---------------------------------------------------------------------------
@@ -682,27 +627,11 @@ def combine_pallas(y, out, gate, visit, group, held: int, interpret: bool = Fals
     return got[:N]
 
 
-@functools.lru_cache(maxsize=None)
-def _combine_kernel_vjp(interpret: bool = False):
-    """The kernel with a backward pass: the scan's, recomputed."""
-    import jax
-
-    @functools.partial(jax.custom_vjp, nondiff_argnums=(8,))
-    def combine(y, out, gate, place, mine, at, visit, group, held):
-        return combine_pallas(y, out, gate, visit, group, held, interpret)
-
-    def fwd(y, out, gate, place, mine, at, visit, group, held):
-        return (combine_pallas(y, out, gate, visit, group, held, interpret),
-                (y, out, gate, place, mine, at))
-
-    def bwd(held, res, g):
-        y, out, gate, place, mine, at = res
-        _, vjp = jax.vjp(lambda y, out, gate: combine_xla(y, out, gate, place, mine, at),
-                         y, out, gate)
-        return (*vjp(g), None, None, None, None, None)
-
-    combine.defvjp(fwd, bwd)
-    return combine
+_combine_pair = pair(
+    lambda y, out, gate, place, mine, at, visit, group, held, interpret:
+        combine_pallas(y, out, gate, visit, group, held, interpret),
+    lambda y, out, gate, place, mine, at, visit, group, held:
+        combine_xla(y, out, gate, place, mine, at), static=1, nondiff=(3, 4, 5, 6, 7))
 
 
 def _combine_kernel_applies(experts_held: int, num_experts: int, backend: str,
@@ -864,7 +793,7 @@ class ExpertLayer(Module):
         in_kernel = _combine_kernel_applies(held, self.num_experts,
                                             jax.default_backend(), xd.dtype)
         gathers = _gmm_gathers(jax.default_backend(), xd.dtype, D, w1.shape[2])
-        laid_out = _layout_kernel_vjp()(xd) if gathers else None
+        laid_out = _layout_pair(xd) if gathers else None
 
         def trip(y, c):
             at = c * rows
@@ -883,7 +812,7 @@ class ExpertLayer(Module):
                         xg = jnp.where(real[:, None], xd[visit // K], 0).astype(dt)
                 with scope("experts"):
                     sizes = jnp.clip(p_end - at, 0, rows) - jnp.clip(p_start - at, 0, rows)
-                    hid = _gather_kernel_vjp()(laid_out, token, w1, sizes, dt) if gathers \
+                    hid = _gather_pair(laid_out, token, w1, sizes, dt) if gathers \
                         else grouped_matmul(xg, w1, sizes, dt, tile)
                     gate_h, up_h = jnp.split(hid.astype(jnp.float32), 2, axis=-1)
                     act = (jax.nn.silu(gate_h) * up_h).astype(dt)
@@ -892,7 +821,7 @@ class ExpertLayer(Module):
                 with scope("combine"):
                     if not in_kernel:
                         return combine_xla(y, out, gate, place, mine, at)
-                    return _combine_kernel_vjp()(
+                    return _combine_pair(
                         y, out, gate, place, mine, at,
                         jnp.where(real, visit, N * K), g, held)
 

@@ -33,6 +33,7 @@ import numpy as np
 
 from ..obs.scopes import scope
 from .module import Module, _rng_split, matmul_dtype
+from .paired import pair
 
 TOKEN_BLOCK = 128         # tokens a kernel step reads: 7.3 MB of four 3584-wide streams
 _LANES = 128
@@ -188,46 +189,19 @@ def _kernels_apply(x, n: int) -> bool:
             and _maps_width(n) < _LANES)
 
 
-@functools.lru_cache(maxsize=None)
-def _kernels_with_vjp(interpret: bool = False):
-    """The two kernels with a backward pass each: the plain form's, recomputed."""
-    import jax
-
-    @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-    def pre(x, phi, pre_ab, n, norm_eps):
-        return mhc_pre_pallas(x, phi, pre_ab, n, norm_eps, interpret)
-
-    def pre_fwd(x, phi, pre_ab, n, norm_eps):
-        return mhc_pre_pallas(x, phi, pre_ab, n, norm_eps, interpret), (x, phi, pre_ab)
-
-    def pre_bwd(n, norm_eps, res, g):
-        return jax.vjp(lambda *a: mhc_pre_plain(*a, n, norm_eps), *res)[1](g)
-
-    pre.defvjp(pre_fwd, pre_bwd)
-
-    @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-    def post(x, y, h, n):
-        return mhc_post_pallas(x, y, h, n, interpret)
-
-    def post_fwd(x, y, h, n):
-        return mhc_post_pallas(x, y, h, n, interpret), (x, y, h)
-
-    def post_bwd(n, res, g):
-        return jax.vjp(lambda *a: mhc_post_plain(*a, n), *res)[1](g)
-
-    post.defvjp(post_fwd, post_bwd)
-    return pre, post
+_pre_pair = pair(mhc_pre_pallas, mhc_pre_plain, static=2)
+_post_pair = pair(mhc_post_pallas, mhc_post_plain, static=1)
 
 
 def mhc_pre(x, phi, pre, n: int, norm_eps: float):
     if _kernels_apply(x, n):
-        return _kernels_with_vjp()[0](x, phi, pre, n, norm_eps)
+        return _pre_pair(x, phi, pre, n, norm_eps)
     return mhc_pre_plain(x, phi, pre, n, norm_eps)
 
 
 def mhc_post(x, y, h, n: int):
     if _kernels_apply(x, n):
-        return _kernels_with_vjp()[1](x, y, h, n)
+        return _post_pair(x, y, h, n)
     return mhc_post_plain(x, y, h, n)
 
 

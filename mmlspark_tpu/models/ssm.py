@@ -41,7 +41,8 @@ from typing import Any, Dict, Optional, Sequence
 import numpy as np
 
 from ..obs.scopes import scope
-from .module import Module, _rng_split
+from .module import Module, _rng_split, equal_pieces
+from .paired import pair
 from .transformer import _mm_dtype, _normal
 
 SCAN_CHUNK = 16     # time steps of the plain form's closed form
@@ -58,9 +59,7 @@ def over_pieces(fn, carry, arrays: Sequence[Any]):
     import jax.numpy as jnp
 
     B, T = arrays[0].shape[:2]
-    pieces = -(-T // PIECE)
-    while T % pieces:
-        pieces += 1
+    pieces = equal_pieces(T, PIECE)
     if pieces == 1:
         return fn(carry, *arrays)
     cut = tuple(jnp.moveaxis(a.reshape(B, pieces, T // pieces, *a.shape[2:]), 1, 0)
@@ -202,23 +201,7 @@ def ssm_scan_pallas(delta, x, Bm, Cm, A, D, h0, interpret: bool = False):
     return y.reshape(B_, T, C), jnp.moveaxis(h, -3, -1).reshape(B_, C, n)
 
 
-@functools.lru_cache(maxsize=None)
-def _scan_kernel_vjp(interpret: bool = False):
-    """The kernel with a backward pass: the plain form's, recomputed."""
-    import jax
-
-    @jax.custom_vjp
-    def scan(*operands):
-        return ssm_scan_pallas(*operands, interpret=interpret)
-
-    def fwd(*operands):
-        return ssm_scan_pallas(*operands, interpret=interpret), operands
-
-    def bwd(operands, g):
-        return jax.vjp(ssm_scan_xla, *operands)[1](g)
-
-    scan.defvjp(fwd, bwd)
-    return scan
+_scan_pair = pair(ssm_scan_pallas, ssm_scan_xla)
 
 
 def _scan_kernel_applies(delta) -> bool:
@@ -233,7 +216,7 @@ def _scan_kernel_applies(delta) -> bool:
 def ssm_scan(delta, x, Bm, Cm, A, D, h0):
     """The selective scan: the kernel where it applies, the plain form elsewhere."""
     if _scan_kernel_applies(delta):
-        return _scan_kernel_vjp()(delta, x, Bm, Cm, A, D, h0)
+        return _scan_pair(delta, x, Bm, Cm, A, D, h0)
     return ssm_scan_xla(delta, x, Bm, Cm, A, D, h0)
 
 

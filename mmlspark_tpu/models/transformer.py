@@ -65,7 +65,8 @@ from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
 import numpy as np
 
 from ..obs.scopes import scope
-from .module import FunctionModel, Module, _rng_split, matmul_dtype
+from .module import FunctionModel, Module, _rng_split, equal_pieces, matmul_dtype
+from . import paired
 
 _NEG = -1e30          # masked score: finite, so a fully masked block stays finite
 LOGITS_BLOCK = 2 ** 28    # logits the head holds at a time: 1.07 GB of float32
@@ -213,33 +214,17 @@ def gqa_xla(q, k, v, window: int, out_dtype=None):
 
 
 # ---------------------------------------------------------------------------
-# grouped-query attention: the Pallas kernel
+# the flash schedule: the blocks and steps of the four flash-attention kernels
 # ---------------------------------------------------------------------------
 
 def _attn_blocks(T: int, window: int) -> Optional[Tuple[int, int]]:
-    """(query block, key block) the kernel takes at this length, or None."""
+    """(query block, key block) the kernels take at this length, or None."""
     if T % 128:
         return None
     bq = next(b for b in (512, 256, 128) if T % b == 0)
     if window:
         return min(bq, 256), 128
     return bq, bq
-
-
-def _kv_steps(bq: int, bk: int, T: int, window: int) -> int:
-    """Key blocks a query block may touch: all of them, or the window's band."""
-    if not window:
-        return T // bk
-    return min(T // bk, bq // bk + -(-(window - 1) // bk))
-
-
-def _first_kv(qi, bq: int, bk: int, window: int):
-    """The first key block the query block ``qi`` reads."""
-    import jax.numpy as jnp
-
-    if not window:
-        return 0
-    return jnp.maximum(qi * bq - (window - 1), 0) // bk
 
 
 def _softmax_step(s, seen, v, m_scr, l_scr, acc_scr, g: int):
@@ -264,47 +249,164 @@ def _softmax_step(s, seen, v, m_scr, l_scr, acc_scr, g: int):
     m_scr[g] = m_new
 
 
-def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                 bq: int, bk: int, window: int, steps: int, scale: float,
-                 group: int, d: int):
+class FlashSchedule:
+    """The block schedule of the flash-attention kernels (``attn_window`` /
+    ``attn_full``, their differential forms, ``attn_mla``,
+    ``attn_mla_sparse``), written once. The grid is ``(B, head groups, query
+    blocks, steps)``; query block ``qi`` takes ``steps`` key blocks from its
+    window's first on (from 0 at window 0) up to its diagonal. A step past
+    the diagonal computes nothing and fetches nothing: its key index stays on
+    the diagonal's block. Each softmax of a step has its running maximum,
+    denominator and accumulator (``m``, ``l``, ``acc``) in VMEM.
+
+    A kernel is a body over it: ``step(refs, qi, kb, masked)`` folds key
+    block ``kb`` into the softmaxes (``refs``: the inputs, the output, then
+    ``m``, ``l``, ``acc``), ``store(refs)`` writes the result. Which blocks
+    it masks (every block or the diagonal's alone) and where it stores (at
+    the last step or at the diagonal's) are the two choices it makes."""
+
+    def __init__(self, T: int, window: int):
+        self.window = window
+        self.bq, self.bk = _attn_blocks(T, window)
+        self.blocks = T // self.bq
+        self.steps = T // self.bk if not window else min(
+            T // self.bk, self.bq // self.bk + -(-(window - 1) // self.bk))
+
+    def key_block(self, qi, j):
+        """The key block step ``j`` of query block ``qi`` reads."""
+        import jax.numpy as jnp
+
+        first = 0 if not self.window else \
+            jnp.maximum(qi * self.bq - (self.window - 1), 0) // self.bk
+        return first + j
+
+    def diagonal(self, qi):
+        """The key block of query block ``qi``'s diagonal: the last it reads."""
+        return (qi * self.bq + self.bq - 1) // self.bk
+
+    def fetched(self, qi, j):
+        """The key block a step fetches: past the diagonal the index stays
+        put, so no block is fetched for a step that computes nothing."""
+        import jax.numpy as jnp
+
+        return jnp.minimum(self.key_block(qi, j), self.diagonal(qi))
+
+    def seen(self, qi, kb):
+        """``[bq, bk]``: the (query, key) pairs of a block that are causal and
+        inside the window."""
+        import jax
+        import jax.numpy as jnp
+
+        qpos = qi * self.bq + jax.lax.broadcasted_iota(jnp.int32, (self.bq, self.bk), 0)
+        kpos = kb * self.bk + jax.lax.broadcasted_iota(jnp.int32, (self.bq, self.bk), 1)
+        seen = kpos <= qpos
+        if self.window:
+            seen = jnp.logical_and(seen, kpos > qpos - self.window)
+        return seen
+
+    def query_spec(self, width: int):
+        """A block of queries (or of the output) of one head group."""
+        from jax.experimental import pallas as pl
+
+        return pl.BlockSpec((None, self.bq, width), lambda b, h, qi, j: (b, qi, h))
+
+    def key_spec(self, width: int, shared: bool = False):
+        """A block of keys of one head group, or (``shared``) of the one
+        column block all groups read."""
+        from jax.experimental import pallas as pl
+
+        def index(b, h, qi, j):
+            return b, self.fetched(qi, j), 0 if shared else h
+
+        return pl.BlockSpec((None, self.bk, width), index)
+
+    def kernel(self, step, store, mask_all: bool, store_at_diagonal: bool = False):
+        """The kernel over ``refs``: ``m``, ``l``, ``acc`` set at the first
+        step, ``step`` on the blocks up to the diagonal (``masked`` on every
+        one of them with ``mask_all``, else on the diagonal's alone),
+        ``store`` at the last step or (``store_at_diagonal``) the diagonal's."""
+        import jax.numpy as jnp
+        from jax.experimental import pallas as pl
+
+        def run(*refs):
+            m_scr, l_scr, acc_scr = refs[-3:]
+            qi, j = pl.program_id(2), pl.program_id(3)
+
+            @pl.when(j == 0)
+            def _init():
+                m_scr[...] = jnp.full_like(m_scr, _NEG)
+                l_scr[...] = jnp.zeros_like(l_scr)
+                acc_scr[...] = jnp.zeros_like(acc_scr)
+
+            kb, last = self.key_block(qi, j), self.diagonal(qi)
+            if mask_all:
+                pl.when(kb <= last)(lambda: step(refs, qi, kb, True))
+            else:                # blocks alike: only the diagonal's needs its mask
+                pl.when(kb < last)(lambda: step(refs, qi, kb, False))
+                pl.when(kb == last)(lambda: step(refs, qi, kb, True))
+            pl.when(kb == last if store_at_diagonal else j == self.steps - 1)(
+                lambda: store(refs))
+
+        return run
+
+    def call(self, kernel, operands, in_specs, out_shape, out_width: int,
+             softmaxes: int, value_width: int, name: str, cost, interpret: bool):
+        """``pallas_call`` of ``kernel`` over the grid: ``out_shape``'s head
+        groups ``out_width`` lanes each, ``softmaxes`` a step with values
+        ``value_width`` wide, under the name a device trace shows
+        (docs/observability.md)."""
+        import jax.numpy as jnp
+        from jax.experimental import pallas as pl
+        from jax.experimental.pallas import tpu as pltpu
+
+        B = operands[0].shape[0]
+        return pl.pallas_call(
+            kernel,
+            grid=(B, out_shape.shape[2] // out_width, self.blocks, self.steps),
+            in_specs=in_specs,
+            out_specs=self.query_spec(out_width),
+            out_shape=out_shape,
+            scratch_shapes=[pltpu.VMEM((softmaxes, self.bq, 128), jnp.float32),
+                            pltpu.VMEM((softmaxes, self.bq, 128), jnp.float32),
+                            pltpu.VMEM((softmaxes, self.bq, value_width), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+                vmem_limit_bytes=64 * 1024 * 1024),
+            name=name,
+            cost_estimate=cost,
+            interpret=interpret,
+        )(*operands)
+
+
+def _divide(refs, group: int, width: int):
+    """The store of a plain softmax: each head's accumulator over its
+    denominator."""
+    o_ref, _, l_scr, acc_scr = refs[-4:]
+    for g in range(group):
+        o_ref[:, g * width:(g + 1) * width] = (
+            acc_scr[g] / l_scr[g][:, :1]).astype(o_ref.dtype)
+
+
+# ---------------------------------------------------------------------------
+# grouped-query attention: the Pallas kernel
+# ---------------------------------------------------------------------------
+
+def _gqa_step(refs, qi, kb, masked: bool, *, sched: FlashSchedule, scale: float,
+              group: int, d: int):
     """One block of queries of the ``group`` query heads that share a
     key/value head, against one block of its keys: the heads run in turn over
-    the same key and value tiles (fetched once for all of them), each with
-    its own running maximum, denominator and accumulator."""
+    the same key and value tiles (fetched once for all of them)."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import pallas as pl
 
-    qi, j = pl.program_id(2), pl.program_id(3)
-
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    kb = _first_kv(qi, bq, bk, window) + j
-    last = (qi * bq + bq - 1) // bk              # the block of the diagonal
-
-    @pl.when(kb <= last)
-    def _block():
-        k, v = k_ref[...], v_ref[...]
-        qpos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-        kpos = kb * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        seen = kpos <= qpos
-        if window:
-            seen = jnp.logical_and(seen, kpos > qpos - window)
-        for g in range(group):
-            q = q_ref[:, g * d:(g + 1) * d]
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32) * scale
-            _softmax_step(s, seen, v, m_scr, l_scr, acc_scr, g)
-
-    @pl.when(j == steps - 1)
-    def _store():
-        for g in range(group):
-            o_ref[:, g * d:(g + 1) * d] = (
-                acc_scr[g] / l_scr[g][:, :1]).astype(o_ref.dtype)
+    q_ref, k_ref, v_ref, _, *softmax = refs
+    k, v = k_ref[...], v_ref[...]
+    seen = sched.seen(qi, kb) if masked else None
+    for g in range(group):
+        q = q_ref[:, g * d:(g + 1) * d]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        _softmax_step(s, seen, v, *softmax, g)
 
 
 def gqa_pallas(q, k, v, window: int, heads: int, kv_heads: int,
@@ -312,51 +414,28 @@ def gqa_pallas(q, k, v, window: int, heads: int, kv_heads: int,
     """q ``[B, T, H * D]``, k / v ``[B, T, KV * D]`` -> ``[B, T, H * D]``: a
     head is a block of 128 lanes of the projection's own layout, so nothing
     is transposed; the ``H / KV`` query heads of a key/value head lie side by
-    side and go through one grid step together."""
+    side and go through one grid step together. Every block is masked."""
     import jax
-    import jax.numpy as jnp
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     B, T, _ = q.shape
     D = q.shape[2] // heads
     group = heads // kv_heads
-    bq, bk = _attn_blocks(T, window)
-    steps = _kv_steps(bq, bk, T, window)
-
-    def kv_index(b, h, qi, j):
-        # past the diagonal the index stays put: no block is fetched for a
-        # step that computes nothing
-        kb = jnp.minimum(_first_kv(qi, bq, bk, window) + j, (qi * bq + bq - 1) // bk)
-        return b, kb, h
-
-    kernel = functools.partial(_attn_kernel, bq=bq, bk=bk, window=window,
-                               steps=steps, scale=1.0 / math.sqrt(D),
-                               group=group, d=D)
+    sched = FlashSchedule(T, window)
+    step = functools.partial(_gqa_step, sched=sched, scale=1.0 / math.sqrt(D),
+                             group=group, d=D)
     pairs = T * (min(window, T) if window else (T + 1) / 2)   # (query, key) seen
-    return pl.pallas_call(
-        kernel,
-        grid=(B, kv_heads, T // bq, steps),
-        in_specs=[pl.BlockSpec((None, bq, group * D), lambda b, h, qi, j: (b, qi, h)),
-                  pl.BlockSpec((None, bk, D), kv_index),
-                  pl.BlockSpec((None, bk, D), kv_index)],
-        out_specs=pl.BlockSpec((None, bq, group * D), lambda b, h, qi, j: (b, qi, h)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((group, bq, 128), jnp.float32),
-                        pltpu.VMEM((group, bq, 128), jnp.float32),
-                        pltpu.VMEM((group, bq, D), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=64 * 1024 * 1024),
-        # the names a device trace shows (docs/observability.md)
-        name="attn_window" if window else "attn_full",
-        cost_estimate=pl.CostEstimate(
+    return sched.call(
+        sched.kernel(step, functools.partial(_divide, group=group, width=D), mask_all=True),
+        (q, k, v), [sched.query_spec(group * D), sched.key_spec(D), sched.key_spec(D)],
+        jax.ShapeDtypeStruct(q.shape, q.dtype), out_width=group * D, softmaxes=group,
+        value_width=D, name="attn_window" if window else "attn_full",
+        cost=pl.CostEstimate(
             flops=int(4 * B * heads * D * pairs),
             transcendentals=int(B * heads * pairs),
             bytes_accessed=int(2 * q.size * q.dtype.itemsize
-                               + 2 * k.size * k.dtype.itemsize * (T // bq))),
-        interpret=interpret,
-    )(q, k, v)
+                               + 2 * k.size * k.dtype.itemsize * (T // sched.bq))),
+        interpret=interpret)
 
 
 def _pallas_applies(q, heads: int, window: int) -> bool:
@@ -382,33 +461,14 @@ def _gqa_flat_xla(q, k, v, window: int, heads: int, kv_heads: int):
     return o.reshape(B, T, -1)
 
 
-@functools.lru_cache(maxsize=None)
-def _gqa_kernel():
-    """The kernel with a backward pass: it has none of its own, so the
-    gradient is the plain form's, recomputed."""
-    import jax
-
-    @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-    def attend(q, k, v, window, heads, kv_heads):
-        return gqa_pallas(q, k, v, window, heads, kv_heads)
-
-    def fwd(q, k, v, window, heads, kv_heads):
-        return gqa_pallas(q, k, v, window, heads, kv_heads), (q, k, v)
-
-    def bwd(window, heads, kv_heads, res, g):
-        _, vjp = jax.vjp(lambda q, k, v: _gqa_flat_xla(
-            q, k, v, window, heads, kv_heads), *res)
-        return vjp(g)
-
-    attend.defvjp(fwd, bwd)
-    return attend
+_gqa_pair = paired.pair(gqa_pallas, _gqa_flat_xla, static=3)
 
 
 def gq_attention(q, k, v, window: int, heads: int, kv_heads: int):
     """Causal grouped-query attention over flat heads (``[B, T, heads * D]``):
     the kernel where it applies, the plain form elsewhere."""
     if _pallas_applies(q, heads, window):
-        return _gqa_kernel()(q, k, v, window, heads, kv_heads)
+        return _gqa_pair(q, k, v, window, heads, kv_heads)
     return _gqa_flat_xla(q, k, v, window, heads, kv_heads)
 
 
@@ -444,132 +504,81 @@ def diff_xla(q, k, v, lam, gain, window: int, pairs: int, kv_pairs: int, eps: fl
     return o.reshape(q.shape).astype(q.dtype)
 
 
-def _diff_kernel(q_ref, k_ref, v_ref, p_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                 bq: int, bk: int, window: int, steps: int, scale: float,
-                 group: int, eps: float):
-    """``_attn_kernel`` for pairs: a block of queries of the ``group`` query
+def _diff_step(refs, qi, kb, masked: bool, *, sched: FlashSchedule, scale: float,
+               group: int):
+    """``_gqa_step`` for pairs: a block of queries of the ``group`` query
     pairs that share a key pair against one block of its keys. A pair's two
     score maps contract its own 64 lanes (the other half of the query tile
     zeroed: a 64-wide product costs the MXU a pass of 128 anyway) and both go
-    against the whole 128-wide value tile, each with its own running maximum,
-    denominator and accumulator; ``p_ref`` row 0 is ``lam``, row 1 ``gain``."""
+    against the whole 128-wide value tile, each its own softmax."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import pallas as pl
 
-    qi, j = pl.program_id(2), pl.program_id(3)
+    q_ref, k_ref, v_ref, _, _, *softmax = refs
+    k, v = k_ref[...], v_ref[...]
+    seen = sched.seen(qi, kb) if masked else None
+    first = jax.lax.broadcasted_iota(jnp.int32, (sched.bq, PAIR), 1) < PAIR // 2
+    for g in range(group):
+        q = q_ref[:, g * PAIR:(g + 1) * PAIR]
+        for half in range(2):
+            qh = jnp.where(first if half == 0 else jnp.logical_not(first),
+                           q, jnp.zeros_like(q))
+            s = jax.lax.dot_general(qh, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32) * scale
+            _softmax_step(s, seen, v, *softmax, 2 * g + half)
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    kb = _first_kv(qi, bq, bk, window) + j
-    last = (qi * bq + bq - 1) // bk              # the block of the diagonal
+def _diff_store(refs, group: int, eps: float):
+    """A pair's two softmaxes subtracted and RMS-normed: ``p_ref`` row 0 is
+    ``lam``, row 1 ``gain``."""
+    import jax
+    import jax.numpy as jnp
 
-    def pairs(masked: bool):
-        k, v = k_ref[...], v_ref[...]
-        seen = None
-        if masked:
-            qpos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            kpos = kb * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            seen = kpos <= qpos
-            if window:
-                seen = jnp.logical_and(seen, kpos > qpos - window)
-        first = jax.lax.broadcasted_iota(jnp.int32, (bq, PAIR), 1) < PAIR // 2
-        for g in range(group):
-            q = q_ref[:, g * PAIR:(g + 1) * PAIR]
-            for half in range(2):
-                qh = jnp.where(first if half == 0 else jnp.logical_not(first),
-                               q, jnp.zeros_like(q))
-                s = jax.lax.dot_general(qh, k, (((1,), (1,)), ((), ())),
-                                        preferred_element_type=jnp.float32) * scale
-                _softmax_step(s, seen, v, m_scr, l_scr, acc_scr, 2 * g + half)
-
-    if window:
-        pl.when(kb <= last)(lambda: pairs(True))
-    else:                    # blocks alike: only the diagonal's needs its mask
-        pl.when(kb < last)(lambda: pairs(False))
-        pl.when(kb == last)(lambda: pairs(True))
-
-    @pl.when(j == steps - 1)
-    def _store():
-        lam, gain = p_ref[0:1, :], p_ref[1:2, :]
-        for g in range(group):
-            o = acc_scr[2 * g] / l_scr[2 * g][:, :1] \
-                - lam * (acc_scr[2 * g + 1] / l_scr[2 * g + 1][:, :1])
-            o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=1, keepdims=True) + eps) * gain
-            o_ref[:, g * PAIR:(g + 1) * PAIR] = o.astype(o_ref.dtype)
+    p_ref, o_ref, _, l_scr, acc_scr = refs[-5:]
+    lam, gain = p_ref[0:1, :], p_ref[1:2, :]
+    for g in range(group):
+        o = acc_scr[2 * g] / l_scr[2 * g][:, :1] \
+            - lam * (acc_scr[2 * g + 1] / l_scr[2 * g + 1][:, :1])
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=1, keepdims=True) + eps) * gain
+        o_ref[:, g * PAIR:(g + 1) * PAIR] = o.astype(o_ref.dtype)
 
 
 def diff_pallas(q, k, v, lam, gain, window: int, pairs: int, kv_pairs: int,
                 eps: float, interpret: bool = False):
     """The kernel form of ``diff_xla`` (same arguments and result): a pair
     is a block of 128 lanes of the projections' own layout, read in place,
-    so another layer's ``k`` and ``v`` cost no copy."""
+    so another layer's ``k`` and ``v`` cost no copy. A window's blocks are
+    all masked, a full layer's diagonal alone."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     B, T, _ = q.shape
     group = pairs // kv_pairs
-    bq, bk = _attn_blocks(T, window)
-    steps = _kv_steps(bq, bk, T, window)
-
-    def kv_index(b, h, qi, j):       # past the diagonal nothing is fetched
-        kb = jnp.minimum(_first_kv(qi, bq, bk, window) + j, (qi * bq + bq - 1) // bk)
-        return b, kb, h
-
+    sched = FlashSchedule(T, window)
+    step = functools.partial(_diff_step, sched=sched, scale=1.0 / math.sqrt(PAIR // 2),
+                             group=group)
     rows = jnp.zeros((8, PAIR), jnp.float32)
     rows = rows.at[0].set(jnp.asarray(lam, jnp.float32)).at[1].set(gain.astype(jnp.float32))
     seen = T * (min(window, T) if window else (T + 1) / 2)    # (query, key) seen
-    return pl.pallas_call(
-        functools.partial(_diff_kernel, bq=bq, bk=bk, window=window, steps=steps,
-                          scale=1.0 / math.sqrt(PAIR // 2), group=group, eps=eps),
-        grid=(B, kv_pairs, T // bq, steps),
-        in_specs=[pl.BlockSpec((None, bq, group * PAIR), lambda b, h, qi, j: (b, qi, h)),
-                  pl.BlockSpec((None, bk, PAIR), kv_index),
-                  pl.BlockSpec((None, bk, PAIR), kv_index),
-                  pl.BlockSpec((8, PAIR), lambda b, h, qi, j: (0, 0))],
-        out_specs=pl.BlockSpec((None, bq, group * PAIR), lambda b, h, qi, j: (b, qi, h)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((2 * group, bq, 128), jnp.float32),
-                        pltpu.VMEM((2 * group, bq, 128), jnp.float32),
-                        pltpu.VMEM((2 * group, bq, PAIR), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=64 * 1024 * 1024),
-        # the names a device trace shows (docs/observability.md)
+    return sched.call(
+        sched.kernel(step, functools.partial(_diff_store, group=group, eps=eps),
+                     mask_all=bool(window)),
+        (q, k, v, rows),
+        [sched.query_spec(group * PAIR), sched.key_spec(PAIR), sched.key_spec(PAIR),
+         pl.BlockSpec((8, PAIR), lambda b, h, qi, j: (0, 0))],
+        jax.ShapeDtypeStruct(q.shape, q.dtype), out_width=group * PAIR,
+        softmaxes=2 * group, value_width=PAIR,
         name="attn_window_diff" if window else "attn_full_diff",
-        cost_estimate=pl.CostEstimate(
+        cost=pl.CostEstimate(
             flops=int(6 * B * pairs * PAIR * seen),
             transcendentals=int(2 * B * pairs * seen),
             bytes_accessed=int(2 * q.size * q.dtype.itemsize
-                               + 2 * k.size * k.dtype.itemsize * (T // bq))),
-        interpret=interpret,
-    )(q, k, v, rows)
+                               + 2 * k.size * k.dtype.itemsize * (T // sched.bq))),
+        interpret=interpret)
 
 
-@functools.lru_cache(maxsize=None)
-def _diff_kernel_vjp(interpret: bool = False):
-    """The kernel with a backward pass: the plain form's, recomputed."""
-    import jax
-
-    @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
-    def attend(q, k, v, lam, gain, window, pairs, kv_pairs, eps):
-        return diff_pallas(q, k, v, lam, gain, window, pairs, kv_pairs, eps, interpret)
-
-    def fwd(q, k, v, lam, gain, window, pairs, kv_pairs, eps):
-        return diff_pallas(q, k, v, lam, gain, window, pairs, kv_pairs, eps,
-                           interpret), (q, k, v, lam, gain)
-
-    def bwd(window, pairs, kv_pairs, eps, res, g):
-        return jax.vjp(lambda *a: diff_xla(*a, window, pairs, kv_pairs, eps), *res)[1](g)
-
-    attend.defvjp(fwd, bwd)
-    return attend
+_diff_pair = paired.pair(diff_pallas, diff_xla, static=4)
 
 
 def _diff_pallas_applies(q, window: int, pairs: int) -> bool:
@@ -588,7 +597,7 @@ def diff_attention(q, k, v, lam, gain, window: int, pairs: int, kv_pairs: int,
     """Causal differential attention over flat pairs: the kernel where it
     applies, the plain form elsewhere."""
     if _diff_pallas_applies(q, window, pairs):
-        return _diff_kernel_vjp()(q, k, v, lam, gain, window, pairs, kv_pairs, eps)
+        return _diff_pair(q, k, v, lam, gain, window, pairs, kv_pairs, eps)
     return diff_xla(q, k, v, lam, gain, window, pairs, kv_pairs, eps)
 
 
@@ -647,106 +656,81 @@ def mla_xla(q, kv, kr, heads: int, dn: int, bits=None):
 MLA_HEADS_A_STEP = 4      # heads that share one fetch of the rotary key
 
 
-def _mla_kernel(q_ref, kv_ref, kr_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                blk: int, group: int, dn: int, dq: int, dv: int):
+def _mla_step(refs, qi, kb, masked: bool, *, sched: FlashSchedule, group: int,
+              dn: int, dq: int, dv: int, bits: bool = False):
     """One block of queries of ``group`` heads against one block of keys:
     the shared rotary key is fetched once for all of them and joins each
-    head's content key as the last lanes of one ``dq``-wide contraction."""
+    head's content key as the last lanes of one ``dq``-wide contraction.
+    With ``bits`` every block is masked by the queries' selection: the
+    block's 128-lane chunks of keys are bits of the one tile of words the
+    block lies in, unpacked in place. The selection lies inside the causal
+    keys, so the diagonal block needs nothing more."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import pallas as pl
 
-    qi, j = pl.program_id(2), pl.program_id(3)
-
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    def heads(seen):
-        kr = kr_ref[...]
-        for g in range(group):
-            at = g * (dn + dv)
-            k = jnp.concatenate([kv_ref[:, at:at + dn], kr], axis=1)
-            s = jax.lax.dot_general(q_ref[:, g * dq:(g + 1) * dq], k,
-                                    (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-            _softmax_step(s, seen, kv_ref[:, at + dn:at + dn + dv],
-                          m_scr, l_scr, acc_scr, g)
-
-    @pl.when(j < qi)
-    def _below():            # every key of the block is before every query
-        heads(None)
-
-    @pl.when(j == qi)
-    def _diagonal():
-        heads(jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 1)
-              <= jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 0))
-        for g in range(group):
-            o_ref[:, g * dv:(g + 1) * dv] = (
-                acc_scr[g] / l_scr[g][:, :1]).astype(o_ref.dtype)
+    q_ref, kv_ref, kr_ref = refs[:3]
+    if bits:
+        words = refs[3][...]
+        c0 = (kb * sched.bk) % SELECT_GROUP // 128
+        seen = jnp.concatenate(
+            [jax.lax.shift_right_logical(words, c0 + c) & 1
+             for c in range(sched.bk // 128)], axis=1) != 0
+    else:
+        seen = sched.seen(qi, kb) if masked else None
+    kr = kr_ref[...]
+    for g in range(group):
+        at = g * (dn + dv)
+        k = jnp.concatenate([kv_ref[:, at:at + dn], kr], axis=1)
+        s = jax.lax.dot_general(q_ref[:, g * dq:(g + 1) * dq], k,
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        _softmax_step(s, seen, kv_ref[:, at + dn:at + dn + dv], *refs[-3:], g)
 
 
-def mla_pallas(q, kv, kr, heads: int, dn: int, interpret: bool = False):
-    """The kernel form of ``mla_xla`` (same arguments and result): ``dn``,
-    ``dq`` and ``dv`` whole tiles of 128 lanes, query and key blocks alike."""
+def _mla_call(q, kv, kr, bits, heads: int, dn: int, interpret: bool):
+    """``attn_mla`` (``bits`` None: the diagonal block masked, causal) or
+    ``attn_mla_sparse`` (every block masked by the selection's tile of words
+    beside it): ``dn``, ``dq`` and ``dv`` whole tiles of 128 lanes, query and
+    key blocks alike, the result stored at the diagonal's step."""
     import jax
-    import jax.numpy as jnp
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     B, T, _ = q.shape
     dq, dv = q.shape[2] // heads, kv.shape[2] // heads - dn
     group = next(g for g in (MLA_HEADS_A_STEP, 2, 1) if heads % g == 0)
-    blk = _attn_blocks(T, 0)[0]
-
-    def key_index(b, h, qi, j):
-        return b, jnp.minimum(j, qi), h      # past the diagonal nothing is fetched
-
+    sched = FlashSchedule(T, 0)
+    sparse = bits is not None
+    step = functools.partial(_mla_step, sched=sched, group=group, dn=dn, dq=dq, dv=dv,
+                             bits=sparse)
+    specs = [sched.query_spec(group * dq), sched.key_spec(group * (dn + dv)),
+             sched.key_spec(dq - dn, shared=True)]
+    operands = (q, kv, kr)
     pairs = T * (T + 1) / 2
-    return pl.pallas_call(
-        functools.partial(_mla_kernel, blk=blk, group=group, dn=dn, dq=dq, dv=dv),
-        grid=(B, heads // group, T // blk, T // blk),
-        in_specs=[pl.BlockSpec((None, blk, group * dq), lambda b, h, qi, j: (b, qi, h)),
-                  pl.BlockSpec((None, blk, group * (dn + dv)), key_index),
-                  pl.BlockSpec((None, blk, dq - dn),
-                               lambda b, h, qi, j: (b, jnp.minimum(j, qi), 0))],
-        out_specs=pl.BlockSpec((None, blk, group * dv), lambda b, h, qi, j: (b, qi, h)),
-        out_shape=jax.ShapeDtypeStruct((B, T, heads * dv), q.dtype),
-        scratch_shapes=[pltpu.VMEM((group, blk, 128), jnp.float32),
-                        pltpu.VMEM((group, blk, 128), jnp.float32),
-                        pltpu.VMEM((group, blk, dv), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=64 * 1024 * 1024),
-        name="attn_mla",         # the name a device trace shows
-        cost_estimate=pl.CostEstimate(
-            flops=int(2 * B * heads * (dq + dv) * pairs),
-            transcendentals=int(B * heads * pairs),
-            bytes_accessed=int(q.size * q.dtype.itemsize * (1 + dv / dq)
-                               + kv.size * kv.dtype.itemsize * (T // blk + 1) / 2)),
-        interpret=interpret,
-    )(q, kv, kr)
+    moved = (q.size * q.dtype.itemsize * (1 + dv / dq)
+             + kv.size * kv.dtype.itemsize * (T // sched.bq + 1) / 2)
+    if sparse:
+        specs.append(pl.BlockSpec((None, sched.bq, 128), lambda b, h, qi, j: (
+            b, qi, sched.fetched(qi, j) * sched.bk // SELECT_GROUP)))
+        operands += (bits,)
+        moved += bits.size * 4 * heads // group
+    return sched.call(
+        sched.kernel(step, functools.partial(_divide, group=group, width=dv),
+                     mask_all=sparse, store_at_diagonal=True),
+        operands, specs, jax.ShapeDtypeStruct((B, T, heads * dv), q.dtype),
+        out_width=group * dv, softmaxes=group, value_width=dv,
+        name="attn_mla_sparse" if sparse else "attn_mla",
+        cost=pl.CostEstimate(flops=int(2 * B * heads * (dq + dv) * pairs),
+                             transcendentals=int(B * heads * pairs),
+                             bytes_accessed=int(moved)),
+        interpret=interpret)
 
 
-@functools.lru_cache(maxsize=None)
-def _mla_kernel_vjp(interpret: bool = False):
-    """The kernel with a backward pass: the plain form's, recomputed."""
-    import jax
+def mla_pallas(q, kv, kr, heads: int, dn: int, interpret: bool = False):
+    """The kernel form of ``mla_xla`` (same arguments and result)."""
+    return _mla_call(q, kv, kr, None, heads, dn, interpret)
 
-    @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-    def attend(q, kv, kr, heads, dn):
-        return mla_pallas(q, kv, kr, heads, dn, interpret)
 
-    def fwd(q, kv, kr, heads, dn):
-        return mla_pallas(q, kv, kr, heads, dn, interpret), (q, kv, kr)
-
-    def bwd(heads, dn, res, g):
-        return jax.vjp(lambda q, kv, kr: mla_xla(q, kv, kr, heads, dn), *res)[1](g)
-
-    attend.defvjp(fwd, bwd)
-    return attend
+_mla_pair = paired.pair(mla_pallas, mla_xla, static=2)
 
 
 def _mla_pallas_applies(x, nope: int, v_dim: int) -> bool:
@@ -986,115 +970,14 @@ def mla_sparse_xla(q, kv, kr, bits, heads: int, dn: int):
     return mla_xla(q, kv, kr, heads, dn, bits)
 
 
-def _mla_sparse_kernel(q_ref, kv_ref, kr_ref, b_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                       blk: int, group: int, dn: int, dq: int, dv: int):
-    """``_mla_kernel`` with every block masked by the query's selection: the
-    block's 128-lane chunks of keys are bits of the one tile of words the
-    block lies in, unpacked in place. The selection lies inside the causal
-    keys, so the diagonal block needs nothing more."""
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    qi, j = pl.program_id(2), pl.program_id(3)
-
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    @pl.when(j <= qi)
-    def _block():
-        words = b_ref[...]
-        c0 = (j * blk) % SELECT_GROUP // 128
-        seen = jnp.concatenate(
-            [jax.lax.shift_right_logical(words, c0 + c) & 1 for c in range(blk // 128)],
-            axis=1) != 0
-        kr = kr_ref[...]
-        for g in range(group):
-            at = g * (dn + dv)
-            k = jnp.concatenate([kv_ref[:, at:at + dn], kr], axis=1)
-            s = jax.lax.dot_general(q_ref[:, g * dq:(g + 1) * dq], k,
-                                    (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-            _softmax_step(s, seen, kv_ref[:, at + dn:at + dn + dv],
-                          m_scr, l_scr, acc_scr, g)
-
-    @pl.when(j == qi)
-    def _store():
-        for g in range(group):
-            o_ref[:, g * dv:(g + 1) * dv] = (
-                acc_scr[g] / l_scr[g][:, :1]).astype(o_ref.dtype)
-
-
 def mla_sparse_pallas(q, kv, kr, bits, heads: int, dn: int, interpret: bool = False):
     """The kernel form of ``mla_sparse_xla`` (same arguments and result):
     ``mla_pallas``'s grid and blocks, the selection's tile of words beside
     each key block."""
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    B, T, _ = q.shape
-    dq, dv = q.shape[2] // heads, kv.shape[2] // heads - dn
-    group = next(g for g in (MLA_HEADS_A_STEP, 2, 1) if heads % g == 0)
-    blk = _attn_blocks(T, 0)[0]
-
-    def key_index(b, h, qi, j):
-        return b, jnp.minimum(j, qi), h      # past the diagonal nothing is fetched
-
-    def bits_index(b, h, qi, j):
-        return b, qi, jnp.minimum(j, qi) * blk // SELECT_GROUP
-
-    pairs = T * (T + 1) / 2
-    return pl.pallas_call(
-        functools.partial(_mla_sparse_kernel, blk=blk, group=group, dn=dn, dq=dq, dv=dv),
-        grid=(B, heads // group, T // blk, T // blk),
-        in_specs=[pl.BlockSpec((None, blk, group * dq), lambda b, h, qi, j: (b, qi, h)),
-                  pl.BlockSpec((None, blk, group * (dn + dv)), key_index),
-                  pl.BlockSpec((None, blk, dq - dn),
-                               lambda b, h, qi, j: (b, jnp.minimum(j, qi), 0)),
-                  pl.BlockSpec((None, blk, 128), bits_index)],
-        out_specs=pl.BlockSpec((None, blk, group * dv), lambda b, h, qi, j: (b, qi, h)),
-        out_shape=jax.ShapeDtypeStruct((B, T, heads * dv), q.dtype),
-        scratch_shapes=[pltpu.VMEM((group, blk, 128), jnp.float32),
-                        pltpu.VMEM((group, blk, 128), jnp.float32),
-                        pltpu.VMEM((group, blk, dv), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=64 * 1024 * 1024),
-        name="attn_mla_sparse",  # the name a device trace shows
-        cost_estimate=pl.CostEstimate(
-            flops=int(2 * B * heads * (dq + dv) * pairs),
-            transcendentals=int(B * heads * pairs),
-            bytes_accessed=int(q.size * q.dtype.itemsize * (1 + dv / dq)
-                               + kv.size * kv.dtype.itemsize * (T // blk + 1) / 2
-                               + bits.size * 4 * heads // group)),
-        interpret=interpret,
-    )(q, kv, kr, bits)
+    return _mla_call(q, kv, kr, bits, heads, dn, interpret)
 
 
-@functools.lru_cache(maxsize=None)
-def _mla_sparse_kernel_vjp(interpret: bool = False):
-    """The kernel with a backward pass: the plain form's, recomputed."""
-    import jax
-
-    @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-    def attend(q, kv, kr, bits, heads, dn):
-        return mla_sparse_pallas(q, kv, kr, bits, heads, dn, interpret)
-
-    def fwd(q, kv, kr, bits, heads, dn):
-        return mla_sparse_pallas(q, kv, kr, bits, heads, dn, interpret), (q, kv, kr, bits)
-
-    def bwd(heads, dn, res, g):
-        q, kv, kr, bits = res
-        return (*jax.vjp(lambda q, kv, kr: mla_sparse_xla(q, kv, kr, bits, heads, dn),
-                         q, kv, kr)[1](g), None)
-
-    attend.defvjp(fwd, bwd)
-    return attend
+_mla_sparse_pair = paired.pair(mla_sparse_pallas, mla_sparse_xla, static=2, nondiff=(3,))
 
 
 def _index_pallas_applies(x, index_dim: int) -> bool:
@@ -1319,7 +1202,8 @@ class LatentAttention(Module):
             qi, ki, wi = self.index_inputs(params, x, c_q)
         with scope("select"):
             if _index_pallas_applies(x, self.index_dim):
-                return mla_index_pallas(qi, ki, wi, self.index_heads, self.index_topk)
+                return mla_index_pallas(qi, ki, wi, self.index_heads, self.index_topk,
+                                        paired.INTERPRET)
             return mla_index_xla(qi, ki, wi, self.index_heads, self.index_topk)
 
     def apply(self, params, x, train: bool = False):
@@ -1375,9 +1259,9 @@ class LatentAttention(Module):
                      "wkv_b", dt)                    # a head's keys, then its values
         with scope("core"):
             if bits is None:
-                o = (_mla_kernel_vjp() if kernel else mla_xla)(q, kv, kr, h, nope)
+                o = (_mla_pair if kernel else mla_xla)(q, kv, kr, h, nope)
             else:
-                o = (_mla_sparse_kernel_vjp() if kernel else mla_sparse_xla)(
+                o = (_mla_sparse_pair if kernel else mla_sparse_xla)(
                     q, kv, kr, bits, h, nope)
         with scope("proj_out"):
             if self.out_gate:
@@ -1477,14 +1361,10 @@ def _by_rows(fn, x, most_tokens: int = 8192, positionwise: bool = False):
 
     B, T, D = x.shape
     if positionwise and T > most_tokens:
-        pieces = -(-T // most_tokens)
-        while T % pieces:
-            pieces += 1
+        pieces = equal_pieces(T, most_tokens)
         return _by_rows(fn, x.reshape(B * pieces, T // pieces, D),
                         most_tokens).reshape(B, T, -1)
-    groups = -(-B // max(1, most_tokens // T))
-    while B % groups:
-        groups += 1
+    groups = equal_pieces(B, max(1, most_tokens // T))
     if groups == 1:
         return fn(x)
     return jax.lax.map(fn, x.reshape(groups, B // groups, T, D)).reshape(x.shape)
@@ -1743,9 +1623,7 @@ class CausalLM(Module):
 
         # a row in equal pieces whose logits are at most LOGITS_BLOCK numbers
         B, T, D = x.shape
-        pieces = -(-T // max(1, LOGITS_BLOCK // self.vocab_size))
-        while T % pieces:
-            pieces += 1
+        pieces = equal_pieces(T, max(1, LOGITS_BLOCK // self.vocab_size))
         return jax.lax.map(row, (x.reshape(B * pieces, T // pieces, D),
                                  target.reshape(B * pieces, T // pieces))).reshape(B, T)
 

@@ -52,3 +52,37 @@ def assert_df_equality(df1, df2, eps: float = 1e-4):
             np.testing.assert_allclose(a, b, atol=eps, err_msg=f"column {name}")
         else:
             np.testing.assert_array_equal(a, b, err_msg=f"column {name}")
+
+
+def _pallas_names(fn, *args):
+    """The names of the Pallas kernels ``fn(*args)`` traces, in order: the
+    names a device trace shows."""
+    from jax._src import core
+
+    names = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                names.append(eqn.params["name"])
+                continue
+            for value in eqn.params.values():
+                for j in value if isinstance(value, (tuple, list)) else (value,):
+                    if isinstance(j, core.ClosedJaxpr):
+                        walk(j.jaxpr)
+                    elif isinstance(j, core.Jaxpr):
+                        walk(j)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return names
+
+
+@pytest.fixture
+def interpreted(monkeypatch):
+    """Every kernel of the models in the Pallas interpreter where its
+    dispatch takes it (``models/paired.py``); the fixture's value lists the
+    kernels a function traces (``names(fn, *args)``)."""
+    from mmlspark_tpu.models import paired
+
+    monkeypatch.setattr(paired, "INTERPRET", True)
+    return _pallas_names

@@ -1,7 +1,6 @@
 """Sequence models: ring attention == dense attention on a real 8-device
 seq mesh, transformer encoder, BiLSTM tagger."""
 
-import functools
 import re
 
 import jax
@@ -391,16 +390,15 @@ class TestLSTMKernel:
         assert float(jnp.abs(got - _plain_lstm(p, x, reverse)).max()) < 2e-2
 
     @pytest.mark.parametrize("reverse", [False, True])
-    def test_gradient_through_the_kernel_is_the_plain_forms(self, reverse):
+    def test_gradient_through_the_kernel_is_the_plain_forms(self, reverse, interpreted):
         x, p = _lstm_operands(8, 19, 3, 5)
         w = jnp.cos(jnp.arange(8 * 19 * 5, dtype=jnp.float32)).reshape(8, 19, 5)
 
         def loss(fn):
             return lambda p, v: jnp.sum(w * fn(p, v))
 
-        kernel = attention._lstm_kernel_vjp(reverse, False, True)
-        got = jax.grad(loss(lambda p, v: kernel(v, p["wx"], p["wh"], p["b"], None)),
-                       argnums=(0, 1))(p, x)
+        got = jax.grad(loss(lambda p, v: attention._lstm_pair(
+            v, p["wx"], p["wh"], p["b"], None, reverse, False)), argnums=(0, 1))(p, x)
         want = jax.grad(loss(lambda p, v: _plain_lstm(p, v, reverse)),
                         argnums=(0, 1))(p, x)
         for g, e in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
@@ -410,18 +408,21 @@ class TestLSTMKernel:
     @staticmethod
     def _kernels_on_the_cpu(monkeypatch, calls):
         """``BiLSTM`` as on a TPU, its kernels in the interpreter at float32."""
+        kernel = attention.lstm_scan_pallas
         monkeypatch.setattr(attention, "_lstm_kernel_applies", lambda x, hidden: True)
-        monkeypatch.setattr(attention, "_lstm_kernel_vjp", lambda reverse, padded=False: (
-            lambda x, wx, wh, b, beside: calls.append((reverse, padded, beside is not None))
-            or attention.lstm_scan_pallas(x, wx, wh, b, reverse, beside, padded,
-                                          interpret=True, operands="float32")))
+        monkeypatch.setattr(attention, "lstm_scan_pallas", lambda x, wx, wh, b, reverse,
+                            beside, padded, interpret: calls.append(
+                                (reverse, padded, beside is not None))
+                            or kernel(x, wx, wh, b, reverse, beside, padded, interpret,
+                                      operands="float32"))
 
     # H = 5, 100, 300 end inside a lane tile (the second direction is rotated
     # to there: 100 + 100 and 300 + 300 spill into a tile of their own, 5 + 5
     # does not), 128 at its end, 130 two lanes into the next
     @pytest.mark.parametrize("B,T,D,H", [(8, 16, 3, 5), (24, 19, 7, 100), (8, 9, 3, 128),
                                          (16, 11, 200, 130), (8, 16, 50, 300)])
-    def test_bilstm_is_the_two_directions_side_by_side(self, B, T, D, H, monkeypatch):
+    def test_bilstm_is_the_two_directions_side_by_side(self, B, T, D, H, monkeypatch,
+                                                       interpreted):
         """The first direction's kernel leaves its result time-major and
         lane-padded, the second's writes both halves of every position."""
         x, pf = _lstm_operands(B, T, D, H)
@@ -437,7 +438,7 @@ class TestLSTMKernel:
         assert float(jnp.abs(got - want).max()) < 2e-6
         assert float(jnp.abs(got - plain).max()) < 2e-6
 
-    def test_gradient_through_both_kernels_of_a_bilstm(self, monkeypatch):
+    def test_gradient_through_both_kernels_of_a_bilstm(self, monkeypatch, interpreted):
         x, pf = _lstm_operands(8, 11, 3, 5)
         _, pb = _lstm_operands(8, 11, 3, 5, seed=7)
         bi, params = BiLSTM(hidden=5), {"fwd": pf, "bwd": pb}
@@ -448,8 +449,6 @@ class TestLSTMKernel:
 
         want = grads()
         monkeypatch.setattr(attention, "_lstm_kernel_applies", lambda x, hidden: True)
-        monkeypatch.setattr(attention, "_lstm_kernel_vjp", functools.partial(
-            attention._lstm_kernel_vjp.__wrapped__, interpret=True))
         for g, e in zip(jax.tree.leaves(grads()), jax.tree.leaves(want)):
             np.testing.assert_allclose(np.asarray(g), np.asarray(e), rtol=1e-4, atol=1e-5)
 

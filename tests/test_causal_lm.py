@@ -248,6 +248,47 @@ def test_put_params_says_how_many_bytes_of_which_dtype(ref, builder):
     assert spans[-1]["attrs"]["dtypes"] == f"bfloat16={nbytes}"
 
 
+# -- the flash schedule -------------------------------------------------------
+
+# (T, window) -> (query block, key block, steps a query block): the language
+# cells' attention layers (attn_window / attn_full in kexaone.score, the
+# differential pair in phi4flash.score, attn_mla in xing4.score,
+# attn_mla_sparse in axk2.score), then tiny ones
+FLASH_SCHEDULES = [
+    (4096, 128, (256, 128, 3)), (4096, 0, (512, 512, 8)), (32768, 512, (256, 128, 6)),
+    (32768, 0, (512, 512, 64)), (16384, 0, (512, 512, 32)),
+    (128, 0, (128, 128, 1)), (256, 64, (256, 128, 2)), (384, 130, (128, 128, 3)),
+    (512, 0, (512, 512, 1)), (512, 130, (256, 128, 4)), (640, 0, (128, 128, 5)),
+    (1024, 512, (256, 128, 6)), (1024, 100, (256, 128, 3)),
+]
+
+
+@pytest.mark.parametrize("T,window,blocks", FLASH_SCHEDULES)
+def test_the_flash_schedule_computes_and_fetches_the_band(T, window, blocks):
+    """The key blocks a query block computes are exactly those holding a key
+    one of its queries sees (causal, inside the window), one a step, in
+    order, the diagonal's last; a step that computes nothing fetches the
+    block the step before it fetched."""
+    s = transformer.FlashSchedule(T, window)
+    assert (s.bq, s.bk, s.steps, s.blocks) == (*blocks, T // blocks[0])
+    qi, j = np.arange(s.blocks)[:, None], np.arange(s.steps)[None, :]
+    kb = np.broadcast_to(np.asarray(s.key_block(qi, j)), (s.blocks, s.steps))
+    last = np.asarray(s.diagonal(qi))
+    fetched = np.asarray(s.fetched(qi, j))
+    # a block pair holds a seen (query, key) where q - k, over [q0 - k1, q1 - k0],
+    # meets [0, window - 1]
+    k0 = np.arange(T // s.bk)[None, :] * s.bk
+    q0 = qi * s.bq
+    band = (q0 + s.bq - 1 - k0 >= 0) & (q0 - (k0 + s.bk - 1) <= (window or T) - 1)
+    for i in range(s.blocks):
+        computes = kb[i] <= last[i]
+        assert list(kb[i][computes]) == list(np.flatnonzero(band[i]))
+        assert kb[i][computes][-1] == last[i]
+        assert (fetched[i][computes] == kb[i][computes]).all()
+        idle = np.flatnonzero(~computes)
+        assert (fetched[i][idle] == fetched[i][idle - 1]).all() and 0 not in idle
+
+
 # -- the kernels, in the interpreter ----------------------------------------
 
 @pytest.mark.parametrize("window", [0, 128, 200])
@@ -389,7 +430,8 @@ def test_the_combines_form_follows_from_the_layers_own_shape(held, experts, back
 
 
 @pytest.mark.parametrize("add_to", [False, True])
-def test_the_gradient_through_the_combine_kernel_is_the_scans(add_to, monkeypatch):
+def test_the_gradient_through_the_combine_kernel_is_the_scans(add_to, monkeypatch,
+                                                              interpreted):
     """A small `ExpertLayer` whose combine is the kernel (in the interpreter,
     groups padded to its chunk of 128 rows): values and gradients, with
     respect to the input, every parameter and `add_to`, are the plain form's."""
@@ -405,15 +447,13 @@ def test_the_gradient_through_the_combine_kernel_is_the_scans(add_to, monkeypatc
         return jnp.sum(y * tilt), y
 
     (_, want_y), want = jax.value_and_grad(loss, (0, 1, 2), has_aux=True)(params, x, base)
-    calls = []
-    kernel = moe._combine_kernel_vjp(True)
     monkeypatch.setattr(moe, "_gmm_tile_rows", lambda x: 128)
     monkeypatch.setattr(moe, "grouped_matmul",
                         lambda lhs, rhs, sizes, dt, tile: moe._gmm_ragged(lhs, rhs, sizes, dt))
     monkeypatch.setattr(moe, "_combine_kernel_applies", lambda *a: True)
-    monkeypatch.setattr(moe, "_combine_kernel_vjp",
-                        lambda: lambda *a: calls.append("moe_combine") or kernel(*a))
-    (_, got_y), got = jax.value_and_grad(loss, (0, 1, 2), has_aux=True)(params, x, base)
+    grads = jax.value_and_grad(loss, (0, 1, 2), has_aux=True)
+    calls = interpreted(grads, params, x, base)
+    (_, got_y), got = grads(params, x, base)
     assert calls == ["moe_combine"]
     assert float(jnp.abs(got_y - want_y).max()) <= 1e-6 * float(jnp.abs(want_y).max())
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
@@ -468,15 +508,13 @@ def _with_the_kernels_in_the_interpreter(monkeypatch):
     """An `ExpertLayer` whose products are the kernels in the interpreter:
     rows padded to a tile of 128; the first product the gathering one while
     the list returned holds True."""
+    from mmlspark_tpu.models import paired
+
     gathers = [False]
-    kernel, layout = moe._gather_kernel_vjp(True), moe._layout_kernel_vjp(True)
+    monkeypatch.setattr(paired, "INTERPRET", True)
     monkeypatch.setattr(moe, "GMM_TILE", (128, 256, 256))
     monkeypatch.setattr(moe, "_gmm_tile_rows", lambda x: 128)
-    monkeypatch.setattr(moe, "grouped_matmul", lambda lhs, rhs, sizes, dt, tile:
-                        moe._gmm_kernel_vjp(True)(lhs, rhs, sizes, dt))
     monkeypatch.setattr(moe, "_gmm_gathers", lambda *a: gathers[0])
-    monkeypatch.setattr(moe, "_gather_kernel_vjp", lambda: kernel)
-    monkeypatch.setattr(moe, "_layout_kernel_vjp", lambda: layout)
     return gathers
 
 

@@ -351,7 +351,7 @@ def test_the_scan_kernel_agrees_with_the_plain_form(t):
         assert float(jnp.abs(a - b).max()) < 1e-5 * max(1.0, float(jnp.abs(b).max()))
 
 
-def test_the_scan_kernels_gradient_is_the_plain_forms():
+def test_the_scan_kernels_gradient_is_the_plain_forms(interpreted):
     ops = _scan_operands(6, B=1, t=16, C=1024, N=4)
     tilt = jnp.cos(jnp.arange(1024, dtype=jnp.float32))
 
@@ -361,7 +361,7 @@ def test_the_scan_kernels_gradient_is_the_plain_forms():
             return jnp.sum(y * tilt) + jnp.sum(h * h)
         return f
 
-    got = jax.grad(loss(ssm._scan_kernel_vjp(True)), range(7))(*ops)
+    got = jax.grad(loss(ssm._scan_pair), range(7))(*ops)
     want = jax.grad(loss(ssm.ssm_scan_xla), range(7))(*ops)
     for a, b in zip(got, want):
         assert float(jnp.abs(a - b).max()) <= 1e-5 * max(float(jnp.abs(b).max()), 1e-30)
@@ -398,20 +398,20 @@ def test_the_plain_differential_form_is_the_two_masked_softmaxes(window, model):
     assert float(jnp.abs(got - want).max()) < 1e-4
 
 
-def test_the_differential_kernels_gradient_is_the_plain_forms():
+def test_the_differential_kernels_gradient_is_the_plain_forms(interpreted):
     q, k, v, lam, gain = _diff_operands(3, 128, pairs=2, kv_pairs=1, dtype=jnp.float32)
     tilt = jnp.cos(jnp.arange(256, dtype=jnp.float32))
 
     def loss(attend):
         return lambda *a: jnp.sum(attend(*a, 0, 2, 1, EPS).astype(jnp.float32) * tilt)
 
-    got = jax.grad(loss(transformer._diff_kernel_vjp(True)), range(5))(q, k, v, lam, gain)
+    got = jax.grad(loss(transformer._diff_pair), range(5))(q, k, v, lam, gain)
     want = jax.grad(loss(transformer.diff_xla), range(5))(q, k, v, lam, gain)
     for a, b in zip(got, want):
         assert float(jnp.abs(a - b).max()) <= 1e-5 * float(jnp.abs(b).max())
 
 
-def test_the_modules_take_their_kernels_where_they_apply(monkeypatch):
+def test_the_modules_take_their_kernels_where_they_apply(monkeypatch, interpreted):
     """On a TPU the mixers hand their cores to the kernels; here the kernels
     run in the interpreter in their place (heads of 64, 1,024 channels), and
     the model's result is that of its plain path."""
@@ -419,19 +419,14 @@ def test_the_modules_take_their_kernels_where_they_apply(monkeypatch):
                                          d_state=4, seed=2)
     ids = np.random.default_rng(1).integers(1, VOCAB, (1, 128), dtype=np.int32)
 
-    def run():
+    def run(through=lambda fn, *a: fn(*a)):     # `interpreted`: the kernels it traces
         with matmul_precision("float32"):
-            return jax.jit(lambda p, x: model.module.apply(p, x))(model.params, ids)
+            return through(jax.jit(lambda p, x: model.module.apply(p, x)), model.params, ids)
 
     plain = run()
-    calls = []
     monkeypatch.setattr(ssm, "_scan_kernel_applies", lambda *a: True)
-    monkeypatch.setattr(ssm, "_scan_kernel_vjp", lambda: (
-        lambda *a: calls.append("ssm_scan") or ssm.ssm_scan_pallas(*a, interpret=True)))
     monkeypatch.setattr(transformer, "_diff_pallas_applies", lambda *a: True)
-    monkeypatch.setattr(transformer, "_diff_kernel_vjp", lambda: (
-        lambda *a: calls.append("attn_window_diff" if a[5] else "attn_full_diff")
-        or transformer.diff_pallas(*a, interpret=True)))
+    calls = run(interpreted)
     kernels = run()
     assert calls == ["ssm_scan", "attn_window_diff", "ssm_scan", "attn_window_diff",
                      "ssm_scan", "attn_full_diff", "attn_full_diff"]

@@ -340,14 +340,14 @@ def test_the_stream_kernels_agree_with_their_plain_forms():
                          - residual.mhc_post_plain(x, y, h, n)).max()) < 1e-5
 
 
-def test_the_kernels_gradients_are_their_plain_forms():
+def test_the_kernels_gradients_are_their_plain_forms(interpreted):
     q, kv, kr = (a.astype(jnp.float32) for a in _mla_operands(7, B=1, H=2))
     tilt = jnp.cos(jnp.arange(2 * 128, dtype=jnp.float32))
 
     def loss(attend):
         return lambda q, kv, kr: jnp.sum(attend(q, kv, kr, 2, 128).astype(jnp.float32) * tilt)
 
-    got = jax.grad(loss(transformer._mla_kernel_vjp(True)), (0, 1, 2))(q, kv, kr)
+    got = jax.grad(loss(transformer._mla_pair), (0, 1, 2))(q, kv, kr)
     want = jax.grad(loss(transformer.mla_xla), (0, 1, 2))(q, kv, kr)
     for a, b in zip(got, want):
         assert float(jnp.abs(a - b).max()) <= 1e-5 * float(jnp.abs(b).max())
@@ -359,7 +359,7 @@ def test_the_kernels_gradients_are_their_plain_forms():
     pre = jnp.asarray([1.0, 0.3, -0.2, 0.5, 0.1], jnp.float32)
     y = jnp.asarray(rng.normal(size=(N, d)), jnp.float32)
     h = jnp.asarray(rng.uniform(size=(N, n + n * n)), jnp.float32)
-    k_pre, k_post = residual._kernels_with_vjp(True)
+    k_pre, k_post = residual._pre_pair, residual._post_pair
 
     def both(pre_fn, post_fn):
         def f(x, phi, pre, y, h):
@@ -375,7 +375,7 @@ def test_the_kernels_gradients_are_their_plain_forms():
         assert float(jnp.abs(a - b).max()) <= 1e-5 * float(jnp.abs(b).max())
 
 
-def test_the_modules_take_their_kernels_where_they_apply(monkeypatch):
+def test_the_modules_take_their_kernels_where_they_apply(monkeypatch, interpreted):
     """On a TPU the two modules pad and lay out their operands for the
     kernels; here the kernels run in the interpreter in their place, and the
     modules' results are those of their plain paths."""
@@ -388,20 +388,18 @@ def test_the_modules_take_their_kernels_where_they_apply(monkeypatch):
     hc_params, _ = hc.init(jax.random.key(6), (128, 128))
     streams = jax.random.normal(jax.random.key(7), (1, 128, 4 * 128), jnp.float32)
 
-    def run():
+    def both():
         with matmul_precision("float32"):
             x_in, coeffs = hc.pre(hc_params, streams)
             return layer.apply(params, x), hc.post(streams, jnp.sin(x_in), coeffs)
 
+    def run(through=lambda fn: fn()):          # `interpreted`: the kernels it traces
+        return through(both)
+
     plain = run()
-    calls = []
     monkeypatch.setattr(transformer, "_mla_pallas_applies", lambda *a: True)
-    monkeypatch.setattr(transformer, "_mla_kernel_vjp", lambda: (
-        lambda *a: calls.append("attn_mla") or transformer.mla_pallas(*a, interpret=True)))
     monkeypatch.setattr(residual, "_kernels_apply", lambda *a: True)
-    monkeypatch.setattr(residual, "_kernels_with_vjp", lambda: (
-        lambda *a: calls.append("mhc_pre") or residual.mhc_pre_pallas(*a, interpret=True),
-        lambda *a: calls.append("mhc_post") or residual.mhc_post_pallas(*a, interpret=True)))
+    calls = run(interpreted)
     kernels = run()
     assert calls == ["mhc_pre", "attn_mla", "mhc_post"]
     for a, b in zip(kernels, plain):

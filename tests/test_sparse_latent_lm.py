@@ -392,7 +392,7 @@ def test_the_sparse_core_kernel_agrees_with_the_plain_form():
     assert relative(got.astype(jnp.float32), want.astype(jnp.float32)) < 1e-2
 
 
-def test_the_sparse_core_kernels_gradient_is_the_plain_forms():
+def test_the_sparse_core_kernels_gradient_is_the_plain_forms(interpreted):
     B, t, H = 1, 256, 2
     k = jax.random.split(jax.random.key(13), 4)
     q, kv = (jax.random.normal(k[i], (B, t, H * 256)) for i in range(2))
@@ -404,17 +404,16 @@ def test_the_sparse_core_kernels_gradient_is_the_plain_forms():
     def loss(fn):
         return lambda q, kv, kr: jnp.sum(fn(q, kv, kr, bits, H, 128) ** 2)
 
-    got = jax.grad(loss(transformer._mla_sparse_kernel_vjp(True)), (0, 1, 2))(q, kv, kr)
+    got = jax.grad(loss(transformer._mla_sparse_pair), (0, 1, 2))(q, kv, kr)
     want = jax.grad(loss(transformer.mla_sparse_xla), (0, 1, 2))(q, kv, kr)
     for a, b in zip(got, want):       # the forward passes differ in rounding alone
         assert relative(a, b) < 1e-4
 
 
-def test_the_layer_takes_its_kernels_where_they_apply(monkeypatch):
+def test_the_layer_takes_its_kernels_where_they_apply(monkeypatch, interpreted):
     """On a TPU at bfloat16 the layer's indexer and core are the kernels;
     here they run in the interpreter in their place, and the layer's output
     is the plain forms'."""
-    calls = []
     layer = transformer.LatentAttention(2, 64, 64, 128, 64, 128, 10000.0, None, 1e-6,
                                         index_heads=2, index_dim=128, index_topk=100,
                                         out_gate=True)
@@ -423,11 +422,7 @@ def test_the_layer_takes_its_kernels_where_they_apply(monkeypatch):
     with matmul_precision("bfloat16"):
         want = layer.apply(params, x)
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        monkeypatch.setattr(transformer, "mla_index_pallas", lambda *a: calls.append(
-            "mla_index") or transformer.mla_index_xla(*a))
-        monkeypatch.setattr(transformer, "_mla_sparse_kernel_vjp", lambda: (
-            lambda *a: calls.append("attn_mla_sparse")
-            or transformer.mla_sparse_pallas(*a, interpret=True)))
+        calls = interpreted(layer.apply, params, x)
         got = layer.apply(params, x)
     assert calls == ["mla_index", "attn_mla_sparse"]
     assert relative(got, want) < 2e-2
