@@ -4,8 +4,9 @@ modules, SwiGLU, a dropless expert layer (``moe.ExpertLayer``), a decoder
 layer whose token mixer (an attention, or a state-space layer or memory unit
 of ``ssm.py``) and residual path are parts of it, and ``CausalLM`` /
 ``causal_lm`` / ``latent_causal_lm`` / ``hybrid_causal_lm``, whose output is
-one number a position (the next token's log-probability): the logits never
-leave the device.
+one number a position (the next token's log-probability; with a
+multi-token-prediction module, a second node, the token two ahead's): the
+logits never leave the device.
 
 Parameters keep the dtype they are handed (``causal_lm(param_dtype=
 "bfloat16")`` makes them bfloat16): a weight matrix is cast to
@@ -49,7 +50,8 @@ Their cores run one of two ways:
     memory grow with ``T x window``; a full or latent layer stops at the
     diagonal; the latent kernel fetches the shared rotary key once a key
     block for all the heads of a step. Taken on a TPU for bfloat16 heads of
-    128 lanes at block-aligned ``T``.
+    128 lanes at block-aligned ``T`` (a latent head's content lanes may be no
+    whole tile: its caller lays them out in tiles).
   - plain XLA otherwise (the CPU, float32 tests): a banded two-block form
     for a window, query blocks against all keys for a full or latent layer;
     none holds a ``[heads, T, T]`` tensor. It is also the kernels' VJP
@@ -606,18 +608,26 @@ def diff_attention(q, k, v, lam, gain, window: int, pairs: int, kv_pairs: int,
 # ---------------------------------------------------------------------------
 #
 # Layout, flat as the projections leave it: ``q [B, T, H * dq]``, a head's
-# ``dn`` content lanes then its rotary lanes (already scaled: the softmax
-# scale is folded into the queries); ``kv [B, T, H * (dn + dv)]``, a head's
-# content keys then its values; ``kr [B, T, dq - dn]``, the rotary key all
-# heads share. The kernel wants whole tiles of 128 lanes, so its caller pads
-# the rotary lanes of q and kr with zeros (a 64-wide contraction costs the
-# MXU a pass of 128 anyway).
+# content lanes then its rotary lanes (already scaled: the softmax scale is
+# folded into the queries); ``kv [B, T, H * (dn + dv)]``, a head's ``dn`` key
+# lanes then its values; ``kr [B, T, r]``, the rotary key all heads share,
+# which meets a head's last ``r`` query lanes: ``s = q[:dn] . k + q[dq - r:]
+# . kr``. The plain layout has ``dn`` the content width and ``dn + r = dq``.
+# The kernel wants whole tiles of 128 lanes, so its caller lays the operands
+# out (``LatentAttention.apply``): where the content keys are whole tiles
+# (128), the rotary lanes of q and kr are padded with zeros to a tile; where
+# they are not (192), a head's keys in kv are its content keys and zeros to
+# the width of its query, and kr holds the rotary key in the lanes of that
+# last tile the content keys leave free (``[0 (64) | k_r (64)]``): the score
+# is one contraction over the query's own width, ``dq = 256``, with no pad
+# lane in it.
 
 def mla_xla(q, kv, kr, heads: int, dn: int, bits=None):
     """-> ``[B, T, H * dv]``; causal, a block of queries against all keys:
     ``s = q_nope . k_nope + q_rope . k_r``, the second product against the
-    one shared key, never repeated to the heads. With ``bits`` (a
-    selection, ``pack_selection``'s) a query sees the keys they select."""
+    one shared key, never repeated to the heads (on the kernel's layout,
+    ``q[:dn] . k + q[dq - r:] . kr``). With ``bits`` (a selection,
+    ``pack_selection``'s) a query sees the keys they select."""
     import jax
     import jax.numpy as jnp
 
@@ -629,13 +639,14 @@ def mla_xla(q, kv, kr, heads: int, dn: int, bits=None):
     q4 = q.reshape(B, (T + pad) // blk, blk, heads, -1)
     kv4 = kv.reshape(B, T + pad, heads, -1)
     kn, v = kv4[..., :dn], kv4[..., dn:]
+    rq = q4.shape[-1] - kr.shape[-1]                   # the query lanes kr meets
     kpos = jnp.arange(T + pad)
 
     def block(a):
         qb, first = a[0], a[1]                          # [B, blk, H, dq]
         s = jnp.einsum("bqhd,bkhd->bhqk", qb[..., :dn], kn,
                        preferred_element_type=jnp.float32) \
-            + jnp.einsum("bqhd,bkd->bhqk", qb[..., dn:], kr,
+            + jnp.einsum("bqhd,bkd->bhqk", qb[..., rq:], kr,
                          preferred_element_type=jnp.float32)
         seen = kpos[None, :] <= (first + jnp.arange(blk))[:, None]
         if bits is not None:     # a padded query sees its causal keys: no 0 / 0
@@ -660,7 +671,9 @@ def _mla_step(refs, qi, kb, masked: bool, *, sched: FlashSchedule, group: int,
               dn: int, dq: int, dv: int, bits: bool = False):
     """One block of queries of ``group`` heads against one block of keys:
     the shared rotary key is fetched once for all of them and joins each
-    head's content key as the last lanes of one ``dq``-wide contraction.
+    head's content key as the last lanes of one ``dq``-wide contraction,
+    after the head's ``dn`` key lanes where they are whole tiles, else added
+    into the zero lanes of their last tile (the layout above ``mla_xla``).
     With ``bits`` every block is masked by the queries' selection: the
     block's 128-lane chunks of keys are bits of the one tile of words the
     block lies in, unpacked in place. The selection lies inside the causal
@@ -678,9 +691,14 @@ def _mla_step(refs, qi, kb, masked: bool, *, sched: FlashSchedule, group: int,
     else:
         seen = sched.seen(qi, kb) if masked else None
     kr = kr_ref[...]
+    lead = dq - kr.shape[1]                  # the key lanes before the rotary key's
     for g in range(group):
         at = g * (dn + dv)
-        k = jnp.concatenate([kv_ref[:, at:at + dn], kr], axis=1)
+        if dn == lead:
+            k = jnp.concatenate([kv_ref[:, at:at + dn], kr], axis=1)
+        else:
+            tail = kv_ref[:, at + lead:at + dn] + kr
+            k = jnp.concatenate([kv_ref[:, at:at + lead], tail], axis=1) if lead else tail
         s = jax.lax.dot_general(q_ref[:, g * dq:(g + 1) * dq], k,
                                 (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
@@ -690,7 +708,8 @@ def _mla_step(refs, qi, kb, masked: bool, *, sched: FlashSchedule, group: int,
 def _mla_call(q, kv, kr, bits, heads: int, dn: int, interpret: bool):
     """``attn_mla`` (``bits`` None: the diagonal block masked, causal) or
     ``attn_mla_sparse`` (every block masked by the selection's tile of words
-    beside it): ``dn``, ``dq`` and ``dv`` whole tiles of 128 lanes, query and
+    beside it): ``dq``, ``dv`` and ``kr``'s width whole tiles of 128 lanes,
+    ``dn`` the lanes before the rotary key's (``dq - r``) or ``dq``, query and
     key blocks alike, the result stored at the diagonal's step."""
     import jax
     from jax.experimental import pallas as pl
@@ -703,7 +722,7 @@ def _mla_call(q, kv, kr, bits, heads: int, dn: int, interpret: bool):
     step = functools.partial(_mla_step, sched=sched, group=group, dn=dn, dq=dq, dv=dv,
                              bits=sparse)
     specs = [sched.query_spec(group * dq), sched.key_spec(group * (dn + dv)),
-             sched.key_spec(dq - dn, shared=True)]
+             sched.key_spec(kr.shape[2], shared=True)]
     operands = (q, kv, kr)
     pairs = T * (T + 1) / 2
     moved = (q.size * q.dtype.itemsize * (1 + dv / dq)
@@ -733,15 +752,15 @@ def mla_pallas(q, kv, kr, heads: int, dn: int, interpret: bool = False):
 _mla_pair = paired.pair(mla_pallas, mla_xla, static=2)
 
 
-def _mla_pallas_applies(x, nope: int, v_dim: int) -> bool:
+def _mla_pallas_applies(x, v_dim: int) -> bool:
     """Whether a latent layer's core takes the kernel for input ``x [B, T,
-    D]``: a TPU, bfloat16 operands, content and value heads of whole tiles."""
+    D]``: a TPU, bfloat16 operands, value heads of whole tiles. Content heads
+    of any width: the caller lays them out in whole tiles."""
     import jax
     import jax.numpy as jnp
 
     return (jax.default_backend() == "tpu" and _mm_dtype() == jnp.bfloat16
-            and nope % 128 == 0 and v_dim % 128 == 0
-            and _attn_blocks(x.shape[1], 0) is not None)
+            and v_dim % 128 == 0 and _attn_blocks(x.shape[1], 0) is not None)
 
 
 # ---------------------------------------------------------------------------
@@ -1213,18 +1232,24 @@ class LatentAttention(Module):
         dt = _mm_dtype()
         B, T, _ = x.shape
         h, nope, rope = self.heads, self.nope, self.rope
-        kernel = _mla_pallas_applies(x, nope, self.v_dim)
-        lanes = -rope % 128 if kernel else 0         # zeros after the rotary lanes
+        kernel = _mla_pallas_applies(x, self.v_dim)
+        # the kernel's layout (above `mla_xla`): a query is its lanes and zeros
+        # to a whole tile; content keys of no whole tile are followed by zeros
+        # to the query's width, and the rotary key lies in the lanes of their
+        # last tile they leave free
+        front = nope % 128 if kernel else 0          # zeros before k_r's lanes
+        lanes = -(nope + rope) % 128 if kernel else 0    # zeros after the rotary lanes
+        keys = nope + rope + lanes if front else nope    # a head's key lanes in kv
 
         def dot(a, name, out=jnp.float32):
             return jnp.dot(a.astype(dt), jnp.asarray(params[name]).astype(dt),
                            preferred_element_type=out)
 
-        def positions(a):                            # [B, T, heads, rope] float32
+        def positions(a, before=0):                  # [B, T, heads, rope] float32
             with scope("proj_in"):
                 a = rotary(a, self.rope_theta, self.rope_scaling)
             with scope("core"):                      # laid out as the core reads it
-                return jnp.pad(a, ((0, 0),) * 3 + ((0, lanes),)).astype(dt)
+                return jnp.pad(a, ((0, 0),) * 3 + ((before, lanes),)).astype(dt)
 
         def columns(first, last):          # of every head's columns of W_qb
             w = jnp.asarray(params["wq_b"]).astype(dt)
@@ -1253,16 +1278,22 @@ class LatentAttention(Module):
             q = jnp.concatenate([q_nope, q_rope], axis=-1).reshape(B, T, -1)
         with scope("proj_in"):
             ckv = dot(x, "wkv_a")
-        kr = positions(ckv[..., None, self.kv_rank:]).reshape(B, T, -1)
+        kr = positions(ckv[..., None, self.kv_rank:], front).reshape(B, T, -1)
         with scope("proj_in"):
-            kv = dot(rms_norm(ckv[..., :self.kv_rank], params["kv_norm"], self.eps),
-                     "wkv_b", dt)                    # a head's keys, then its values
+            c_kv = rms_norm(ckv[..., :self.kv_rank], params["kv_norm"], self.eps)
+            if keys == nope:                         # a head's keys, then its values
+                kv = dot(c_kv, "wkv_b", dt)
+            else:                # W_kvb's columns laid out: zero columns after the keys
+                w = jnp.asarray(params["wkv_b"]).astype(dt).reshape(self.kv_rank, h, -1)
+                w = jnp.concatenate([w[..., :nope], jnp.zeros_like(w[..., :keys - nope]),
+                                     w[..., nope:]], axis=-1).reshape(self.kv_rank, -1)
+                kv = jnp.dot(c_kv.astype(dt), w, preferred_element_type=dt)
         with scope("core"):
             if bits is None:
-                o = (_mla_pair if kernel else mla_xla)(q, kv, kr, h, nope)
+                o = (_mla_pair if kernel else mla_xla)(q, kv, kr, h, keys)
             else:
                 o = (_mla_sparse_pair if kernel else mla_sparse_xla)(
-                    q, kv, kr, bits, h, nope)
+                    q, kv, kr, bits, h, keys)
         with scope("proj_out"):
             if self.out_gate:
                 gate = jnp.dot(c_q.astype(dt), columns(nope + rope, None),
@@ -1564,23 +1595,46 @@ class CausalLM(Module):
     ``norm`` chooses the final norm (``"rms"`` or ``"layer"``); with ``tied``
     the head is the embedding table transposed and ``params`` has no ``head``.
 
+    **The multi-token-prediction module** (``mtp``, a ``DecoderLayer``;
+    DeepSeek-V3's, arXiv:2412.19437 section 2.2): with ``h_t`` the last
+    layer's output before the final norm, ``u_t = [RMSNorm_e(E[id_{t+1}]) ;
+    RMSNorm_h(h_t)] W_eh``, ``v = mtp(u)``, and ``mtp[t] =
+    log_softmax(RMSNorm_s(v_t) W_head)[id_{t+2}]``, the embedding and the head
+    the model's own, the last two positions' targets (and position ``T -
+    1``'s ``id_{t+1}``) the pad id. It runs only where the node
+    ``mtp_logprob`` (``[B, T]`` float32) is fetched; its logits go through
+    the head's loop over blocks of positions as the main ones do. Scopes
+    ``mtp``: ``fuse`` (the embedding of the next ids, the two norms, ``W_eh``),
+    ``layer<L>`` (its decoder layer, named as the layer after the ``L`` held
+    ones), ``logits`` (its norm and the head's loop).
+
     A container for ``DNNModel``'s ``fetchDict``: the node ``expert_load``
     is ``[B, sparse layers, experts held]``, the visits each held expert took
-    from that row's positions (float32: what the routing counted)."""
+    from that row's positions (float32: what the routing counted); where
+    ``mtp_logprob`` is fetched too, the module's expert layer is its last
+    row."""
 
     is_container = True
     LOAD = "expert_load"
+    MTP = "mtp_logprob"
     tied = False             # a model pickled before the tied head has none
+    mtp = None               # nor one pickled before the MTP module
 
     def __init__(self, vocab_size: int, hidden: int, layers: Sequence[DecoderLayer],
                  pad_id: int = 0, eps: float = 1e-5, param_dtype: str = "float32",
-                 streams: int = 1, norm: str = "rms", tied: bool = False):
+                 streams: int = 1, norm: str = "rms", tied: bool = False,
+                 mtp: Optional[DecoderLayer] = None):
+        if mtp is not None and streams > 1:
+            raise ValueError("a multi-token-prediction module reads one stream")
         self.vocab_size, self.hidden, self.pad_id = vocab_size, hidden, pad_id
         self.layers = list(layers)
         self.final_norm = _norm(norm, eps, param_dtype)
         self.param_dtype = param_dtype
         self.streams = streams
         self.tied = tied
+        self.mtp = mtp
+        if mtp is not None:                  # RMSNorm_e, RMSNorm_h, RMSNorm_s
+            self.mtp_norm = RMSNorm(eps, param_dtype)
 
     def init(self, rng, in_shape):
         (t,) = in_shape
@@ -1596,15 +1650,29 @@ class CausalLM(Module):
                                                 self.hidden ** -0.5, self.param_dtype)}
         for i, (layer, k) in enumerate(zip(self.layers, keys[2:])):
             params[f"layer{i}"] = layer.init(k, (t, self.hidden))[0]
+        if self.mtp is not None:
+            import jax
+
+            k_eh, k_layer = _rng_split(jax.random.fold_in(rng, len(keys)), 2)
+            gain = self.mtp_norm.init(None, (t, self.hidden))[0]
+            params["mtp"] = {
+                "enorm": gain, "hnorm": dict(gain), "norm": dict(gain),
+                "eh_proj": {"kernel": _normal(k_eh, (2 * self.hidden, self.hidden),
+                                              (2 * self.hidden) ** -0.5, self.param_dtype)},
+                "layer": self.mtp.init(k_layer, (t, self.hidden))[0]}
         return params, (t,)
 
     def layer_paths(self, prefix: str = "") -> List[str]:
-        return [prefix + self.LOAD] + [f"{prefix}layer{i}" for i in range(len(self.layers))]
+        return [prefix + self.LOAD] + [prefix + self.MTP] * (self.mtp is not None) \
+            + [f"{prefix}layer{i}" for i in range(len(self.layers))]
 
-    def _log_probs(self, params, x, ids):
+    def _log_probs(self, params, x, ids, norm=None):
+        """The log-probability of each position's next id (``norm``: the
+        ``(module, params)`` before the head; the final norm without it)."""
         import jax
         import jax.numpy as jnp
 
+        norm, gain = norm or (self.final_norm, params["final_norm"])
         dt = _mm_dtype()
         # [hidden, vocab], or the tied table [vocab, hidden] contracted in place
         head = jnp.asarray(params["embed"]["table"] if self.tied
@@ -1615,7 +1683,7 @@ class CausalLM(Module):
 
         def row(a):
             xr, tr = a
-            xn = self.final_norm.apply(params["final_norm"], xr).astype(dt)
+            xn = norm.apply(gain, xr).astype(dt)
             logits = jax.lax.dot_general(xn, head, over,
                                          preferred_element_type=jnp.float32)
             picked = jnp.take_along_axis(logits, tr[:, None], axis=-1)[:, 0]
@@ -1626,6 +1694,26 @@ class CausalLM(Module):
         pieces = equal_pieces(T, max(1, LOGITS_BLOCK // self.vocab_size))
         return jax.lax.map(row, (x.reshape(B * pieces, T // pieces, D),
                                  target.reshape(B * pieces, T // pieces))).reshape(B, T)
+
+    def _mtp(self, params, h, ids):
+        """(``mtp[t]``, ``[B, T]`` float32; the module's expert visits or
+        None) from the last layer's output ``h`` before the final norm."""
+        import jax.numpy as jnp
+
+        dt, p = _mm_dtype(), params["mtp"]
+        following = jnp.concatenate(
+            [ids[:, 1:], jnp.full_like(ids[:, :1], self.pad_id)], axis=1)
+        with scope("fuse"):
+            e = jnp.take(jnp.asarray(params["embed"]["table"]), following, axis=0
+                         ).astype(jnp.float32)
+            joined = jnp.concatenate([self.mtp_norm.apply(p["enorm"], e),
+                                      self.mtp_norm.apply(p["hnorm"], h)], axis=-1)
+            u = jnp.dot(joined.astype(dt), jnp.asarray(p["eh_proj"]["kernel"]).astype(dt),
+                        preferred_element_type=jnp.float32)
+        with scope(f"layer{len(self.layers)}"):
+            v, load = self.mtp.apply_with_load(p["layer"], u, {})
+        with scope("logits"):        # the targets: the ids two ahead
+            return self._log_probs(params, v, following, (self.mtp_norm, p["norm"])), load
 
     def apply(self, params, x, train: bool = False,
               taps: Optional[Set[str]] = None,
@@ -1648,6 +1736,13 @@ class CausalLM(Module):
                 loads.append(load)
             if taps and taps_out is not None and f"{_prefix}layer{i}" in taps:
                 taps_out[f"{_prefix}layer{i}"] = h
+        if taps and taps_out is not None and _prefix + self.MTP in taps:
+            if self.mtp is None:
+                raise KeyError("mtp_logprob: the model has no multi-token-prediction module")
+            with scope("mtp"):
+                taps_out[_prefix + self.MTP], load = self._mtp(params, h, ids)
+            if load is not None:
+                loads.append(load)
         if taps and taps_out is not None and _prefix + self.LOAD in taps:
             if not loads:
                 raise KeyError("expert_load: the model has no expert layer")
@@ -1718,7 +1813,7 @@ def latent_causal_lm(seq_len: int, vocab_size: int, hidden: int, heads: int,
                      init: bool = True, index_heads: int = 0, index_dim: int = 0,
                      index_topk: int = 0, out_gate: bool = False,
                      gated_norm_rank: int = 0, n_group: int = 1,
-                     topk_group: int = 1) -> FunctionModel:
+                     topk_group: int = 1, nextn: int = 0) -> FunctionModel:
     """``causal_lm``'s sibling for a model with latent attention and, where
     ``streams`` > 1, a hyper-connected residual path: the same ``CausalLM`` of
     the same ``DecoderLayer`` s, every layer a ``LatentAttention`` and an
@@ -1726,18 +1821,23 @@ def latent_causal_lm(seq_len: int, vocab_size: int, hidden: int, heads: int,
     the attention sparse and ``out_gate`` gates its heads (``LatentAttention``);
     ``gated_norm_rank`` > 0 gates the two norms before a layer's sublayers
     (``GatedRMSNorm``; the final norm stays plain); ``n_group`` /
-    ``topk_group`` limit the routing to groups (``moe.ExpertLayer``). Their
-    defaults are the model without any of them."""
+    ``topk_group`` limit the routing to groups (``moe.ExpertLayer``);
+    ``nextn`` 1 adds the multi-token-prediction module (``CausalLM``: a
+    latent-attention and expert layer, the config's
+    ``num_nextn_predict_layers``). Their defaults are the model without any
+    of them."""
     import jax
 
     from .moe import ExpertLayer
     from .residual import HyperConnection
 
+    if nextn not in (0, 1):
+        raise ValueError(f"{nextn} multi-token-prediction modules: one is built")
     hyper = HyperConnection(streams, sinkhorn_iters, hc_eps, hc_clamp, eps,
                             param_dtype) if streams > 1 else None
     norm = dict(norm="gated", norm_rank=gated_norm_rank) if gated_norm_rank else {}
     layers = []
-    for is_sparse in sparse:
+    for is_sparse in list(sparse) + [True] * nextn:     # the MTP's layer last
         attn = LatentAttention(heads, q_rank, kv_rank, nope, rope, v_dim, rope_theta,
                                rope_scaling, eps, param_dtype, index_heads, index_dim,
                                index_topk, out_gate)
@@ -1754,7 +1854,9 @@ def latent_causal_lm(seq_len: int, vocab_size: int, hidden: int, heads: int,
             layers.append(DecoderLayer(attn, mlp=SwiGLU(dense_hidden, param_dtype),
                                        eps=eps, param_dtype=param_dtype, hyper=hyper,
                                        **norm))
-    module = CausalLM(vocab_size, hidden, layers, pad_id, eps, param_dtype, streams)
+    mtp = layers.pop() if nextn else None
+    module = CausalLM(vocab_size, hidden, layers, pad_id, eps, param_dtype, streams,
+                      mtp=mtp)
     params = module.init(jax.random.key(seed), (seq_len,))[0] if init else {}
     names = [CausalLM.LOAD] + [f"layer{i}" for i in reversed(range(len(layers)))]
     return FunctionModel(module, params, (seq_len,), names, "latent_causal_lm")

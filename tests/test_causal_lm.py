@@ -647,6 +647,20 @@ def test_the_latent_attention_kernel_compiles_at_the_published_widths(one_chip):
     assert "tpu_custom_call" in text and "attn_mla" in text
 
 
+def test_the_latent_kernel_compiles_at_192_content_lanes(one_chip):
+    """GLM-4.7-Flash's core: 20 heads, a 256-wide score (192 content lanes
+    and 64 rotary ones, no pad lane) whose keys are read a tile at a time
+    (each head's 192 content keys and 64 zero lanes, the rotary key in that
+    last tile's free lanes), values of 256, a row of 16,384."""
+    B, t, H = 1, 16384, 20
+    q = jax.ShapeDtypeStruct((B, t, H * 256), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((B, t, H * 512), jnp.bfloat16, sharding=one_chip)
+    kr = jax.ShapeDtypeStruct((B, t, 128), jnp.bfloat16, sharding=one_chip)
+    text = _compiled_text(lambda q, kv, kr: transformer.mla_pallas(q, kv, kr, H, 256),
+                          q, kv, kr)
+    assert "tpu_custom_call" in text and "attn_mla" in text
+
+
 def test_the_stream_mix_kernels_compile_at_the_published_widths(one_chip):
     from mmlspark_tpu.models import residual
 

@@ -297,29 +297,42 @@ def test_by_rows_groups_rows_and_cuts_a_long_row_only_position_wise(
 
 # -- the kernels, in the interpreter ----------------------------------------
 
-def _mla_operands(seed, B=2, H=4, t=512):
+def _mla_operands(seed, B=2, H=4, t=512, nope=128, v=128):
+    """The kernel's layout (above `transformer.mla_xla`) with 64 rotary
+    lanes: at 128 content lanes q ``[nope | rope | 0]``, kv ``[k | v]``, kr
+    ``[k_r | 0]``; at 192 q ``[nope | rope]``, kv ``[k | 0 (64) | v]``, kr
+    ``[0 (64) | k_r]``."""
     rng = np.random.default_rng(seed)
     q = jnp.asarray(rng.normal(size=(B, t, H * 256)) * 0.2, jnp.bfloat16)
-    q = q.reshape(B, t, H, 256).at[..., 192:].set(0).reshape(B, t, -1)   # 64 rotary lanes
-    kv = jnp.asarray(rng.normal(size=(B, t, H * 256)), jnp.bfloat16)
-    kr = jnp.asarray(rng.normal(size=(B, t, 128)), jnp.bfloat16).at[..., 64:].set(0)
-    return q, kv, kr
+    keys = 256 if nope % 128 else nope
+    kv = jnp.asarray(rng.normal(size=(B, t, H, keys + v)), jnp.bfloat16)
+    kv = kv.at[..., nope:keys].set(0).reshape(B, t, -1)
+    kr = jnp.asarray(rng.normal(size=(B, t, 128)), jnp.bfloat16)
+    if nope % 128:
+        return q, kv, kr.at[..., :nope % 128].set(0)
+    q = q.reshape(B, t, H, 256).at[..., nope + 64:].set(0).reshape(B, t, -1)
+    return q, kv, kr.at[..., 64:].set(0)
 
 
-@pytest.mark.parametrize("t", [512, 1024])
-def test_the_latent_kernel_agrees_with_the_plain_form(t):
-    q, kv, kr = _mla_operands(t, t=t)
-    got = transformer.mla_pallas(q, kv, kr, 4, 128, interpret=True)
-    want = transformer.mla_xla(*(a.astype(jnp.float32) for a in (q, kv, kr)), 4, 128)
-    assert got.shape == (2, t, 4 * 128) and got.dtype == jnp.bfloat16
+@pytest.mark.parametrize("t,nope,v", [(512, 128, 128), (1024, 128, 128), (512, 192, 256)],
+                         ids=["512", "1024", "512-192-256"])
+def test_the_latent_kernel_agrees_with_the_plain_form(t, nope, v):
+    q, kv, kr = _mla_operands(t, t=t, nope=nope, v=v)
+    keys = kv.shape[2] // 4 - v                  # a head's key lanes in kv
+    got = transformer.mla_pallas(q, kv, kr, 4, keys, interpret=True)
+    want = transformer.mla_xla(*(a.astype(jnp.float32) for a in (q, kv, kr)), 4, keys)
+    assert got.shape == (2, t, 4 * v) and got.dtype == jnp.bfloat16
     assert float(jnp.abs(got.astype(jnp.float32) - want).max()) < 0.02
-    # the plain form is the masked softmax of both products
+    # the plain form is the masked softmax of both products: the content
+    # lanes against the content keys, the 64 rotary lanes against k_r
     B, H = 2, 4
-    q4, kv4 = (a.astype(jnp.float32).reshape(B, t, H, 256) for a in (q, kv))
-    s = jnp.einsum("bqhd,bkhd->bhqk", q4[..., :128], kv4[..., :128]) \
-        + jnp.einsum("bqhd,bkd->bhqk", q4[..., 128:], kr.astype(jnp.float32))
+    q4 = q.astype(jnp.float32).reshape(B, t, H, 256)
+    kv4 = kv.astype(jnp.float32).reshape(B, t, H, -1)
+    k_r = kr.astype(jnp.float32)[..., nope % 128:nope % 128 + 64]
+    s = jnp.einsum("bqhd,bkhd->bhqk", q4[..., :nope], kv4[..., :nope]) \
+        + jnp.einsum("bqhd,bkd->bhqk", q4[..., nope:nope + 64], k_r)
     p = jax.nn.softmax(jnp.where(np.tril(np.ones((t, t), bool)), s, -jnp.inf), axis=-1)
-    direct = jnp.einsum("bhqk,bkhd->bqhd", p, kv4[..., 128:]).reshape(B, t, -1)
+    direct = jnp.einsum("bhqk,bkhd->bqhd", p, kv4[..., keys:]).reshape(B, t, -1)
     assert float(jnp.abs(direct - want).max()) < 1e-4
 
 
