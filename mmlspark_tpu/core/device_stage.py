@@ -151,6 +151,11 @@ class DeviceFn:
     # bytes the host still holds. Later in-segment stages read the column
     # on the device as before.
     passthrough: Optional[Dict[str, str]] = None
+    # count(arrays): {name: int} of a host batch's inputs (``{in col:
+    # [B, ...]}`` as shipped), what the program's work depends on in them
+    # (``DNNModel``: the attention's live query blocks); kept per batch in
+    # ``BatchTiming.counts``. None: nothing counted
+    count: Optional[Callable[[Dict[str, Any]], Dict[str, int]]] = None
 
     def __post_init__(self):
         self.in_cols = tuple(self.in_cols)

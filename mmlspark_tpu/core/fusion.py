@@ -1131,7 +1131,16 @@ class SegmentExecutor:
                 obs[0].record_batch(
                     "fill", obs[1], w0, time.perf_counter() - t0, batch=batch,
                     bytes=sum(a.nbytes for a in arrays.values()))
-            yield Batch(arrays, mask, m, staging=lease)
+            yield Batch(arrays, mask, m, staging=lease, counts=self._counts(arrays))
+
+    def _counts(self, arrays: Dict[str, np.ndarray]) -> Dict[str, int]:
+        """What the segment's stages count in a batch of their inputs
+        (``DeviceFn.count``), each stage over the columns it reads."""
+        counts: Dict[str, int] = {}
+        for d in self.segment.dfns:
+            if d.count is not None and all(c in arrays for c in d.in_cols):
+                counts.update(d.count({c: arrays[c] for c in d.in_cols}))
+        return counts
 
     def _put(self, batch):
         import jax

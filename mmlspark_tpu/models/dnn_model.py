@@ -227,6 +227,10 @@ class DNNModel(Model, HasInputCol, HasOutputCol, HasBatchSize):
                 return True
             return p["sparse"] or p["dtype"].kind in "fuib"
 
+        # what the model's work depends on in a batch of its input as shipped
+        # (a causal scorer's live query blocks), kept in BatchTiming.counts
+        counter = getattr(model.module, "batch_counts", None) if spec is None else None
+
         return DeviceFn(
             key=key, in_cols=(in_col,), out_cols=out_cols, fn=fn,
             params=model.params, accepts=accepts, reject_sparse=False,
@@ -234,7 +238,8 @@ class DNNModel(Model, HasInputCol, HasOutputCol, HasBatchSize):
             # pod-scale planner declaration (parallel/shardplan.py): flat
             # [N, F] feature inputs may shard their feature dim over the
             # mesh's tensor axis (GSPMD inserts the activation collectives)
-            shard_dims={in_col: 1})
+            shard_dims={in_col: 1},
+            count=None if counter is None else lambda arrays: counter(arrays[in_col]))
 
     def transform_schema(self, schema: Schema) -> Schema:
         if self.get("model") is None:

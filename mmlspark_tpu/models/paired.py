@@ -4,7 +4,9 @@ Every kernel of the models has a plain XLA form of the same result. The code
 takes the kernel where a predicate of what it can observe (the backend, the
 dtype, the widths) says it applies, and the plain form elsewhere. No kernel
 has a backward pass of its own: ``pair`` gives it the plain form's VJP,
-recomputed from the operands.
+recomputed from the operands. A kernel is traced once for each configuration
+and operand shapes and inlined wherever it is called again, so a model of
+many alike layers pays one trace of it in its set-up.
 """
 
 from __future__ import annotations
@@ -27,9 +29,14 @@ def pair(kernel: Callable, plain: Callable, static: int = 0,
     def with_vjp():
         import jax
 
+        # tracing a Pallas kernel is most of tracing a model: once a
+        # configuration and shapes, inlined where it is called again
+        traced = jax.jit(lambda config, *operands: kernel(
+            *operands, *config[1:], interpret=config[0]), static_argnums=(0,), inline=True)
+
         @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
         def run(config, *operands):          # config: (interpret, *static)
-            return kernel(*operands, *config[1:], interpret=config[0])
+            return traced(config, *operands)
 
         def fwd(config, *operands):
             return run(config, *operands), operands

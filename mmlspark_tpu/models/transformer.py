@@ -153,6 +153,53 @@ def rotary(x, theta: float, scaling: Optional[Dict[str, Any]] = None):
 
 
 # ---------------------------------------------------------------------------
+# the bound: a row's positions up to its last real token
+# ---------------------------------------------------------------------------
+
+LIVE_COUNT_BLOCK = 512    # the query block the counter counts in (a full layer's at 16,384)
+
+
+def live_lengths(ids, pad_id: int):
+    """``[B]`` int32 of ``ids [B, T]`` (a NumPy or a JAX array, and the same
+    kind back): the positions of each row up to and including its last id
+    that is not ``pad_id``, 0 for a row of pads alone. What the attention
+    kernels stop at (``FlashSchedule``), and what ``CausalLM.batch_counts``
+    counts on the host: one function, so the two cannot drift."""
+    through = (ids != pad_id) * np.arange(1, ids.shape[-1] + 1, dtype=np.int32)
+    return through.max(axis=-1).astype(np.int32)
+
+
+def _zero_past(o, live):
+    """``o [B, T, ...]`` with the positions of each row at and past ``live``
+    zero, as the kernels store them (None: ``o`` as it is)."""
+    if live is None:
+        return o
+    import jax.numpy as jnp
+
+    keep = jnp.arange(o.shape[1])[None, :] < live[:, None]
+    return jnp.where(keep.reshape(keep.shape + (1,) * (o.ndim - 2)), o,
+                     jnp.zeros((), o.dtype))
+
+
+def _bound(live, x):
+    """The bound a kernel reads: ``live``, or every row whole where there is
+    none."""
+    import jax.numpy as jnp
+
+    return jnp.full((x.shape[0],), x.shape[1], jnp.int32) if live is None \
+        else live.astype(jnp.int32)
+
+
+def _bound_last(fn, operands: int):
+    """``fn`` (which takes ``live`` by keyword) as a pair's side: ``f(*its
+    operands, live, *config)``, the bound an operand of the pair."""
+    def call(*args, **kw):
+        return fn(*args[:operands], *args[operands + 1:], live=args[operands], **kw)
+
+    return call
+
+
+# ---------------------------------------------------------------------------
 # grouped-query attention: plain XLA
 # ---------------------------------------------------------------------------
 
@@ -165,12 +212,13 @@ def _softmax_rows(s, seen):
     return p / jnp.sum(p, axis=-1, keepdims=True)
 
 
-def gqa_xla(q, k, v, window: int, out_dtype=None):
+def gqa_xla(q, k, v, window: int, out_dtype=None, live=None):
     """q ``[B, T, H, D]``, k ``[B, T, KV, D]``, v ``[B, T, KV, Dv]`` -> ``[B,
     T, H, Dv]`` (``out_dtype``; None: v's); causal, query t sees keys ``t -
-    window < s <= t`` (every ``s <= t`` at window 0). Blocked over queries: a
-    window reads its own and the previous block of ``window`` keys, a full
-    layer a block of queries against all keys."""
+    window < s <= t`` (every ``s <= t`` at window 0), and a position at or
+    past its row's ``live`` is zero. Blocked over queries: a window reads its
+    own and the previous block of ``window`` keys, a full layer a block of
+    queries against all keys."""
     import jax
     import jax.numpy as jnp
 
@@ -212,7 +260,8 @@ def gqa_xla(q, k, v, window: int, out_dtype=None):
             lambda a: probs_values(a[0], k, v, a[1] + jnp.arange(blk), kpos),
             (jnp.moveaxis(qb, 1, 0), first))
         o = jnp.moveaxis(o, 0, 1)
-    return o.reshape(B, T + pad, H, v.shape[-1])[:, :T].astype(out_dtype or v.dtype)
+    o = o.reshape(B, T + pad, H, v.shape[-1])[:, :T].astype(out_dtype or v.dtype)
+    return _zero_past(o, live)
 
 
 # ---------------------------------------------------------------------------
@@ -261,6 +310,16 @@ class FlashSchedule:
     the diagonal's block. Each softmax of a step has its running maximum,
     denominator and accumulator (``m``, ``l``, ``acc``) in VMEM.
 
+    **The bound.** A call's first operand is ``live [B]`` int32, prefetched
+    into SMEM: the positions of each row up to and including its last real
+    token (``live_lengths``). A query block wholly past it (``qi * bq >=
+    live[b]``) is dead: its steps compute nothing, and every index map stays
+    on the last live block's last step, which the grid step before fetched,
+    so they fetch nothing; its store writes zeros, and so does the store of
+    the block that holds the bound, in its rows past it. A block wholly
+    before the bound runs as it would without one, to the bit. The bound is
+    data: one program serves every length.
+
     A kernel is a body over it: ``step(refs, qi, kb, masked)`` folds key
     block ``kb`` into the softmaxes (``refs``: the inputs, the output, then
     ``m``, ``l``, ``acc``), ``store(refs)`` writes the result. Which blocks
@@ -293,6 +352,15 @@ class FlashSchedule:
 
         return jnp.minimum(self.key_block(qi, j), self.diagonal(qi))
 
+    def at(self, qi, j, live):
+        """``(query block, step)`` whose blocks grid step ``(qi, j)`` of a row
+        of ``live`` fetches: its own in a live query block; in a dead one the
+        last live block's last step (block 0's in a row of no real token)."""
+        import jax.numpy as jnp
+
+        last = jnp.maximum(live - 1, 0) // self.bq
+        return jnp.minimum(qi, last), jnp.where(qi * self.bq >= live, self.steps - 1, j)
+
     def seen(self, qi, kb):
         """``[bq, bk]``: the (query, key) pairs of a block that are causal and
         inside the window."""
@@ -307,32 +375,38 @@ class FlashSchedule:
         return seen
 
     def query_spec(self, width: int):
-        """A block of queries (or of the output) of one head group."""
+        """A block of queries of one head group."""
         from jax.experimental import pallas as pl
 
-        return pl.BlockSpec((None, self.bq, width), lambda b, h, qi, j: (b, qi, h))
+        def index(b, h, qi, j, live):
+            return b, self.at(qi, j, live[b])[0], h
+
+        return pl.BlockSpec((None, self.bq, width), index)
 
     def key_spec(self, width: int, shared: bool = False):
         """A block of keys of one head group, or (``shared``) of the one
         column block all groups read."""
         from jax.experimental import pallas as pl
 
-        def index(b, h, qi, j):
-            return b, self.fetched(qi, j), 0 if shared else h
+        def index(b, h, qi, j, live):
+            return b, self.fetched(*self.at(qi, j, live[b])), 0 if shared else h
 
         return pl.BlockSpec((None, self.bk, width), index)
 
     def kernel(self, step, store, mask_all: bool, store_at_diagonal: bool = False):
-        """The kernel over ``refs``: ``m``, ``l``, ``acc`` set at the first
-        step, ``step`` on the blocks up to the diagonal (``masked`` on every
-        one of them with ``mask_all``, else on the diagonal's alone),
-        ``store`` at the last step or (``store_at_diagonal``) the diagonal's."""
+        """The kernel over ``live`` and ``refs``: ``m``, ``l``, ``acc`` set at
+        the first step, ``step`` on a live block's blocks up to the diagonal
+        (``masked`` on every one of them with ``mask_all``, else on the
+        diagonal's alone), ``store`` at the last step or
+        (``store_at_diagonal``) the diagonal's, and zeros past the bound."""
+        import jax
         import jax.numpy as jnp
         from jax.experimental import pallas as pl
 
-        def run(*refs):
+        def run(live, *refs):
             m_scr, l_scr, acc_scr = refs[-3:]
-            qi, j = pl.program_id(2), pl.program_id(3)
+            b, qi, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+            edge = live[b] - qi * self.bq        # the block's queries before the bound
 
             @pl.when(j == 0)
             def _init():
@@ -342,21 +416,28 @@ class FlashSchedule:
 
             kb, last = self.key_block(qi, j), self.diagonal(qi)
             if mask_all:
-                pl.when(kb <= last)(lambda: step(refs, qi, kb, True))
+                pl.when((edge > 0) & (kb <= last))(lambda: step(refs, qi, kb, True))
             else:                # blocks alike: only the diagonal's needs its mask
-                pl.when(kb < last)(lambda: step(refs, qi, kb, False))
-                pl.when(kb == last)(lambda: step(refs, qi, kb, True))
-            pl.when(kb == last if store_at_diagonal else j == self.steps - 1)(
-                lambda: store(refs))
+                pl.when((edge > 0) & (kb < last))(lambda: step(refs, qi, kb, False))
+                pl.when((edge > 0) & (kb == last))(lambda: step(refs, qi, kb, True))
+            stores = kb == last if store_at_diagonal else j == self.steps - 1
+            pl.when(stores & (edge > 0))(lambda: store(refs))
+
+            @pl.when(stores & (edge < self.bq))
+            def _past_bound():   # zeros at and past the bound: a dead block's all
+                o_ref = refs[-4]
+                rows = jax.lax.broadcasted_iota(jnp.int32, o_ref.shape, 0)
+                o_ref[...] = jnp.where(rows < edge, o_ref[...],
+                                       jnp.zeros(o_ref.shape, o_ref.dtype))
 
         return run
 
-    def call(self, kernel, operands, in_specs, out_shape, out_width: int,
+    def call(self, kernel, live, operands, in_specs, out_shape, out_width: int,
              softmaxes: int, value_width: int, name: str, cost, interpret: bool):
-        """``pallas_call`` of ``kernel`` over the grid: ``out_shape``'s head
-        groups ``out_width`` lanes each, ``softmaxes`` a step with values
-        ``value_width`` wide, under the name a device trace shows
-        (docs/observability.md)."""
+        """``pallas_call`` of ``kernel`` over the grid, ``live`` prefetched:
+        ``out_shape``'s head groups ``out_width`` lanes each, ``softmaxes`` a
+        step with values ``value_width`` wide, under the name a device trace
+        shows (docs/observability.md)."""
         import jax.numpy as jnp
         from jax.experimental import pallas as pl
         from jax.experimental.pallas import tpu as pltpu
@@ -364,20 +445,23 @@ class FlashSchedule:
         B = operands[0].shape[0]
         return pl.pallas_call(
             kernel,
-            grid=(B, out_shape.shape[2] // out_width, self.blocks, self.steps),
-            in_specs=in_specs,
-            out_specs=self.query_spec(out_width),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(B, out_shape.shape[2] // out_width, self.blocks, self.steps),
+                in_specs=in_specs,
+                out_specs=pl.BlockSpec((None, self.bq, out_width),
+                                       lambda b, h, qi, j, live: (b, qi, h)),
+                scratch_shapes=[pltpu.VMEM((softmaxes, self.bq, 128), jnp.float32),
+                                pltpu.VMEM((softmaxes, self.bq, 128), jnp.float32),
+                                pltpu.VMEM((softmaxes, self.bq, value_width), jnp.float32)]),
             out_shape=out_shape,
-            scratch_shapes=[pltpu.VMEM((softmaxes, self.bq, 128), jnp.float32),
-                            pltpu.VMEM((softmaxes, self.bq, 128), jnp.float32),
-                            pltpu.VMEM((softmaxes, self.bq, value_width), jnp.float32)],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
                 vmem_limit_bytes=64 * 1024 * 1024),
             name=name,
             cost_estimate=cost,
             interpret=interpret,
-        )(*operands)
+        )(live, *operands)
 
 
 def _divide(refs, group: int, width: int):
@@ -412,11 +496,12 @@ def _gqa_step(refs, qi, kb, masked: bool, *, sched: FlashSchedule, scale: float,
 
 
 def gqa_pallas(q, k, v, window: int, heads: int, kv_heads: int,
-               interpret: bool = False):
+               interpret: bool = False, live=None):
     """q ``[B, T, H * D]``, k / v ``[B, T, KV * D]`` -> ``[B, T, H * D]``: a
     head is a block of 128 lanes of the projection's own layout, so nothing
     is transposed; the ``H / KV`` query heads of a key/value head lie side by
-    side and go through one grid step together. Every block is masked."""
+    side and go through one grid step together. Every block is masked; the
+    schedule stops at ``live`` (None: every row whole)."""
     import jax
     from jax.experimental import pallas as pl
 
@@ -429,7 +514,8 @@ def gqa_pallas(q, k, v, window: int, heads: int, kv_heads: int,
     pairs = T * (min(window, T) if window else (T + 1) / 2)   # (query, key) seen
     return sched.call(
         sched.kernel(step, functools.partial(_divide, group=group, width=D), mask_all=True),
-        (q, k, v), [sched.query_spec(group * D), sched.key_spec(D), sched.key_spec(D)],
+        _bound(live, q), (q, k, v),
+        [sched.query_spec(group * D), sched.key_spec(D), sched.key_spec(D)],
         jax.ShapeDtypeStruct(q.shape, q.dtype), out_width=group * D, softmaxes=group,
         value_width=D, name="attn_window" if window else "attn_full",
         cost=pl.CostEstimate(
@@ -456,22 +542,24 @@ def _pallas_applies(q, heads: int, window: int) -> bool:
             and _attn_blocks(q.shape[1], window) is not None)
 
 
-def _gqa_flat_xla(q, k, v, window: int, heads: int, kv_heads: int):
+def _gqa_flat_xla(q, k, v, window: int, heads: int, kv_heads: int, live=None):
     B, T, _ = q.shape
     o = gqa_xla(q.reshape(B, T, heads, -1), k.reshape(B, T, kv_heads, -1),
-                v.reshape(B, T, kv_heads, -1), window)
+                v.reshape(B, T, kv_heads, -1), window, live=live)
     return o.reshape(B, T, -1)
 
 
-_gqa_pair = paired.pair(gqa_pallas, _gqa_flat_xla, static=3)
+_gqa_pair = paired.pair(_bound_last(gqa_pallas, 3), _bound_last(_gqa_flat_xla, 3),
+                        static=3, nondiff=(3,))
 
 
-def gq_attention(q, k, v, window: int, heads: int, kv_heads: int):
-    """Causal grouped-query attention over flat heads (``[B, T, heads * D]``):
-    the kernel where it applies, the plain form elsewhere."""
+def gq_attention(q, k, v, window: int, heads: int, kv_heads: int, live=None):
+    """Causal grouped-query attention over flat heads (``[B, T, heads * D]``),
+    zero at and past each row's ``live`` (None: no bound): the kernel where
+    it applies, the plain form elsewhere."""
     if _pallas_applies(q, heads, window):
-        return _gqa_pair(q, k, v, window, heads, kv_heads)
-    return _gqa_flat_xla(q, k, v, window, heads, kv_heads)
+        return _gqa_pair(q, k, v, _bound(live, q), window, heads, kv_heads)
+    return _gqa_flat_xla(q, k, v, window, heads, kv_heads, live)
 
 
 # ---------------------------------------------------------------------------
@@ -490,10 +578,11 @@ def gq_attention(q, k, v, window: int, heads: int, kv_heads: int):
 PAIR = 128
 
 
-def diff_xla(q, k, v, lam, gain, window: int, pairs: int, kv_pairs: int, eps: float):
-    """-> ``[B, T, pairs * 128]`` in q's dtype: the two softmaxes through
-    ``gqa_xla`` (heads of 64 against values of 128), everything after them in
-    float32."""
+def diff_xla(q, k, v, lam, gain, window: int, pairs: int, kv_pairs: int, eps: float,
+             live=None):
+    """-> ``[B, T, pairs * 128]`` in q's dtype, zero at and past each row's
+    ``live``: the two softmaxes through ``gqa_xla`` (heads of 64 against
+    values of 128), everything after them in float32."""
     import jax.numpy as jnp
 
     B, T, _ = q.shape
@@ -503,7 +592,7 @@ def diff_xla(q, k, v, lam, gain, window: int, pairs: int, kv_pairs: int, eps: fl
     o1, o2 = (gqa_xla(q4[:, :, :, i], k4[:, :, :, i], v4, window, jnp.float32)
               for i in range(2))
     o = rms_norm(o1 - lam * o2, gain, eps)
-    return o.reshape(q.shape).astype(q.dtype)
+    return _zero_past(o.reshape(q.shape).astype(q.dtype), live)
 
 
 def _diff_step(refs, qi, kb, masked: bool, *, sched: FlashSchedule, scale: float,
@@ -546,7 +635,7 @@ def _diff_store(refs, group: int, eps: float):
 
 
 def diff_pallas(q, k, v, lam, gain, window: int, pairs: int, kv_pairs: int,
-                eps: float, interpret: bool = False):
+                eps: float, interpret: bool = False, live=None):
     """The kernel form of ``diff_xla`` (same arguments and result): a pair
     is a block of 128 lanes of the projections' own layout, read in place,
     so another layer's ``k`` and ``v`` cost no copy. A window's blocks are
@@ -566,9 +655,9 @@ def diff_pallas(q, k, v, lam, gain, window: int, pairs: int, kv_pairs: int,
     return sched.call(
         sched.kernel(step, functools.partial(_diff_store, group=group, eps=eps),
                      mask_all=bool(window)),
-        (q, k, v, rows),
+        _bound(live, q), (q, k, v, rows),
         [sched.query_spec(group * PAIR), sched.key_spec(PAIR), sched.key_spec(PAIR),
-         pl.BlockSpec((8, PAIR), lambda b, h, qi, j: (0, 0))],
+         pl.BlockSpec((8, PAIR), lambda b, h, qi, j, live: (0, 0))],
         jax.ShapeDtypeStruct(q.shape, q.dtype), out_width=group * PAIR,
         softmaxes=2 * group, value_width=PAIR,
         name="attn_window_diff" if window else "attn_full_diff",
@@ -580,7 +669,8 @@ def diff_pallas(q, k, v, lam, gain, window: int, pairs: int, kv_pairs: int,
         interpret=interpret)
 
 
-_diff_pair = paired.pair(diff_pallas, diff_xla, static=4)
+_diff_pair = paired.pair(_bound_last(diff_pallas, 5), _bound_last(diff_xla, 5),
+                         static=4, nondiff=(5,))
 
 
 def _diff_pallas_applies(q, window: int, pairs: int) -> bool:
@@ -595,12 +685,14 @@ def _diff_pallas_applies(q, window: int, pairs: int) -> bool:
 
 
 def diff_attention(q, k, v, lam, gain, window: int, pairs: int, kv_pairs: int,
-                   eps: float):
-    """Causal differential attention over flat pairs: the kernel where it
-    applies, the plain form elsewhere."""
+                   eps: float, live=None):
+    """Causal differential attention over flat pairs, zero at and past each
+    row's ``live`` (None: no bound): the kernel where it applies, the plain
+    form elsewhere."""
     if _diff_pallas_applies(q, window, pairs):
-        return _diff_pair(q, k, v, lam, gain, window, pairs, kv_pairs, eps)
-    return diff_xla(q, k, v, lam, gain, window, pairs, kv_pairs, eps)
+        return _diff_pair(q, k, v, lam, gain, _bound(live, q), window, pairs, kv_pairs,
+                          eps)
+    return diff_xla(q, k, v, lam, gain, window, pairs, kv_pairs, eps, live)
 
 
 # ---------------------------------------------------------------------------
@@ -622,12 +714,13 @@ def diff_attention(q, k, v, lam, gain, window: int, pairs: int, kv_pairs: int,
 # is one contraction over the query's own width, ``dq = 256``, with no pad
 # lane in it.
 
-def mla_xla(q, kv, kr, heads: int, dn: int, bits=None):
+def mla_xla(q, kv, kr, heads: int, dn: int, bits=None, live=None):
     """-> ``[B, T, H * dv]``; causal, a block of queries against all keys:
     ``s = q_nope . k_nope + q_rope . k_r``, the second product against the
     one shared key, never repeated to the heads (on the kernel's layout,
     ``q[:dn] . k + q[dq - r:] . kr``). With ``bits`` (a selection,
-    ``pack_selection``'s) a query sees the keys they select."""
+    ``pack_selection``'s) a query sees the keys they select. A position at or
+    past its row's ``live`` is zero."""
     import jax
     import jax.numpy as jnp
 
@@ -661,7 +754,8 @@ def mla_xla(q, kv, kr, heads: int, dn: int, bits=None):
         bits = jnp.pad(bits, ((0, 0), (0, pad), (0, 0)))
         blocks += (jnp.moveaxis(bits.reshape(B, -1, blk, bits.shape[-1]), 1, 0),)
     o = jax.lax.map(block, blocks)
-    return jnp.moveaxis(o, 0, 1).reshape(B, T + pad, -1)[:, :T].astype(kv.dtype)
+    return _zero_past(jnp.moveaxis(o, 0, 1).reshape(B, T + pad, -1)[:, :T].astype(kv.dtype),
+                      live)
 
 
 MLA_HEADS_A_STEP = 4      # heads that share one fetch of the rotary key
@@ -705,12 +799,13 @@ def _mla_step(refs, qi, kb, masked: bool, *, sched: FlashSchedule, group: int,
         _softmax_step(s, seen, kv_ref[:, at + dn:at + dn + dv], *refs[-3:], g)
 
 
-def _mla_call(q, kv, kr, bits, heads: int, dn: int, interpret: bool):
+def _mla_call(q, kv, kr, bits, heads: int, dn: int, interpret: bool, live):
     """``attn_mla`` (``bits`` None: the diagonal block masked, causal) or
     ``attn_mla_sparse`` (every block masked by the selection's tile of words
     beside it): ``dq``, ``dv`` and ``kr``'s width whole tiles of 128 lanes,
     ``dn`` the lanes before the rotary key's (``dq - r``) or ``dq``, query and
-    key blocks alike, the result stored at the diagonal's step."""
+    key blocks alike, the result stored at the diagonal's step, the schedule
+    stopped at ``live`` (None: every row whole)."""
     import jax
     from jax.experimental import pallas as pl
 
@@ -728,14 +823,17 @@ def _mla_call(q, kv, kr, bits, heads: int, dn: int, interpret: bool):
     moved = (q.size * q.dtype.itemsize * (1 + dv / dq)
              + kv.size * kv.dtype.itemsize * (T // sched.bq + 1) / 2)
     if sparse:
-        specs.append(pl.BlockSpec((None, sched.bq, 128), lambda b, h, qi, j: (
-            b, qi, sched.fetched(qi, j) * sched.bk // SELECT_GROUP)))
+        def words(b, h, qi, j, live):     # the query block's tile beside the key block
+            at, step = sched.at(qi, j, live[b])
+            return b, at, sched.fetched(at, step) * sched.bk // SELECT_GROUP
+
+        specs.append(pl.BlockSpec((None, sched.bq, 128), words))
         operands += (bits,)
         moved += bits.size * 4 * heads // group
     return sched.call(
         sched.kernel(step, functools.partial(_divide, group=group, width=dv),
                      mask_all=sparse, store_at_diagonal=True),
-        operands, specs, jax.ShapeDtypeStruct((B, T, heads * dv), q.dtype),
+        _bound(live, q), operands, specs, jax.ShapeDtypeStruct((B, T, heads * dv), q.dtype),
         out_width=group * dv, softmaxes=group, value_width=dv,
         name="attn_mla_sparse" if sparse else "attn_mla",
         cost=pl.CostEstimate(flops=int(2 * B * heads * (dq + dv) * pairs),
@@ -744,12 +842,13 @@ def _mla_call(q, kv, kr, bits, heads: int, dn: int, interpret: bool):
         interpret=interpret)
 
 
-def mla_pallas(q, kv, kr, heads: int, dn: int, interpret: bool = False):
+def mla_pallas(q, kv, kr, heads: int, dn: int, interpret: bool = False, live=None):
     """The kernel form of ``mla_xla`` (same arguments and result)."""
-    return _mla_call(q, kv, kr, None, heads, dn, interpret)
+    return _mla_call(q, kv, kr, None, heads, dn, interpret, live)
 
 
-_mla_pair = paired.pair(mla_pallas, mla_xla, static=2)
+_mla_pair = paired.pair(_bound_last(mla_pallas, 3), _bound_last(mla_xla, 3), static=2,
+                        nondiff=(3,))
 
 
 def _mla_pallas_applies(x, v_dim: int) -> bool:
@@ -984,19 +1083,21 @@ def mla_index_pallas(qi, ki, wi, heads: int, topk: int, interpret: bool = False)
     )(qi, ki, wi)
 
 
-def mla_sparse_xla(q, kv, kr, bits, heads: int, dn: int):
+def mla_sparse_xla(q, kv, kr, bits, heads: int, dn: int, live=None):
     """``mla_xla`` over the keys each query's ``bits`` select."""
-    return mla_xla(q, kv, kr, heads, dn, bits)
+    return mla_xla(q, kv, kr, heads, dn, bits, live)
 
 
-def mla_sparse_pallas(q, kv, kr, bits, heads: int, dn: int, interpret: bool = False):
+def mla_sparse_pallas(q, kv, kr, bits, heads: int, dn: int, interpret: bool = False,
+                      live=None):
     """The kernel form of ``mla_sparse_xla`` (same arguments and result):
     ``mla_pallas``'s grid and blocks, the selection's tile of words beside
     each key block."""
-    return _mla_call(q, kv, kr, bits, heads, dn, interpret)
+    return _mla_call(q, kv, kr, bits, heads, dn, interpret, live)
 
 
-_mla_sparse_pair = paired.pair(mla_sparse_pallas, mla_sparse_xla, static=2, nondiff=(3,))
+_mla_sparse_pair = paired.pair(_bound_last(mla_sparse_pallas, 4),
+                               _bound_last(mla_sparse_xla, 4), static=2, nondiff=(3, 4))
 
 
 def _index_pallas_applies(x, index_dim: int) -> bool:
@@ -1090,7 +1191,7 @@ class GQAttention(Module):
             params["k_norm"] = np.ones((self.head_dim,), dt)
         return params, (t, d)
 
-    def apply(self, params, x, train: bool = False):
+    def apply(self, params, x, train: bool = False, live=None):
         import jax.numpy as jnp
 
         dt = _mm_dtype()
@@ -1114,7 +1215,7 @@ class GQAttention(Module):
             k = heads_of("wk", self.kv_heads, params.get("k_norm"))
             v = project("wv").astype(dt)
         with scope("core"):
-            o = gq_attention(q, k, v, self.window, self.heads, self.kv_heads)
+            o = gq_attention(q, k, v, self.window, self.heads, self.kv_heads, live)
         with scope("proj_out"):
             return jnp.dot(o, jnp.asarray(params["wo"]).astype(dt),
                            preferred_element_type=jnp.float32)
@@ -1225,7 +1326,7 @@ class LatentAttention(Module):
                                         paired.INTERPRET)
             return mla_index_xla(qi, ki, wi, self.index_heads, self.index_topk)
 
-    def apply(self, params, x, train: bool = False):
+    def apply(self, params, x, train: bool = False, live=None):
         import jax
         import jax.numpy as jnp
 
@@ -1289,11 +1390,12 @@ class LatentAttention(Module):
                                      w[..., nope:]], axis=-1).reshape(self.kv_rank, -1)
                 kv = jnp.dot(c_kv.astype(dt), w, preferred_element_type=dt)
         with scope("core"):
-            if bits is None:
-                o = (_mla_pair if kernel else mla_xla)(q, kv, kr, h, keys)
+            if not kernel:
+                o = mla_xla(q, kv, kr, h, keys, bits, live)
+            elif bits is None:
+                o = _mla_pair(q, kv, kr, _bound(live, x), h, keys)
             else:
-                o = (_mla_sparse_pair if kernel else mla_sparse_xla)(
-                    q, kv, kr, bits, h, keys)
+                o = _mla_sparse_pair(q, kv, kr, bits, _bound(live, x), h, keys)
         with scope("proj_out"):
             if self.out_gate:
                 gate = jnp.dot(c_q.astype(dt), columns(nope + rope, None),
@@ -1371,15 +1473,16 @@ class DiffAttention(Module):
             lam = jnp.exp(jnp.sum(vector("lq1") * vector("lk1"))) \
                 - jnp.exp(jnp.sum(vector("lq2") * vector("lk2"))) + self.lambda_init
             o = diff_attention(q, k, v, lam, vector("subln") * (1.0 - self.lambda_init),
-                               self.window, self.heads // 2, self.kv_heads // 2, self.eps)
+                               self.window, self.heads // 2, self.kv_heads // 2, self.eps,
+                               carry.get("live"))
         with scope("proj_out"):
             return affine(o, "wo", "bo")
 
-    def apply(self, params, x, train: bool = False):
-        return self.apply_carry(params, x, {})
+    def apply(self, params, x, train: bool = False, live=None):
+        return self.apply_carry(params, x, {} if live is None else {"live": live})
 
 
-def _by_rows(fn, x, most_tokens: int = 8192, positionwise: bool = False):
+def _by_rows(fn, x, most_tokens: int = 8192, positionwise: bool = False, live=None):
     """``fn`` over ``x [B, T, D]``, rows in equal groups of at most
     ``most_tokens`` tokens one after the other: what a sublayer holds between
     its products (float32 heads before their norm, a wide layer's hidden
@@ -1387,7 +1490,8 @@ def _by_rows(fn, x, most_tokens: int = 8192, positionwise: bool = False):
     ``most_tokens`` goes through alone; where ``fn`` is ``positionwise`` (it
     treats every position by itself: a norm, a SwiGLU) such a row is cut into
     equal pieces of at most ``most_tokens`` positions, which go through as
-    rows do."""
+    rows do. With ``live`` (``[B]``: each row's bound, ``live_lengths``) it
+    is ``fn(rows, their live)``."""
     import jax
 
     B, T, D = x.shape
@@ -1395,10 +1499,12 @@ def _by_rows(fn, x, most_tokens: int = 8192, positionwise: bool = False):
         pieces = equal_pieces(T, most_tokens)
         return _by_rows(fn, x.reshape(B * pieces, T // pieces, D),
                         most_tokens).reshape(B, T, -1)
+    rows = (x,) if live is None else (x, live)
     groups = equal_pieces(B, max(1, most_tokens // T))
     if groups == 1:
-        return fn(x)
-    return jax.lax.map(fn, x.reshape(groups, B // groups, T, D)).reshape(x.shape)
+        return fn(*rows)
+    return jax.lax.map(lambda a: fn(*a), tuple(
+        a.reshape(groups, B // groups, *a.shape[1:]) for a in rows)).reshape(x.shape)
 
 
 class SwiGLU(Module):
@@ -1470,7 +1576,9 @@ class DecoderLayer(Module):
     (``GatedMemoryUnit``, a cross ``DiffAttention``). Such a mixer sees the
     whole batch at once (what it writes must outlive the layer, so no loop
     over rows may hold it) and bounds its own memory (``ssm.over_pieces``).
-    Every other mixer runs under ``_by_rows`` and never sees the carry.
+    Every other mixer runs under ``_by_rows`` and never sees the carry; an
+    attention gets its ``live`` (``CausalLM``'s ``[B]`` bound, which
+    ``_by_rows`` slices with the rows) as ``apply(..., live=...)``.
 
     The residual path:
 
@@ -1511,20 +1619,25 @@ class DecoderLayer(Module):
     def apply_with_load(self, params, x, carry: Optional[Dict[str, Any]] = None):
         part = dict(self.parts)
         mixer = self.mixer
+        # the attention's bound: a state-space mixer reads every position
+        live = (carry or {}).get("live") if mixer == "attn" else None
 
         def apply(name, xc):
             with scope(name):
                 return part[name].apply(params[name], xc)
 
         def normed(name, norm):          # the sublayer behind its norm
-            return lambda xc: part[name].apply(params[name], apply(norm, xc))
+            def run(xc, live=None):
+                bound = {} if live is None else {"live": live}
+                return part[name].apply(params[name], apply(norm, xc), **bound)
+            return run
 
         if "attn_hc" in part:
-            return self._hyper_connected(part, params, x, normed)
+            return self._hyper_connected(part, params, x, normed, live)
 
         def sublayer(name, norm):
-            def run(xc):
-                return xc + normed(name, norm)(xc)
+            def run(xc, live=None):
+                return xc + normed(name, norm)(xc, live)
             return run
 
         # a part's scope is pushed around its `_by_rows`, so the loop's own
@@ -1534,7 +1647,7 @@ class DecoderLayer(Module):
                 h = x + part[mixer].apply_carry(params[mixer],
                                                 apply(mixer + "_norm", x), carry)
             else:
-                h = _by_rows(sublayer(mixer, mixer + "_norm"), x)
+                h = _by_rows(sublayer(mixer, mixer + "_norm"), x, live=live)
         if "mlp" in part:
             with scope("mlp"):
                 return _by_rows(sublayer("mlp", "mlp_norm"), h, positionwise=True), None
@@ -1544,14 +1657,14 @@ class DecoderLayer(Module):
         with scope("moe"):
             return part["moe"].apply_with_load(params["moe"], hn, add_to=h)
 
-    def _hyper_connected(self, part, params, x, normed):
+    def _hyper_connected(self, part, params, x, normed, live):
         T = x.shape[1]
         hc = part["attn_hc"]
         with scope("attn_hc"):
             x_in, coeffs = hc.pre(params["attn_hc"], x)
         # attention needs a row's every key: rows one after the other, whole
         with scope("attn"):
-            y = _by_rows(normed("attn", "attn_norm"), x_in, max(T, 8192))
+            y = _by_rows(normed("attn", "attn_norm"), x_in, max(T, 8192), live=live)
         with scope("attn_hc"):
             x = hc.post(x, y, coeffs)
         with scope("mlp_hc"):
@@ -1588,9 +1701,13 @@ class CausalLM(Module):
     a copy of the embedding, and their sum goes into the final norm.
 
     **The carry between layers.** Beside ``h`` the loop over layers hands on
-    one small mapping, empty at layer 0, which a layer's mixer may write and
-    a later one read (``DecoderLayer``): a scan's output as ``memory``, a
-    layer's keys and values as ``kv``. It lives for one call and is no output.
+    one small mapping, which a layer's mixer may write and a later one read
+    (``DecoderLayer``): a scan's output as ``memory``, a layer's keys and
+    values as ``kv``. At layer 0 it holds ``live``, each row's positions up
+    to its last id that is not ``pad_id`` (``live_lengths``), at which the
+    attention kernels stop: a position past it is a pad no real position
+    reads (the model is causal), and its attention output is zero. It lives
+    for one call and is no output.
 
     ``norm`` chooses the final norm (``"rms"`` or ``"layer"``); with ``tied``
     the head is the embedding table transposed and ``params`` has no ``head``.
@@ -1662,6 +1779,17 @@ class CausalLM(Module):
                 "layer": self.mtp.init(k_layer, (t, self.hidden))[0]}
         return params, (t,)
 
+    def batch_counts(self, ids) -> Dict[str, int]:
+        """What the attention kernels' work depends on in a host batch of ids
+        ``[B, T]``, by the function that bounds them (``live_lengths``):
+        ``attn_query_blocks``, the rows' query blocks of ``LIVE_COUNT_BLOCK``
+        positions, and ``attn_live_blocks``, those that hold a position up to
+        their row's bound (``DNNModel`` keeps them in ``BatchTiming.counts``)."""
+        ids = np.asarray(ids)
+        live = live_lengths(ids.reshape(-1, ids.shape[-1]), self.pad_id)
+        return {"attn_query_blocks": int(live.size * -(-ids.shape[-1] // LIVE_COUNT_BLOCK)),
+                "attn_live_blocks": int(np.sum(-(-live // LIVE_COUNT_BLOCK)))}
+
     def layer_paths(self, prefix: str = "") -> List[str]:
         return [prefix + self.LOAD] + [prefix + self.MTP] * (self.mtp is not None) \
             + [f"{prefix}layer{i}" for i in range(len(self.layers))]
@@ -1695,9 +1823,10 @@ class CausalLM(Module):
         return jax.lax.map(row, (x.reshape(B * pieces, T // pieces, D),
                                  target.reshape(B * pieces, T // pieces))).reshape(B, T)
 
-    def _mtp(self, params, h, ids):
+    def _mtp(self, params, h, ids, live):
         """(``mtp[t]``, ``[B, T]`` float32; the module's expert visits or
-        None) from the last layer's output ``h`` before the final norm."""
+        None) from the last layer's output ``h`` before the final norm, its
+        attention bounded at ``live``."""
         import jax.numpy as jnp
 
         dt, p = _mm_dtype(), params["mtp"]
@@ -1711,7 +1840,7 @@ class CausalLM(Module):
             u = jnp.dot(joined.astype(dt), jnp.asarray(p["eh_proj"]["kernel"]).astype(dt),
                         preferred_element_type=jnp.float32)
         with scope(f"layer{len(self.layers)}"):
-            v, load = self.mtp.apply_with_load(p["layer"], u, {})
+            v, load = self.mtp.apply_with_load(p["layer"], u, {"live": live})
         with scope("logits"):        # the targets: the ids two ahead
             return self._log_probs(params, v, following, (self.mtp_norm, p["norm"])), load
 
@@ -1727,8 +1856,9 @@ class CausalLM(Module):
                          ).astype(jnp.float32)
             if self.streams > 1:
                 h = jnp.tile(h, (1, 1, self.streams))
+            live = live_lengths(ids, self.pad_id)
         loads = []
-        carry: Dict[str, Any] = {}          # what a layer hands a later one
+        carry: Dict[str, Any] = {"live": live}      # what a layer hands a later one
         for i, layer in enumerate(self.layers):
             with scope(f"layer{i}"):
                 h, load = layer.apply_with_load(params[f"layer{i}"], h, carry)
@@ -1740,7 +1870,7 @@ class CausalLM(Module):
             if self.mtp is None:
                 raise KeyError("mtp_logprob: the model has no multi-token-prediction module")
             with scope("mtp"):
-                taps_out[_prefix + self.MTP], load = self._mtp(params, h, ids)
+                taps_out[_prefix + self.MTP], load = self._mtp(params, h, ids, live)
             if load is not None:
                 loads.append(load)
         if taps and taps_out is not None and _prefix + self.LOAD in taps:

@@ -403,13 +403,18 @@ class Batch:
     ``owner``: whatever the producer wants to find again once the batch is
     staged — the fused path's partition record, where one ring carries the
     batches of a call's partitions (core/fusion.py). The ring never reads
-    it."""
+    it.
+
+    ``counts``: what the stages that consume the batch counted in it
+    (``DeviceFn.count``), which ``timed_stage`` keeps in its
+    ``BatchTiming``."""
 
     arrays: Dict[str, np.ndarray]
     mask: np.ndarray          # [B] bool, True = real row
     num_valid: int
     staging: Any = None
     owner: Any = None
+    counts: Optional[Dict[str, int]] = None
 
     @property
     def size(self) -> int:

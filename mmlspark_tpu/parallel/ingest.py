@@ -153,6 +153,10 @@ class BatchTiming:
                    fixed cost the cost model's ``choose_mega_k`` needs —
                    without the tag, an active K>1 makes dispatch look
                    cheap, the tuner proposes K=1, and K oscillates.
+    ``counts``   — what the program's work depends on in the batch, counted
+                   on the host from what was shipped (``Batch.counts``: a
+                   causal scorer's ``attn_query_blocks`` and
+                   ``attn_live_blocks``); empty where nothing counted.
     """
 
     queue_s: float = 0.0
@@ -164,6 +168,7 @@ class BatchTiming:
     rows: int = 0
     padded_rows: int = 0
     mega_k: int = 1
+    counts: Dict[str, int] = dataclasses.field(default_factory=dict)
 
 
 class IngestStats:
@@ -878,7 +883,8 @@ def timed_stage(put: Optional[Callable], item: Any,
     recorded as an ``h2d`` span on every traced request in the batch
     (``batch``: the batch's ordinal in the call, when the caller counts)."""
     timing = BatchTiming(bytes_in=_tree_nbytes(item), rows=_tree_rows(item),
-                         padded_rows=_tree_padded(item))
+                         padded_rows=_tree_padded(item),
+                         counts=dict(getattr(item, "counts", None) or {}))
     # slot-staged batches (SlotPool) carry their lease: the transfer window
     # is recorded for the per-slot overlap metric and the buffer returns to
     # the pool the moment the staged arrays are device-resident

@@ -98,8 +98,10 @@ def ref_gmu(w, u, m):
     return (m * jax.nn.silu(u @ w["w1"])) @ w["w2"]
 
 
-def ref_diff(w, u, i, window, kv=None, fault=None):
-    """-> (the mixer's output, its (k, v)); `kv`: another layer's."""
+def ref_diff(w, u, i, window, kv=None, fault=None, live=None):
+    """-> (the mixer's output, its (k, v)); `kv`: another layer's. A position
+    at or past `live` (the row's last real token's, plus one) attends to
+    nothing: its heads' output is zero."""
     t = len(u)
     q = (u @ w["wq"] + w["bq"]).reshape(t, HEADS // 2, 2, HD)
     if kv is None:
@@ -121,7 +123,10 @@ def ref_diff(w, u, i, window, kv=None, fault=None):
         o = p1 @ v[:, g] - lam * (p2 @ v[:, g])
         out.append(o / jnp.sqrt((o * o).mean(-1, keepdims=True) + EPS)
                    * w["subln"] * (1 - init))
-    return jnp.concatenate(out, axis=-1) @ w["wo"] + w["bo"], kv
+    heads = jnp.concatenate(out, axis=-1)
+    if live is not None:
+        heads = jnp.where(np.arange(t)[:, None] < live, heads, 0.0)
+    return heads @ w["wo"] + w["bo"], kv
 
 
 def ref_swiglu(w, x):
@@ -143,9 +148,10 @@ def ref_layer(w, x, i, carry, fault=None):
         kv = carry["kv"]
         if fault == "cross_own_keys":       # layer 17's weights on its own input
             kv = jnp.split(u @ carry["wkv"]["wkv"] + carry["wkv"]["bkv"], 2, axis=-1)
-        y, _ = ref_diff(w["attn"], u, i, 0, kv, fault)
+        y, _ = ref_diff(w["attn"], u, i, 0, kv, fault, carry.get("live"))
     else:
-        y, kv = ref_diff(w["attn"], u, i, WINDOW if kind == "window" else 0, None, fault)
+        y, kv = ref_diff(w["attn"], u, i, WINDOW if kind == "window" else 0, None, fault,
+                         carry.get("live"))
         if kind == "full":
             carry["kv"], carry["wkv"] = kv, w["attn"]
     h = x + y
@@ -155,7 +161,10 @@ def ref_layer(w, x, i, carry, fault=None):
 def ref_model(params, ids, fault=None):
     out = []
     for row in ids:
-        x, carry = params["embed"]["table"][row], {}
+        # the row's positions up to its last real (non-pad) id: past them the
+        # attention layers attend to nothing
+        live = int(np.flatnonzero(row != 0).max(initial=-1)) + 1
+        x, carry = params["embed"]["table"][row], {"live": live}
         for i in range(L):
             x = ref_layer(params[f"layer{i}"], x, i, carry, fault)
         logp = jax.nn.log_softmax(ref_norm(params["final_norm"], x)
@@ -405,7 +414,9 @@ def test_the_differential_kernels_gradient_is_the_plain_forms(interpreted):
     def loss(attend):
         return lambda *a: jnp.sum(attend(*a, 0, 2, 1, EPS).astype(jnp.float32) * tilt)
 
-    got = jax.grad(loss(transformer._diff_pair), range(5))(q, k, v, lam, gain)
+    whole = jnp.full((q.shape[0],), q.shape[1], jnp.int32)   # every row to its end
+    got = jax.grad(loss(lambda *a: transformer._diff_pair(*a[:5], whole, *a[5:])),
+                   range(5))(q, k, v, lam, gain)
     want = jax.grad(loss(transformer.diff_xla), range(5))(q, k, v, lam, gain)
     for a, b in zip(got, want):
         assert float(jnp.abs(a - b).max()) <= 1e-5 * float(jnp.abs(b).max())

@@ -360,7 +360,9 @@ def test_the_kernels_gradients_are_their_plain_forms(interpreted):
     def loss(attend):
         return lambda q, kv, kr: jnp.sum(attend(q, kv, kr, 2, 128).astype(jnp.float32) * tilt)
 
-    got = jax.grad(loss(transformer._mla_pair), (0, 1, 2))(q, kv, kr)
+    whole = jnp.full((1,), q.shape[1], jnp.int32)         # every row to its end
+    got = jax.grad(loss(lambda q, kv, kr, *c: transformer._mla_pair(q, kv, kr, whole, *c)),
+                   (0, 1, 2))(q, kv, kr)
     want = jax.grad(loss(transformer.mla_xla), (0, 1, 2))(q, kv, kr)
     for a, b in zip(got, want):
         assert float(jnp.abs(a - b).max()) <= 1e-5 * float(jnp.abs(b).max())

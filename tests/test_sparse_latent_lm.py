@@ -404,7 +404,9 @@ def test_the_sparse_core_kernels_gradient_is_the_plain_forms(interpreted):
     def loss(fn):
         return lambda q, kv, kr: jnp.sum(fn(q, kv, kr, bits, H, 128) ** 2)
 
-    got = jax.grad(loss(transformer._mla_sparse_pair), (0, 1, 2))(q, kv, kr)
+    whole = jnp.full((B,), t, jnp.int32)                  # every row to its end
+    got = jax.grad(loss(lambda q, kv, kr, bits, *c: transformer._mla_sparse_pair(
+        q, kv, kr, bits, whole, *c)), (0, 1, 2))(q, kv, kr)
     want = jax.grad(loss(transformer.mla_sparse_xla), (0, 1, 2))(q, kv, kr)
     for a, b in zip(got, want):       # the forward passes differ in rounding alone
         assert relative(a, b) < 1e-4
