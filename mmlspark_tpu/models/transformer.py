@@ -1689,12 +1689,184 @@ class DecoderLayer(Module):
         return self.apply_with_load(params, x)[0]
 
 
+# ---------------------------------------------------------------------------
+# the language model's head: each position's log-probability of its target
+# ---------------------------------------------------------------------------
+#
+# ``x [B, T, hidden]`` (normed rows in the matmul dtype), the head ``[hidden,
+# vocab]`` or a tied table ``[vocab, hidden]`` contracted in place, targets
+# ``[B, T]`` int32 -> ``logits[target] - logsumexp(logits)`` a position,
+# float32; the logits are products in the operands' dtype accumulated in
+# float32.
+
+HEAD_VMEM = 48 * 2 ** 20       # what a step of `head_logprob` may hold
+HEAD_ROWS = 512                # positions a tile: the head is read once a tile
+HEAD_LOGITS = 2 ** 20          # float32 logits a step may hold (half from a tied table)
+
+
+def _head_tiles(hidden: int, vocab: int, tied: bool = False) -> Optional[Tuple[int, int]]:
+    """``(tm, tn)`` of ``head_logprob``: ``HEAD_ROWS`` positions, and a
+    vocabulary tile of whole 128-lane tiles whose step fits ``HEAD_VMEM`` (two
+    buffers of the rows' and the head's bfloat16 tiles, three float32 ``[tm,
+    tn]`` temporaries: the product, its exponentials, the target's pick) and
+    holds at most ``HEAD_LOGITS`` logits (a tied table's block is contracted
+    on its minor dimension; at ``2 ** 20`` such a step ran 1.4 times as long
+    on a v5e). Of those, the widest that divides the vocabulary if one is at
+    least half the widest: a last tile that is partial in the head's minor
+    dimension ran its step several times as long on a v5e. None where no
+    tile fits."""
+    tm = HEAD_ROWS
+    most = HEAD_LOGITS // (2 if tied else 1) // tm
+    fits = [tn for tn in range(128, most + 1, 128)
+            if 4 * hidden * (tm + tn) + 12 * tm * tn <= HEAD_VMEM]
+    if not fits:
+        return None
+    whole = [tn for tn in fits if vocab % tn == 0 and 2 * tn >= fits[-1]]
+    return tm, (whole or fits)[-1]
+
+
+def _head_step(x_ref, w_ref, t_ref, m_scr, l_scr, p_scr, j, *, tn: int, tied: bool,
+               valid: Optional[int]):
+    """Vocabulary tile ``j`` of a position tile: its block of logits, float32,
+    folded into the rows' running maximum and sum of exponentials; the
+    target's logit added where it falls in the tile. Columns from ``valid``
+    on (a last tile past the vocabulary) are ``-inf``."""
+    import jax
+    import jax.numpy as jnp
+
+    s = jax.lax.dot_general(x_ref[...], w_ref[...],
+                            (((1,), (1 if tied else 0,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    p_scr[...] += jnp.sum(jnp.where(col == t_ref[...] - j * tn, s, 0.0),
+                          axis=1, keepdims=True)
+    if valid is not None:
+        s = jnp.where(col < valid, s, -jnp.inf)
+    m_prev = m_scr[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    l_scr[...] = jnp.exp(m_prev - m_new) * l_scr[...] + jnp.sum(
+        jnp.exp(s - m_new), axis=1, keepdims=True)
+    m_scr[...] = m_new
+
+
+def head_logprob_pallas(x, head, target, tied: bool, interpret: bool = False):
+    """The kernel form of ``head_logprob_xla`` (same arguments and result):
+    the ``N = B T`` positions in tiles, ``parallel``, the vocabulary in tiles,
+    ``arbitrary``; a step's ``[tm, tn]`` logits never leave VMEM, and the
+    head is read once a position tile. A last tile of positions or of the
+    vocabulary may be partial: its rows past ``N`` are never stored, its
+    columns past ``vocab`` are masked, and the head is never padded."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    B, T, hidden = x.shape
+    N = B * T
+    vocab = head.shape[0 if tied else 1]
+    tm, tn = _head_tiles(hidden, vocab, tied)
+    tm, tn = min(tm, N), min(tn, vocab)       # a whole dimension is a legal block
+    nm, nv = -(-N // tm), -(-vocab // tn)
+    step = functools.partial(_head_step, tn=tn, tied=tied)
+
+    def kernel(x_ref, w_ref, t_ref, o_ref, m_scr, l_scr, p_scr):
+        j = pl.program_id(1)
+        args = (x_ref, w_ref, t_ref, m_scr, l_scr, p_scr, j)
+
+        @pl.when(j == 0)
+        def _init():
+            m_scr[...] = jnp.full_like(m_scr, _NEG)
+            l_scr[...] = jnp.zeros_like(l_scr)
+            p_scr[...] = jnp.zeros_like(p_scr)
+
+        if vocab % tn:       # only the last tile has columns past the vocabulary
+            pl.when(j < nv - 1)(lambda: step(*args, valid=None))
+            pl.when(j == nv - 1)(lambda: step(*args, valid=vocab - (nv - 1) * tn))
+        else:
+            step(*args, valid=None)
+
+        @pl.when(j == nv - 1)
+        def _store():
+            o_ref[...] = p_scr[...] - (m_scr[...] + jnp.log(l_scr[...]))
+
+    head_spec = (pl.BlockSpec((tn, hidden), lambda i, j: (j, 0)) if tied
+                 else pl.BlockSpec((hidden, tn), lambda i, j: (0, j)))
+    out = pl.pallas_call(
+        kernel,
+        grid=(nm, nv),
+        in_specs=[pl.BlockSpec((tm, hidden), lambda i, j: (i, 0)), head_spec,
+                  pl.BlockSpec((tm, 1), lambda i, j: (i, 0))],
+        out_specs=pl.BlockSpec((tm, 1), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((N, 1), f32),
+        scratch_shapes=[pltpu.VMEM((tm, 1), f32)] * 3,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        name="head_logprob",         # the name a device trace shows
+        cost_estimate=pl.CostEstimate(
+            flops=int(2 * N * hidden * vocab), transcendentals=int(N * vocab),
+            bytes_accessed=int(x.size * x.dtype.itemsize
+                               + nm * head.size * head.dtype.itemsize + 8 * N)),
+        interpret=interpret,
+    )(x.reshape(N, hidden), head, target.astype(jnp.int32).reshape(N, 1))
+    return out.reshape(B, T)
+
+
+def head_logprob_xla(x, head, target, tied: bool):
+    """The plain form: a row in equal pieces whose float32 logits are at
+    most ``LOGITS_BLOCK`` numbers, one piece at a time; the target's logit
+    picked from them and their ``logsumexp`` taken. It is also the kernel's
+    VJP (recomputed)."""
+    import jax
+    import jax.numpy as jnp
+
+    B, T, hidden = x.shape
+    vocab = head.shape[0 if tied else 1]
+    over = (((1,), (1 if tied else 0,)), ((), ()))
+
+    def piece(a):
+        xr, tr = a
+        logits = jax.lax.dot_general(xr, head, over, preferred_element_type=jnp.float32)
+        picked = jnp.take_along_axis(logits, tr[:, None], axis=-1)[:, 0]
+        return picked - jax.nn.logsumexp(logits, axis=-1)
+
+    pieces = equal_pieces(T, max(1, LOGITS_BLOCK // vocab))
+    return jax.lax.map(piece, (x.reshape(B * pieces, T // pieces, hidden),
+                               target.reshape(B * pieces, T // pieces))).reshape(B, T)
+
+
+_head_pair = paired.pair(head_logprob_pallas, head_logprob_xla, static=1, nondiff=(2,))
+
+
+def _head_kernel_applies(backend: str, dtype, hidden: int, vocab: int) -> bool:
+    """Whether the head takes ``head_logprob_pallas``: a TPU, bfloat16
+    operands, a hidden width of whole 128-lane tiles, and tiles whose step
+    fits VMEM (``_head_tiles``)."""
+    import jax.numpy as jnp
+
+    return (backend == "tpu" and dtype == jnp.bfloat16 and hidden % 128 == 0
+            and _head_tiles(hidden, vocab) is not None)
+
+
+def head_logprob(x, head, target, tied: bool):
+    """Each position's ``log_softmax(x head)[target]``, float32: the kernel
+    where it applies, the plain form elsewhere."""
+    import jax
+
+    vocab = head.shape[0 if tied else 1]
+    if _head_kernel_applies(jax.default_backend(), x.dtype, x.shape[2], vocab):
+        return _head_pair(x, head, target, tied)
+    return head_logprob_xla(x, head, target, tied)
+
+
 class CausalLM(Module):
     """Token ids ``[B, T]`` -> the next token's log-probability at every
     position, ``[B, T]`` float32: ``out[t] = log_softmax(logits_t)[id_{t+1}]``,
-    the last position's target the pad id. The logits exist a block of
-    positions at a time (at most ``LOGITS_BLOCK`` numbers: a whole row where
-    the vocabulary lets it), on the device, and are never an output.
+    the last position's target the pad id. The head is ``head_logprob``: on
+    a TPU one kernel whose float32 logits never leave VMEM, elsewhere blocks
+    of positions at a time (at most ``LOGITS_BLOCK`` numbers); the logits are
+    never an output.
 
     With ``streams`` > 1 (layers with a hyper-connected residual path) the
     state between layers is ``[B, T, streams hidden]``: every stream starts as
@@ -1720,10 +1892,10 @@ class CausalLM(Module):
     the model's own, the last two positions' targets (and position ``T -
     1``'s ``id_{t+1}``) the pad id. It runs only where the node
     ``mtp_logprob`` (``[B, T]`` float32) is fetched; its logits go through
-    the head's loop over blocks of positions as the main ones do. Scopes
+    ``head_logprob`` as the main ones do. Scopes
     ``mtp``: ``fuse`` (the embedding of the next ids, the two norms, ``W_eh``),
     ``layer<L>`` (its decoder layer, named as the layer after the ``L`` held
-    ones), ``logits`` (its norm and the head's loop).
+    ones), ``logits`` (its norm and the head).
 
     A container for ``DNNModel``'s ``fetchDict``: the node ``expert_load``
     is ``[B, sparse layers, experts held]``, the visits each held expert took
@@ -1797,7 +1969,6 @@ class CausalLM(Module):
     def _log_probs(self, params, x, ids, norm=None):
         """The log-probability of each position's next id (``norm``: the
         ``(module, params)`` before the head; the final norm without it)."""
-        import jax
         import jax.numpy as jnp
 
         norm, gain = norm or (self.final_norm, params["final_norm"])
@@ -1805,23 +1976,9 @@ class CausalLM(Module):
         # [hidden, vocab], or the tied table [vocab, hidden] contracted in place
         head = jnp.asarray(params["embed"]["table"] if self.tied
                            else params["head"]["kernel"]).astype(dt)
-        over = (((1,), (1 if self.tied else 0,)), ((), ()))
         target = jnp.concatenate(
             [ids[:, 1:], jnp.full_like(ids[:, :1], self.pad_id)], axis=1)
-
-        def row(a):
-            xr, tr = a
-            xn = norm.apply(gain, xr).astype(dt)
-            logits = jax.lax.dot_general(xn, head, over,
-                                         preferred_element_type=jnp.float32)
-            picked = jnp.take_along_axis(logits, tr[:, None], axis=-1)[:, 0]
-            return picked - jax.nn.logsumexp(logits, axis=-1)
-
-        # a row in equal pieces whose logits are at most LOGITS_BLOCK numbers
-        B, T, D = x.shape
-        pieces = equal_pieces(T, max(1, LOGITS_BLOCK // self.vocab_size))
-        return jax.lax.map(row, (x.reshape(B * pieces, T // pieces, D),
-                                 target.reshape(B * pieces, T // pieces))).reshape(B, T)
+        return head_logprob(norm.apply(gain, x).astype(dt), head, target, self.tied)
 
     def _mtp(self, params, h, ids, live):
         """(``mtp[t]``, ``[B, T]`` float32; the module's expert visits or
@@ -1877,7 +2034,7 @@ class CausalLM(Module):
             if not loads:
                 raise KeyError("expert_load: the model has no expert layer")
             taps_out[_prefix + self.LOAD] = jnp.stack(loads, axis=1)
-        with scope("head"):          # the final norm and the loop over the logits
+        with scope("head"):          # the final norm and head_logprob
             if self.streams > 1:
                 h = h.reshape(*h.shape[:2], self.streams, self.hidden).sum(axis=2)
             return self._log_probs(params, h, ids)

@@ -860,3 +860,29 @@ def test_the_sparse_latent_kernel_compiles_at_the_published_widths(one_chip):
     text = _compiled_text(lambda q, kv, kr, b: transformer.mla_sparse_pallas(
         q, kv, kr, b, H, 128), q, q, kr, bits)
     assert "tpu_custom_call" in text and "attn_mla_sparse" in text
+
+
+HEAD_SHAPES = {    # rows, positions, hidden, vocabulary, tied: the five scorer cells' heads
+    "kexaone.score": (8, 4096, 6144, 19200, False),
+    "xing4.score": (1, 16384, 3584, 131072, False),
+    "phi4flash.score": (1, 32768, 2560, 200064, True),
+    "axk2.score": (1, 32768, 7168, 20480, False),
+    "glm47flash.score": (1, 16384, 2048, 154880, False),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(HEAD_SHAPES))
+def test_the_head_kernel_compiles_at_the_scorer_cells_heads(shape, one_chip):
+    """The head as one kernel: a batch's positions against the whole
+    vocabulary (the tied table contracted in place), its tiles within VMEM;
+    a position's result one float32."""
+    B, t, d, vocab, tied = HEAD_SHAPES[shape]
+    x = jax.ShapeDtypeStruct((B, t, d), jnp.bfloat16, sharding=one_chip)
+    head = jax.ShapeDtypeStruct((vocab, d) if tied else (d, vocab), jnp.bfloat16,
+                                sharding=one_chip)
+    target = jax.ShapeDtypeStruct((B, t), jnp.int32, sharding=one_chip)
+    assert transformer._head_kernel_applies("tpu", jnp.bfloat16, d, vocab)
+    text = _compiled_text(lambda *a: transformer.head_logprob_pallas(*a, tied),
+                          x, head, target)
+    assert "tpu_custom_call" in text and "head_logprob" in text
+    assert f"f32[{B * t},1]" in text
